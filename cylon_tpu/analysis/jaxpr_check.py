@@ -65,7 +65,7 @@ def _norm(prim_name: str) -> str:
 
 def _sub_jaxprs(eqn):
     """Yield every (sub)jaxpr referenced by an eqn's params."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     for val in eqn.params.values():
         if isinstance(val, (ClosedJaxpr, Jaxpr)):
             yield val
@@ -78,7 +78,7 @@ def _sub_jaxprs(eqn):
 def iter_eqns(jaxpr, ctx=()):
     """Depth-first walk yielding ``(eqn, ctx)`` where ``ctx`` is the tuple
     of enclosing control-primitive names (outermost first)."""
-    from jax.core import ClosedJaxpr
+    from jax.extend.core import ClosedJaxpr
     if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:

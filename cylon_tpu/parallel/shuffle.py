@@ -188,7 +188,11 @@ def _skew_split_targets_fn(mesh: Mesh, w: int, k: int, nkeys: int,
         # counts — a single heavy key can exceed int32 at target scale
         eqi = eq.astype(jnp.int64)
         loc = jnp.cumsum(eqi, axis=0) - eqi          # within-shard index
-        loc_k = jnp.take_along_axis(loc, kidx[:, None], axis=1)[:, 0]
+        # one-hot select, not take_along_axis: jax 0.9 widens that
+        # call's (cap, 1) int32 index to int64 under x64 (JX203)
+        pick = jnp.arange(eq.shape[1], dtype=jnp.int32)[None, :] \
+            == kidx[:, None]
+        loc_k = jnp.sum(jnp.where(pick, loc, 0), axis=1)
         j = srcoff[my, kidx] + loc_k
         # fan arrives born-wide int64 (K,) so the row-scale modulus never
         # widens an int32 lane (JX203)

@@ -424,7 +424,7 @@ class TestAdaptiveSkewSplit:
         n = 8000
         lt, rt = self._skewed_pair(env8, rng, n=n, frac=0.7)
         # make the BUILD side heavy on the same key too
-        rk = np.asarray(rt.to_pandas()["k"], np.int64)
+        rk = np.array(rt.to_pandas()["k"], np.int64)  # pandas 3: read-only view
         rk[: len(rk) // 2] = 700
         rt2 = ct.Table.from_pydict(
             {"k": rk,
