@@ -1,0 +1,429 @@
+"""chip_smoke.py — the join -> groupby main path on the chip, checked.
+
+The quickest proof that the system still starts on a TPU.  ONE process
+(a parent that has touched JAX holds the chip), started from a shell
+that has not touched JAX:
+
+    python chip_smoke.py [--rows N] [--seed S]      # one chip
+    python chip_smoke.py --chips 4 [--rows N]       # one host, four chips
+
+One chip (the default, what the driver runs): bench.py's workload — two
+tables of two int64 columns, keys uniform in ``[0, 0.9 n)`` — at 32M rows
+per side (the resident in-HBM regime), through the public entry points:
+
+* resident phase: ``join_tables(how="inner")`` ->
+  ``groupby_aggregate(sum a, sum b)``, pulled to the host and compared
+  EXACTLY with ``pandas.merge(...).groupby(...).sum()``;
+* pipelined phase: the same device tables through
+  ``exec.pipelined_join(n_chunks=4, sink=GroupBySink)``, bit-equal with
+  the resident result; the input tables must still be readable after it
+  (buffer donation is real on the chip).
+
+It fails if anything degraded on the way: a recovery event, a taken pad-
+ladder rung, a spill or checkpoint event, a compile in a warm call, or a
+resident grouped reduce that was eligible for the windowed Pallas gather
+and did not go through it.
+
+``--chips 4`` runs the distributed ``join_tables`` + ``groupby_aggregate``
+over ``TPUConfig(world_size=4)`` at 2^23 rows per chip per side against
+the same pandas reference, and no other phase.
+
+The last line of standard output is one JSON object,
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
+No accelerator: non-zero exit and no result line.  A phase that raises
+ends the run with its traceback.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+#: no default below 2^24 rows per side; the default is the resident regime
+MIN_ROWS = 1 << 24
+DEFAULT_ROWS = 32_000_000
+DEFAULT_ROWS_PER_CHIP_4 = 1 << 23
+N_CHUNKS = 4
+AGGS = [("a", "sum"), ("b", "sum")]
+
+
+def say(msg: str) -> None:
+    print(f"[chip_smoke] {msg}", flush=True)
+
+
+# ---------------------------------------------------------------------------
+# data + reference
+# ---------------------------------------------------------------------------
+
+def make_inputs(rows: int, seed: int, unique: float = 0.9) -> dict:
+    """bench.py's workload: keys uniform in [0, unique * rows)."""
+    import numpy as np
+    max_val = max(int(rows * unique), 1)
+    rng = np.random.default_rng(seed)
+    return {
+        "lk": rng.integers(0, max_val, rows).astype(np.int64),
+        "a": rng.integers(0, max_val, rows).astype(np.int64),
+        "rk": rng.integers(0, max_val, rows).astype(np.int64),
+        "b": rng.integers(0, max_val, rows).astype(np.int64),
+    }
+
+
+def reference(inp: dict):
+    """The plain reference: pandas merge -> groupby -> sum, sorted by key.
+    Returns (frame[k, a_sum, b_sum], joined row count)."""
+    import pandas as pd
+    left = pd.DataFrame({"k": inp["lk"], "a": inp["a"]})
+    right = pd.DataFrame({"k": inp["rk"], "b": inp["b"]})
+    j = pd.merge(left, right, on="k", how="inner")
+    g = j.groupby("k", sort=True)[["a", "b"]].sum().reset_index()
+    g.columns = ["k", "a_sum", "b_sum"]
+    return g, len(j)
+
+
+def build_tables(env, inp: dict):
+    import cylon_tpu as ct
+    lt = ct.Table.from_pydict({"k": inp["lk"], "a": inp["a"]}, env)
+    rt = ct.Table.from_pydict({"k": inp["rk"], "b": inp["b"]}, env)
+    return lt, rt
+
+
+def _sorted_frame(table):
+    df = table.to_pandas()
+    return df.sort_values("k", kind="stable").reset_index(drop=True)
+
+
+def assert_equal_exact(got, want, what: str) -> None:
+    """Exact (int64) equality of two k-sorted frames, column by column."""
+    import numpy as np
+    assert list(got.columns) == list(want.columns), \
+        f"{what}: columns {list(got.columns)} != {list(want.columns)}"
+    assert len(got) == len(want), \
+        f"{what}: {len(got)} groups != reference {len(want)}"
+    for c in want.columns:
+        g = np.asarray(got[c])
+        w = np.asarray(want[c])
+        assert g.dtype == np.int64, f"{what}: column {c} is {g.dtype}"
+        if not np.array_equal(g, w):
+            bad = int(np.flatnonzero(g != w)[0])
+            raise AssertionError(
+                f"{what}: column {c} differs from the reference at sorted "
+                f"row {bad}: {g[bad]} != {w[bad]}")
+
+
+# ---------------------------------------------------------------------------
+# what a phase reports, and what counts as degraded
+# ---------------------------------------------------------------------------
+
+def _plan_routes(qplan) -> list:
+    """(op, route, n_chunks/n_ranges) of every plan node, pre-order."""
+    out = []
+
+    def walk(d):
+        attrs = d.get("attrs") or {}
+        if "route" in attrs:
+            out.append({k: attrs[k] for k in
+                        ("route", "n_chunks", "n_ranges", "shape_family")
+                        if k in attrs} | {"op": d.get("op")})
+        for c in d.get("children", ()):
+            walk(c)
+    for root in qplan.to_dict()["roots"]:
+        walk(root)
+    return out
+
+
+def _timed_calls(step):
+    """Cold call, then one warm call; returns (result, cold_s, warm_s,
+    compiles_in_warm_call)."""
+    from cylon_tpu.exec import compiler
+    t0 = time.perf_counter()
+    step()
+    cold = time.perf_counter() - t0
+    before = compiler.stats()["compile_events"]
+    t0 = time.perf_counter()
+    res = step()
+    warm = time.perf_counter() - t0
+    return res, cold, warm, compiler.stats()["compile_events"] - before
+
+
+def _sync(table) -> None:
+    from cylon_tpu.utils.host import sync_pull
+    sync_pull(next(iter(table.columns.values())).data)
+
+
+def gather_variant(env) -> dict:
+    """What the fused join->groupby dispatch settled on for this env:
+    relational/fused._SEG_CACHE holds (segment bucket, windowed allowed,
+    window) per callsite signature (sig[0] is the env serial)."""
+    from cylon_tpu.relational import fused
+    vals = [v for k, v in fused._SEG_CACHE.items()
+            if k[0] == env.serial and isinstance(v, tuple)]
+    assert vals, "the fused join->groupby pushdown did not run"
+    seg, win_allowed, win = vals[-1]
+    return {"segment_space": int(seg), "window": int(win),
+            "windowed_allowed": bool(win_allowed),
+            "variant": f"windowed_pallas(w={win})" if win else "xla_gather"}
+
+
+def windowed_eligible(env, n_groups: int, live_rows: int,
+                      seg: int) -> bool:
+    """relational/fused._win_size's own rule: TPU, measured density at or
+    above the coverage floor, segment space >= 2^20."""
+    from cylon_tpu import config
+    from cylon_tpu.ops import pallas_gather as pg
+    on_tpu = next(iter(env.mesh.devices.flat)).platform == "tpu"
+    dens = n_groups / max(live_rows, 1)
+    return bool(on_tpu and config.WINDOWED_GATHER
+                and dens >= pg.MIN_DENSITY and seg >= (1 << 20))
+
+
+def assert_not_degraded(where: str) -> None:
+    """No recovery event (a taken pad-ladder rung is one), no rung
+    remembered, no spill, no disk page, no checkpoint."""
+    from cylon_tpu.exec import checkpoint, memory, recovery
+    from cylon_tpu.relational import groupby
+    ev = recovery.recovery_events()
+    assert not ev, f"{where}: recovery events {ev}"
+    rungs = {k: v for k, v in groupby._PAD_CACHE.items() if v}
+    assert not rungs, f"{where}: pad-ladder rungs taken {rungs}"
+    mem, ck = memory.stats(), checkpoint.stats()
+    for k in ("spill_events", "disk_events"):
+        assert not mem[k], f"{where}: {k}={mem[k]}"
+    assert not ck["checkpoint_events"], \
+        f"{where}: checkpoint_events={ck['checkpoint_events']}"
+
+
+# ---------------------------------------------------------------------------
+# phases
+# ---------------------------------------------------------------------------
+
+def resident_phase(env, lt, rt, ref, ref_join_rows: int) -> dict:
+    """join_tables -> groupby_aggregate on the resident tables, exact
+    against the pandas reference."""
+    from cylon_tpu import obs
+    from cylon_tpu.relational import groupby_aggregate, join_tables
+
+    def step():
+        j = join_tables(lt, rt, "k", "k", how="inner")
+        g = groupby_aggregate(j, "k", AGGS)
+        _sync(g)
+        return g
+
+    g, cold, warm, warm_compiles = _timed_calls(step)
+    got = _sorted_frame(g)
+    assert_equal_exact(got, ref, "resident phase")
+    assert int(got["a_sum"].sum()) == int(ref["a_sum"].sum())
+
+    # the route, from one more (profiled) call: EXPLAIN ANALYZE's tree
+    qplan = obs.explain_analyze(step, profile_keys=False)
+    routes = _plan_routes(qplan)
+    variant = gather_variant(env)
+    live = lt.row_count + rt.row_count
+    eligible = windowed_eligible(env, len(ref), live,
+                                 variant["segment_space"])
+    info = {"phase": "resident", "rows_in": [lt.row_count, rt.row_count],
+            "join_rows": ref_join_rows, "groups": len(got),
+            "cold_s": cold, "warm_s": warm,
+            "compiles_in_warm_call": warm_compiles, "routes": routes,
+            "gather": variant, "windowed_eligible": eligible}
+    say(json.dumps(info))
+    assert any(r["route"] == "fused_pushdown" for r in routes), \
+        f"resident phase did not take the fused pushdown: {routes}"
+    assert warm_compiles == 0, \
+        f"resident phase compiled {warm_compiles} program(s) in its warm call"
+    if eligible:
+        assert variant["window"] > 0, \
+            f"eligible for the windowed Pallas gather, ran {variant}"
+    assert_not_degraded("resident phase")
+    return {"frame": got, "info": info}
+
+
+def pipelined_phase(env, lt, rt, want, inp: dict) -> dict:
+    """The same device tables through the range pipeline and the groupby
+    sink, bit-equal with the resident result; the inputs stay readable."""
+    import numpy as np
+    from cylon_tpu import obs
+    from cylon_tpu.exec import GroupBySink, pipelined_join
+
+    def step():
+        sink = GroupBySink("k", AGGS)
+        pipelined_join(lt, rt, "k", "k", how="inner", n_chunks=N_CHUNKS,
+                       sink=sink)
+        g = sink.finalize()
+        _sync(g)
+        return g
+
+    g, cold, warm, warm_compiles = _timed_calls(step)
+    got = _sorted_frame(g)
+    assert_equal_exact(got, want, "pipelined phase")
+    qplan = obs.explain_analyze(step, profile_keys=False)
+    routes = _plan_routes(qplan)
+    info = {"phase": "pipelined", "n_chunks": N_CHUNKS,
+            "groups": len(got), "cold_s": cold, "warm_s": warm,
+            "compiles_in_warm_call": warm_compiles, "routes": routes}
+    say(json.dumps(info))
+    assert any(r["route"] == "range_pipeline" for r in routes), \
+        f"pipelined phase did not take the range pipeline: {routes}"
+    assert warm_compiles == 0, \
+        f"pipelined phase compiled {warm_compiles} program(s) in its warm call"
+    # donation consumed scratch, never the caller's tables
+    for t, kname, vname, vcol in ((lt, "lk", "a", "a"), (rt, "rk", "b", "b")):
+        df = t.to_pandas()
+        assert np.array_equal(np.asarray(df["k"]), inp[kname]) \
+            and np.array_equal(np.asarray(df[vcol]), inp[vname]), \
+            "an input table is no longer readable after the pipelined phase"
+    say("input tables still readable after the pipelined phase")
+    assert_not_degraded("pipelined phase")
+    return {"frame": got, "info": info}
+
+
+def distributed_phase(env, lt, rt, ref, rows_per_chip: int) -> dict:
+    """shuffle -> local join -> groupby over the mesh, exact against the
+    pandas reference; rows spread over every device and really moved."""
+    import numpy as np
+    from cylon_tpu import obs
+    from cylon_tpu.obs import comm
+    from cylon_tpu.relational import groupby_aggregate, join_tables
+
+    w = env.world_size
+    for name, t in (("left", lt), ("right", rt)):
+        vc = np.asarray(t.valid_counts, np.int64)
+        assert vc.shape == (w,) and vc.sum() == t.row_count, (name, vc)
+        assert vc.min() >= 0.9 * rows_per_chip, \
+            f"{name} table is not spread over the devices: {vc.tolist()}"
+        col = next(iter(t.columns.values())).data
+        devs = {sh.device for sh in col.addressable_shards}
+        sizes = {sh.data.shape for sh in col.addressable_shards}
+        assert len(devs) == w and len(sizes) == 1, \
+            f"{name} table sits on {len(devs)} device(s), shards {sizes}"
+        say(f"{name}: valid_counts={vc.tolist()} on {len(devs)} devices, "
+            f"shard shape {next(iter(sizes))}")
+
+    exch = obs.counter("exchange_rows_total")
+    exch_before = exch.value
+    comm.arm(True)
+    comm.reset()
+
+    def step():
+        j = join_tables(lt, rt, "k", "k", how="inner")
+        g = groupby_aggregate(j, "k", AGGS)
+        _sync(g)
+        return g
+
+    g, cold, warm, warm_compiles = _timed_calls(step)
+    rep = comm.report()
+    comm.arm(False)
+    got = _sorted_frame(g)
+    assert_equal_exact(got, ref, "distributed phase")
+    moved = exch.value - exch_before
+    m = np.asarray(rep["rows"], np.int64) if rep else np.zeros((w, w))
+    off = float(m.sum() - np.trace(m)) / max(float(m.sum()), 1.0)
+    info = {"phase": "distributed", "world": w,
+            "rows_per_chip": rows_per_chip, "groups": len(got),
+            "cold_s": cold, "warm_s": warm,
+            "compiles_in_warm_call": warm_compiles,
+            "exchange_rows_total": int(moved),
+            "off_diagonal_share": off}
+    say(json.dumps(info))
+    assert moved > 0, "no row went through the exchange"
+    assert rep and 0.5 < off < 0.95, \
+        f"exchange did not cross devices as a uniform hash would: {off}"
+    assert warm_compiles == 0, \
+        f"distributed phase compiled {warm_compiles} program(s) warm"
+    assert_not_degraded("distributed phase")
+    return {"frame": got, "info": info}
+
+
+# ---------------------------------------------------------------------------
+# process set-up
+# ---------------------------------------------------------------------------
+
+def _cache_entries(d: str) -> int:
+    return len(os.listdir(d)) if d and os.path.isdir(d) else 0
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rows", type=int, default=None,
+                    help="rows per side (one chip; default 32M, at least "
+                         "2^24) or per chip per side (--chips 4; default "
+                         "2^23)")
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
+    args = ap.parse_args(argv)
+
+    import jax
+    devs = jax.devices()   # no platform set: jax takes the accelerator
+    say(f"jax {jax.__version__}; devices: {devs}")
+    if devs[0].platform != "tpu":
+        print(f"chip_smoke: no TPU found — jax reports platform "
+              f"{devs[0].platform!r} ({devs[0].device_kind}); this script "
+              "runs on the chip only", file=sys.stderr)
+        return 1
+    if len(devs) < args.chips:
+        print(f"chip_smoke: --chips {args.chips} needs {args.chips} TPU "
+              f"devices, jax reports {len(devs)}", file=sys.stderr)
+        return 1
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": args.chips}
+
+    import cylon_tpu as ct
+    from cylon_tpu import config
+    from cylon_tpu.ctx.context import TPUConfig
+    from cylon_tpu.exec import compiler, memory, recovery
+    from cylon_tpu.native import native_available
+
+    cache_dir = config.jax_cache_dir()
+    say(f"native string hash built: {native_available()}")
+    say(f"jax compilation cache: {cache_dir or 'off'} "
+        f"({_cache_entries(cache_dir)} entries before the run)")
+    compiler.install_listener()
+    recovery.reset_events()
+    memory.reset_stats()
+
+    env = ct.CylonEnv(config=TPUConfig(world_size=args.chips))
+    if args.chips == 1:
+        rows = DEFAULT_ROWS if args.rows is None else args.rows
+        if rows < MIN_ROWS:
+            print(f"chip_smoke: --rows {rows} is below the resident size "
+                  f"this script checks (at least {MIN_ROWS})",
+                  file=sys.stderr)
+            return 1
+        total = rows
+    else:
+        rows = DEFAULT_ROWS_PER_CHIP_4 if args.rows is None else args.rows
+        total = rows * args.chips
+    say(f"chips={args.chips} rows per side={total} seed={args.seed}")
+
+    t0 = time.perf_counter()
+    inp = make_inputs(total, args.seed)
+    ref, join_rows = reference(inp)
+    say(f"pandas reference: {join_rows} joined rows, {len(ref)} groups "
+        f"({time.perf_counter() - t0:.1f} s on the host)")
+    t0 = time.perf_counter()
+    lt, rt = build_tables(env, inp)
+    _sync(lt), _sync(rt)
+    say(f"tables on the device in {time.perf_counter() - t0:.1f} s")
+
+    if args.chips == 1:
+        res = resident_phase(env, lt, rt, ref, join_rows)
+        pipelined_phase(env, lt, rt, res["frame"], inp)
+    else:
+        distributed_phase(env, lt, rt, ref, rows)
+
+    for d in devs[:args.chips]:
+        st = d.memory_stats() or {}
+        say(f"{d}: peak_bytes_in_use={st.get('peak_bytes_in_use')} "
+            f"bytes_limit={st.get('bytes_limit')}")
+    cst = compiler.stats()
+    say(f"compiles: {cst['compile_events']} in {cst['compile_seconds']:.1f} s;"
+        f" cache {cache_dir or 'off'} holds {_cache_entries(cache_dir)} "
+        "entries after the run")
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

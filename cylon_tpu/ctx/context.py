@@ -30,13 +30,7 @@ ROW_AXIS = "cyl_rows"  # the mesh axis tables are row-sharded over
 
 
 def _distributed_initialized() -> bool:
-    """jax < 0.5 compatibility: ``jax.distributed.is_initialized`` landed
-    after 0.4.x; fall back to probing the distributed client state."""
-    try:
-        return jax.distributed.is_initialized()
-    except AttributeError:
-        from jax._src import distributed
-        return getattr(distributed.global_state, "client", None) is not None
+    return jax.distributed.is_initialized()
 
 
 class CommConfig:
@@ -124,6 +118,24 @@ class CPUMeshConfig(TPUConfig):
                     f"--xla_force_host_platform_device_count={self.world_size}")
             devs = devs[: self.world_size]
         return devs
+
+
+def device_config(world_size: int | None = None) -> TPUConfig:
+    """The config of a driver script that takes no device option: the
+    CPU mesh ONLY where the process was pinned to the CPU on purpose
+    (``JAX_PLATFORMS=cpu`` / ``jax_platforms``, as the test rig does);
+    otherwise the TPU, and no TPU is an error — never a silent CPU run
+    under a device metric's name."""
+    from .. import config
+    if config._cpu_only():
+        return CPUMeshConfig(world_size=world_size)
+    d = jax.devices()[0]
+    if d.platform != "tpu":
+        raise InvalidError(
+            f"no TPU found — jax reports platform {d.platform!r} "
+            f"({d.device_kind}); set JAX_PLATFORMS=cpu to run on the CPU "
+            "mesh on purpose")
+    return TPUConfig()
 
 
 _seq = itertools.count()

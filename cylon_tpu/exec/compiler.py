@@ -23,11 +23,10 @@ The facade makes compilation **bounded, persistent and typed-failing**:
   crash point (re-use recompiles, warm from the persistent cache where
   armed).  In multiprocess sessions the eviction count rides the
   existing count-consensus wire so every rank drops the same programs.
-* **persistent layer** (``CYLON_TPU_COMPILE_CACHE_DIR``) — arms jax's
-  on-disk compilation cache under ``<dir>/xla`` (accelerator platforms
-  only: XLA:CPU executable (de)serialization segfaults, see config.py)
-  and keeps three facade-owned files beside it with the checkpoint
-  tier's atomic-write (+ bounded ``retry_io``) discipline: a
+* **persistent layer** (``CYLON_TPU_COMPILE_CACHE_DIR``) — keeps three
+  facade-owned files there with the checkpoint tier's atomic-write
+  (+ bounded ``retry_io``) discipline (jax's own on-disk compilation
+  cache is config.py's business and does not move with this dir): a
   warm **manifest** of successfully compiled signatures (content-hashed
   — a corrupted entry fails its hash and is DROPPED: clean miss →
   recompile, never wrong code), a **quarantine** ledger, and a per-rank
@@ -262,17 +261,6 @@ def _ensure_dir() -> dict | None:
             return _DIR_STATE
         from . import recovery
         recovery.retry_io(lambda: os.makedirs(d, exist_ok=True), SITE)
-        if not config._cpu_only():
-            # the facade dir wins over config.py's fingerprint default;
-            # CPU-only processes stay uncached (XLA:CPU executable
-            # (de)serialization segfaults — config.py's documented stance)
-            try:
-                jax.config.update("jax_compilation_cache_dir",
-                                  os.path.join(d, "xla"))
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 1.0)
-            except Exception:  # noqa: BLE001 — stale jax: journal-only
-                pass
         q = _read_json(os.path.join(d, "quarantine.json")) or {}
         quarantine = set(q.get("signatures", ()))
         man = _read_json(os.path.join(d, "manifest.json")) or {}

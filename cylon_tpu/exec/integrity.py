@@ -295,12 +295,8 @@ def _fingerprint_fn(mesh: Mesh, w: int, n_arrs: int, mask_kind: str):
     # replicated (lax.reduce has no rep rule); the value IS — every
     # shard folds the identical gathered matrix — so disable the check
     # (the jaxpr gate still asserts the program's collective set)
-    import inspect
-    params = inspect.signature(shard_map).parameters
-    norep = {"check_rep": False} if "check_rep" in params else (
-        {"check_vma": False} if "check_vma" in params else {})
     return jit(shard_map(per_shard, mesh=mesh, in_specs=specs,
-                         out_specs=P(), **norep))
+                         out_specs=P(), check_vma=False))
 
 
 def _pull_fp(pair_dev) -> int:

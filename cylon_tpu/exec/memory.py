@@ -138,11 +138,17 @@ def _session_tag() -> str | None:
 
 _BUDGET_CACHE: list = []  # [int] once detected; empty = not yet probed
 
+#: per-chip HBM for devices whose ``memory_stats()`` has no
+#: ``bytes_limit`` (Google Cloud documentation, "TPU v5e": 16 GB).  A
+#: device that is not here is an error, never a default.
+_HBM_BYTES_BY_KIND = {"TPU v5 lite": 16 * 1024**3}
+
 
 def budget_bytes() -> int:
     """The ledger's budget in TOTAL bytes across the mesh: the
     ``CYLON_TPU_HBM_BUDGET`` override when set, else per-chip
-    ``bytes_limit`` × device count on accelerators, else 0 (unlimited —
+    ``bytes_limit`` (or the ``_HBM_BYTES_BY_KIND`` entry) × device count
+    on accelerators — an unknown device kind raises — else 0 (unlimited:
     CPU rigs where host RAM, not HBM, is the ceiling).  Detected lazily
     (the backend must already be initialized) and cached."""
     if config.HBM_BUDGET_BYTES > 0:
@@ -153,16 +159,18 @@ def budget_bytes() -> int:
     total = 0
     try:
         devs = jax.devices()
-        if devs and devs[0].platform != "cpu":
-            per = 0
-            try:
-                per = int((devs[0].memory_stats() or {}).get(
-                    "bytes_limit", 0))
-            except Exception:  # noqa: BLE001 — backend without stats
-                per = 0
-            total = (per or 16 * 1024**3) * len(devs)
     except Exception:  # noqa: BLE001 — no backend yet: stay unlimited
         return 0
+    if devs and devs[0].platform != "cpu":
+        per = int((devs[0].memory_stats() or {}).get("bytes_limit", 0))
+        if not per:
+            per = _HBM_BYTES_BY_KIND.get(devs[0].device_kind, 0)
+        if not per:
+            raise RuntimeError(
+                f"device kind {devs[0].device_kind!r} reports no "
+                "bytes_limit and is not in exec/memory._HBM_BYTES_BY_KIND:"
+                " set CYLON_TPU_HBM_BUDGET or add it to the table")
+        total = per * len(devs)
     _BUDGET_CACHE.append(total)
     return total
 

@@ -67,21 +67,6 @@ def _interleave() -> None:
     scheduler.maybe_yield()
 
 
-def _norep_kwargs() -> dict:
-    """shard_map kwargs disabling replication checking — required when a
-    pallas_call is in the program (no replication rule on jax < 0.5; the
-    vma shim in ops/pallas_probe covers jax >= 0.5, whose flag is named
-    check_vma).  The program stays pure-local; the jaxpr gate still
-    asserts it contains no collective."""
-    import inspect
-    params = inspect.signature(shard_map).parameters
-    if "check_rep" in params:
-        return {"check_rep": False}
-    if "check_vma" in params:
-        return {"check_vma": False}
-    return {}
-
-
 @program_cache()
 def _chunk_fn(mesh: Mesh, cap: int, step: int):
     """Per-shard dynamic slice [start, start+step) of every column."""
@@ -624,7 +609,10 @@ def _probe_targets_fn(mesh: Mesh, n_ranges: int, narrow: tuple,
         return tgt, counts[:n_ranges]
 
     in_specs = (REP, ROW, ROW) + (ROW,) * n_ops
-    sm_kwargs = _norep_kwargs() if use_pallas else {}
+    # a pallas_call in the program: the varying-axes check is off (the
+    # program stays pure-local; the jaxpr gate still asserts it contains
+    # no collective)
+    sm_kwargs = {"check_vma": False} if use_pallas else {}
     jit_kwargs = {"donate_argnums": tuple(range(3, 3 + n_ops))} \
         if donate else {}
     return jit(shard_map(per_shard, mesh=mesh, in_specs=in_specs,

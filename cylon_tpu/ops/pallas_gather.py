@@ -149,15 +149,11 @@ def _pallas_take(mat_t, idx2, ws, window: int, interpret: bool):
         ],
     )
     # under shard_map (check_vma) the output must declare which mesh axes
-    # it varies over — the union of the inputs'.  jax < 0.5 has no vma
-    # concept on ShapeDtypeStruct (check_rep validates differently there).
-    try:
-        vma = frozenset()
-        for a in (ws, idx2, mat_t):
-            vma = vma | getattr(a.aval, "vma", frozenset())
-        out_shape = jax.ShapeDtypeStruct((L, G * tile), jnp.uint32, vma=vma)
-    except TypeError:
-        out_shape = jax.ShapeDtypeStruct((L, G * tile), jnp.uint32)
+    # it varies over — the union of the inputs'
+    vma = frozenset()
+    for a in (ws, idx2, mat_t):
+        vma = vma | getattr(a.aval, "vma", frozenset())
+    out_shape = jax.ShapeDtypeStruct((L, G * tile), jnp.uint32, vma=vma)
     return pl.pallas_call(
         partial(_kernel, window=window, n_lanes=L),
         grid_spec=grid_spec,

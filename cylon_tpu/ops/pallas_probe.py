@@ -16,7 +16,7 @@ R-1 <= a few dozen scalars per operand), accumulating the ge-count
 in-register: no comparison matrix ever touches HBM, and the row operands
 are read exactly once.  Same structure as :mod:`cylon_tpu.ops.
 pallas_gather` (the proven MXU-kernel route in this repo): interpreter
-fallback on CPU rigs, ``ShapeDtypeStruct(vma=)`` shim for jax >= 0.5,
+fallback on CPU rigs, ``ShapeDtypeStruct(vma=)`` on the output,
 registered with the trace-safety jaxpr gate through its consumer
 (``exec/pipeline._probe_targets_fn[pallas]``).
 
@@ -29,10 +29,9 @@ tests/test_pipeline.py).  Float64 key operands (kind 'f', NaN-aware
 compares) are NOT eligible — callers gate on :func:`supported` and keep
 the XLA path.
 
-One Mosaic note beyond the pallas_gather landmine list: pallas_call has
-no shard_map replication rule on jax < 0.5, so the consumer's shard_map
-must pass ``check_rep=False`` when this kernel is in the program (the
-program is still pure-local — the jaxpr gate asserts no collective).
+One note beyond the pallas_gather landmine list: the consumer's
+shard_map passes ``check_vma=False`` when this kernel is in the program
+(the program is still pure-local — the jaxpr gate asserts no collective).
 """
 
 from __future__ import annotations
@@ -121,18 +120,12 @@ def count_ge_splitters(ops: tuple, sops: tuple,
                                lambda j, *_: (j, jnp.int32(0),
                                               jnp.int32(0))),
     )
-    # under shard_map (check_vma, jax >= 0.5) the output must declare the
-    # mesh axes it varies over — the union of the inputs'.  jax < 0.5 has
-    # no vma concept on ShapeDtypeStruct (its check_rep has no pallas
-    # rule at all — consumers pass check_rep=False).
-    try:
-        vma = frozenset()
-        for a in (*scalars, *blocks):
-            vma = vma | getattr(a.aval, "vma", frozenset())
-        out_shape = jax.ShapeDtypeStruct((G, 8, TILE // 8), jnp.int32,
-                                         vma=vma)
-    except TypeError:
-        out_shape = jax.ShapeDtypeStruct((G, 8, TILE // 8), jnp.int32)
+    # under shard_map (check_vma) the output must declare the mesh axes
+    # it varies over — the union of the inputs'
+    vma = frozenset()
+    for a in (*scalars, *blocks):
+        vma = vma | getattr(a.aval, "vma", frozenset())
+    out_shape = jax.ShapeDtypeStruct((G, 8, TILE // 8), jnp.int32, vma=vma)
     out = pl.pallas_call(
         partial(_kernel, n_split=n_split, n_ops=n_ops),
         grid_spec=grid_spec,

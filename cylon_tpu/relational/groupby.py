@@ -59,11 +59,9 @@ _PAD_CACHE = BoundedCache()
 
 def _is_compiler_crash(e: Exception) -> bool:
     """True when the XLA compiler process died rather than the program
-    being invalid — delegates to the per-process probe-compiled
-    signature set (:func:`cylon_tpu.exec.recovery.is_compiler_crash`,
-    primed at first env creation, ``CYLON_TPU_CRASH_SIGS`` overrides),
-    so the pad ladder engages on whatever surfacing shape THIS platform
-    produces instead of a substring list frozen at authoring time."""
+    being invalid (a kernel Mosaic refuses is invalid, and raises) —
+    delegates to :func:`cylon_tpu.exec.recovery.is_compiler_crash`
+    (``CYLON_TPU_CRASH_SIGS`` overrides the signature set)."""
     from ..exec.recovery import is_compiler_crash
     return is_compiler_crash(e)
 
@@ -85,11 +83,16 @@ def _pad_ladder(sig_key, attempts):
             return res
         except Exception as e:  # noqa: BLE001
             if idx + 1 < len(attempts) and _is_compiler_crash(e):
+                from ..exec import recovery
                 from ..utils.logging import log
                 log.warning(
                     "TPU compiler crash on groupby variant %r; retrying "
                     "with %r: %.300s", attempts[idx][0],
                     attempts[idx + 1][0], e)
+                # a taken rung is a degradation the caller can see
+                recovery._record(f"groupby.pad_ladder.{attempts[idx][0]}",
+                                 "compiler_crash",
+                                 f"rung:{attempts[idx + 1][0]}")
                 last = e
                 continue
             raise

@@ -202,14 +202,12 @@ def run_serving(tenants: int = 4, queries: int = 4, scale: float = 0.01,
     import jax
     import cylon_tpu as ct
     from cylon_tpu import config, obs, tpch
-    from cylon_tpu.ctx.context import CPUMeshConfig, TPUConfig
+    from cylon_tpu.ctx.context import device_config
     from cylon_tpu.exec import checkpoint, memory, recovery
     from cylon_tpu.exec.scheduler import (QueryScheduler,
                                           estimate_footprint)
 
-    on_accel = jax.devices()[0].platform != "cpu"
-    env = ct.CylonEnv(config=TPUConfig() if on_accel
-                      else CPUMeshConfig(world_size=world))
+    env = ct.CylonEnv(config=device_config(world_size=world))
     dfs = tpch.generate_tables(scale=scale, env=env, seed=seed)
     row_counts = {k: int(v._table.row_count) for k, v in dfs.items()}
 

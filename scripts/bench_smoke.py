@@ -58,11 +58,8 @@ def run_smoke(env=None, rows: int = 65536, n_chunks: int = 4,
 
     assert rows <= 65536, "smoke stays tiny: <= 64k rows"
     if env is None:
-        from cylon_tpu.ctx.context import CPUMeshConfig, TPUConfig
-        import jax
-        cfg = TPUConfig() if jax.devices()[0].platform != "cpu" \
-            else CPUMeshConfig()
-        env = ct.CylonEnv(config=cfg)
+        from cylon_tpu.ctx.context import device_config
+        env = ct.CylonEnv(config=device_config())
 
     rng = np.random.default_rng(7)
     max_val = max(int(rows * 0.9), 1)

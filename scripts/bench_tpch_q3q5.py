@@ -39,7 +39,7 @@ def main():
     import jax
     import cylon_tpu as ct
     from cylon_tpu import obs, tpch
-    from cylon_tpu.ctx.context import CPUMeshConfig, TPUConfig
+    from cylon_tpu.ctx.context import device_config
     from cylon_tpu.exec import checkpoint, memory, recovery
 
     recovery.reset_events()
@@ -47,8 +47,7 @@ def main():
     checkpoint.reset_stats()
 
     devs = jax.devices()
-    on_accel = devs[0].platform != "cpu"
-    env = ct.CylonEnv(config=TPUConfig() if on_accel else CPUMeshConfig())
+    env = ct.CylonEnv(config=device_config())
 
     pdfs = tpch.generate_pandas(scale=scale)
     dfs = {name: ct.DataFrame(pdfs.pop(name)[cols], env=env)

@@ -21,7 +21,7 @@ import numpy as np
 
 import cylon_tpu as ct
 from cylon_tpu import config
-from cylon_tpu.ctx.context import CPUMeshConfig, TPUConfig
+from cylon_tpu.ctx.context import device_config
 from cylon_tpu.relational import groupby_aggregate, join_tables
 
 
@@ -47,10 +47,7 @@ def main():
         if a.startswith("--unique="):
             unique = float(a.split("=", 1)[1])
 
-    devs = jax.devices()
-    on_accel = devs[0].platform != "cpu"
-    cfg = TPUConfig() if on_accel else CPUMeshConfig()
-    env = ct.CylonEnv(config=cfg)
+    env = ct.CylonEnv(config=device_config())
     w = env.world_size
     n = rows * w
     max_val = max(int(n * unique), 1)

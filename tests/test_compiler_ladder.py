@@ -17,8 +17,8 @@ from cylon_tpu.ops import groupby as gbk
 from cylon_tpu.relational import groupby as rel_gb
 
 
-def _crash(msg="INTERNAL: http://127.0.0.1:1/remote_compile: HTTP 500: "
-                "tpu_compile_helper subprocess exit signal SIGSEGV (11)"):
+def _crash(msg="INTERNAL: tpu_compile_helper subprocess exit signal "
+                "SIGSEGV (11)"):
     raise RuntimeError(msg)
 
 
@@ -38,8 +38,16 @@ class TestPadLadder:
         attempts = [make("pad0", True), make("pad1", True),
                     make("scatter", False)]
         key = ("sig", 1)
+        from cylon_tpu.exec import recovery
+        recovery.reset_events()
         assert rel_gb._pad_ladder(key, attempts) == "scatter"
         assert calls == ["pad0", "pad1", "scatter"]
+        # every taken rung is a recorded recovery event, not only a log
+        # line (chip_smoke.py fails on it)
+        assert [(e["kind"], e["action"])
+                for e in recovery.drain_events()] == [
+            ("compiler_crash", "rung:pad1"),
+            ("compiler_crash", "rung:scatter")]
         # second run dispatches straight to the remembered variant
         calls.clear()
         assert rel_gb._pad_ladder(key, attempts) == "scatter"
@@ -62,9 +70,13 @@ class TestPadLadder:
                                   [("only", lambda: "ok")]) == "ok"
 
     def test_crash_detector(self):
-        e = RuntimeError("INTERNAL: http://x/remote_compile: HTTP 500: "
-                         "tpu_compile_helper subprocess exit signal SIGSEGV")
+        e = RuntimeError("INTERNAL: tpu_compile_helper subprocess exit "
+                         "signal SIGSEGV")
         assert rel_gb._is_compiler_crash(e)
+        # a kernel Mosaic REFUSES is an invalid program, not a dead
+        # compiler: it must raise, not give way to another gather
+        assert not rel_gb._is_compiler_crash(RuntimeError(
+            "INTERNAL: Mosaic failed to compile TPU kernel: unsupported"))
         assert not rel_gb._is_compiler_crash(RuntimeError("RESOURCE_EXHAUSTED"))
 
     def test_probe_classifies_once_per_process(self, env1):
