@@ -54,6 +54,16 @@ def say(msg: str) -> None:
     print(f"[chip_smoke] {msg}", flush=True)
 
 
+class SmokeFailure(Exception):
+    """A check of this script did not hold."""
+
+
+def check(cond, msg) -> None:
+    """Not ``assert``: these checks are the script, and stay under -O."""
+    if not cond:
+        raise SmokeFailure(msg)
+
+
 # ---------------------------------------------------------------------------
 # data + reference
 # ---------------------------------------------------------------------------
@@ -95,20 +105,21 @@ def _sorted_frame(table):
     return df.sort_values("k", kind="stable").reset_index(drop=True)
 
 
-def assert_equal_exact(got, want, what: str) -> None:
+def check_equal_exact(got, want, what: str) -> None:
     """Exact (int64) equality of two k-sorted frames, column by column."""
     import numpy as np
-    assert list(got.columns) == list(want.columns), \
-        f"{what}: columns {list(got.columns)} != {list(want.columns)}"
-    assert len(got) == len(want), \
-        f"{what}: {len(got)} groups != reference {len(want)}"
+    check(list(got.columns) == list(want.columns),
+          f"{what}: columns {list(got.columns)} != {list(want.columns)}")
+    check(len(got) == len(want),
+          f"{what}: {len(got)} groups != reference {len(want)}")
     for c in want.columns:
         g = np.asarray(got[c])
         w = np.asarray(want[c])
-        assert g.dtype == np.int64, f"{what}: column {c} is {g.dtype}"
+        check(g.dtype == np.int64,
+              f"{what}: column {c} is {g.dtype}")
         if not np.array_equal(g, w):
             bad = int(np.flatnonzero(g != w)[0])
-            raise AssertionError(
+            raise SmokeFailure(
                 f"{what}: column {c} differs from the reference at sorted "
                 f"row {bad}: {g[bad]} != {w[bad]}")
 
@@ -160,7 +171,8 @@ def gather_variant(env) -> dict:
     from cylon_tpu.relational import fused
     vals = [v for k, v in fused._SEG_CACHE.items()
             if k[0] == env.serial and isinstance(v, tuple)]
-    assert vals, "the fused join->groupby pushdown did not run"
+    check(vals,
+          "the fused join->groupby pushdown did not run")
     seg, win_allowed, win = vals[-1]
     return {"segment_space": int(seg), "window": int(win),
             "windowed_allowed": bool(win_allowed),
@@ -179,20 +191,23 @@ def windowed_eligible(env, n_groups: int, live_rows: int,
                 and dens >= pg.MIN_DENSITY and seg >= (1 << 20))
 
 
-def assert_not_degraded(where: str) -> None:
+def check_not_degraded(where: str) -> None:
     """No recovery event (a taken pad-ladder rung is one), no rung
     remembered, no spill, no disk page, no checkpoint."""
     from cylon_tpu.exec import checkpoint, memory, recovery
     from cylon_tpu.relational import groupby
     ev = recovery.recovery_events()
-    assert not ev, f"{where}: recovery events {ev}"
+    check(not ev,
+          f"{where}: recovery events {ev}")
     rungs = {k: v for k, v in groupby._PAD_CACHE.items() if v}
-    assert not rungs, f"{where}: pad-ladder rungs taken {rungs}"
+    check(not rungs,
+          f"{where}: pad-ladder rungs taken {rungs}")
     mem, ck = memory.stats(), checkpoint.stats()
     for k in ("spill_events", "disk_events"):
-        assert not mem[k], f"{where}: {k}={mem[k]}"
-    assert not ck["checkpoint_events"], \
-        f"{where}: checkpoint_events={ck['checkpoint_events']}"
+        check(not mem[k],
+              f"{where}: {k}={mem[k]}")
+    check(not ck["checkpoint_events"],
+          f"{where}: checkpoint_events={ck['checkpoint_events']}")
 
 
 # ---------------------------------------------------------------------------
@@ -213,8 +228,7 @@ def resident_phase(env, lt, rt, ref, ref_join_rows: int) -> dict:
 
     g, cold, warm, warm_compiles = _timed_calls(step)
     got = _sorted_frame(g)
-    assert_equal_exact(got, ref, "resident phase")
-    assert int(got["a_sum"].sum()) == int(ref["a_sum"].sum())
+    check_equal_exact(got, ref, "resident phase")
 
     # the route, from one more (profiled) call: EXPLAIN ANALYZE's tree
     qplan = obs.explain_analyze(step, profile_keys=False)
@@ -229,14 +243,14 @@ def resident_phase(env, lt, rt, ref, ref_join_rows: int) -> dict:
             "compiles_in_warm_call": warm_compiles, "routes": routes,
             "gather": variant, "windowed_eligible": eligible}
     say(json.dumps(info))
-    assert any(r["route"] == "fused_pushdown" for r in routes), \
-        f"resident phase did not take the fused pushdown: {routes}"
-    assert warm_compiles == 0, \
-        f"resident phase compiled {warm_compiles} program(s) in its warm call"
+    check(any(r["route"] == "fused_pushdown" for r in routes),
+          f"resident phase did not take the fused pushdown: {routes}")
+    check(warm_compiles == 0,
+          f"resident phase: {warm_compiles} compile(s) in the warm call")
     if eligible:
-        assert variant["window"] > 0, \
-            f"eligible for the windowed Pallas gather, ran {variant}"
-    assert_not_degraded("resident phase")
+        check(variant["window"] > 0,
+              f"eligible for the windowed Pallas gather, ran {variant}")
+    check_not_degraded("resident phase")
     return {"frame": got, "info": info}
 
 
@@ -257,25 +271,25 @@ def pipelined_phase(env, lt, rt, want, inp: dict) -> dict:
 
     g, cold, warm, warm_compiles = _timed_calls(step)
     got = _sorted_frame(g)
-    assert_equal_exact(got, want, "pipelined phase")
+    check_equal_exact(got, want, "pipelined phase")
     qplan = obs.explain_analyze(step, profile_keys=False)
     routes = _plan_routes(qplan)
     info = {"phase": "pipelined", "n_chunks": N_CHUNKS,
             "groups": len(got), "cold_s": cold, "warm_s": warm,
             "compiles_in_warm_call": warm_compiles, "routes": routes}
     say(json.dumps(info))
-    assert any(r["route"] == "range_pipeline" for r in routes), \
-        f"pipelined phase did not take the range pipeline: {routes}"
-    assert warm_compiles == 0, \
-        f"pipelined phase compiled {warm_compiles} program(s) in its warm call"
+    check(any(r["route"] == "range_pipeline" for r in routes),
+          f"pipelined phase did not take the range pipeline: {routes}")
+    check(warm_compiles == 0,
+          f"pipelined phase: {warm_compiles} compile(s) in the warm call")
     # donation consumed scratch, never the caller's tables
     for t, kname, vname, vcol in ((lt, "lk", "a", "a"), (rt, "rk", "b", "b")):
         df = t.to_pandas()
-        assert np.array_equal(np.asarray(df["k"]), inp[kname]) \
-            and np.array_equal(np.asarray(df[vcol]), inp[vname]), \
-            "an input table is no longer readable after the pipelined phase"
+        check(np.array_equal(np.asarray(df["k"]), inp[kname])
+              and np.array_equal(np.asarray(df[vcol]), inp[vname]),
+              "an input table is not readable after the pipelined phase")
     say("input tables still readable after the pipelined phase")
-    assert_not_degraded("pipelined phase")
+    check_not_degraded("pipelined phase")
     return {"frame": got, "info": info}
 
 
@@ -290,14 +304,15 @@ def distributed_phase(env, lt, rt, ref, rows_per_chip: int) -> dict:
     w = env.world_size
     for name, t in (("left", lt), ("right", rt)):
         vc = np.asarray(t.valid_counts, np.int64)
-        assert vc.shape == (w,) and vc.sum() == t.row_count, (name, vc)
-        assert vc.min() >= 0.9 * rows_per_chip, \
-            f"{name} table is not spread over the devices: {vc.tolist()}"
+        check(vc.shape == (w,) and vc.sum() == t.row_count,
+              (name, vc))
+        check(vc.min() >= 0.9 * rows_per_chip,
+              f"{name} table is not spread over devices: {vc.tolist()}")
         col = next(iter(t.columns.values())).data
         devs = {sh.device for sh in col.addressable_shards}
         sizes = {sh.data.shape for sh in col.addressable_shards}
-        assert len(devs) == w and len(sizes) == 1, \
-            f"{name} table sits on {len(devs)} device(s), shards {sizes}"
+        check(len(devs) == w and len(sizes) == 1,
+              f"{name} table: {len(devs)} device(s), shards {sizes}")
         say(f"{name}: valid_counts={vc.tolist()} on {len(devs)} devices, "
             f"shard shape {next(iter(sizes))}")
 
@@ -316,7 +331,7 @@ def distributed_phase(env, lt, rt, ref, rows_per_chip: int) -> dict:
     rep = comm.report()
     comm.arm(False)
     got = _sorted_frame(g)
-    assert_equal_exact(got, ref, "distributed phase")
+    check_equal_exact(got, ref, "distributed phase")
     moved = exch.value - exch_before
     m = np.asarray(rep["rows"], np.int64) if rep else np.zeros((w, w))
     off = float(m.sum() - np.trace(m)) / max(float(m.sum()), 1.0)
@@ -327,12 +342,13 @@ def distributed_phase(env, lt, rt, ref, rows_per_chip: int) -> dict:
             "exchange_rows_total": int(moved),
             "off_diagonal_share": off}
     say(json.dumps(info))
-    assert moved > 0, "no row went through the exchange"
-    assert rep and 0.5 < off < 0.95, \
-        f"exchange did not cross devices as a uniform hash would: {off}"
-    assert warm_compiles == 0, \
-        f"distributed phase compiled {warm_compiles} program(s) warm"
-    assert_not_degraded("distributed phase")
+    check(moved > 0,
+          "no row went through the exchange")
+    check(rep and 0.5 < off < 0.95,
+          f"exchange did not cross devices as a uniform hash would: {off}")
+    check(warm_compiles == 0,
+          f"distributed phase compiled {warm_compiles} program(s) warm")
+    check_not_degraded("distributed phase")
     return {"frame": got, "info": info}
 
 

@@ -8,7 +8,7 @@ optimization).  The XLA path materializes the full ``(rows, splitters)``
 lexicographic comparison matrix (:func:`cylon_tpu.ops.pack.
 rows_ge_splitters`): at 125M rows x R splitters x K operands that is an
 O(n*R*K) HBM-resident boolean intermediate, and ``pipe.targets`` was
-~1.2 s of the 12.75 s BENCH_r05 iteration.
+~1.2 s of the 12.75 s round-5 iteration (an earlier runtime).
 
 This kernel streams the probe rows through VMEM in (8, 128) tiles with
 the splitter operands resident in SMEM (scalar prefetch — splitters are

@@ -7,7 +7,7 @@ seed materialized each window back into a full Table — dynamic-slice,
 unpack EVERY column to full-width HBM arrays — only for the join to
 immediately re-pack the keys into sort operands and the payloads into a
 lane matrix.  That unpack→repack round trip was the single largest phase
-of the pipelined join at the 125M-row operating point (BENCH_r05:
+of the pipelined join at the 125M-row operating point (round 5:
 ``pipe.piece_slice`` 3.74 s of 12.75 s).
 
 :class:`PackedPiece` removes the wall: it is a pure HOST-SIDE descriptor
