@@ -333,6 +333,7 @@ def distributed_phase(env, lt, rt, ref, rows_per_chip: int) -> dict:
     got = _sorted_frame(g)
     check_equal_exact(got, ref, "distributed phase")
     moved = exch.value - exch_before
+    routes = _plan_routes(obs.explain_analyze(step, profile_keys=False))
     m = np.asarray(rep["rows"], np.int64) if rep else np.zeros((w, w))
     off = float(m.sum() - np.trace(m)) / max(float(m.sum()), 1.0)
     info = {"phase": "distributed", "world": w,
@@ -340,7 +341,7 @@ def distributed_phase(env, lt, rt, ref, rows_per_chip: int) -> dict:
             "cold_s": cold, "warm_s": warm,
             "compiles_in_warm_call": warm_compiles,
             "exchange_rows_total": int(moved),
-            "off_diagonal_share": off}
+            "off_diagonal_share": off, "routes": routes}
     say(json.dumps(info))
     check(moved > 0,
           "no row went through the exchange")

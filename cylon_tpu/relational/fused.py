@@ -128,9 +128,11 @@ def _fused_fn(mesh: Mesh, n_l: int, all_live: bool, lspec, rspec,
         my = jax.lax.axis_index(ROW_AXIS)
         side_r = idx_s >= n_l
         if all_live:
+            n_live = jnp.int32(N)
             live = jnp.ones(N, bool)
         else:
-            live = pos < (vcl[my] + vcr[my]).astype(jnp.int32)
+            n_live = (vcl[my] + vcr[my]).astype(jnp.int32)
+            live = pos < n_live
         lefts_b = ~side_r & live
         rights_b = side_r & live
         lefts = lefts_b.astype(jnp.int32)
@@ -150,7 +152,14 @@ def _fused_fn(mesh: Mesh, n_l: int, all_live: bool, lspec, rspec,
         kstart = first & keep
         kgid = jnp.cumsum(kstart.astype(jnp.int32)).astype(jnp.int32) - 1
         n_groups = (jnp.max(jnp.where(keep, kgid, -1)) + 1).astype(jnp.int32)
-        starts = jnp.full(seg_cap, N, jnp.int32).at[
+        # empty segment slots point at the END OF THE LIVE PREFIX, not at
+        # N: every dead row is masked out of every lane, so the prefix
+        # there already holds the full totals — and the tile of starts
+        # that straddles n_groups then spans a few rows, not the whole
+        # capacity pad (shape-family padding, N - n_live ~ 1M rows at 32M
+        # rows/side, overflowed every window and silently lost the
+        # windowed gather)
+        starts = jnp.full(seg_cap, n_live, jnp.int32).at[
             jnp.where(kstart, kgid, jnp.int32(seg_cap))].set(pos, mode="drop")
 
         nl_lanes = lspec.n_lanes
@@ -184,7 +193,7 @@ def _fused_fn(mesh: Mesh, n_l: int, all_live: bool, lspec, rspec,
         key_datas = [ldat[ci] for ci in key_cols]
         key_valids = [lval[ci] for ci in key_cols]
         inters, key_out, kval_out, wok = gbk.grouped_reduce(
-            ops_list, vals, masks, starts, jnp.int32(N), key_datas,
+            ops_list, vals, masks, starts, n_live, key_datas,
             key_valids, seg_cap, key_narrow=key_narrow,
             pad_lanes=pad_lanes, gather_parts=gather_parts,
             use_window=use_window)

@@ -68,3 +68,49 @@ class TestWindowedTake:
         assert pg.pick_window(0.45) == 1024
         assert pg.pick_window(0.25) == 2048
         assert pg.pick_window(0.05) == pg.MAX_WINDOW
+
+
+def test_fused_windowed_gather_survives_capacity_padding(env1, rng,
+                                                         monkeypatch):
+    """Shape-family padding leaves dead rows behind the live prefix of the
+    fused join->groupby's sorted state.  Empty segment slots must point at
+    the END OF THE LIVE PREFIX: pointing at the capacity made the tile of
+    starts that straddles n_groups span the whole pad, overflow every
+    window, and silently lose the windowed gather on the chip (first chip
+    run of chip_smoke.py, PR 22)."""
+    import cylon_tpu as ct
+    from cylon_tpu.relational import fused, groupby_aggregate, join_tables
+
+    n = 66000                      # cap 69632: 3632 dead rows per side
+    mk = lambda: rng.integers(0, int(n * 0.9), n).astype(np.int64)  # noqa: E731
+    lt = ct.Table.from_pydict({"k": mk(), "a": mk()}, env1)
+    rt = ct.Table.from_pydict({"k": mk(), "b": mk()}, env1)
+    assert lt.capacity - n > 2048
+    calls = []
+    orig = fused._fused_fn
+
+    def builder(mesh, *static):
+        fn = orig(mesh, *static)
+
+        def call(*args):
+            calls.append((static, args, fn(*args)))
+            return calls[-1][2]
+        return call
+    monkeypatch.setattr(fused, "_fused_fn", builder)
+    groupby_aggregate(join_tables(lt, rt, "k", "k", how="inner"), "k",
+                      [("a", "sum"), ("b", "sum")]).to_pandas()
+    monkeypatch.undo()
+    static, args, plain = calls[-1]
+    assert static[-1] == 0 and static[7] % pg.TILE == 0
+    # interpret mode on the CPU; jax 0.9's Pallas interpreter cannot type
+    # varying axes inside shard_map, so this one program skips that check
+    from functools import partial
+    monkeypatch.setattr(fused, "shard_map",
+                        partial(jax.shard_map, check_vma=False))
+    win = orig(env1.mesh, *static[:11], 4096)(*args)
+    n_groups, wok = np.asarray(win[4]).reshape(-1, 2)[0]
+    assert wok == 1, "the windowed gather reported a span overflow"
+    assert n_groups == np.asarray(plain[4]).reshape(-1, 2)[0][0]
+    for a, b in zip(jax.tree.leaves(win[:4]), jax.tree.leaves(plain[:4])):
+        np.testing.assert_array_equal(np.asarray(a)[:n_groups],
+                                      np.asarray(b)[:n_groups])
