@@ -115,8 +115,7 @@ def check_equal_exact(got, want, what: str) -> None:
     for c in want.columns:
         g = np.asarray(got[c])
         w = np.asarray(want[c])
-        check(g.dtype == np.int64,
-              f"{what}: column {c} is {g.dtype}")
+        check(g.dtype == np.int64, f"{what}: column {c} is {g.dtype}")
         if not np.array_equal(g, w):
             bad = int(np.flatnonzero(g != w)[0])
             raise SmokeFailure(
@@ -171,24 +170,11 @@ def gather_variant(env) -> dict:
     from cylon_tpu.relational import fused
     vals = [v for k, v in fused._SEG_CACHE.items()
             if k[0] == env.serial and isinstance(v, tuple)]
-    check(vals,
-          "the fused join->groupby pushdown did not run")
+    check(vals, "the fused join->groupby pushdown did not run")
     seg, win_allowed, win = vals[-1]
     return {"segment_space": int(seg), "window": int(win),
             "windowed_allowed": bool(win_allowed),
             "variant": f"windowed_pallas(w={win})" if win else "xla_gather"}
-
-
-def windowed_eligible(env, n_groups: int, live_rows: int,
-                      seg: int) -> bool:
-    """relational/fused._win_size's own rule: TPU, measured density at or
-    above the coverage floor, segment space >= 2^20."""
-    from cylon_tpu import config
-    from cylon_tpu.ops import pallas_gather as pg
-    on_tpu = next(iter(env.mesh.devices.flat)).platform == "tpu"
-    dens = n_groups / max(live_rows, 1)
-    return bool(on_tpu and config.WINDOWED_GATHER
-                and dens >= pg.MIN_DENSITY and seg >= (1 << 20))
 
 
 def check_not_degraded(where: str) -> None:
@@ -197,15 +183,12 @@ def check_not_degraded(where: str) -> None:
     from cylon_tpu.exec import checkpoint, memory, recovery
     from cylon_tpu.relational import groupby
     ev = recovery.recovery_events()
-    check(not ev,
-          f"{where}: recovery events {ev}")
+    check(not ev, f"{where}: recovery events {ev}")
     rungs = {k: v for k, v in groupby._PAD_CACHE.items() if v}
-    check(not rungs,
-          f"{where}: pad-ladder rungs taken {rungs}")
+    check(not rungs, f"{where}: pad-ladder rungs taken {rungs}")
     mem, ck = memory.stats(), checkpoint.stats()
     for k in ("spill_events", "disk_events"):
-        check(not mem[k],
-              f"{where}: {k}={mem[k]}")
+        check(not mem[k], f"{where}: {k}={mem[k]}")
     check(not ck["checkpoint_events"],
           f"{where}: checkpoint_events={ck['checkpoint_events']}")
 
@@ -218,7 +201,7 @@ def resident_phase(env, lt, rt, ref, ref_join_rows: int) -> dict:
     """join_tables -> groupby_aggregate on the resident tables, exact
     against the pandas reference."""
     from cylon_tpu import obs
-    from cylon_tpu.relational import groupby_aggregate, join_tables
+    from cylon_tpu.relational import fused, groupby_aggregate, join_tables
 
     def step():
         j = join_tables(lt, rt, "k", "k", how="inner")
@@ -234,9 +217,10 @@ def resident_phase(env, lt, rt, ref, ref_join_rows: int) -> dict:
     qplan = obs.explain_analyze(step, profile_keys=False)
     routes = _plan_routes(qplan)
     variant = gather_variant(env)
-    live = lt.row_count + rt.row_count
-    eligible = windowed_eligible(env, len(ref), live,
-                                 variant["segment_space"])
+    # relational/fused's own rule, at the density the reference shows
+    eligible = fused.window_for(
+        env.mesh, variant["segment_space"],
+        len(ref) / (lt.row_count + rt.row_count)) > 0
     info = {"phase": "resident", "rows_in": [lt.row_count, rt.row_count],
             "join_rows": ref_join_rows, "groups": len(got),
             "cold_s": cold, "warm_s": warm,
@@ -304,8 +288,7 @@ def distributed_phase(env, lt, rt, ref, rows_per_chip: int) -> dict:
     w = env.world_size
     for name, t in (("left", lt), ("right", rt)):
         vc = np.asarray(t.valid_counts, np.int64)
-        check(vc.shape == (w,) and vc.sum() == t.row_count,
-              (name, vc))
+        check(vc.shape == (w,) and vc.sum() == t.row_count, (name, vc))
         check(vc.min() >= 0.9 * rows_per_chip,
               f"{name} table is not spread over devices: {vc.tolist()}")
         col = next(iter(t.columns.values())).data
@@ -343,8 +326,7 @@ def distributed_phase(env, lt, rt, ref, rows_per_chip: int) -> dict:
             "exchange_rows_total": int(moved),
             "off_diagonal_share": off, "routes": routes}
     say(json.dumps(info))
-    check(moved > 0,
-          "no row went through the exchange")
+    check(moved > 0, "no row went through the exchange")
     check(rep and 0.5 < off < 0.95,
           f"exchange did not cross devices as a uniform hash would: {off}")
     check(warm_compiles == 0,

@@ -157,11 +157,6 @@ class CylonEnv:
         devs = self.config.resolve_devices()
         self._devices = devs
         self._mesh = Mesh(np.asarray(devs, dtype=object), (ROW_AXIS,))
-        # settle the compiler-crash signature classification while the
-        # backend is known-good (one probe compile, cached per process) —
-        # the operator compile ladders dispatch on it (exec/recovery)
-        from ..exec.recovery import prime_compiler_probe
-        prime_compiler_probe()
         # spot/preemptible semantics: arm the SIGTERM grace drain when
         # CYLON_TPU_PREEMPT_GRACE_S declares a budget (exec/preempt —
         # one env read and no handler otherwise)

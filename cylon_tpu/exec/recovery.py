@@ -229,11 +229,8 @@ def classify(e: Exception) -> CylonError | None:
 
 
 # ---------------------------------------------------------------------------
-# compiler-crash classification — probe-compiled per process (VERDICT 8)
+# compiler-crash classification
 # ---------------------------------------------------------------------------
-
-#: [tuple] once probed; empty = not yet.
-_CRASH_SIG_CACHE: list = []
 
 #: the shape of a compiler-PROCESS death: the helper subprocess's name
 #: and the signal.  A kernel Mosaic *refuses* ("Mosaic failed to
@@ -243,48 +240,25 @@ _BASE_CRASH_SIGS = ("tpu_compile_helper", "SIGSEGV")
 
 
 def compiler_crash_signatures() -> tuple:
-    """The compiler-crash message signatures, pinned ONCE per process
-    after a probe compile (primed at first env creation,
-    ``ctx/context.CylonEnv``) proved the backend's toolchain is live: a
-    TPU compile runs in the ``tpu_compile_helper`` subprocess, and its
-    death surfaces under that name with the signal.
-    ``CYLON_TPU_CRASH_SIGS`` (``|``-separated) overrides the set
-    entirely, which is how tests prove the pad ladder still engages
-    under a synthetic signature change."""
+    """The compiler-crash message signatures: a TPU compile runs in the
+    ``tpu_compile_helper`` subprocess, and its death surfaces under that
+    name with the signal.  ``CYLON_TPU_CRASH_SIGS`` (``|``-separated)
+    overrides the set entirely, which is how tests prove the pad ladder
+    still engages under a synthetic signature change."""
     env_sigs = os.environ.get("CYLON_TPU_CRASH_SIGS")
     if env_sigs is not None:
         return tuple(s for s in env_sigs.split("|") if s)
-    if _CRASH_SIG_CACHE:
-        return _CRASH_SIG_CACHE[0]
-    try:
-        import jax.numpy as jnp
-        # probe compile: a working toolchain proves the backend is live;
-        # rides the facade pinned — the probe must run even while the
-        # lifecycle is quarantining
-        from .compiler import jit as _jit
-        _jit(lambda x: x + 1, pinned=True)(jnp.zeros((), jnp.int32))
-    except Exception:  # noqa: BLE001 — no backend yet: defaults stand,
-        return _BASE_CRASH_SIGS  # re-probe on the next call
-    _CRASH_SIG_CACHE.append(_BASE_CRASH_SIGS)
-    return _CRASH_SIG_CACHE[0]
+    return _BASE_CRASH_SIGS
 
 
 def is_compiler_crash(e: Exception) -> bool:
     """True when the XLA compiler process died (SIGSEGV landmines: f64
     sort payloads and specific gather lane widths, v5e libtpu 2026-07)
-    rather than the program being invalid — matched against the
-    per-process probed signature set, so the pad ladder
-    (``relational/groupby._pad_ladder``) engages on whatever surfacing
-    shape THIS platform produces."""
+    rather than the program being invalid — matched against
+    :func:`compiler_crash_signatures`, which the pad ladder
+    (``relational/groupby._pad_ladder``) engages on."""
     s = str(e)
     return any(sig in s for sig in compiler_crash_signatures())
-
-
-def prime_compiler_probe() -> None:
-    """Run (and cache) the compiler-crash signature probe — called at
-    first env creation so the classification is settled before any
-    operator's compile ladder can need it."""
-    compiler_crash_signatures()
 
 
 # ---------------------------------------------------------------------------

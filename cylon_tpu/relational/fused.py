@@ -232,6 +232,20 @@ def _fused_fn(mesh: Mesh, n_l: int, all_live: bool, lspec, rspec,
                              out_specs=(ROW, ROW, ROW, ROW, ROW)))
 
 
+def window_for(mesh, seg_cap: int, density: float) -> int:
+    """Windowed-gather request for a dispatch at segment space
+    ``seg_cap`` (0 = plain): TPU only, measured group density above the
+    coverage floor, segment space big enough for the plain gather to
+    hurt."""
+    from ..ops import pallas_gather as pg
+    on_tpu = next(iter(mesh.devices.flat)).platform == "tpu"
+    if not (on_tpu and config.WINDOWED_GATHER):
+        return 0
+    if density < pg.MIN_DENSITY or seg_cap < (1 << 20):
+        return 0
+    return pg.pick_window(density)
+
+
 class _PendingFused:
     """A DISPATCHED (not yet pulled) fused join+groupby.  The first device
     program is already enqueued; :meth:`resolve` pulls its meta sidecar,
@@ -334,19 +348,6 @@ def try_begin_join_groupby(table: Table, by: list, specs: list,
            int(state.vcl.sum()), int(state.vcr.sum()), ddof)
 
     from .groupby import _FIRST_SEG_CAP, _is_compiler_crash, _pad_ladder
-    from ..ops import pallas_gather as pg
-
-    on_tpu = next(iter(env.mesh.devices.flat)).platform == "tpu"
-
-    def _win_size(sc: int, dens: float) -> int:
-        """Windowed-gather request for a dispatch at segment space ``sc``
-        (0 = plain): TPU only, measured group density above the coverage
-        floor, segment space big enough for the plain gather to hurt."""
-        if not (on_tpu and config.WINDOWED_GATHER):
-            return 0
-        if dens < pg.MIN_DENSITY or sc < (1 << 20):
-            return 0
-        return pg.pick_window(dens)
 
     def call(sc, win):
         # same compiler-crash ladder as every other grouped_reduce dispatch
@@ -415,7 +416,8 @@ def try_begin_join_groupby(table: Table, by: list, specs: list,
                     seg_cap = max(seg_cap, ng_cap)
                     dens = float((n_groups / np.maximum(live, 1)).min()) \
                         if n_groups.size else 0.0
-                    win = _win_size(seg_cap, dens) if win_allowed else 0
+                    win = window_for(env.mesh, seg_cap, dens) \
+                        if win_allowed else 0
                     res = call(seg_cap, win)
             except Exception as e:  # noqa: BLE001
                 if _is_compiler_crash(e):

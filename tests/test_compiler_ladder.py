@@ -79,17 +79,15 @@ class TestPadLadder:
             "INTERNAL: Mosaic failed to compile TPU kernel: unsupported"))
         assert not rel_gb._is_compiler_crash(RuntimeError("RESOURCE_EXHAUSTED"))
 
-    def test_probe_classifies_once_per_process(self, env1):
-        """The signature set comes from the per-process probe (primed at
-        env creation), not an inline literal: the cache is populated and
-        contains the platform-independent base shapes."""
+    def test_signature_set_is_the_helper_death_shapes(self, env1):
+        """The signature set is one place (exec/recovery), not an inline
+        literal per call site: the helper subprocess's name and the
+        signal, and nothing a refusal would carry."""
         from cylon_tpu.exec import recovery
         sigs = recovery.compiler_crash_signatures()
-        assert recovery._CRASH_SIG_CACHE, "env creation did not prime probe"
-        assert set(recovery._BASE_CRASH_SIGS) <= set(sigs)
-        # probed again: same (cached) classification
-        assert recovery.compiler_crash_signatures() is \
-            recovery._CRASH_SIG_CACHE[0]
+        assert sigs == recovery._BASE_CRASH_SIGS
+        assert set(sigs) == {"tpu_compile_helper", "SIGSEGV"}
+        assert recovery.compiler_crash_signatures() is sigs
 
     def test_ladder_engages_under_synthetic_signature_change(self,
                                                              monkeypatch):
