@@ -163,18 +163,19 @@ def _sync(table) -> None:
     sync_pull(next(iter(table.columns.values())).data)
 
 
-def gather_variant(env) -> dict:
-    """What the fused join->groupby dispatch settled on for this env:
+def gather_variants(env, skip=()) -> list:
+    """What each fused join->groupby callsite of this env settled on:
     relational/fused._SEG_CACHE holds (segment bucket, windowed allowed,
-    window) per callsite signature (sig[0] is the env serial)."""
+    window) per callsite signature (sig[0] is the env serial).  ``skip``:
+    signatures to leave out (those an earlier phase made)."""
     from cylon_tpu.relational import fused
-    vals = [v for k, v in fused._SEG_CACHE.items()
-            if k[0] == env.serial and isinstance(v, tuple)]
-    check(vals, "the fused join->groupby pushdown did not run")
-    seg, win_allowed, win = vals[-1]
-    return {"segment_space": int(seg), "window": int(win),
-            "windowed_allowed": bool(win_allowed),
-            "variant": f"windowed_pallas(w={win})" if win else "xla_gather"}
+    return [{"sig": k, "segment_space": int(v[0]), "window": int(v[2]),
+             "windowed_allowed": bool(v[1]),
+             "variant": f"windowed_pallas(w={v[2]})" if v[2]
+             else "xla_gather"}
+            for k, v in fused._SEG_CACHE.items()
+            if k[0] == env.serial and isinstance(v, tuple)
+            and k not in skip]
 
 
 def check_not_degraded(where: str) -> None:
@@ -216,7 +217,10 @@ def resident_phase(env, lt, rt, ref, ref_join_rows: int) -> dict:
     # the route, from one more (profiled) call: EXPLAIN ANALYZE's tree
     qplan = obs.explain_analyze(step, profile_keys=False)
     routes = _plan_routes(qplan)
-    variant = gather_variant(env)
+    variants = gather_variants(env)
+    check(len(variants) == 1,
+          f"expected one fused join->groupby callsite, found {variants}")
+    variant = {k: v for k, v in variants[0].items() if k != "sig"}
     # relational/fused's own rule, at the density the reference shows
     eligible = fused.window_for(
         env.mesh, variant["segment_space"],
@@ -253,14 +257,18 @@ def pipelined_phase(env, lt, rt, want, inp: dict) -> dict:
         _sync(g)
         return g
 
+    before = {v["sig"] for v in gather_variants(env)}
     g, cold, warm, warm_compiles = _timed_calls(step)
     got = _sorted_frame(g)
     check_equal_exact(got, want, "pipelined phase")
+    pieces = sorted({(v["segment_space"], v["variant"])
+                     for v in gather_variants(env, skip=before)})
     qplan = obs.explain_analyze(step, profile_keys=False)
     routes = _plan_routes(qplan)
     info = {"phase": "pipelined", "n_chunks": N_CHUNKS,
             "groups": len(got), "cold_s": cold, "warm_s": warm,
-            "compiles_in_warm_call": warm_compiles, "routes": routes}
+            "compiles_in_warm_call": warm_compiles, "routes": routes,
+            "piece_gathers": pieces}
     say(json.dumps(info))
     check(any(r["route"] == "range_pipeline" for r in routes),
           f"pipelined phase did not take the range pipeline: {routes}")
