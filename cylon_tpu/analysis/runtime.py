@@ -149,22 +149,36 @@ def tag_program(name: str, program, key=()):
     check; ``__wrapped__`` exposes the raw program for tracing.
     """
 
+    from ..exec import compiler
+    from ..utils import timing
+    from ..utils.cache import short_name
+    short = short_name(name)
+    span = "launch." + short
+
     def tagged(*args, **kwargs):
-        st = _state
-        if st is None:
-            return program(*args, **kwargs)
-        prev = getattr(_local, "builder", None)
-        prev_flag = getattr(_local, "call_compiled", False)
-        _local.builder = (name, key, _signature(args, kwargs))
-        _local.call_compiled = False
-        try:
-            return program(*args, **kwargs)
-        finally:
-            if getattr(_local, "call_compiled", False):
-                with _lock:
-                    st.compiles[_local.builder] += 1
-            _local.builder = prev
-            _local.call_compiled = prev_flag
+        # the host side of one program launch: enqueue (and, on first
+        # sight, trace + compile) — `launch.<builder>` on every sink of
+        # timing.span: the profiler's trace and the flight recorder
+        with timing.span(span):
+            was = compiler.launching(short)
+            try:
+                st = _state
+                if st is None:
+                    return program(*args, **kwargs)
+                prev = getattr(_local, "builder", None)
+                prev_flag = getattr(_local, "call_compiled", False)
+                _local.builder = (name, key, _signature(args, kwargs))
+                _local.call_compiled = False
+                try:
+                    return program(*args, **kwargs)
+                finally:
+                    if getattr(_local, "call_compiled", False):
+                        with _lock:
+                            st.compiles[_local.builder] += 1
+                    _local.builder = prev
+                    _local.call_compiled = prev_flag
+            finally:
+                compiler.launching(was)
 
     tagged.__wrapped__ = program
     tagged.__name__ = f"tagged[{name}]"

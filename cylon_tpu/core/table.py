@@ -27,6 +27,7 @@ import numpy as np
 
 from ..ctx.context import CylonEnv, LocalConfig
 from ..status import CylonKeyError, InvalidError
+from ..utils import timing
 from .column import Column
 from .dtypes import Field, LogicalType
 
@@ -75,8 +76,13 @@ class Table:
     @staticmethod
     def from_pydict(data: Mapping[str, np.ndarray], env: CylonEnv | None = None) -> "Table":
         env = env or default_env()
-        cols = {k: Column.from_numpy(np.asarray(v)) for k, v in data.items()}
-        return _ingest(cols, env)
+        arrays = {k: np.asarray(v) for k, v in data.items()}
+        with timing.region(
+                "table.from_pydict",
+                rows=len(next(iter(arrays.values()))) if arrays else 0,
+                bytes=sum(int(a.nbytes) for a in arrays.values())):
+            cols = {k: Column.from_numpy(a) for k, a in arrays.items()}
+            return _ingest(cols, env)
 
     @staticmethod
     def from_pandas(df, env: CylonEnv | None = None) -> "Table":
@@ -410,12 +416,13 @@ def _ingest(cols: dict[str, Column], env: CylonEnv) -> Table:
     one compiled program per plan shape, bit- and order-equal.
     ``CYLON_TPU_SHAPE_FAMILIES=0`` (and already-canonical or empty
     ingests) keep the zero-copy exact placement."""
-    if env.world_size == 1:
-        from ..exec.compiler import family_cap
-        n = len(next(iter(cols.values()))) if cols else 0
-        if family_cap(n) == n:
-            return Table(_place_local(cols, env), env)
-    return _distribute(cols, env)
+    n = len(next(iter(cols.values()))) if cols else 0
+    with timing.span("table.upload", rows=n):
+        if env.world_size == 1:
+            from ..exec.compiler import family_cap
+            if family_cap(n) == n:
+                return Table(_place_local(cols, env), env)
+        return _distribute(cols, env)
 
 
 def _distribute(cols: dict[str, Column], env: CylonEnv) -> Table:

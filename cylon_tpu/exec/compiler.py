@@ -114,6 +114,12 @@ _MANIFEST_DROPS = metrics.counter(
     help="persistent warm-manifest entries dropped on a failed content "
          "hash (clean miss; never loads wrong code)")
 
+#: backend-compile seconds / events by the builder whose program was being
+#: launched when the compile fired (``launching``; "untagged" outside any):
+#: which program compiles twice on a cold cache, which one misses a warm one
+_SECONDS_BY = metrics.namespace("compile_seconds_by_builder")
+_EVENTS_BY = metrics.namespace("compile_events_by_builder")
+
 _lock = threading.RLock()
 _tls = threading.local()
 
@@ -138,6 +144,25 @@ def _on_compile_event(event: str, duration: float, **kw) -> None:
     if event.startswith("/jax/core/compile/backend_compile"):
         _SECONDS.inc(duration)
         _EVENTS.inc()
+        builder = getattr(_tls, "launching", None) or "untagged"
+        with _lock:
+            _SECONDS_BY[builder] = _SECONDS_BY.get(builder, 0.0) + duration
+            _EVENTS_BY[builder] = _EVENTS_BY.get(builder, 0) + 1
+        from ..obs import trace
+        rec = trace.recorder()
+        if rec is not None:   # the event arrives when the compile ENDS
+            rec.span("compile." + builder, time.perf_counter() - duration,
+                     duration)
+
+
+def launching(builder: str | None) -> str | None:
+    """Mark this thread as launching ``builder``'s program (None = no
+    longer); returns the previous mark.  ``analysis/runtime.tag_program``
+    brackets every ``program_cache`` program's call with it, so a backend
+    compile fired inside is attributed to its builder."""
+    prev = getattr(_tls, "launching", None)
+    _tls.launching = builder
+    return prev
 
 
 def install_listener() -> None:
@@ -394,8 +419,10 @@ def _watchdog(label: str, sig: str, thunk, stalled: bool):
     if t <= 0:
         return thunk()
     box: dict = {}
+    builder = getattr(_tls, "launching", None)
 
     def run():
+        launching(builder)     # the compile fires on THIS thread
         if stalled:
             time.sleep(4 * max(t, 0.5))   # simulated hung compiler
             return
@@ -502,7 +529,9 @@ def jit(fun=None, pinned: bool = False, **kw):
     if fun is None:
         return functools.partial(jit, pinned=pinned, **kw)
     _install_listener()
-    return _Program(jax.jit(fun, **kw), _label(fun), pinned=pinned)
+    from ..utils import cache
+    named = cache.named_for_device(fun, getattr(cache._building, "name", None))
+    return _Program(jax.jit(named, **kw), _label(fun), pinned=pinned)
 
 
 def _unwrap_program(fn):
@@ -673,6 +702,10 @@ def stats() -> dict:
         "mesh_table_evictions": _MESH_EVICT.value,
         "compile_seconds": round(float(_SECONDS.value), 6),
         "compile_events": _EVENTS.value,
+        "by_builder": {b: {"seconds": round(float(_SECONDS_BY.get(b, 0.0)),
+                                            6),
+                           "events": int(_EVENTS_BY[b])}
+                       for b in sorted(_EVENTS_BY)},
         "quarantined": len(_DIR_STATE["quarantine"]),
         "quarantine_adoptions": _QUARANTINED.value,
         "watchdog_timeouts": _TIMEOUTS.value,
@@ -690,3 +723,5 @@ def reset_stats() -> None:
         c.reset()
     with _lock:
         _SEEN.clear()
+        _SECONDS_BY.clear()
+        _EVENTS_BY.clear()

@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.stages import stage, staged
+
 #: ops whose intermediates are plain segment reductions (associative —
 #: eligible for local pre-combine before the shuffle, groupby.cpp:76-81)
 ASSOCIATIVE = {"sum", "count", "min", "max", "mean", "var", "std",
@@ -71,6 +73,7 @@ def _ident(kind: str, dt):
     return jnp.asarray(0, dt)  # sum
 
 
+@staged("segment_reduce")
 def _seg_apply(kind: str, values, g, ns: int, out_len: int):
     """Segment reduce over ROUTED gids ``g`` (trash segment included in
     ``ns``), returning the first ``out_len`` segments.  Dense one-hot
@@ -124,6 +127,7 @@ def _ftype(values):
 # prefix diffs are exact; float inputs accumulate in float64.
 # ---------------------------------------------------------------------------
 
+@staged("segment_starts")
 def grouped_starts(gids, first, mask, n_live, seg_cap: int):
     """First live row position of each group id, for grouped input (each
     group one contiguous run in the live prefix).  Slots past the last
@@ -188,7 +192,9 @@ def grouped_reduce(ops, values_list, vmasks, starts, n_live, key_datas,
 
     def prefix_lanes(src, islot, name):
         if jnp.issubdtype(src.dtype, jnp.floating):
-            ps = jnp.concatenate([jnp.zeros(1, src.dtype), jnp.cumsum(src)])
+            with stage("scan"):
+                ps = jnp.concatenate([jnp.zeros(1, src.dtype),
+                                      jnp.cumsum(src)])
             if src.dtype == jnp.float32 and not jax.config.jax_enable_x64:
                 u32_cols.append(_u32(ps))
                 recipes.append(("prefix", islot, name, "u32",
@@ -198,13 +204,15 @@ def grouped_reduce(ops, values_list, vmasks, starts, n_live, key_datas,
                 recipes.append(("prefix", islot, name, "f64",
                                 (len(f64_cols) - 1,), None))
             return
-        ps = jnp.concatenate([jnp.zeros(1, acc_i),
-                              jnp.cumsum(src.astype(acc_i))])
+        with stage("scan"):
+            ps = jnp.concatenate([jnp.zeros(1, acc_i),
+                                  jnp.cumsum(src.astype(acc_i))])
         narrow = name == "count" or (
             name == "sum" and value_narrow is not None
             and bool(value_narrow[islot]))
         narrow = narrow or np.dtype(ps.dtype).itemsize == 4
-        ls = lanes_mod._to_lanes(ps, narrow)   # 1 lane narrow, else (hi, lo)
+        with stage("pack"):
+            ls = lanes_mod._to_lanes(ps, narrow)   # 1 lane narrow, else (hi, lo)
         u32_cols.extend(ls)
         recipes.append(("prefix", islot, name, "u32",
                         tuple(range(len(u32_cols) - len(ls),
@@ -212,6 +220,7 @@ def grouped_reduce(ops, values_list, vmasks, starts, n_live, key_datas,
                         ("int32" if np.dtype(ps.dtype).itemsize == 4
                          else "int64", narrow)))
 
+    @staged("pack")
     def pass_lanes(src, kind, kslot):
         """Passthrough (gathered at start, no diff): key data / validity.
         Lane split/reconstruct delegates to lanes._to_lanes/_from_lanes
@@ -254,6 +263,7 @@ def grouped_reduce(ops, values_list, vmasks, starts, n_live, key_datas,
         if v is not None:
             pass_lanes(v, "kval", ki)
 
+    @staged("segment_gather")
     def gather_pair(cols):
         mat = jnp.stack(cols, axis=1)                  # (n+1, L)
         g = mat[starts]                                # THE gather
@@ -296,12 +306,13 @@ def grouped_reduce(ops, values_list, vmasks, starts, n_live, key_datas,
         # lane-major stack (a post-hoc transpose would cost ~700 ms; the
         # axis-0 stack is a plain concat); f64 side columns keep the XLA
         # gather below
-        mat_t = jnp.stack(u32_cols, axis=0)
-        g_u, win_ok = pg.windowed_take_t(mat_t, starts, use_window)
-        tail = jax.lax.dynamic_slice(
-            mat_t, (jnp.int32(0), jnp.minimum(n_live, jnp.int32(n))),
-            (len(u32_cols), 1))
-        gn_u = jnp.concatenate([g_u[:, 1:], tail], axis=1)
+        with stage("segment_gather"):
+            mat_t = jnp.stack(u32_cols, axis=0)
+            g_u, win_ok = pg.windowed_take_t(mat_t, starts, use_window)
+            tail = jax.lax.dynamic_slice(
+                mat_t, (jnp.int32(0), jnp.minimum(n_live, jnp.int32(n))),
+                (len(u32_cols), 1))
+            gn_u = jnp.concatenate([g_u[:, 1:], tail], axis=1)
     elif u32_cols:
         g_u, gn_u = gather_pair_multi(u32_cols)
     if f64_cols:
@@ -311,6 +322,7 @@ def grouped_reduce(ops, values_list, vmasks, starts, n_live, key_datas,
         src = gn_u if at_next else g_u
         return src[li] if windowed else src[:, li]
 
+    @staged("unpack")
     def prefix_recon(lane_ids, meta, at_next: bool):
         """Gathered prefix lanes -> accumulator value (i32/i64/f32/f64)."""
         if meta is None:  # f64 side channel
@@ -327,19 +339,22 @@ def grouped_reduce(ops, values_list, vmasks, starts, n_live, key_datas,
     kval_out = [None] * len(key_datas)
     for kind, slot, name, space, lane_ids, meta in recipes:
         if kind == "prefix":
-            d = prefix_recon(lane_ids, meta, True) \
-                - prefix_recon(lane_ids, meta, False)
-            if name == "count":
-                d = d.astype(_int_dtype())
+            hi = prefix_recon(lane_ids, meta, True)
+            lo = prefix_recon(lane_ids, meta, False)
+            with stage("segment_reduce"):
+                d = hi - lo
+                if name == "count":
+                    d = d.astype(_int_dtype())
             inters[slot][name] = d
         else:
             dt_name, nrw = meta
             if space == "f64":
                 v = g_f[:, lane_ids[0]]
             else:
-                v = lanes_mod._from_lanes([ucol(li, False)
-                                           for li in lane_ids],
-                                          dt_name, nrw)
+                with stage("unpack"):
+                    v = lanes_mod._from_lanes([ucol(li, False)
+                                               for li in lane_ids],
+                                              dt_name, nrw)
             if kind == "key":
                 key_out[slot] = v
             else:  # validity lanes are always planned as bool
@@ -396,6 +411,7 @@ def reduce_intermediates(inter: dict, gids, num_segments, mask=None):
             for k, v in inter.items()}
 
 
+@staged("segment_reduce")
 def finalize(op: str, inter: dict, ddof: int = 1):
     """Stage 5: intermediates → (result_values, result_validity|None)."""
     cnt = inter.get("count")
@@ -436,7 +452,8 @@ def nunique(value_keyops, gids, num_segments, mask=None):
     g, ns = _route(gids, num_segments, mask)
     keys = (g,) + value_keyops.ops
     kinds = ("i",) + value_keyops.kinds
-    srt = jax.lax.sort(keys, num_keys=len(keys), is_stable=False)
+    with stage("sort_keys"):
+        srt = jax.lax.sort(keys, num_keys=len(keys), is_stable=False)
     gs = srt[0]
     first = jnp.concatenate([jnp.ones(1, jnp.int32),
                              jnp.zeros(gs.shape[0] - 1, jnp.int32)]) \
@@ -451,7 +468,8 @@ def quantile(values, gids, num_segments, q: float, mask=None):
     f = values.astype(_ftype(values))
     g, ns = _route(gids, num_segments, mask)
     v = f if mask is None else jnp.where(mask, f, jnp.inf)
-    g_s, v_s = jax.lax.sort((g, v), num_keys=2, is_stable=False)
+    with stage("sort_keys"):
+        g_s, v_s = jax.lax.sort((g, v), num_keys=2, is_stable=False)
     cnt_all = _seg_apply("sum", jnp.ones_like(g, dtype=_int_dtype()), g,
                          ns, ns)
     offs_all = jnp.concatenate(

@@ -40,6 +40,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..utils.stages import stage, staged
 from .pack import KeyOps, concat_keyops, neighbor_flags
 
 
@@ -70,14 +71,16 @@ def join_sort_state(ko_l: KeyOps, ko_r: KeyOps, payloads: tuple = ()):
     cat = concat_keyops(ko_l, ko_r)
     n = cat.n
     idx = jnp.arange(n, dtype=jnp.int32)
-    sorted_all = jax.lax.sort(cat.ops + (idx,) + tuple(payloads),
-                              num_keys=len(cat.ops), is_stable=True)
+    with stage("sort_keys"):
+        sorted_all = jax.lax.sort(cat.ops + (idx,) + tuple(payloads),
+                                  num_keys=len(cat.ops), is_stable=True)
     nk = len(cat.ops)
     idx_s = sorted_all[nk]
     bnd = neighbor_flags(sorted_all[:nk], cat.kinds)
     return bnd, idx_s, tuple(sorted_all[nk + 1:])
 
 
+@staged("join_count")
 def join_carry(bnd, idx_s, live_cat, n_l: int, how: str) -> tuple:
     """Phase-1 geometry: returns ``(total, JoinCarry)`` with ``total`` the
     exact output row count (device scalar int32).
@@ -94,17 +97,20 @@ def join_carry(bnd, idx_s, live_cat, n_l: int, how: str) -> tuple:
     n = bnd.shape[0]
     pos = jnp.arange(n, dtype=jnp.int32)
     side = idx_s >= n_l
-    if live_cat is None:
-        lefts = (~side).astype(jnp.int32)
-        rights = side.astype(jnp.int32)
-    else:
-        live = live_cat[idx_s]
-        lefts = ((~side) & live).astype(jnp.int32)
-        rights = (side & live).astype(jnp.int32)
-    first = bnd.astype(bool) | (pos == 0)
+    with stage("liveness"):
+        if live_cat is None:
+            lefts = (~side).astype(jnp.int32)
+            rights = side.astype(jnp.int32)
+        else:
+            live = live_cat[idx_s]
+            lefts = ((~side) & live).astype(jnp.int32)
+            rights = (side & live).astype(jnp.int32)
+    with stage("boundaries"):
+        first = bnd.astype(bool) | (pos == 0)
 
-    s_l = jnp.cumsum(lefts).astype(jnp.int32)    # inclusive prefix counts
-    s_r = jnp.cumsum(rights).astype(jnp.int32)
+    with stage("scan"):
+        s_l = jnp.cumsum(lefts).astype(jnp.int32)  # inclusive prefix counts
+        s_r = jnp.cumsum(rights).astype(jnp.int32)
 
     emit_right = how == "right"
     keep_unmatched = how in ("left", "right", "outer")
@@ -114,13 +120,15 @@ def join_carry(bnd, idx_s, live_cat, n_l: int, how: str) -> tuple:
         # S_l exclusive at the group start, broadcast forward: s_l - lefts is
         # non-decreasing, so a cummax of its masked group-start values holds
         # each position's own-group start state
-        b_l = jax.lax.cummax(jnp.where(first, s_l - lefts, jnp.int32(0)))
+        with stage("scan"):
+            b_l = jax.lax.cummax(jnp.where(first, s_l - lefts, jnp.int32(0)))
 
     if emit_right:
         # group left-count = S_l[p] - S_l[group start - 1]; for a right row
         # all group lefts precede it (stability), so s_l[p] includes them all
         cnt = (s_l - b_l).astype(jnp.int32)
-        mstart = jax.lax.cummax(jnp.where(first, pos, jnp.int32(0)))
+        with stage("scan"):
+            mstart = jax.lax.cummax(jnp.where(first, pos, jnp.int32(0)))
         emits = rights != 0
     else:
         # S_l/S_r at the group END, broadcast backward: the prefixes are
@@ -128,8 +136,9 @@ def join_carry(bnd, idx_s, live_cat, n_l: int, how: str) -> tuple:
         # gives each position its own group's end state
         ebnd = jnp.concatenate([first[1:], jnp.ones(1, bool)])
         imax = jnp.int32(2**31 - 1)
-        e_l = jax.lax.cummin(jnp.where(ebnd, s_l, imax), reverse=True)
-        e_r = jax.lax.cummin(jnp.where(ebnd, s_r, imax), reverse=True)
+        with stage("scan"):
+            e_l = jax.lax.cummin(jnp.where(ebnd, s_l, imax), reverse=True)
+            e_r = jax.lax.cummin(jnp.where(ebnd, s_r, imax), reverse=True)
         t_l = e_l - (s_l - lefts)            # lefts in [p .. end]
         cnt = e_r - (s_r - rights)           # rights in [p .. end]
         mstart = pos + t_l                   # first right position of group
@@ -138,7 +147,8 @@ def join_carry(bnd, idx_s, live_cat, n_l: int, how: str) -> tuple:
     eff = jnp.where(emits,
                     jnp.maximum(cnt, 1) if keep_unmatched else cnt,
                     0).astype(jnp.int32)
-    csum = jnp.cumsum(eff)
+    with stage("scan"):
+        csum = jnp.cumsum(eff)
     offs = (csum - eff).astype(jnp.int32)
     total = (csum[-1] if n > 0 else jnp.int32(0)).astype(jnp.int32)
 
@@ -169,6 +179,7 @@ class JoinTake(NamedTuple):
     extra: tuple          # carried emit-side u32 lanes at the owning row
 
 
+@staged("join_expand")
 def join_take(carry: JoinCarry, n_l: int, how: str, out_cap: int,
               extra: tuple = (), carry_emit: bool = False,
               carry_match: bool = False, emit_idx: bool = False,
@@ -214,7 +225,8 @@ def join_take(carry: JoinCarry, n_l: int, how: str, out_cap: int,
     # the cummax fill assigns them their predecessor's owner
     scat = jnp.where(eff > 0, offs, jnp.int32(out_cap))
     p0 = jnp.zeros(out_cap, jnp.int32).at[scat].set(pos, mode="drop")
-    p_of_k = jax.lax.cummax(p0)
+    with stage("scan"):
+        p_of_k = jax.lax.cummax(p0)
 
     need_cnt = how != "inner"
     need_own_idx = (not carry_emit) or emit_idx
@@ -253,7 +265,8 @@ def join_take(carry: JoinCarry, n_l: int, how: str, out_cap: int,
 
     total = total_main
     if how == "outer":
-        unpos = (jnp.cumsum(un) - un).astype(jnp.int32)
+        with stage("scan"):
+            unpos = (jnp.cumsum(un) - un).astype(jnp.int32)
         slot = jnp.where(un > 0, total_main + unpos, jnp.int32(out_cap))
         r_take = r_take.at[slot].set(idx_s - n_l, mode="drop")
         total = total_main + jnp.sum(un, dtype=jnp.int32)

@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.stages import staged
+
 
 class ColLanes(NamedTuple):
     """Static description of one column's slot in the lane matrix."""
@@ -121,6 +123,7 @@ def _from_lanes(lanes, dtype: str, narrow: bool = False):
     return lanes[0].astype(jdt)
 
 
+@staged("pack")
 def pack_lanes(spec: LaneSpec, datas, valids):
     """(n, spec.n_lanes) uint32 lane matrix from parallel column arrays
     (laneless f64 columns contribute only their validity bit).
@@ -142,6 +145,7 @@ def pack_lanes(spec: LaneSpec, datas, valids):
     return jnp.stack(lanes, axis=1)
 
 
+@staged("unpack")
 def unpack_lanes(spec: LaneSpec, mat):
     """Inverse of :func:`pack_lanes`: (datas, valids) tuples — laneless
     (f64) columns yield None data (moved separately); valids entries are
@@ -169,6 +173,7 @@ def slice_lanes(spec: LaneSpec, mat, start, window: int):
                                  (window, spec.n_lanes))
 
 
+@staged("unpack")
 def unpack_column(spec: LaneSpec, mat, i: int):
     """Lazily unpack ONE column ``i`` from the lane matrix: ``(data,
     valid)``, either None when the column is laneless (f64 side channel) /
@@ -186,6 +191,7 @@ def unpack_column(spec: LaneSpec, mat, i: int):
     return d, v
 
 
+@staged("gather_rows")
 def gather_laneless(spec: LaneSpec, datas, take) -> dict:
     """{col_index: gathered data} for ONLY the laneless (f64) columns of
     ``spec`` — one batched (n, K) f64 matrix gather.  Used by the join's
@@ -202,6 +208,7 @@ def gather_laneless(spec: LaneSpec, datas, take) -> dict:
     return {i: fmat[:, j] for j, i in enumerate(idxs)}
 
 
+@staged("gather_rows")
 def gather_columns(spec: LaneSpec, datas, valids, take):
     """Move whole rows by index: ONE (n, L) matrix gather for every laneable
     column + validity bits, plus ONE (n, K) f64 matrix gather batching all

@@ -47,6 +47,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.stages import stage, staged
+
 NULL_FIRST = 0
 NULL_LAST = 2
 
@@ -139,6 +141,7 @@ def _sort_value(x: jax.Array, descending: bool,
     raise TypeError(f"unsortable dtype {dt}")
 
 
+@staged("pack")
 def key_operands(datas, validities=None, row_mask=None, descendings=None,
                  nulls_position: int = NULL_LAST, pad_key: int = 4,
                  need_null_flags=None, narrow32=None) -> KeyOps:
@@ -235,6 +238,7 @@ def op_eq(a, b, kind: str):
     return a == b
 
 
+@staged("boundaries")
 def neighbor_flags(sorted_ops, kinds):
     """int32 flags: row i != row i-1 under the key tuple (row 0 → 0)."""
     n = sorted_ops[0].shape[0]
@@ -251,16 +255,22 @@ def dense_rank(keyops: KeyOps):
     the keys — an order-preserving perfect hash over this batch)."""
     n = keyops.n
     idx = jnp.arange(n, dtype=jnp.int32)
-    sorted_all = jax.lax.sort(keyops.ops + (idx,), num_keys=len(keyops.ops),
-                              is_stable=True)
+    with stage("sort_keys"):
+        sorted_all = jax.lax.sort(keyops.ops + (idx,),
+                                  num_keys=len(keyops.ops), is_stable=True)
     sidx = sorted_all[-1]
-    gid_sorted = jnp.cumsum(neighbor_flags(sorted_all[:-1], keyops.kinds))
-    gids = jnp.zeros(n, jnp.int32).at[sidx].set(gid_sorted.astype(jnp.int32))
+    flags = neighbor_flags(sorted_all[:-1], keyops.kinds)
+    with stage("scan"):
+        gid_sorted = jnp.cumsum(flags)
+    with stage("gather_rows"):
+        gids = jnp.zeros(n, jnp.int32).at[sidx].set(
+            gid_sorted.astype(jnp.int32))
     n_groups = (jnp.where(n > 0, gid_sorted[-1] + 1, 0).astype(jnp.int32)
                 if n > 0 else jnp.int32(0))
     return gids, n_groups
 
 
+@staged("boundaries")
 def row_neq_prev(datas, validities=None, narrow32=None):
     """(n,) bool: row i's key tuple differs from row i-1's (row 0 -> False).
     Null-aware (null == null, null != value) and float-total (NaN == NaN,
@@ -296,7 +306,8 @@ def grouped_gids(datas, validities, mask, narrow32=None):
     pos = jnp.arange(n, dtype=jnp.int32)
     first0 = pos == 0
     bnd = (row_neq_prev(datas, validities, narrow32) | first0) & mask
-    gid = jnp.cumsum(bnd.astype(jnp.int32)).astype(jnp.int32) - 1
+    with stage("scan"):
+        gid = jnp.cumsum(bnd.astype(jnp.int32)).astype(jnp.int32) - 1
     n_groups = jnp.max(jnp.where(mask, gid, -1)) + 1
     return jnp.where(mask, gid, n), n_groups.astype(jnp.int32), bnd
 

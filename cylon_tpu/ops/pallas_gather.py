@@ -58,6 +58,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils.stages import staged
+
 #: output rows per grid step
 TILE = 256
 #: don't attempt the windowed path below this measured density (average
@@ -159,6 +161,7 @@ def _pallas_take(mat_t, idx2, ws, window: int, interpret: bool):
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="cylon_windowed_take",
     )(ws, idx2, mat_t)
 
 
@@ -169,6 +172,7 @@ def supported(n_rows: int, seg_cap: int, n_lanes: int, window: int) -> bool:
             and n_rows >= window and n_lanes >= 1)
 
 
+@staged("segment_gather")
 def windowed_take_t(mat_t, idx, window: int, interpret: bool | None = None):
     """``mat_t[:, idx]`` for SORTED int32 ``idx`` into a LANE-MAJOR (L, M)
     u32 ``mat_t``.  Returns ``(out, ok)``: out is (L, S) — row l holds

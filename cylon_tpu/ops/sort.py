@@ -17,8 +17,10 @@ import jax
 import jax.numpy as jnp
 
 from ..utils.cache import jit
+from ..utils.stages import staged
 
 
+@staged("sort_keys")
 def sort_permutation(keyops) -> jax.Array:
     """Stable argsort of rows under a :class:`~cylon_tpu.ops.pack.KeyOps`
     lexicographic operand list."""
@@ -29,11 +31,13 @@ def sort_permutation(keyops) -> jax.Array:
     return out[-1]
 
 
+@staged("gather_rows")
 def take_data(data: jax.Array, idx: jax.Array) -> jax.Array:
     """Gather rows; idx must be in-bounds (a permutation/selection)."""
     return data[idx]
 
 
+@staged("gather_rows")
 def take_with_nulls(data: jax.Array, validity, idx: jax.Array):
     """Gather rows where idx == -1 yields a null (outer-join null side).
     Returns (data, validity) with validity None when provably all-valid."""
@@ -47,6 +51,7 @@ def take_with_nulls(data: jax.Array, validity, idx: jax.Array):
 
 
 @partial(jit, static_argnames=("out_cap",))
+@staged("compact")
 def compact_by_flag(flag: jax.Array, out_cap: int):
     """Indices of rows with flag set, in original row order, padded to
     ``out_cap`` with -1; plus the true count.  The static-shape analog of the

@@ -20,6 +20,7 @@ from ..core.dtypes import LogicalType, physical_np_dtype
 from ..core.table import Table
 from ..ctx.context import ROW_AXIS, CylonEnv
 from ..status import CylonTypeError, InvalidError
+from ..utils.stages import staged
 
 ROW = P(ROW_AXIS)
 REP = P()
@@ -76,6 +77,7 @@ def sample_positions(n, m: int, cap: int) -> jax.Array:
     return jnp.clip(idx, 0, cap - 1)
 
 
+@staged("liveness")
 def live_mask(vc: jax.Array, cap: int) -> jax.Array:
     """Per-shard row-liveness mask (call inside shard_map): the first
     ``vc[my_rank]`` rows of the shard are real, the rest padding."""

@@ -46,6 +46,7 @@ from ..ops import groupby as gbk
 from ..ops import lanes
 from ..utils import timing
 from ..utils.host import host_array
+from ..utils.stages import stage, staged
 from .common import REP, ROW, BoundedCache
 
 shard_map = jax.shard_map
@@ -127,31 +128,40 @@ def _fused_fn(mesh: Mesh, n_l: int, all_live: bool, lspec, rspec,
         pos = jnp.arange(N, dtype=jnp.int32)
         my = jax.lax.axis_index(ROW_AXIS)
         side_r = idx_s >= n_l
-        if all_live:
-            n_live = jnp.int32(N)
-            live = jnp.ones(N, bool)
-        else:
-            n_live = (vcl[my] + vcr[my]).astype(jnp.int32)
-            live = pos < n_live
-        lefts_b = ~side_r & live
-        rights_b = side_r & live
-        lefts = lefts_b.astype(jnp.int32)
-        rights = rights_b.astype(jnp.int32)
-        first = bnd.astype(bool) | (pos == 0)
-        s_l = jnp.cumsum(lefts).astype(jnp.int32)
-        s_r = jnp.cumsum(rights).astype(jnp.int32)
-        ebnd = jnp.concatenate([first[1:], jnp.ones(1, bool)])
+        with stage("liveness"):
+            if all_live:
+                n_live = jnp.int32(N)
+                live = jnp.ones(N, bool)
+            else:
+                n_live = (vcl[my] + vcr[my]).astype(jnp.int32)
+                live = pos < n_live
+            lefts_b = ~side_r & live
+            rights_b = side_r & live
+            lefts = lefts_b.astype(jnp.int32)
+            rights = rights_b.astype(jnp.int32)
+        with stage("boundaries"):
+            first = bnd.astype(bool) | (pos == 0)
+            ebnd = jnp.concatenate([first[1:], jnp.ones(1, bool)])
         imax = jnp.int32(2**31 - 1)
-        e_l = jax.lax.cummin(jnp.where(ebnd, s_l, imax), reverse=True)
-        e_r = jax.lax.cummin(jnp.where(ebnd, s_r, imax), reverse=True)
-        b_l = jax.lax.cummax(jnp.where(first, s_l - lefts, jnp.int32(0)))
-        b_r = jax.lax.cummax(jnp.where(first, s_r - rights, jnp.int32(0)))
-        l_grp = e_l - b_l        # own group's left count, per position
-        r_grp = e_r - b_r
-        keep = (l_grp > 0) & (r_grp > 0) & live
-        kstart = first & keep
-        kgid = jnp.cumsum(kstart.astype(jnp.int32)).astype(jnp.int32) - 1
-        n_groups = (jnp.max(jnp.where(keep, kgid, -1)) + 1).astype(jnp.int32)
+        with stage("scan"):
+            s_l = jnp.cumsum(lefts).astype(jnp.int32)
+            s_r = jnp.cumsum(rights).astype(jnp.int32)
+            e_l = jax.lax.cummin(jnp.where(ebnd, s_l, imax), reverse=True)
+            e_r = jax.lax.cummin(jnp.where(ebnd, s_r, imax), reverse=True)
+            b_l = jax.lax.cummax(jnp.where(first, s_l - lefts, jnp.int32(0)))
+            b_r = jax.lax.cummax(jnp.where(first, s_r - rights,
+                                           jnp.int32(0)))
+        with stage("join_count"):
+            l_grp = e_l - b_l    # own group's left count, per position
+            r_grp = e_r - b_r
+            keep = (l_grp > 0) & (r_grp > 0) & live
+        with stage("boundaries"):
+            kstart = first & keep
+        with stage("scan"):
+            kgid = jnp.cumsum(kstart.astype(jnp.int32)).astype(jnp.int32) - 1
+        with stage("boundaries"):
+            n_groups = (jnp.max(jnp.where(keep, kgid, -1)) + 1).astype(
+                jnp.int32)
         # empty segment slots point at the END OF THE LIVE PREFIX, not at
         # N: every dead row is masked out of every lane, so the prefix
         # there already holds the full totals — and the tile of starts
@@ -159,15 +169,19 @@ def _fused_fn(mesh: Mesh, n_l: int, all_live: bool, lspec, rspec,
         # capacity pad (shape-family padding, N - n_live ~ 1M rows at 32M
         # rows/side, overflowed every window and silently lost the
         # windowed gather)
-        starts = jnp.full(seg_cap, n_live, jnp.int32).at[
-            jnp.where(kstart, kgid, jnp.int32(seg_cap))].set(pos, mode="drop")
+        with stage("segment_starts"):
+            starts = jnp.full(seg_cap, n_live, jnp.int32).at[
+                jnp.where(kstart, kgid, jnp.int32(seg_cap))].set(
+                    pos, mode="drop")
 
         nl_lanes = lspec.n_lanes
-        lmat = jnp.stack(pl_s[:nl_lanes], axis=1)
+        with stage("unpack"):
+            lmat = jnp.stack(pl_s[:nl_lanes], axis=1)
+            rmat = jnp.stack(pl_s[nl_lanes:], axis=1)
         ldat, lval = lanes.unpack_lanes(lspec, lmat)
-        rmat = jnp.stack(pl_s[nl_lanes:], axis=1)
         rdat, rval = lanes.unpack_lanes(rspec, rmat)
 
+        @staged("liveness")
         def value_of(side, ci):
             d = ldat[ci] if side == "l" else rdat[ci]
             v = lval[ci] if side == "l" else rval[ci]
@@ -204,20 +218,21 @@ def _fused_fn(mesh: Mesh, n_l: int, all_live: bool, lspec, rspec,
         for i, (side, ci, op) in enumerate(vspecs):
             mult = (r_cnt if side == "l" else l_cnt)
             inter = inters[i]
-            if op == "sum":
-                s = inter["sum"]
-                d, v = s * mult.astype(s.dtype), None
-            elif op == "sumsq":
-                s = inter["sumsq"]
-                d, v = s * mult.astype(s.dtype), None
-            elif op == "count":
-                d, v = inter["count"] * mult, None
-            elif op == "mean":
-                d, v = gbk.finalize("mean", inter, ddof)
-            else:  # var/std: moments scale by mult; ddof sees the full count
-                scaled = {k: (a * mult.astype(a.dtype) if k != "count"
-                              else a * mult) for k, a in inter.items()}
-                d, v = gbk.finalize(op, scaled, ddof)
+            with stage("segment_reduce"):
+                if op == "sum":
+                    s = inter["sum"]
+                    d, v = s * mult.astype(s.dtype), None
+                elif op == "sumsq":
+                    s = inter["sumsq"]
+                    d, v = s * mult.astype(s.dtype), None
+                elif op == "count":
+                    d, v = inter["count"] * mult, None
+                elif op == "mean":
+                    d, v = gbk.finalize("mean", inter, ddof)
+                else:  # var/std: moments scale by mult; ddof sees the count
+                    scaled = {k: (a * mult.astype(a.dtype) if k != "count"
+                                  else a * mult) for k, a in inter.items()}
+                    d, v = gbk.finalize(op, scaled, ddof)
             res_d.append(d)
             res_v.append(v)
         # n_groups and the windowed-gather span flag ride ONE output so

@@ -28,6 +28,7 @@ from ..core.table import Table
 from ..ctx.context import ROW_AXIS
 from ..ops import groupby as gbk
 from ..ops import pack
+from ..utils.stages import stage, staged
 from ..status import InvalidError
 from ..utils import timing
 from ..utils.host import host_array
@@ -159,6 +160,7 @@ def _group_keys(by_datas, by_valids, vc, grouped: bool = False,
     return gids, n_groups.astype(jnp.int32), mask, None
 
 
+@staged("liveness")
 def _value_mask(mask, val, valid):
     """Row mask for aggregation payloads: live row AND valid AND (for float
     payloads) not-NaN — pandas skipna=True semantics (NaN is stored as a
@@ -194,6 +196,7 @@ def _plan_vspec(val_cols, by_cols, narrow, n_inters: int = 1):
     return cand if sort_ns <= scatter_ns else None
 
 
+@staged("gather_rows")
 def _rep_keys(by_datas, by_valids, gids, seg_cap):
     """Representative key row per group (first source index)."""
     rep = gbk.group_first_index(gids, seg_cap)
@@ -235,15 +238,20 @@ def _sort_state(vc, by_datas, by_valids, val_datas, val_valids, narrow,
     nk = len(ko.ops)
     nl = vspec.n_lanes
     lane_ops = tuple(vmat[:, j] for j in range(nl)) if vmat is not None else ()
-    sorted_all = jax.lax.sort(ko.ops + lane_ops + extra,
-                              num_keys=nk, is_stable=False)
+    with stage("sort_keys"):
+        sorted_all = jax.lax.sort(ko.ops + lane_ops + extra,
+                                  num_keys=nk, is_stable=False)
     pos = jnp.arange(cap, dtype=jnp.int32)
-    mask = pos < n_live
-    first = (pack.neighbor_flags(sorted_all[:nk], ko.kinds)
-             .astype(bool) | (pos == 0)) & mask
-    gid = jnp.cumsum(first.astype(jnp.int32)).astype(jnp.int32) - 1
-    n_groups = (jnp.max(jnp.where(mask, gid, -1)) + 1).astype(jnp.int32)
-    gids = jnp.where(mask, gid, cap)
+    with stage("liveness"):
+        mask = pos < n_live
+    flags = pack.neighbor_flags(sorted_all[:nk], ko.kinds)
+    with stage("boundaries"):
+        first = (flags.astype(bool) | (pos == 0)) & mask
+    with stage("scan"):
+        gid = jnp.cumsum(first.astype(jnp.int32)).astype(jnp.int32) - 1
+    with stage("boundaries"):
+        n_groups = (jnp.max(jnp.where(mask, gid, -1)) + 1).astype(jnp.int32)
+        gids = jnp.where(mask, gid, cap)
     if nl:
         smat = jnp.stack(sorted_all[nk:nk + nl], axis=1)
         sdatas, svalids = lanes.unpack_lanes(vspec, smat)
@@ -252,9 +260,10 @@ def _sort_state(vc, by_datas, by_valids, val_datas, val_valids, narrow,
         sdatas = [None] * len(vspec.cols)
         svalids = [None] * len(vspec.cols)
     if laneless:
-        perm = sorted_all[-1].astype(jnp.int32)
-        fmat = jnp.stack([all_datas[i] for i in laneless], axis=1)
-        fsorted = fmat[perm]
+        with stage("gather_rows"):
+            perm = sorted_all[-1].astype(jnp.int32)
+            fmat = jnp.stack([all_datas[i] for i in laneless], axis=1)
+            fsorted = fmat[perm]
         for j, i in enumerate(laneless):
             sdatas[i] = fsorted[:, j]
     nv = len(val_datas)
@@ -508,6 +517,7 @@ def _raw_fn(mesh: Mesh, specs: tuple, seg_cap: int, ddof: int, grouped: bool,
 
 @program_cache()
 def _shrink_fn(mesh: Mesh, new_cap: int):
+    @staged("compact")
     def per_shard(d):
         return d[:new_cap]
 

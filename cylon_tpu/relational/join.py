@@ -41,6 +41,7 @@ from ..ops import pack
 from ..status import InvalidError
 from ..utils import timing
 from ..utils.host import host_array
+from ..utils.stages import stage, staged
 from .common import (PAD_L, PAD_R, REP, ROW, BoundedCache, build_table,
                      check_same_env,
                      sample_positions,
@@ -269,6 +270,7 @@ def _shuffle_for_join(lwork: Table, rwork: Table, left_on, right_on,
             False)
 
 
+@staged("liveness")
 def _live_cat(vcl, vcr, cap_l: int, cap_r: int):
     """Concat-row liveness for (left ++ right) per shard."""
     return jnp.concatenate([live_mask(vcl, cap_l), live_mask(vcr, cap_r)])
@@ -299,8 +301,9 @@ def _sorted_state(vcl, vcr, l_datas, l_valids, r_datas, r_valids,
                              pad_key=PAD_R, need_null_flags=need_nf,
                              narrow32=narrow)
     bnd, idx_s, pl_s = joink.join_sort_state(ko_l, ko_r, payloads)
-    live_cat = None if all_live \
-        else jnp.concatenate([mask_l, mask_r])
+    with stage("liveness"):
+        live_cat = None if all_live \
+            else jnp.concatenate([mask_l, mask_r])
     return bnd, idx_s, live_cat, pl_s
 
 
@@ -368,14 +371,16 @@ def _count_fn(mesh: Mesh, how: str, narrow: tuple,
         payloads = ()
         if lspec is not None:
             lmat = lanes.pack_lanes(lspec, lg_cols, lg_valids)
-            zr = jnp.zeros(cap_r, jnp.uint32)
-            payloads += tuple(jnp.concatenate([lmat[:, j], zr])
-                              for j in range(lspec.n_lanes))
+            with stage("pack"):
+                zr = jnp.zeros(cap_r, jnp.uint32)
+                payloads += tuple(jnp.concatenate([lmat[:, j], zr])
+                                  for j in range(lspec.n_lanes))
         if rspec is not None:
             rmat = lanes.pack_lanes(rspec, rg_cols, rg_valids)
-            zl = jnp.zeros(cap_l, jnp.uint32)
-            payloads += tuple(jnp.concatenate([zl, rmat[:, j]])
-                              for j in range(rspec.n_lanes))
+            with stage("pack"):
+                zl = jnp.zeros(cap_l, jnp.uint32)
+                payloads += tuple(jnp.concatenate([zl, rmat[:, j]])
+                                  for j in range(rspec.n_lanes))
         bnd, idx_s, live, pl_s = _sorted_state(
             vcl, vcr, l_datas, l_valids, r_datas, r_valids, narrow, payloads,
             all_live)

@@ -48,6 +48,7 @@ from ..core.column import Column
 from ..core.table import Table
 from ..ctx.context import ROW_AXIS
 from ..utils.cache import jit, program_cache
+from ..utils.stages import staged
 from .common import REP, ROW
 
 shard_map = jax.shard_map
@@ -65,6 +66,7 @@ def _piece_pack_fn(mesh: Mesh, spec, pad: int, donate: bool = False):
     live together."""
     from ..ops import lanes
 
+    @staged("piece_pack")
     def per_shard(datas, valids):
         mat = lanes.pack_lanes(spec, list(datas), list(valids))
         if pad:
@@ -79,6 +81,7 @@ def _piece_pack_fn(mesh: Mesh, spec, pad: int, donate: bool = False):
 
 @program_cache()
 def _pad_rows_fn(mesh: Mesh, pad: int, donate: bool = False):
+    @staged("piece_pad")
     def per_shard(d):
         return jnp.concatenate([d, jnp.zeros((pad,), d.dtype)]) if pad else d
 
@@ -97,6 +100,7 @@ def _piece_slice_fn(mesh: Mesh, spec, piece_cap: int):
     has_mat = spec.n_lanes > 0
     n_f64 = sum(1 for cl in spec.cols if not cl.lanes)
 
+    @staged("piece_slice")
     def per_shard(starts, *arrs):
         my = jax.lax.axis_index(ROW_AXIS)
         s = starts[my]

@@ -32,7 +32,9 @@ from ..ctx.context import ROW_AXIS
 from ..ops import pack
 from ..ops import sort as sortk
 from ..status import InvalidError
+from ..utils import timing
 from ..utils.host import host_array
+from ..utils.stages import stage
 from .common import (PAD_L, REP, ROW, col_arrays, live_mask,
                      narrow32_flags, rebuild_like, sample_positions)
 from .repart import exchange_by_targets
@@ -99,15 +101,17 @@ def _local_sort_fn(mesh: Mesh, descendings: tuple, nulls_position: int,
         if need_perm:
             payloads += (jnp.arange(cap, dtype=jnp.int32),)
         nk = len(ko.ops)
-        sorted_all = jax.lax.sort(ko.ops + payloads, num_keys=nk,
-                                  is_stable=True)
+        with stage("sort_keys"):
+            sorted_all = jax.lax.sort(ko.ops + payloads, num_keys=nk,
+                                      is_stable=True)
         smat = jnp.stack(sorted_all[nk:nk + vspec.n_lanes], axis=1)
         out_d, out_v = lanes.unpack_lanes(vspec, smat)
         out_d, out_v = list(out_d), list(out_v)
         if need_perm:
             perm = sorted_all[-1]
-            for i in f64_idx:
-                out_d[i] = datas[i][perm]
+            with stage("gather_rows"):
+                for i in f64_idx:
+                    out_d[i] = datas[i][perm]
         return tuple(out_d), tuple(out_v)
 
     jit_kwargs = {"donate_argnums": (1, 2)} if donate else {}
@@ -387,14 +391,16 @@ def _sort_table_impl(table: Table, by: list, ascending,
             pn.annotate(route="sample_sort", num_samples=m,
                         splitters=w - 1)
             _plan.profile_keys(pn, table, by)
-        sample_ops, live = _sample_fn(env.mesh, m, descendings, npos,
-                                      narrow_keys)(
-            vc, by_datas, by_valids)
-        splitters = _pick_splitters(sample_ops, live, w)
-        tgt = _target_fn(env.mesh, descendings, npos, narrow_keys)(
-            vc, by_datas, by_valids, splitters)
-        counts = shuffle.count_targets(env.mesh, tgt)
-        table = exchange_by_targets(table, tgt, counts)
+        with timing.region("sort.sample"):
+            sample_ops, live = _sample_fn(env.mesh, m, descendings, npos,
+                                          narrow_keys)(
+                vc, by_datas, by_valids)
+            splitters = _pick_splitters(sample_ops, live, w)
+        with timing.region("sort.exchange"):
+            tgt = _target_fn(env.mesh, descendings, npos, narrow_keys)(
+                vc, by_datas, by_valids, splitters)
+            counts = shuffle.count_targets(env.mesh, tgt)
+            table = exchange_by_targets(table, tgt, counts)
 
     # ---- local sort per shard -------------------------------------------
     out = local_sort_table(table, by, ascending, nulls_position)
@@ -443,9 +449,10 @@ def local_sort_table(table: Table, by, ascending=True,
     narrow = narrow32_flags(by_cols)
     vspec = table_lane_spec([c for _, c in items])
     f64_idx = tuple(i for i, c in enumerate(vspec.cols) if not c.lanes)
-    out_d, out_v = _local_sort_fn(env.mesh, descendings, npos, narrow,
-                                  vspec, f64_idx, by_idx, donate)(
-        vc, datas, valids)
+    with timing.region("sort.local"):
+        out_d, out_v = _local_sort_fn(env.mesh, descendings, npos, narrow,
+                                      vspec, f64_idx, by_idx, donate)(
+            vc, datas, valids)
     cols = {}
     for (n, c), d, v in zip(items, out_d, out_v):
         cols[n] = Column(d, c.type, v, c.dictionary, bounds=c.bounds)

@@ -54,6 +54,55 @@ _TABLES: "OrderedDict[int, tuple]" = OrderedDict()
 
 _lock = threading.RLock()
 
+#: the builder whose factory is running on this thread (``program_cache``
+#: sets it around ``fn(mesh, ...)``): the ``jit`` calls inside are that
+#: builder's programs and take its name
+_building = threading.local()
+
+
+def short_name(qualified: str) -> str:
+    """``join__count_fn`` of ``cylon_tpu.relational.join._count_fn``:
+    ``<module's last part>_<function>``, the name a builder's programs
+    carry on the device (HLO module ``jit_join__count_fn``, the trace's
+    ``XLA Modules`` line) and in the ``launch.<builder>`` spans."""
+    mod, _, fn = qualified.rpartition(".")
+    return f"{mod.rpartition('.')[2]}_{fn}" if mod else fn
+
+
+def named_for_device(fun, builder: str | None = None):
+    """``fun`` behind a wrapper whose ``__name__`` is the builder's short
+    name (a module-level kernel's own ``<module>_<function>`` where no
+    builder is being built), run under its family's root stage
+    (utils/stages.ROOT_OF_MODULE).  ``jax.jit`` names the HLO module after
+    ``__name__``; ``functools.wraps`` leaves ``__wrapped__`` for signature
+    inspection (static/donated argument names) and the inner function —
+    ``per_shard`` — keeps its own name.  Idempotent."""
+    if getattr(fun, "_cylon_named", False):
+        return fun
+    import functools
+    import re
+    from . import stages
+    if builder is None:
+        qual = getattr(fun, "__qualname__", None)
+        if not qual:
+            return fun
+        builder = (f"{getattr(fun, '__module__', None) or ''}."
+                   f"{qual.replace('.<locals>.', '_')}")
+    name = re.sub(r"\W", "_", short_name(builder))
+    root = stages.ROOT_OF_MODULE.get(builder.rpartition(".")[0]
+                                     .rpartition(".")[2])
+
+    if root is not None:
+        named = stages.staged(root)(fun)
+    else:
+        @functools.wraps(fun)
+        def named(*args, **kwargs):
+            return fun(*args, **kwargs)
+
+    named.__name__ = name
+    named._cylon_named = True
+    return named
+
 
 def _track_table(mesh, table) -> None:
     """Register a mesh's table in the global LRU; evict the oldest mesh's
@@ -107,18 +156,21 @@ class _LazyJit:
     # __weakref__: jax weakrefs callables it is handed (jit cache keys,
     # shard_map trace bookkeeping) — a slotted class without it fails
     # deep inside tracing with "cannot create weak reference"
-    __slots__ = ("_fun", "_kw", "_prog", "__weakref__")
+    __slots__ = ("_fun", "_kw", "_prog", "_builder", "__weakref__")
 
     def __init__(self, fun, kw):
         self._fun = fun
         self._kw = kw
         self._prog = None
+        # the builder being built NOW: by the first call it is long gone
+        self._builder = getattr(_building, "name", None)
 
     def _resolve(self):
         prog = self._prog
         if prog is None:
             from ..exec.compiler import jit as _jit
-            prog = self._prog = _jit(self._fun, **self._kw)
+            prog = self._prog = _jit(
+                named_for_device(self._fun, self._builder), **self._kw)
         return prog
 
     def __call__(self, *args, **kwargs):
@@ -181,7 +233,12 @@ def program_cache(maxsize: int | None = None):
                 compiler.on_hit(mesh, name, key)
                 return hit
             runtime.note_builder(name, key, miss=True)
-            built = fn(mesh, *args, **kwargs)
+            prev = getattr(_building, "name", None)
+            _building.name = name
+            try:
+                built = fn(mesh, *args, **kwargs)
+            finally:
+                _building.name = prev
             # the retrace identity includes the mesh: the same static key
             # on another mesh (tests run 1/4/8-rank worlds side by side)
             # legitimately compiles once per mesh

@@ -12,21 +12,34 @@ host pull of a possibly-sharded device array in the framework goes through
 
 from __future__ import annotations
 
+import contextlib
+
 import numpy as np
 
+from . import timing
 
-def _sanctioned_pull(kind: str):
+
+def _nbytes(xs) -> int:
+    return sum(int(getattr(x, "nbytes", 0) or 0) for x in xs)
+
+
+@contextlib.contextmanager
+def _sanctioned_pull(kind: str, nbytes: int = 0):
     """The DOCUMENTED device→host boundary: every framework host pull runs
     inside this scope, so test sessions can run under
     ``jax.transfer_guard_device_to_host("disallow")``
     (``CYLON_TPU_TRACECHECK=1``) and still permit the sidecar pulls this
     module funnels — any implicit D2H transfer *outside* this funnel is a
     trace-safety violation.  Also feeds the per-op transfer ledger
-    (:func:`cylon_tpu.analysis.runtime.note_transfer`, rule RT303)."""
+    (:func:`cylon_tpu.analysis.runtime.note_transfer`, rule RT303), and is
+    the ``pull.<kind>`` span (``bytes=``): the host time a query spends
+    WAITING for the device inside the program, and how often."""
     import jax
     from ..analysis import runtime
     runtime.note_transfer(kind)
-    return jax.transfer_guard_device_to_host("allow")
+    with timing.span("pull." + kind, bytes=int(nbytes)), \
+            jax.transfer_guard_device_to_host("allow"):
+        yield
 
 
 def host_array(x) -> np.ndarray:
@@ -37,9 +50,9 @@ def host_array(x) -> np.ndarray:
     if jax.process_count() > 1 and not getattr(x, "is_fully_addressable",
                                                True):
         from jax.experimental import multihost_utils
-        with _sanctioned_pull("host_array"):
+        with _sanctioned_pull("host_array", _nbytes((x,))):
             return np.asarray(multihost_utils.process_allgather(x, tiled=True))
-    with _sanctioned_pull("host_array"):
+    with _sanctioned_pull("host_array", _nbytes((x,))):
         return np.asarray(x)
 
 
@@ -53,7 +66,7 @@ def host_arrays(xs) -> list:
     if jax.process_count() > 1:
         return [None if x is None else host_array(x) for x in xs]
     devs = [x for x in xs if x is not None and not isinstance(x, np.ndarray)]
-    with _sanctioned_pull("host_arrays"):
+    with _sanctioned_pull("host_arrays", _nbytes(devs)):
         fetched = iter(jax.device_get(devs))
     return [x if x is None or isinstance(x, np.ndarray) else next(fetched)
             for x in xs]
@@ -72,7 +85,7 @@ def host_shard_blocks(x, world: int) -> list:
         return [x]
     per = x.shape[0] // world
     blocks: list = [None] * world
-    with _sanctioned_pull("host_shards"):
+    with _sanctioned_pull("host_shards", _nbytes((x,))):
         for sh in x.addressable_shards:
             i = (sh.index[0].start or 0) // per
             blocks[i] = np.asarray(sh.data)
@@ -86,4 +99,5 @@ def sync_pull(arr) -> None:
     chip_smoke.py): ``jax.block_until_ready``, which the directly
     attached runtime honours — no tiny host pull rides behind it."""
     import jax
-    jax.block_until_ready(arr)
+    with timing.span("pull.sync"):
+        jax.block_until_ready(arr)

@@ -4,9 +4,22 @@ The reference wraps hot regions in a compile-time ``CYLON_BENCH_TIMER(ctx,
 tag, ...)`` macro that prints ``[BENCH] tag ms`` on rank 0 when built with
 ``-D_CYLON_BENCH`` (util/macros.hpp:102-117).  Here the switch is the
 runtime flag ``config.BENCH_TIMINGS`` (env ``CYLON_TPU_BENCH=1``): when off,
-:func:`region` is a no-op context manager with near-zero overhead; when on,
-wall-time per named region accumulates in a process-global table that
-``bench.py`` snapshots into its phase-breakdown detail.
+:func:`region` is one ``jax.profiler.TraceAnnotation`` (a TraceMe that
+checks the profiler's flag) and nothing else; when on, wall-time per named
+region accumulates in a process-global table that ``bench.py`` snapshots
+into its phase-breakdown detail.
+
+:func:`region` is the ONE choke point of the program's own tracing, with
+four sinks: the phase table (``CYLON_TPU_BENCH``), the per-session
+:class:`AttributionScope`, the flight recorder's ring (``obs/trace.py``,
+``CYLON_TPU_TRACE``) and — always, with no switch of its own — jax's
+profiler, where every region is a ``cylon.<name>`` span on the device
+events' clock whenever someone runs ``jax.profiler.trace`` around the work
+(docs/observability.md).  The two boundaries where the host meets the
+device — ``launch.<builder>`` (analysis/runtime.tag_program: the enqueue of
+every program_cache program) and ``pull.<kind>`` (utils/host: every
+sanctioned host pull) — go through :func:`span`, the same thing without the
+tables: they nest inside the operator regions and must not be summed twice.
 
 JAX dispatch is async — a region covering only device work would time the
 dispatch, not the execution.  Regions are therefore placed around phases
@@ -50,7 +63,12 @@ import contextlib
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 from .. import config
+
+#: every region is also a ``cylon.<name>`` span of jax's profiler
+ANNOTATION_PREFIX = "cylon."
 
 #: name -> [total_seconds, call_count]
 _ACCUM: dict[str, list] = {}
@@ -169,44 +187,86 @@ def attribution_scope(tag: str = ""):
         stack.pop()
 
 
+def _annotation(name: str, sc, args: dict):
+    """The ``cylon.<name>`` span of jax's profiler for one region/span: a
+    TraceMe that checks the profiler's flag on enter.  The active scope's
+    tag rides as ``session``."""
+    if sc is not None and sc.tag:
+        return TraceAnnotation(ANNOTATION_PREFIX + name, session=sc.tag,
+                               **args)
+    return TraceAnnotation(ANNOTATION_PREFIX + name, **args)
+
+
 @contextlib.contextmanager
-def region(name: str, block=None):
+def span(name: str, **args):
+    """A BOUNDARY span: on jax's profiler and in the flight recorder's
+    ring, like a region, but in no table.  For the two places where the
+    host meets the device — ``launch.<builder>`` (the enqueue of a
+    program) and ``pull.<kind>`` (a host pull) — which nest inside the
+    operator regions: entered into the phase/scope tables they would be
+    counted twice in every sum over a table (a session's fair-share
+    clock, a plan node's seconds, bench.py's dispatch total)."""
+    with _annotation(name, _scope(), args):
+        tr = _TRACE[0]
+        if tr is None:
+            yield
+            return
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            tr.span(name, t0, time.perf_counter() - t0, args or None)
+
+
+@contextlib.contextmanager
+def region(name: str, block=None, **args):
     """Time a named region (when ``config.BENCH_TIMINGS`` — or always,
     scope-locally, inside an :func:`attribution_scope`).  ``block`` may be
     a jax array (or pytree leaf list) to block_until_ready before stopping
-    the clock, charging async device work to this region."""
+    the clock, charging async device work to this region.
+
+    Every region is ALSO a ``cylon.<name>`` span on jax's profiler
+    (``TraceAnnotation``): whoever runs ``jax.profiler.trace`` around the
+    work finds the program's spans on the trace's host plane, on the
+    device events' clock, nested under the call that opened them — the
+    program is not told and has no switch for it.  With no profiler
+    running that is a ``TraceMe`` that checks one flag.  ``args`` (small
+    scalars: ``bytes=``, ``rows=``) go to the annotation and to the
+    flight recorder's span; the active scope's tag goes in as
+    ``session``."""
     sc = _scope()
     if sc is not None:
         sc.last = name
     else:
         _LAST_REGION[0] = name
-    if not config.BENCH_TIMINGS and sc is None and _TRACE[0] is None:
-        yield
-        return
-    t0 = time.perf_counter()
-    ex0 = sc._excluded if sc is not None else 0.0
-    gex0 = getattr(_SCOPE_TLS, "excluded", 0.0)
-    try:
-        yield
-    finally:
-        if block is not None and not config.TIMING_ASYNC:
-            import jax
-            jax.block_until_ready(block)
-        dt = time.perf_counter() - t0
-        tr = _TRACE[0]
-        if tr is not None:
-            tr.span(name, t0, dt)
-        if config.BENCH_TIMINGS:
-            # baton-park time that fell inside this region's window is
-            # not this THREAD's work (exclude_from_scope); like the
-            # scope table below, the global table nets it out — the
-            # cumulative counters handle nesting correctly
-            gnet = getattr(_SCOPE_TLS, "excluded", 0.0) - gex0
-            acc = _ACCUM.setdefault(name, [0.0, 0])
-            acc[0] += max(dt - gnet, 0.0)
-            acc[1] += 1
-        if sc is not None:
-            sc._add(name, max(dt - (sc._excluded - ex0), 0.0))
+    with _annotation(name, sc, args):
+        if not config.BENCH_TIMINGS and sc is None and _TRACE[0] is None:
+            yield
+            return
+        t0 = time.perf_counter()
+        ex0 = sc._excluded if sc is not None else 0.0
+        gex0 = getattr(_SCOPE_TLS, "excluded", 0.0)
+        try:
+            yield
+        finally:
+            if block is not None and not config.TIMING_ASYNC:
+                import jax
+                jax.block_until_ready(block)
+            dt = time.perf_counter() - t0
+            tr = _TRACE[0]
+            if tr is not None:
+                tr.span(name, t0, dt, args or None)
+            if config.BENCH_TIMINGS:
+                # baton-park time that fell inside this region's window
+                # is not this THREAD's work (exclude_from_scope); like
+                # the scope table below, the global table nets it out —
+                # the cumulative counters handle nesting correctly
+                gnet = getattr(_SCOPE_TLS, "excluded", 0.0) - gex0
+                acc = _ACCUM.setdefault(name, [0.0, 0])
+                acc[0] += max(dt - gnet, 0.0)
+                acc[1] += 1
+            if sc is not None:
+                sc._add(name, max(dt - (sc._excluded - ex0), 0.0))
 
 
 #: snapshot-key suffix marking a BLOCKING host-sync region — the

@@ -192,6 +192,18 @@ def test_fuzz_world1(env1):
 # happy path proves nothing).
 # ---------------------------------------------------------------------------
 
+@pytest.fixture(autouse=True)
+def _nothing_resident_to_spill():
+    """The retry ladder's FIRST rung spills whatever is resident and
+    retries at the same configuration; the tests below pin the rungs
+    after it.  A registration that another test file left alive in this
+    xdist worker (tests/test_stream.py's windows do) would take that
+    rung first, so which file ran before this one decided the outcome:
+    spill it now, through the scheduler's facade as the ladder would."""
+    from cylon_tpu.exec import scheduler
+    scheduler.spill_retry()
+
+
 def _counter(name: str) -> int:
     from cylon_tpu.utils import timing
     return timing.snapshot().get(name, {}).get("n", 0)

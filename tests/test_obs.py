@@ -433,15 +433,22 @@ class TestTraceRecorder:
 
 
 class TestUnarmedContract:
-    """The happy-path acceptance: with nothing armed, zero filesystem
-    writes and no recording — the same no-op style the checkpoint
-    tier's unarmed assertions use."""
+    """The happy-path acceptance: with no profiler running and nothing
+    armed, a region is one ``TraceMe`` that checks the profiler's flag
+    and nothing else — zero filesystem writes, no ring, no phase table,
+    no scope table.  (The cost is stated in docs/observability.md from a
+    measurement; no wall-clock assertion here.)"""
 
     def test_unarmed_records_and_writes_nothing(self, tmp_path,
                                                 monkeypatch):
         monkeypatch.chdir(tmp_path)
         assert not trace.armed() and timing._TRACE[0] is None
+        assert not timing.TraceAnnotation.is_enabled()   # no profiler
         with timing.region("t.off"):
+            pass
+        with timing.region("t.off_args", bytes=7, rows=3):
+            pass
+        with timing.span("t.off_span_only", bytes=1):   # launch./pull.
             pass
         timing.bump("t.off_bump")
         trace.instant("t.off_instant")
@@ -451,6 +458,36 @@ class TestUnarmedContract:
         assert not rank_report.armed()
         assert metrics.maybe_write_snapshot() is False
         assert os.listdir(tmp_path) == []
+        # nothing was pushed anywhere: no recorder came to be, and the
+        # phase table holds the bump alone (regions time only when asked)
+        assert trace.recorder() is None
+        assert set(timing.snapshot()) == {"t.off_bump"}
+        assert timing.last_region() == "t.off_args"      # the breadcrumb
+
+    def test_region_is_a_profiler_annotation_and_nothing_more(self):
+        """What an unarmed region costs is what it constructs: one
+        ``TraceAnnotation`` (jaxlib's TraceMe, which checks the
+        profiler's flag on enter) named ``cylon.<region>``; the timed
+        sinks are not entered."""
+        made = []
+
+        class Spy(timing.TraceAnnotation):
+            def __init__(self, name, **kw):
+                made.append((name, kw))
+                super().__init__(name, **kw)
+
+        real, timing.TraceAnnotation = timing.TraceAnnotation, Spy
+        try:
+            with timing.region("t.one"):
+                pass
+            with timing.attribution_scope("tenantA"):
+                with timing.region("t.two", bytes=5):
+                    pass
+        finally:
+            timing.TraceAnnotation = real
+        assert made == [("cylon.t.one", {}),
+                        ("cylon.t.two", {"session": "tenantA", "bytes": 5})]
+        assert timing.snapshot() == {}      # BENCH_TIMINGS off: no table
 
     def test_autoarm_needs_env(self, monkeypatch):
         monkeypatch.delenv("CYLON_TPU_TRACE", raising=False)
@@ -460,6 +497,151 @@ class TestUnarmedContract:
         trace.autoarm()
         assert trace.armed()
         assert trace.recorder().path == "/tmp/t.json"
+
+
+# ---------------------------------------------------------------------------
+# the program's spans on the profiler's clock (ISSUE 26, part B)
+# ---------------------------------------------------------------------------
+
+def _host_spans(trace_dir):
+    """``(name, start_ns, end_ns, args)`` of every ``cylon.*`` event on
+    the host planes of the newest xplane under ``trace_dir``."""
+    import glob
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("cylon."):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return sorted(out, key=lambda x: x[1])
+
+
+def _inside(spans, inner, outer):
+    """Some ``inner`` span lies within some ``outer`` span."""
+    return any(o[1] <= i[1] and i[2] <= o[2]
+               for i in spans if i[0] == inner
+               for o in spans if o[0] == outer)
+
+
+@pytest.fixture()
+def profiled(env1, tmp_path):
+    """Run a thunk once to warm, then under ``jax.profiler`` with the
+    flight recorder armed: ``(host spans, ring names)``."""
+    import jax
+
+    def run(thunk):
+        thunk()
+        rec = trace.arm(capacity=4096)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with timing.attribution_scope("tenantA"):
+                thunk()
+        finally:
+            jax.profiler.stop_trace()
+        return _host_spans(str(tmp_path)), [e[3] for e in rec.events()]
+
+    return run
+
+
+def _toy(env, n=4096):
+    import cylon_tpu as ct
+    rng = np.random.default_rng(3)
+    left = ct.Table.from_pydict({"k": rng.integers(0, 3000, n),
+                                 "a": rng.integers(0, 100, n)}, env)
+    right = ct.Table.from_pydict({"k": rng.integers(0, 3000, n),
+                                  "b": rng.integers(0, 100, n)}, env)
+    return left, right
+
+
+def test_join_groupby_spans_on_the_profilers_clock(env1, profiled):
+    from cylon_tpu.relational import groupby_aggregate, join_tables
+    left, right = _toy(env1)
+
+    def query():
+        g = groupby_aggregate(join_tables(left, right, "k", "k"), "k",
+                              [("a", "sum"), ("b", "sum")])
+        assert g.row_count > 0
+
+    spans, ring = profiled(query)
+    names = [s[0] for s in spans]
+    for want in ("cylon.join.sort_count", "cylon.launch.join__count_fn",
+                 "cylon.groupby.fused", "cylon.launch.fused__fused_fn",
+                 "cylon.pull.host_array"):
+        assert want in names, names
+    assert _inside(spans, "cylon.launch.join__count_fn",
+                   "cylon.join.sort_count")
+    assert _inside(spans, "cylon.launch.fused__fused_fn",
+                   "cylon.groupby.fused")
+    assert _inside(spans, "cylon.pull.host_array", "cylon.groupby.fused")
+    # the scope's tag is every span's `session`; a pull says its bytes
+    assert all(s[3].get("session") == "tenantA" for s in spans)
+    pulls = [s for s in spans if s[0] == "cylon.pull.host_array"]
+    assert all(int(p[3]["bytes"]) > 0 for p in pulls)
+    # one source, two sinks: the ring got the same spans from the same run
+    assert sorted(n[len("cylon."):] for n in names) == \
+        sorted(n for n in ring if not n.startswith("compile."))
+
+
+def test_groupby_sort_spans_on_the_profilers_clock(env1, profiled):
+    from cylon_tpu.relational import groupby_aggregate, sort_table
+    left, _ = _toy(env1)
+
+    def query():
+        s = sort_table(groupby_aggregate(left, "k", [("a", "sum")]),
+                       "a_sum")
+        assert s.row_count > 0
+
+    spans, ring = profiled(query)
+    names = [s[0] for s in spans]
+    for want in ("cylon.groupby.raw", "cylon.launch.groupby__raw_fn",
+                 "cylon.sort.local", "cylon.launch.sort__local_sort_fn",
+                 "cylon.pull.host_array"):
+        assert want in names, names
+    assert _inside(spans, "cylon.launch.groupby__raw_fn",
+                   "cylon.groupby.raw")
+    assert _inside(spans, "cylon.launch.sort__local_sort_fn",
+                   "cylon.sort.local")
+    assert {"groupby.raw", "sort.local", "launch.groupby__raw_fn",
+            "launch.sort__local_sort_fn", "pull.host_array"} <= set(ring)
+
+
+def test_ingest_regions_carry_rows_and_bytes(env1):
+    rec = trace.arm(capacity=64)
+    _toy(env1, n=512)
+    spans = {e[3]: e[6] for e in rec.events() if e[2] == "X"}
+    assert spans["table.from_pydict"] == {"rows": 512, "bytes": 2 * 512 * 8}
+    assert spans["table.upload"] == {"rows": 512}
+
+
+def test_compile_seconds_by_builder(env1):
+    """A forced compile (a row count no other test uses) is attributed to
+    the builder whose program was being launched, in
+    ``compiler.stats()["by_builder"]`` and as a ``compile.<builder>`` span
+    of the ring."""
+    from cylon_tpu.exec import compiler
+    from cylon_tpu.relational import groupby_aggregate
+    compiler.install_listener()
+    left, _ = _toy(env1, n=1536 + 8)
+    before = compiler.stats()["by_builder"].get(
+        "groupby__raw_fn", {"seconds": 0.0, "events": 0})
+    total_before = compiler.stats()["compile_events"]
+    rec = trace.arm(capacity=256)
+    assert groupby_aggregate(left, "k", [("a", "max")]).row_count > 0
+    st = compiler.stats()
+    after = st["by_builder"]["groupby__raw_fn"]
+    assert after["events"] >= before["events"] + 1
+    assert after["seconds"] > before["seconds"]
+    assert sum(b["events"] for b in st["by_builder"].values()) \
+        <= st["compile_events"]
+    assert st["compile_events"] > total_before
+    assert "compile.groupby__raw_fn" in [e[3] for e in rec.events()]
 
 
 # ---------------------------------------------------------------------------
