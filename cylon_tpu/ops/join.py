@@ -14,7 +14,8 @@ reductions with large segment counts are expensive — prefix scans are cheap.
      left rows precede right rows within every equal-key run, so the sorted
      order itself encodes the merge.
   2. ``join_carry``: per-position geometry from *segmented scans* only
-     (``associative_scan`` — no segment reductions, no group-space gather):
+     (no segment reductions, no group-space gather; row liveness is a
+     position compare against the live prefix, ``live_sides``):
      reverse segmented counts give every left row its group's right-count
      and the position where its matches start; forward counts give right
      rows their left-count (for right/outer emission).
@@ -64,6 +65,14 @@ def join_sort_state(ko_l: KeyOps, ko_r: KeyOps, payloads: tuple = ()):
     new key group (p=0 -> 0).  Stability ⇒ within a group, left rows come
     first, each side in source order.
 
+    Invariant every consumer of the state relies on (:func:`live_sides`):
+    *a liveness operand leads the sort ⇔ ``n_live`` is not None ⇔ padding
+    occupies sorted positions ``[n_live, N)``*.  Callers build both sides'
+    operands with a ``row_mask`` (:func:`.pack.key_operands`: live rows 0,
+    padding 4 / 5) exactly when the tables are not at capacity, so live
+    rows sort first whatever their keys (int64 max included) and
+    ``n_live`` is the two sides' valid counts summed — a per-shard scalar.
+
     ``payloads``: optional (n_l+n_r,) arrays carried through the sort —
     moving data as sort payload costs ~2 ns/row/operand vs ~20 ns/row for
     a later gather, so callers ride small column sets along.
@@ -80,8 +89,25 @@ def join_sort_state(ko_l: KeyOps, ko_r: KeyOps, payloads: tuple = ()):
     return bnd, idx_s, tuple(sorted_all[nk + 1:])
 
 
+@staged("liveness")
+def live_sides(idx_s, n_l: int, n_live=None):
+    """The live prefix of a sorted join state: ``(lefts, rights, live)``
+    bool arrays over sorted positions — live left rows, live right rows,
+    and row liveness itself (None when ``n_live`` is None: every row is
+    live).  Liveness at sorted position p is the position compare
+    ``p < n_live`` (:func:`join_sort_state`'s invariant), never an
+    N-length mask gathered through ``idx_s`` (a random 1-byte gather,
+    ~10 ns/row measured on v5e: 0.63 s of a 2.2 s query at 65M rows)."""
+    side = idx_s >= n_l
+    if n_live is None:
+        return ~side, side, None
+    assert jnp.ndim(n_live) == 0, "n_live is a scalar, not a row mask"
+    live = jnp.arange(idx_s.shape[0], dtype=jnp.int32) < n_live
+    return ~side & live, side & live, live
+
+
 @staged("join_count")
-def join_carry(bnd, idx_s, live_cat, n_l: int, how: str) -> tuple:
+def join_carry(bnd, idx_s, n_live, n_l: int, how: str) -> tuple:
     """Phase-1 geometry: returns ``(total, JoinCarry)`` with ``total`` the
     exact output row count (device scalar int32).
 
@@ -91,20 +117,18 @@ def join_carry(bnd, idx_s, live_cat, n_l: int, how: str) -> tuple:
     for a scan) and NOT ``associative_scan``, whose XLA:TPU compile time
     explodes superlinearly with array size (~200 s at 2M rows, measured).
 
-    ``live_cat=None`` asserts every concat row is live (host-known
-    ``valid_counts == capacity`` — the common case for exact-bucket tables):
-    it skips the ~15 ns/row ``live_cat[idx_s]`` gather entirely."""
+    ``n_live``: int32 scalar, the live rows of the concat — they are the
+    sorted prefix ``[0, n_live)`` because the liveness operand led the
+    sort (:func:`join_sort_state`'s invariant), so row liveness is a
+    position compare.  ``None`` asserts every concat row is live
+    (host-known ``valid_counts == capacity``; no liveness operand was
+    sorted)."""
     n = bnd.shape[0]
     pos = jnp.arange(n, dtype=jnp.int32)
-    side = idx_s >= n_l
+    lefts_b, rights_b, _live = live_sides(idx_s, n_l, n_live)
     with stage("liveness"):
-        if live_cat is None:
-            lefts = (~side).astype(jnp.int32)
-            rights = side.astype(jnp.int32)
-        else:
-            live = live_cat[idx_s]
-            lefts = ((~side) & live).astype(jnp.int32)
-            rights = (side & live).astype(jnp.int32)
+        lefts = lefts_b.astype(jnp.int32)
+        rights = rights_b.astype(jnp.int32)
     with stage("boundaries"):
         first = bnd.astype(bool) | (pos == 0)
 

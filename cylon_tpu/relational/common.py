@@ -85,6 +85,15 @@ def live_mask(vc: jax.Array, cap: int) -> jax.Array:
     return jnp.arange(cap) < vc[my]
 
 
+@staged("liveness")
+def live_count(vcl: jax.Array, vcr: jax.Array) -> jax.Array:
+    """Live rows of this shard's (left ++ right) concat as an int32 scalar
+    (call inside shard_map): the length of a sorted join state's live
+    prefix (ops/join.join_sort_state's invariant)."""
+    my = jax.lax.axis_index(ROW_AXIS)
+    return (vcl[my] + vcr[my]).astype(jnp.int32)
+
+
 def valid_flag(col: Column):
     """Boolean filter payload of a bool column with null rows forced False
     (pandas/Arrow semantics: a null predicate never selects a row).  Every

@@ -41,13 +41,13 @@ from ..utils.cache import jit, program_cache
 from ..core.column import Column
 from ..core.dtypes import LogicalType
 from ..core.table import DeferredTable, Table
-from ..ctx.context import ROW_AXIS
 from ..ops import groupby as gbk
+from ..ops import join as joink
 from ..ops import lanes
 from ..utils import timing
 from ..utils.host import host_array
 from ..utils.stages import stage, staged
-from .common import REP, ROW, BoundedCache
+from .common import REP, ROW, BoundedCache, live_count
 
 shard_map = jax.shard_map
 
@@ -121,22 +121,15 @@ def _fused_fn(mesh: Mesh, n_l: int, all_live: bool, lspec, rspec,
     ``vspecs``: per aggregation (side, lane_col_idx, op); ``key_cols``:
     left lane-col index per groupby key.  Live rows form a sorted PREFIX
     (the row-liveness operand sorts padding last), so liveness is a
-    position compare — no gather."""
+    position compare — no gather (ops/join.live_sides, the one statement
+    of that rule)."""
 
     def per_shard(vcl, vcr, idx_s, bnd, pl_s):
         N = bnd.shape[0]
         pos = jnp.arange(N, dtype=jnp.int32)
-        my = jax.lax.axis_index(ROW_AXIS)
-        side_r = idx_s >= n_l
+        n_live = jnp.int32(N) if all_live else live_count(vcl, vcr)
+        lefts_b, rights_b, live = joink.live_sides(idx_s, n_l, n_live)
         with stage("liveness"):
-            if all_live:
-                n_live = jnp.int32(N)
-                live = jnp.ones(N, bool)
-            else:
-                n_live = (vcl[my] + vcr[my]).astype(jnp.int32)
-                live = pos < n_live
-            lefts_b = ~side_r & live
-            rights_b = side_r & live
             lefts = lefts_b.astype(jnp.int32)
             rights = rights_b.astype(jnp.int32)
         with stage("boundaries"):

@@ -41,11 +41,12 @@ from ..ops import pack
 from ..status import InvalidError
 from ..utils import timing
 from ..utils.host import host_array
-from ..utils.stages import stage, staged
+from ..utils.stages import stage
 from .common import (PAD_L, PAD_R, REP, ROW, BoundedCache, build_table,
                      check_same_env,
                      sample_positions,
-                     col_arrays, live_mask, narrow32_flags, promote_key_pair)
+                     col_arrays, live_count, live_mask, narrow32_flags,
+                     promote_key_pair)
 from .piece import PackedPiece
 from .repart import shuffle_table
 
@@ -270,16 +271,10 @@ def _shuffle_for_join(lwork: Table, rwork: Table, left_on, right_on,
             False)
 
 
-@staged("liveness")
-def _live_cat(vcl, vcr, cap_l: int, cap_r: int):
-    """Concat-row liveness for (left ++ right) per shard."""
-    return jnp.concatenate([live_mask(vcl, cap_l), live_mask(vcr, cap_r)])
-
-
 def _sorted_state(vcl, vcr, l_datas, l_valids, r_datas, r_valids,
                   narrow: tuple, payloads: tuple = (),
                   all_live: bool = False):
-    """Per-shard single-sort join state (bnd, idx_s, live_cat, sorted
+    """Per-shard single-sort join state (bnd, idx_s, n_live, sorted
     payloads).
 
     Both sides must build structurally identical operand lists, so the
@@ -287,8 +282,9 @@ def _sorted_state(vcl, vcr, l_datas, l_valids, r_datas, r_valids,
     narrow-key decision is made by the caller for the pair.
 
     ``all_live=True`` (host-known: both tables' valid_counts == capacity)
-    drops the row-liveness sort operand AND the downstream liveness gather
-    (live_cat=None) — one less sort pass and one less ~15 ns/row gather."""
+    drops the row-liveness sort operand (n_live=None) — one less sort
+    pass; otherwise that operand leads the sort, which is what makes the
+    live rows the sorted prefix ``[0, n_live)``."""
     cap_l, cap_r = l_datas[0].shape[0], r_datas[0].shape[0]
     mask_l = None if all_live else live_mask(vcl, cap_l)
     mask_r = None if all_live else live_mask(vcr, cap_r)
@@ -301,10 +297,7 @@ def _sorted_state(vcl, vcr, l_datas, l_valids, r_datas, r_valids,
                              pad_key=PAD_R, need_null_flags=need_nf,
                              narrow32=narrow)
     bnd, idx_s, pl_s = joink.join_sort_state(ko_l, ko_r, payloads)
-    with stage("liveness"):
-        live_cat = None if all_live \
-            else jnp.concatenate([mask_l, mask_r])
-    return bnd, idx_s, live_cat, pl_s
+    return bnd, idx_s, None if all_live else live_count(vcl, vcr), pl_s
 
 
 @program_cache()
@@ -319,19 +312,13 @@ def _semi_flag_fn(mesh: Mesh, narrow: tuple, all_live: bool, anti: bool):
 
     def per_shard(vcl, vcr, l_datas, l_valids, r_datas, r_valids):
         cap_l = l_datas[0].shape[0]
-        bnd, idx_s, live_cat, _pl = _sorted_state(
+        bnd, idx_s, n_live, _pl = _sorted_state(
             vcl, vcr, l_datas, l_valids, r_datas, r_valids, narrow, (),
             all_live)
         n = bnd.shape[0]
         pos = jnp.arange(n, dtype=jnp.int32)
-        side_r = idx_s >= cap_l
-        if live_cat is None:
-            lefts_b = ~side_r
-            rights = side_r.astype(jnp.int32)
-        else:
-            live = live_cat[idx_s]
-            lefts_b = (~side_r) & live
-            rights = (side_r & live).astype(jnp.int32)
+        lefts_b, rights_b, _live = joink.live_sides(idx_s, cap_l, n_live)
+        rights = rights_b.astype(jnp.int32)
         first = bnd.astype(bool) | (pos == 0)
         s_r = jnp.cumsum(rights).astype(jnp.int32)
         ebnd = jnp.concatenate([first[1:], jnp.ones(1, bool)])
@@ -381,10 +368,10 @@ def _count_fn(mesh: Mesh, how: str, narrow: tuple,
                 zl = jnp.zeros(cap_l, jnp.uint32)
                 payloads += tuple(jnp.concatenate([zl, rmat[:, j]])
                                   for j in range(rspec.n_lanes))
-        bnd, idx_s, live, pl_s = _sorted_state(
+        bnd, idx_s, n_live, pl_s = _sorted_state(
             vcl, vcr, l_datas, l_valids, r_datas, r_valids, narrow, payloads,
             all_live)
-        n, carry = joink.join_carry(bnd, idx_s, live, cap_l, how)
+        n, carry = joink.join_carry(bnd, idx_s, n_live, cap_l, how)
         if slim:
             # deferred-join state: only what the fused consumer needs
             # (relational/fused.py) — dropping the other carry arrays frees
@@ -404,16 +391,15 @@ def _count_fn(mesh: Mesh, how: str, narrow: tuple,
 
 
 @program_cache()
-def _carry_fn(mesh: Mesh, how: str, cap_l: int, cap_r: int,
-              all_live: bool):
+def _carry_fn(mesh: Mesh, how: str, cap_l: int, all_live: bool):
     """Recompute the full phase-1 carry from a held SLIM state (idx_s, bnd)
     — prefix scans only (~1 ns/row), no re-sort.  Used when a deferred
     join materializes: the slim outputs are a superset of what join_carry
     needs as inputs, so the dominant single-sort never runs twice."""
 
     def per_shard(vcl, vcr, idx_s, bnd):
-        live = None if all_live else _live_cat(vcl, vcr, cap_l, cap_r)
-        _, carry = joink.join_carry(bnd, idx_s, live, cap_l, how)
+        n_live = None if all_live else live_count(vcl, vcr)
+        _, carry = joink.join_carry(bnd, idx_s, n_live, cap_l, how)
         return tuple(carry)
 
     return jit(shard_map(per_shard, mesh=mesh,
@@ -607,8 +593,8 @@ def _packed_count_fn(mesh: Mesh, how: str, narrow: tuple, need_nf: tuple,
             payloads += tuple(jnp.concatenate([zl, mat_r[:, j]])
                               for j in range(rspec.n_lanes))
         bnd, idx_s, pl_s = joink.join_sort_state(ko_l, ko_r, payloads)
-        live_cat = None if all_live else jnp.concatenate([mask_l, mask_r])
-        n, carry = joink.join_carry(bnd, idx_s, live_cat, cap_l, how)
+        n_live = None if all_live else live_count(vcl, vcr)
+        n, carry = joink.join_carry(bnd, idx_s, n_live, cap_l, how)
         if slim:
             return (n.reshape(1), idx_s, bnd) + pl_s
         return (n.reshape(1),) + tuple(carry) + pl_s
@@ -917,7 +903,7 @@ def _join_packed_impl(pl: PackedPiece, pr: PackedPiece, left_on, right_on,
             out_cap = config.pow2ceil(int(counts.max())
                                       if counts.size else 1)
             with timing.region("join.materialize"):
-                carry = _carry_fn(env.mesh, how, cap_l, cap_r, all_live)(
+                carry = _carry_fn(env.mesh, how, cap_l, all_live)(
                     vcl, vcr, idx_s_s, bnd_s)
                 # donate the freshly built carry (exclusively owned here)
                 # but NOT pl_s — the JoinState shares those lanes with any
@@ -1365,9 +1351,8 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
                 # the slim state already holds the sorted payloads and
                 # (idx_s, bnd); the carry rebuilds from scans alone — the
                 # dominant single-sort does NOT run a second time
-                carry = _carry_fn(env.mesh, how, lwork.capacity,
-                                  rwork.capacity, all_live)(
-                                      vcl, vcr, idx_s_s, bnd_s)
+                carry = _carry_fn(env.mesh, how, lwork.capacity, all_live)(
+                    vcl, vcr, idx_s_s, bnd_s)
                 fn = _materialize_fn(env.mesh, how, out_cap, lwork.capacity,
                                      tuple(plan), lspec, rspec, carry_emit,
                                      carry_match)
@@ -1562,7 +1547,7 @@ def _trace_count(mesh):
 def _trace_carry(mesh):
     w, S, vc, _keys, _valids = _decl_args(mesh)
     cap = 1024
-    fn = _unwrap(_carry_fn(mesh, "inner", cap, cap, False))
+    fn = _unwrap(_carry_fn(mesh, "inner", cap, False))
     cat = S((w * 2 * cap,), np.int32)
     return jax.make_jaxpr(fn)(vc, vc, cat, cat)
 

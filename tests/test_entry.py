@@ -27,15 +27,29 @@ def test_dryrun_multichip_8():
 
 
 def test_entry_jit_compiles_and_runs():
+    """The jitted step against pandas: join total, group keys, both sums —
+    a kernel signature that moved under entry() has to show here."""
     import jax
+    import pandas as pd
     sys.path.insert(0, REPO)
     try:
         import __graft_entry__
         fn, args = __graft_entry__.entry()
-        out = jax.jit(fn)(*args)
-        jax.block_until_ready(out)
+        gkeys, a_sum, b_sum, total = jax.jit(fn)(*args)
     finally:
         sys.path.remove(REPO)
+    l_keys, l_vals, r_keys, r_vals = args
+    joined = pd.DataFrame({"k": l_keys, "a": l_vals}).merge(
+        pd.DataFrame({"k": r_keys, "b": r_vals}), on="k")
+    exp = joined.groupby("k", as_index=False).sum()
+    assert int(total) == len(joined)
+    n = len(exp)
+    got = pd.DataFrame({"k": np.asarray(gkeys)[:n], "a": np.asarray(a_sum)[:n],
+                        "b": np.asarray(b_sum)[:n]})
+    got = got.sort_values("k").reset_index(drop=True)
+    np.testing.assert_array_equal(got["k"], exp["k"])
+    np.testing.assert_allclose(got["a"], exp["a"], rtol=1e-9)
+    np.testing.assert_allclose(got["b"], exp["b"], rtol=1e-9)
 
 
 def test_dryrun_touches_only_cpu_backend():

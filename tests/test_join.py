@@ -380,3 +380,215 @@ class TestJoinTablesMulti:
             ["k", "k", "k"]).to_pandas()
         exp = a.merge(b, on="k").merge(c, on="k")
         assert len(out) == len(exp)
+
+
+# ---------------------------------------------------------------------------
+# Row liveness by sorted position (ops/join.live_sides): tables whose
+# valid_counts < capacity, with padding rows that would MATCH live keys if
+# any program took them for live.  Every caller of the rule, against pandas.
+# ---------------------------------------------------------------------------
+
+I64 = np.iinfo(np.int64)
+#: live keys: duplicates, both int64 extremes (live rows must still sort
+#: before padding — the liveness flag leads the sort) and nulls
+KEY_POOL = [I64.min, I64.max, -1, 0, 1, 2, 3, 5, 7, 11, None]
+#: what padding rows hold: keys that live rows hold too, marked non-null
+PAD_KEYS = np.asarray([I64.max, I64.min, 0, 1, 7, 11], np.int64)
+LAYOUTS = ["ragged", "left_shard_empty", "left_all_padding",
+           "right_all_padding", "one_shard_full"]
+
+
+def _homes(df, w, key="k"):
+    """Shard of every row: equal keys share one, nulls go to shard 0."""
+    return np.asarray([0 if pd.isna(k) else int(k) % w for k in df[key]],
+                      np.int64)
+
+
+def _padded_table(env, df, cap, key="k"):
+    """Colocated Table of ``df`` (nullable-Int64 key, int64 values): shard
+    s holds the rows whose key maps to s (:func:`_homes`), then padding
+    up to ``cap`` filled with PAD_KEYS / large values, non-null."""
+    from cylon_tpu.core.column import Column
+    from cylon_tpu.core.table import _put
+    w = env.world_size
+    home = _homes(df, w, key)
+    valid = np.bincount(home, minlength=w)
+    assert valid.max(initial=0) <= cap
+    cols = {}
+    for name in df.columns:
+        s = df[name]
+        isna = np.asarray(s.isna(), bool)
+        vals = s.to_numpy(dtype=np.int64, na_value=0)
+        data = np.resize(PAD_KEYS if name == key
+                         else np.asarray([10**12 + 7], np.int64), w * cap)
+        ok = np.ones(w * cap, bool)
+        for sh in range(w):
+            rows = np.flatnonzero(home == sh)
+            data[sh * cap: sh * cap + len(rows)] = vals[rows]
+            ok[sh * cap: sh * cap + len(rows)] = ~isna[rows]
+        host = Column.from_numpy(data)      # bounds cover the padding too
+        cols[name] = Column(
+            _put(data, env.sharding()), host.type,
+            _put(ok, env.sharding()) if name == key else None,
+            host.dictionary, bounds=host.bounds)
+    return ct.Table(cols, env, valid)
+
+
+def _padded_frames(rng, layout, w, cap):
+    def frame(n, val):
+        ks = [KEY_POOL[i] for i in rng.integers(0, len(KEY_POOL), n)]
+        return pd.DataFrame({"k": pd.array(ks, dtype="Int64"),
+                             val: rng.integers(-50, 50, n).astype(np.int64)})
+
+    ldf, rdf = frame(3 * w, "a"), frame(2 * w, "b")
+    if layout == "left_shard_empty":
+        ldf = ldf[_homes(ldf, w) != 1 % w].reset_index(drop=True)
+    elif layout == "left_all_padding":
+        ldf = ldf.iloc[:0]
+    elif layout == "right_all_padding":
+        rdf = rdf.iloc[:0]
+    elif layout == "one_shard_full":
+        # shard 0 of the left table exactly at capacity, the rest ragged
+        fill = pd.DataFrame({"k": pd.array([0] * cap, dtype="Int64"),
+                             "a": np.arange(cap, dtype=np.int64)})
+        ldf = pd.concat([ldf[_homes(ldf, w) != 0], fill], ignore_index=True)
+    return ldf, rdf
+
+
+def _exact_rows(df):
+    """Sorted row tuples with python ints and None for nulls: an exact
+    comparison (int64 extremes do not survive pandas' float upcast of a
+    nullable column)."""
+    cols = [[None if pd.isna(x) else int(x) for x in df[c]]
+            for c in df.columns]
+    return sorted(zip(*cols),
+                  key=lambda r: tuple((x is None, x or 0) for x in r))
+
+
+def _assert_exact(table, exp: pd.DataFrame):
+    got = table.to_pandas()
+    assert list(got.columns) == list(exp.columns)
+    assert _exact_rows(got) == _exact_rows(exp)
+
+
+class TestPaddedShards:
+    CAP = 32
+
+    def _tables(self, env, rng, layout):
+        ldf, rdf = _padded_frames(rng, layout, env.world_size, self.CAP)
+        lt = _padded_table(env, ldf, self.CAP)
+        rt = _padded_table(env, rdf, self.CAP)
+        assert (lt.valid_counts < lt.capacity).any() \
+            or (rt.valid_counts < rt.capacity).any()
+        return ldf, rdf, lt, rt
+
+    @pytest.mark.parametrize("envname", ["env1", "env4"])
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    @pytest.mark.parametrize("how", HOWS)
+    def test_join_matches_pandas(self, request, rng, envname, layout, how):
+        env = request.getfixturevalue(envname)
+        ldf, rdf, lt, rt = self._tables(env, rng, layout)
+        got = join_tables(lt, rt, "k", "k", how=how, assume_colocated=True)
+        exp = ldf.merge(rdf, on="k", how=how)
+        assert got.row_count == len(exp)
+        _assert_exact(got, exp)
+
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    @pytest.mark.parametrize("how", ["semi", "anti"])
+    def test_semi_anti_matches_pandas(self, env4, rng, layout, how):
+        ldf, rdf, lt, rt = self._tables(env4, rng, layout)
+        got = join_tables(lt, rt, "k", "k", how=how, assume_colocated=True)
+        # null keys match null keys (pandas-merge semantics, as above)
+        hit = ldf.merge(rdf[["k"]].drop_duplicates(), on="k", how="left",
+                        indicator=True)["_merge"].to_numpy() == "both"
+        exp = ldf[hit if how == "semi" else ~hit]
+        _assert_exact(got, exp)
+
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    def test_deferred_join_materializes_exact(self, env4, rng, layout):
+        """The slim count program, then _carry_fn's rebuild of the carry
+        from the held (idx_s, bnd) at materialization."""
+        from cylon_tpu.core.table import DeferredTable
+        ldf, rdf, lt, rt = self._tables(env4, rng, layout)
+        got = join_tables(lt, rt, "k", "k", how="inner",
+                          assume_colocated=True, allow_defer=True)
+        assert isinstance(got, DeferredTable) and not got.materialized
+        exp = ldf.merge(rdf, on="k", how="inner")
+        assert got.row_count == len(exp)
+        _assert_exact(got, exp)
+
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    def test_fused_groupby_matches_pandas(self, env4, rng, layout):
+        """fused._fused_fn takes its live prefix from the same helper."""
+        from cylon_tpu.relational import groupby_aggregate
+        ldf, rdf, lt, rt = self._tables(env4, rng, layout)
+        j = join_tables(lt, rt, "k", "k", how="inner",
+                        assume_colocated=True, allow_defer=True)
+        got = groupby_aggregate(j, "k", [("a", "sum"), ("b", "sum")])
+        exp = ldf.merge(rdf, on="k", how="inner").groupby(
+            "k", dropna=False, as_index=False).agg(
+                a_sum=("a", "sum"), b_sum=("b", "sum"))
+        _assert_exact(got, exp)
+
+
+def _carry_by_gather(bnd, idx_s, live_cat, n_l, how):
+    """The plain reference of ops/join.join_carry in numpy, with row
+    liveness taken the way the join layer used to: the concat-row mask
+    gathered to sorted positions, ``live_cat[idx_s]``."""
+    n = len(bnd)
+    pos = np.arange(n)
+    live = live_cat[idx_s]
+    side = idx_s >= n_l
+    lefts = (~side & live).astype(np.int64)
+    rights = (side & live).astype(np.int64)
+    first = bnd.astype(bool) | (pos == 0)
+    gid = np.cumsum(first) - 1
+    g_start = np.flatnonzero(first)[gid]
+    g_end = np.append(np.flatnonzero(first)[1:] - 1, n - 1)[gid]
+    s_l, s_r = np.cumsum(lefts), np.cumsum(rights)
+    b_l = (s_l - lefts)[g_start]
+    if how == "right":
+        cnt, mstart, emits = s_l - b_l, g_start, rights != 0
+    else:
+        cnt = s_r[g_end] - (s_r - rights)
+        mstart = pos + s_l[g_end] - (s_l - lefts)
+        emits = lefts != 0
+    keep_unmatched = how in ("left", "right", "outer")
+    eff = np.where(emits, np.maximum(cnt, 1) if keep_unmatched else cnt, 0)
+    offs = np.cumsum(eff) - eff
+    total = int(eff.sum())
+    un = np.zeros(n, np.int64)
+    if how == "outer":
+        un = ((rights != 0) & (s_l - b_l == 0)).astype(np.int64)
+        total += int(un.sum())
+    return total, (offs, eff, cnt, mstart, idx_s, un)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("how", HOWS)
+def test_join_carry_equals_gather_formulation(how, seed):
+    """Kernel level: on random sorted states with padding, position-compare
+    liveness gives the gather formulation's carry, field for field."""
+    import jax.numpy as jnp
+    from cylon_tpu.ops import join as joink
+    from cylon_tpu.ops import pack
+    from cylon_tpu.relational.common import PAD_L, PAD_R
+    rng = np.random.default_rng(seed)
+    n_l, n_r = 48, 40
+    vl, vr = [(29, 17), (0, 23), (48, 0)][seed]      # live rows a side
+    pool = np.asarray([I64.min, I64.max, 0, 1, 2, 3, 5], np.int64)
+    kl, kr = rng.choice(pool, n_l), rng.choice(pool, n_r)
+    nl_ok, nr_ok = rng.random(n_l) > 0.2, rng.random(n_r) > 0.2   # null keys
+    mask_l, mask_r = np.arange(n_l) < vl, np.arange(n_r) < vr
+    ko_l = pack.key_operands([jnp.asarray(kl)], [jnp.asarray(nl_ok)],
+                             row_mask=jnp.asarray(mask_l), pad_key=PAD_L)
+    ko_r = pack.key_operands([jnp.asarray(kr)], [jnp.asarray(nr_ok)],
+                             row_mask=jnp.asarray(mask_r), pad_key=PAD_R)
+    bnd, idx_s, _ = joink.join_sort_state(ko_l, ko_r)
+    total, carry = joink.join_carry(bnd, idx_s, jnp.int32(vl + vr), n_l, how)
+    ref_total, ref = _carry_by_gather(
+        np.asarray(bnd), np.asarray(idx_s),
+        np.concatenate([mask_l, mask_r]), n_l, how)
+    assert int(total) == ref_total
+    for name, got, exp in zip(joink.JoinCarry._fields, carry, ref):
+        np.testing.assert_array_equal(np.asarray(got), exp, err_msg=name)

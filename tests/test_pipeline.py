@@ -545,6 +545,47 @@ class TestPackedPieces:
         pd.testing.assert_frame_equal(got, exp, check_exact=True)
 
 
+class TestPackedWindowPadding:
+    """A piece whose ``lens < piece_cap``: the rows of the window past
+    ``lens`` are REAL rows of the source (the next range's keys), so a
+    program that took one of them for live would join it.  Row liveness
+    in the packed count program is the sorted position compare
+    (ops/join.live_sides)."""
+
+    @pytest.mark.parametrize("how,defer", [
+        ("inner", False), ("inner", True),      # only inner joins defer
+        ("left", False), ("right", False), ("outer", False)])
+    def test_short_window_matches_pandas(self, env4, rng, how, defer):
+        from cylon_tpu.relational.join import join_tables as jt
+        from cylon_tpu.relational.piece import PieceSource
+        from cylon_tpu.relational.repart import shuffle_table
+        from cylon_tpu.relational.sort import local_sort_table
+        n = 1500
+        ldf = pd.DataFrame({"k": rng.integers(0, 90, n).astype(np.int64),
+                            "a": rng.integers(0, 50, n).astype(np.int64)})
+        rdf = pd.DataFrame({"k": rng.integers(0, 90, n).astype(np.int64),
+                            "b": rng.integers(0, 50, n).astype(np.int64)})
+        ls = local_sort_table(shuffle_table(
+            ct.Table.from_pandas(ldf, env4), ["k"]), ["k"])
+        rs = local_sort_table(shuffle_table(
+            ct.Table.from_pandas(rdf, env4), ["k"]), ["k"])
+        w = env4.world_size
+        zero = np.zeros(w, np.int64)
+        # a third of each shard's left rows, two thirds of its right rows
+        # (shard 1: no left row at all) in windows of the full capacity
+        len_l = np.asarray(ls.valid_counts) // 3
+        len_l[1] = 0
+        len_r = 2 * np.asarray(rs.valid_counts) // 3
+        pl = PieceSource(ls, 0).packed(zero, len_l, ls.capacity)
+        pr = PieceSource(rs, 0).packed(zero, len_r, rs.capacity)
+        assert (pl.lens < pl.piece_cap).all()
+        got = jt(pl, pr, ["k"], ["k"], how=how, allow_defer=defer)
+        exp = pl.to_table().to_pandas().merge(pr.to_table().to_pandas(),
+                                              on="k", how=how)
+        assert got.row_count == len(exp)
+        assert_table_matches(got, exp, sort_by=list(exp.columns))
+
+
 class TestRangeBoundsSentinel:
     """_range_bounds_fn's +inf sentinel edge: a build shard whose live
     prefix is exactly at capacity (n == cap) has NO padding row to serve

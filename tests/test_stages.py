@@ -189,3 +189,63 @@ def test_names_do_not_change_the_program(env1):
     b = jax.jit(scoped).lower(x).as_text().replace("scoped", "f")
     assert a == b
     assert not jax.config.jax_compilation_cache_include_metadata_in_key
+
+
+# ---- (c) the join's liveness is a position compare (ISSUE 27) --------------
+
+def _join_programs(mesh, cap):
+    """The per-shard functions of every join program that knows row
+    liveness in sorted space, with ``all_live=False``, and their abstract
+    arguments at ``cap`` rows a side a shard."""
+    from cylon_tpu.ops import lanes
+    from cylon_tpu.relational import join as rj
+    w = int(mesh.devices.size)
+    S = jax.ShapeDtypeStruct
+    vc = S((w,), np.int32)
+    keys, valids = (S((w * cap,), np.int64),), (S((w * cap,), np.bool_),)
+    cat = S((w * 2 * cap,), np.int32)
+    table_args = (vc, vc, keys, valids, keys, valids)
+    spec = lanes.plan_lanes(("int32", "int32"), (False, False))
+    mat = S((w * 2 * cap, spec.n_lanes), np.uint32)
+
+    def packed(slim):
+        return rj._packed_count_fn(mesh, "left", (False,), (False,), spec,
+                                   spec, (0,), (0,), cap, cap, 1, 1, False,
+                                   True, True, slim)
+
+    return {
+        "count_full": (rj._count_fn(mesh, "outer", (False,), None, None,
+                                    False, False),
+                       table_args + ((), (), (), ())),
+        "count_slim": (rj._count_fn(mesh, "inner", (False,), None, None,
+                                    False, True),
+                       table_args + ((), (), (), ())),
+        "carry": (rj._carry_fn(mesh, "right", cap, False),
+                  (vc, vc, cat, cat)),
+        "packed_count": (packed(False), (vc, vc, vc, vc, mat, mat)),
+        "packed_count_slim": (packed(True), (vc, vc, vc, vc, mat, mat)),
+        "semi_flag": (rj._semi_flag_fn(mesh, (False,), False, False),
+                      table_args),
+    }
+
+
+@pytest.mark.parametrize("program", [
+    "count_full", "count_slim", "carry", "packed_count",
+    "packed_count_slim", "semi_flag"])
+def test_join_liveness_is_not_a_gather(program, env4):
+    """No join program gathers an N-length bool mask through ``idx_s``:
+    live rows are the sorted prefix, liveness is ``pos < n_live``
+    (ops/join.live_sides).  The gather cost 0.63 s of a 2.2 s query at
+    65M rows (PERF.md, PR 27); this keeps it from coming back on a route
+    no benchmark cell runs."""
+    from cylon_tpu.analysis.jaxpr_check import iter_eqns
+    cap = 512
+    fn, args = _join_programs(env4.mesh, cap)[program]
+    traced = jax.make_jaxpr(registry.unwrap(fn))(*args)
+    prims = [e.primitive.name for e, _ in iter_eqns(traced)]
+    assert "sort" in prims or program == "carry"     # the walk sees inside
+    masks = [e for e, _ in iter_eqns(traced)
+             if e.primitive.name == "gather"
+             and e.invars[0].aval.dtype == np.bool_
+             and e.invars[0].aval.shape == (2 * cap,)]
+    assert masks == []
