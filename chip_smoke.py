@@ -26,7 +26,19 @@ and did not go through it.
 
 ``--chips 4`` runs the distributed ``join_tables`` + ``groupby_aggregate``
 over ``TPUConfig(world_size=4)`` at 2^23 rows per chip per side against
-the same pandas reference, and no other phase.
+the same pandas reference, and no other phase.  It passes since PR 28 (my
+chip runs, PR 28, four TPU v5 lite of one host, from ``git archive`` copies
+of the tree; the final tree's run with ``--seed 5``): exact against pandas
+over 13,585,255 groups, 134,217,728 rows through the exchange in two calls,
+off-diagonal share 0.7500, no compile in the warm call (3.09 s), no
+recovery event, peak 1.34 GB a chip.  Cold compile seconds by builder
+(the last line but one of a run; an empty cache, an earlier tree of PR 28
+whose exchange and join programs are the final one's): ``join__count_fn``
+90.5, ``shuffle__prep_fn`` 17.5, ``common__key_sample_fn`` 1.5,
+``shuffle__hash_targets_fn`` and ``shuffle__round_fn`` 1.2 each, the rest
+0.1; the final tree's ``fused__fused_fn`` 58.1 for its two dispatches (512
+segment slots, then the settled space).  On the parent of PR 28 the same
+command died in XLA:TPU's compile of the fused program (exit 139).
 
 The last line of standard output is one JSON object,
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
@@ -428,6 +440,8 @@ def main(argv=None) -> int:
     say(f"compiles: {cst['compile_events']} in {cst['compile_seconds']:.1f} s;"
         f" cache {cache_dir or 'off'} holds {_cache_entries(cache_dir)} "
         "entries after the run")
+    say("compile seconds by builder: " + json.dumps(
+        {b: round(v["seconds"], 1) for b, v in cst["by_builder"].items()}))
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 

@@ -152,10 +152,39 @@ def _u32(x):
     return jax.lax.bitcast_convert_type(x, jnp.uint32)
 
 
+#: XLA:TPU's scan rewriter (``tpu-reduce-window-rewriter``) leaves a scan
+#: of at most this many elements as it is
+_SCAN_BLOCK = 128
+
+
+def blocked_cumsum(x):
+    """``jnp.cumsum`` of a 1-D array written as scans of at most
+    :data:`_SCAN_BLOCK` elements: inside each block of 128, over the
+    block totals (recursively), then one add - the two-level form
+    XLA:TPU's scan rewriter gives a long scan itself.  For a 64-bit
+    accumulator on a mesh of more than one device: there the rewriter
+    dies (SIGSEGV, a use after free inside the compiler, in-process)
+    rewriting the (hi, lo) variadic reduce-windows a long 64-bit scan
+    lowers to, once a program holds about four of them (described
+    ``v5e:2x2`` compiles, PERF.md PR 28); a scan it does not rewrite
+    cannot meet that.  Integer sums are equal bit for bit; a float sum is
+    reassociated as the rewriter would."""
+    n = x.shape[0]
+    if n <= _SCAN_BLOCK:
+        return jnp.cumsum(x)
+    m = -(-n // _SCAN_BLOCK)
+    blocks = jnp.pad(x, (0, m * _SCAN_BLOCK - n)).reshape(m, _SCAN_BLOCK)
+    inner = jnp.cumsum(blocks, axis=1)
+    total = inner[:, -1]
+    before = blocked_cumsum(total) - total     # exclusive, per block
+    return (inner + before[:, None]).reshape(-1)[:n]
+
+
 def grouped_reduce(ops, values_list, vmasks, starts, n_live, key_datas,
                    key_valids, seg_cap: int, key_narrow=None,
                    value_narrow=None, pad_lanes: int = 0,
-                   gather_parts: int = 1, use_window: int = 0):
+                   gather_parts: int = 1, use_window: int = 0,
+                   blocked_scans: bool = False):
     """Grouped-input fast path, fully batched: per-group sums for the
     cumsum-able ops (sum/count/mean/var/std) AND the representative-key
     gather share ONE u32 lane-matrix gather (plus one f64 side gather when
@@ -178,7 +207,11 @@ def grouped_reduce(ops, values_list, vmasks, starts, n_live, key_datas,
     gather at bench density.  Returns (inter dicts per op, key_out tuple,
     kval_out tuple, win_ok) — win_ok is a scalar bool that is False when
     a windowed tile's index span overflowed (results are then garbage and
-    the DISPATCH layer must re-run with use_window=0)."""
+    the DISPATCH layer must re-run with use_window=0).
+
+    ``blocked_scans``: the program is compiled for more than one device
+    (relational/common.multi_shard), so every 64-bit prefix sum is a
+    :func:`blocked_cumsum`."""
     from . import lanes as lanes_mod
     n = key_datas[0].shape[0]
 
@@ -190,11 +223,15 @@ def grouped_reduce(ops, values_list, vmasks, starts, n_live, key_datas,
 
     acc_i = _int_dtype()   # int64, or int32 under the CYLON_TPU_X64=0 opt-out
 
+    def prefix_sum(x):
+        wide = np.dtype(x.dtype).itemsize == 8
+        return blocked_cumsum(x) if blocked_scans and wide else jnp.cumsum(x)
+
     def prefix_lanes(src, islot, name):
         if jnp.issubdtype(src.dtype, jnp.floating):
             with stage("scan"):
                 ps = jnp.concatenate([jnp.zeros(1, src.dtype),
-                                      jnp.cumsum(src)])
+                                      prefix_sum(src)])
             if src.dtype == jnp.float32 and not jax.config.jax_enable_x64:
                 u32_cols.append(_u32(ps))
                 recipes.append(("prefix", islot, name, "u32",
@@ -204,9 +241,12 @@ def grouped_reduce(ops, values_list, vmasks, starts, n_live, key_datas,
                 recipes.append(("prefix", islot, name, "f64",
                                 (len(f64_cols) - 1,), None))
             return
+        # a count never passes the row count (< 2^31): its prefix is an
+        # int32 scan, one lane, not an (hi, lo) pair narrowed afterwards
+        acc = jnp.int32 if name == "count" else acc_i
         with stage("scan"):
-            ps = jnp.concatenate([jnp.zeros(1, acc_i),
-                                  jnp.cumsum(src.astype(acc_i))])
+            ps = jnp.concatenate([jnp.zeros(1, acc),
+                                  prefix_sum(src.astype(acc))])
         narrow = name == "count" or (
             name == "sum" and value_narrow is not None
             and bool(value_narrow[islot]))

@@ -35,6 +35,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from .. import config
 from ..obs import comm as _comm, metrics as _metrics, plan as _plan
 from ..topo import model as _topo
+from ..utils import timing
 from ..utils.cache import jit, program_cache
 from ..ctx.context import ROW_AXIS
 from ..ops import hashing
@@ -518,30 +519,35 @@ def exchange(mesh: Mesh, tgt, counts: np.ndarray, cols: tuple,
         # active EXPLAIN ANALYZE only — the happy path skips on two
         # cached loads)
         _plan.record_exchange(counts, row_bytes, site=owner, tiers=tiers)
-    if hplan is not None:
-        # the voted hierarchical route (cylon_tpu/topo/exchange): the
-        # plan hash is consensus-adopted BEFORE the first hierarchical
-        # collective (one set lookup after the first exchange), then
-        # phase B runs as slice-local ICI alignment + one aggregated
-        # cross-slice DCN hop — bit- and order-equal to the flat branch
-        # below (docs/topology.md)
-        _topo.ensure_adopted(mesh, hplan)
-        outs, _pd = _topo_exchange.two_hop(mesh, hplan, tgt, counts,
-                                           tuple(cols), out_cap,
-                                           prep=hprep)
-    else:
-        if rounds > 1:
-            # countable path marker (tests/test_fuzz.py regime tier):
-            # the multi-round protocol actually engaged
-            from ..utils import timing
-            timing.bump("exchange.multiround")
-        counts_i = np.asarray(counts, np.int32)
-        tgt_s, perm, pos = _prep_fn(mesh, w)(tgt, counts_i)
-        outs = tuple(_alloc_fn(mesh, out_cap, str(c.dtype), c.shape[1:])()
-                     for c in cols)
-        # all rounds run in ONE compiled program (fori_loop if rounds>1)
-        fn = _round_fn(mesh, w, block, out_cap, max(rounds, 1))
-        outs = fn(tgt_s, perm, pos, counts_i, outs, tuple(cols))
+    # the exchange's host side on every sink of timing.span (profiler
+    # trace, flight recorder): what it moved, and how long the host took
+    # to enqueue its programs (docs/observability.md)
+    with timing.span("exchange." + route, rows=total,
+                     bytes=total * row_bytes):
+        if hplan is not None:
+            # the voted hierarchical route (cylon_tpu/topo/exchange): the
+            # plan hash is consensus-adopted BEFORE the first hierarchical
+            # collective (one set lookup after the first exchange), then
+            # phase B runs as slice-local ICI alignment + one aggregated
+            # cross-slice DCN hop — bit- and order-equal to the flat
+            # branch below (docs/topology.md)
+            _topo.ensure_adopted(mesh, hplan)
+            outs, _pd = _topo_exchange.two_hop(mesh, hplan, tgt, counts,
+                                               tuple(cols), out_cap,
+                                               prep=hprep)
+        else:
+            if rounds > 1:
+                # countable path marker (tests/test_fuzz.py regime tier):
+                # the multi-round protocol actually engaged
+                timing.bump("exchange.multiround")
+            counts_i = np.asarray(counts, np.int32)
+            tgt_s, perm, pos = _prep_fn(mesh, w)(tgt, counts_i)
+            outs = tuple(_alloc_fn(mesh, out_cap, str(c.dtype),
+                                   c.shape[1:])() for c in cols)
+            # all rounds run in ONE compiled program (fori_loop if
+            # rounds>1)
+            fn = _round_fn(mesh, w, block, out_cap, max(rounds, 1))
+            outs = fn(tgt_s, perm, pos, counts_i, outs, tuple(cols))
     # integrity audit tier (exec/integrity, docs/robustness.md): the
     # corruption drill first (so the audit below is what catches it),
     # then the always-on conservation laws — pure host math on the

@@ -94,6 +94,14 @@ def live_count(vcl: jax.Array, vcr: jax.Array) -> jax.Array:
     return (vcl[my] + vcr[my]).astype(jnp.int32)
 
 
+def multi_shard() -> bool:
+    """Whether the program being traced is compiled for more than one
+    device (call inside shard_map; static): what
+    ``ops/groupby.grouped_reduce`` needs to know to keep a long 64-bit
+    scan away from XLA:TPU's scan rewriter (``blocked_scans``)."""
+    return jax.lax.axis_size(ROW_AXIS) > 1
+
+
 def valid_flag(col: Column):
     """Boolean filter payload of a bool column with null rows forced False
     (pandas/Arrow semantics: a null predicate never selects a row).  Every

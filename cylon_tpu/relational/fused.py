@@ -47,7 +47,7 @@ from ..ops import lanes
 from ..utils import timing
 from ..utils.host import host_array
 from ..utils.stages import stage, staged
-from .common import REP, ROW, BoundedCache, live_count
+from .common import REP, ROW, BoundedCache, live_count, multi_shard
 
 shard_map = jax.shard_map
 
@@ -203,7 +203,7 @@ def _fused_fn(mesh: Mesh, n_l: int, all_live: bool, lspec, rspec,
             ops_list, vals, masks, starts, n_live, key_datas,
             key_valids, seg_cap, key_narrow=key_narrow,
             pad_lanes=pad_lanes, gather_parts=gather_parts,
-            use_window=use_window)
+            use_window=use_window, blocked_scans=multi_shard())
         l_cnt = inters[-2]["count"]
         r_cnt = inters[-1]["count"]
 

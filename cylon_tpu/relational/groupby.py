@@ -33,7 +33,7 @@ from ..status import InvalidError
 from ..utils import timing
 from ..utils.host import host_array
 from .common import (PAD_L, REP, ROW, BoundedCache, col_arrays,
-                     live_mask, narrow32_flags)
+                     live_mask, multi_shard, narrow32_flags)
 from .repart import shuffle_table
 
 shard_map = jax.shard_map
@@ -295,7 +295,7 @@ def _runs_reduce(specs_ops, val_datas, vmasks, gids, first, mask, vc,
         list(by_datas), list(by_valids), seg_cap, key_narrow=narrow,
         value_narrow=[(bool(vnarrow[b[1]]) if vnarrow else False)
                       for b in batch], pad_lanes=pad_lanes,
-        gather_parts=gather_parts)
+        gather_parts=gather_parts, blocked_scans=multi_shard())
     inters: dict = {}
     for (op, i), d in zip(batch, inters_b):
         inters.setdefault(i, {}).update(d)
@@ -412,7 +412,7 @@ def _final_fn(mesh: Mesh, ops: tuple, seg_cap: int, ddof: int, narrow: tuple,
             ["sum"] * len(sum_idx), [s_arrs[j] for j in sum_idx],
             [mask] * len(sum_idx), starts, n_live, list(s_by), list(s_byv),
             seg_cap, key_narrow=narrow, pad_lanes=pad_lanes,
-            gather_parts=gather_parts)
+            gather_parts=gather_parts, blocked_scans=multi_shard())
         red_flat = [None] * len(flat_arrs)
         for j, d in zip(sum_idx, inters_b):
             red_flat[j] = d["sum"]

@@ -200,12 +200,13 @@ def _annotation(name: str, sc, args: dict):
 @contextlib.contextmanager
 def span(name: str, **args):
     """A BOUNDARY span: on jax's profiler and in the flight recorder's
-    ring, like a region, but in no table.  For the two places where the
+    ring, like a region, but in no table.  For the places where the
     host meets the device — ``launch.<builder>`` (the enqueue of a
-    program) and ``pull.<kind>`` (a host pull) — which nest inside the
-    operator regions: entered into the phase/scope tables they would be
-    counted twice in every sum over a table (a session's fair-share
-    clock, a plan node's seconds, bench.py's dispatch total)."""
+    program), ``pull.<kind>`` (a host pull) and ``exchange.<route>`` (one
+    exchange's launches) — which nest inside the operator regions:
+    entered into the phase/scope tables they would be counted twice in
+    every sum over a table (a session's fair-share clock, a plan node's
+    seconds, bench.py's dispatch total)."""
     with _annotation(name, _scope(), args):
         tr = _TRACE[0]
         if tr is None:

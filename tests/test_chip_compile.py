@@ -45,6 +45,13 @@ def mesh1(topo):
     return Mesh(np.array(topo.devices[:1]), (ROW_AXIS,))
 
 
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    """The four described chips of the v5e:2x2 host, as the engine's mesh."""
+    from cylon_tpu.ctx.context import ROW_AXIS
+    return Mesh(np.array(topo.devices[:4]), (ROW_AXIS,))
+
+
 @pytest.fixture(autouse=True, scope="module")
 def _no_persistent_cache():
     """A compile for a described device is written to the persistent
@@ -185,3 +192,95 @@ def test_packed_piece_join_compiles_for_v5e(mesh1, env1, monkeypatch):
     _, _, piece_fn, piece_calls = _capture_main_path(env1, monkeypatch)
     static, args = piece_calls[0]
     compiler.aot_compile(piece_fn(mesh1, *static), *_abstract(args, mesh1))
+
+
+# ---- four chips (ISSUE 28) --------------------------------------------------
+# The distributed join->groupby's per-shard programs on the described 2x2
+# mesh at the benchmark cell's size: 8,912,896 rows per side per shard
+# (17 * 2^19, the receive capacity of a 2^23-row shuffle: a capacity of
+# config.pow2ceil's family that is no power of two), 17,825,792 concat rows.
+# XLA:TPU's scan rewriter dies with SIGSEGV, in-process and within a second
+# of starting, on a fused program for four devices that holds about four
+# LONG 64-bit scans (described compiles, PR 28; PERF.md): the parent
+# (efc7d6d: two int64 sums + the two counts widened to int64) at 512 slots
+# with the plain gather and with the windowed one alike; with the counts as
+# int32 scans and nothing else changed, two and three int64 sums compile
+# and four sums, or a mean beside a var, die again.  A death kills the test
+# process, so only this tree's programs (ops/groupby.blocked_cumsum on a
+# mesh of more than one device) are compiled here.
+
+_ROWS4 = 17 << 19
+
+
+def _fused_args(mesh, n_side: int, n_lanes: int = 3):
+    from cylon_tpu.ctx.context import ROW_AXIS
+    w = int(mesh.devices.size)
+    rep, row = NamedSharding(mesh, P()), NamedSharding(mesh, P(ROW_AXIS))
+    S = jax.ShapeDtypeStruct
+    vc = S((w,), np.int32, sharding=rep)
+    idx = S((w * 2 * n_side,), np.int32, sharding=row)
+    lane = S((w * 2 * n_side,), np.uint32, sharding=row)
+    return vc, vc, idx, idx, (lane,) * n_lanes
+
+
+def _fused_static(n_sums: int = 2):
+    """Lane specs and aggregations of the benchmark's query, all int64
+    within int32 bounds: left (k, a), right (b); sum(a), sum(b) by k -
+    or, for four sums, left (k, a, c), right (b, d)."""
+    from cylon_tpu.ops import lanes
+    nl = n_sums // 2
+    lspec = lanes.plan_lanes(("int64",) * (1 + nl), (False,) * (1 + nl),
+                             (True,) * (1 + nl))
+    rspec = lanes.plan_lanes(("int64",) * nl, (False,) * nl, (True,) * nl)
+    vspecs = tuple(("l", 1 + i, "sum") for i in range(nl)) \
+        + tuple(("r", i, "sum") for i in range(nl))
+    return (lspec, rspec, vspecs, (0,), (True,))
+
+
+@pytest.mark.parametrize("n_sums", [2, 4])
+def test_first_sight_compiles_for_four_chips(mesh4, n_sums):
+    """The first dispatch of a fused callsite: 512 segment slots, always
+    XLA's gather (relational/groupby._FIRST_SEG_CAP).  Every four-chip run
+    meets this program first."""
+    from cylon_tpu.exec import compiler
+    from cylon_tpu.relational import fused
+    prog = fused._fused_fn(mesh4, _ROWS4, False, *_fused_static(n_sums),
+                           512, 1)
+    compiled = compiler.aot_compile(
+        prog, *_fused_args(mesh4, _ROWS4, 1 + n_sums))
+    assert not _has_kernel(compiled)
+
+
+@pytest.mark.parametrize("window,n_sums", [(4096, 2), (0, 2), (4096, 4)])
+def test_fused_compiles_for_four_chips(mesh4, monkeypatch, window, n_sums):
+    """The settled dispatch at segment space 3,407,872 (density 0.2): with
+    the windowed Pallas gather inside, as an eligible callsite runs it
+    (the cell's two sums, and four), and with XLA's gather, as one below
+    the density floor does."""
+    from cylon_tpu.exec import compiler
+    from cylon_tpu.relational import fused
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prog = fused._fused_fn(mesh4, _ROWS4, False, *_fused_static(n_sums),
+                           3407872, 1, 0, 1, window)
+    compiled = compiler.aot_compile(
+        prog, *_fused_args(mesh4, _ROWS4, 1 + n_sums))
+    assert _has_kernel(compiled) == bool(window)
+
+
+def test_shuffle_round_compiles_for_four_chips(mesh4):
+    """One table's exchange round: scatter into the send blocks, the
+    all_to_all, scatter into the receive buffer (one u32 lane matrix of two
+    lanes, 2^21 rows a shard in, the next capacity out)."""
+    from cylon_tpu.ctx.context import ROW_AXIS
+    from cylon_tpu.exec import compiler
+    from cylon_tpu.parallel import shuffle
+    w, cap, block, out_cap = 4, 1 << 21, 17 << 15, 17 << 17
+    rep, row = NamedSharding(mesh4, P()), NamedSharding(mesh4, P(ROW_AXIS))
+    S = jax.ShapeDtypeStruct
+    i32 = S((w * cap,), np.int32, sharding=row)
+    prog = shuffle._round_fn(mesh4, w, block, out_cap, 1)
+    compiled = compiler.aot_compile(
+        prog, i32, i32, i32, S((w, w), np.int32, sharding=rep),
+        (S((w * out_cap, 2), np.uint32, sharding=row),),
+        (S((w * cap, 2), np.uint32, sharding=row),))
+    assert "all-to-all" in compiled.as_text()

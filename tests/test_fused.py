@@ -216,3 +216,190 @@ def test_f64_columns_carry_lite(env4, rng):
     exp = ej.sort_values(keycols).reset_index(drop=True)
     pd.testing.assert_frame_equal(got[exp.columns], exp, check_dtype=False,
                                   rtol=1e-12)
+
+
+# ---- ISSUE 28: the numpy reference, first sight, counts as int32 scans ------
+
+def _np_join_groupby_sum(lk, a, rk, b):
+    """Plain numpy: inner join on the key, sum(a) and sum(b) per key over
+    the joined rows, rows by key.  A side's row appears once per row of the
+    other side with its key."""
+    n_keys = int(max(lk.max(), rk.max())) + 1
+    lc = np.bincount(lk, minlength=n_keys)
+    rc = np.bincount(rk, minlength=n_keys)
+    sa = np.zeros(n_keys, np.int64)
+    sb = np.zeros(n_keys, np.int64)
+    np.add.at(sa, lk, a)
+    np.add.at(sb, rk, b)
+    keys = np.flatnonzero((lc > 0) & (rc > 0))
+    return keys.astype(np.int64), (sa * rc)[keys], (sb * lc)[keys]
+
+
+def _draw_keys(rng, dist: str, n: int):
+    if dist == "uniform":          # the benchmark's: density ~0.2
+        return rng.integers(0, int(0.9 * n), n).astype(np.int64)
+    # Zipf s = 1.1: a few huge groups, group density far under the
+    # windowed gather's floor (ops/pallas_gather.MIN_DENSITY)
+    return np.minimum(rng.zipf(1.1, n) - 1, 10 * n).astype(np.int64)
+
+
+@pytest.mark.parametrize("world", ["env1", "env4"])
+@pytest.mark.parametrize("dist", ["uniform", "zipf"])
+@pytest.mark.parametrize("n", [5000, 4096])
+def test_fused_sums_equal_numpy_reference(world, dist, n, request):
+    """join -> groupby-sum through the public entry points, cell by cell
+    against numpy: uniform keys and a low-density key set, shards padded
+    (5000 rows pad to the shape family) and exactly full, one device and
+    the 4-device mesh (shuffled first), first sight and the cached
+    segment space (the second call)."""
+    env = request.getfixturevalue(world)
+    rng = np.random.default_rng([28, n, dist == "zipf"])
+    lk, rk = _draw_keys(rng, dist, n), _draw_keys(rng, dist, n)
+    a = rng.integers(-1000, 1000, n).astype(np.int64)
+    b = rng.integers(0, 1 << 40, n).astype(np.int64)   # needs both lanes
+    lt = ct.Table.from_pydict({"k": lk, "a": a}, env)
+    rt = ct.Table.from_pydict({"k": rk, "b": b}, env)
+    wk, wa, wb = _np_join_groupby_sum(lk, a, rk, b)
+    for _call in range(2):
+        g = groupby_aggregate(join_tables(lt, rt, "k", "k", how="inner"),
+                              "k", [("a", "sum"), ("b", "sum")])
+        got = g.to_pandas().sort_values("k", kind="stable")
+        assert np.array_equal(np.asarray(got["k"]), wk)
+        assert np.array_equal(np.asarray(got["a_sum"]), wa)
+        assert np.array_equal(np.asarray(got["b_sum"]), wb)
+
+
+@pytest.mark.parametrize("world", ["env1", "env4"])
+def test_first_sight_counts_what_the_settled_program_counts(world, request):
+    """The first dispatch of a callsite runs at the 512-slot segment space
+    only to learn n_groups; the redispatch at the space chosen from that
+    count reports, shard by shard, the same count."""
+    from cylon_tpu.relational import fused
+    from cylon_tpu.relational.groupby import _FIRST_SEG_CAP
+    env = request.getfixturevalue(world)
+    rng = np.random.default_rng(281)
+    n = 20011     # a callsite signature no other test of this file has;
+                  # over 512 groups a shard at world 4 too
+    lt = ct.Table.from_pydict({"k": _draw_keys(rng, "uniform", n),
+                               "a": rng.integers(0, 99, n)}, env)
+    rt = ct.Table.from_pydict({"k": _draw_keys(rng, "uniform", n),
+                               "b": rng.integers(0, 99, n)}, env)
+    seen = []
+    real = fused._fused_fn
+
+    def builder(mesh, *static, **kw):
+        prog = real(mesh, *static, **kw)
+
+        def call(*args):
+            out = prog(*args)
+            seen.append((static[7], np.asarray(out[4]).reshape(-1, 2)[:, 0]))
+            return out
+        return call
+
+    try:
+        fused._fused_fn = builder
+        g = groupby_aggregate(join_tables(lt, rt, "k", "k", how="inner"),
+                              "k", [("a", "sum"), ("b", "sum")])
+    finally:
+        fused._fused_fn = real
+    (first_seg, first_n), (seg, n_groups) = seen
+    assert first_seg == _FIRST_SEG_CAP < int(first_n.max()) <= seg
+    assert np.array_equal(first_n, n_groups)
+    assert int(n_groups.sum()) == g.row_count > 0
+
+
+def _cumsums(traced):
+    """(accumulator dtype, scan length) of every cumsum equation."""
+    from cylon_tpu.analysis.jaxpr_check import iter_eqns
+    return [(str(e.outvars[0].aval.dtype),
+             e.invars[0].aval.shape[e.params["axis"]])
+            for e, _ in iter_eqns(traced) if e.primitive.name == "cumsum"]
+
+
+@pytest.mark.parametrize("world", ["env1", "env4"])
+def test_fused_program_keeps_wide_scans_from_the_rewriter(world, request):
+    """Two rules about the fused program's prefix sums, from the death of
+    XLA:TPU's scan rewriter on a 4-device mesh (PERF.md, PR 28: it dies
+    rewriting the (hi, lo) variadic reduce-windows that long 64-bit scans
+    lower to, about four of them in one program).  A count never passes the
+    row count, so its prefix is an int32 scan on every mesh (also 112 ms
+    of a 1.57 s query on one chip).  And on a mesh of more than one device
+    every 64-bit prefix sum is a ``blocked_cumsum``: no 64-bit scan is
+    longer than the 128 elements the rewriter leaves alone."""
+    import jax
+    from cylon_tpu.analysis import registry
+    from cylon_tpu.ops import lanes
+    from cylon_tpu.relational import fused
+    env = request.getfixturevalue(world)
+    w, cap = env.world_size, 1024
+    lspec = lanes.plan_lanes(("int64", "int64"), (False, False),
+                             (True, False))
+    rspec = lanes.plan_lanes(("int64",), (False,), (False,))
+    fn = fused._fused_fn(env.mesh, cap, False, lspec, rspec,
+                         (("l", 1, "sum"), ("r", 0, "sum"), ("l", 1, "mean")),
+                         (0,), (True,), 512, 1)
+    S = jax.ShapeDtypeStruct
+    vc = S((w,), np.int64)
+    row = S((w * 2 * cap,), np.int32)
+    pl = tuple(S((w * 2 * cap,), np.uint32) for _ in range(5))
+    traced = jax.make_jaxpr(registry.unwrap(fn))(vc, vc, row, row, pl)
+    scans = _cumsums(traced)
+    long_ = sorted(dt for dt, n in scans if n > 128)
+    # int32, over all 2 * cap rows: the geometry's three cumsums and three
+    # counts (the two multiplicities, the mean's)
+    assert long_.count("int32") == 6, scans
+    wide = [dt for dt in long_ if dt != "int32"]
+    if w == 1:
+        # as XLA's rewriter gets them: two int64 sums, the mean's f64 sum
+        assert wide == ["float64", "int64", "int64"], scans
+    else:
+        assert wide == [], scans
+        assert {dt for dt, n in scans if n <= 128} == {"int64", "float64"}
+
+
+def test_blocked_cumsum_equals_cumsum(rng):
+    """Bit for bit on int64 (sums past 2^32, negative values, a length that
+    is no multiple of 128 and one that needs three levels), to rounding on
+    float64."""
+    import jax.numpy as jnp
+    from cylon_tpu.ops.groupby import blocked_cumsum
+    for n in (1, 128, 129, 5000, 128 * 128 + 7, 128 ** 3 + 1):
+        x = rng.integers(-(1 << 40), 1 << 40, n).astype(np.int64)
+        assert np.array_equal(np.asarray(blocked_cumsum(jnp.asarray(x))),
+                              np.cumsum(x)), n
+    f = rng.normal(size=70001)
+    np.testing.assert_allclose(np.asarray(blocked_cumsum(jnp.asarray(f))),
+                               np.cumsum(f), rtol=1e-12, atol=1e-9)
+
+
+@pytest.mark.parametrize("world", ["env1", "env4"])
+def test_every_grouped_reduce_is_told_the_mesh(world, request, monkeypatch):
+    """The rule is one statement (relational/common.multi_shard) and every
+    caller of ``grouped_reduce`` passes it: the fused program, the
+    standalone groupby's per-shard reduce and its distributed final
+    step."""
+    from cylon_tpu.ops import groupby as gbk
+    env = request.getfixturevalue(world)
+    told = []
+    real = gbk.grouped_reduce
+
+    def spy(*args, **kwargs):
+        told.append(kwargs.get("blocked_scans"))
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(gbk, "grouped_reduce", spy)
+    rng = np.random.default_rng(283)
+    n = 7001      # shapes no other test has: the builders trace here
+    lt = ct.Table.from_pydict({"k": rng.integers(0, n // 3, n),
+                               "a": rng.integers(-(1 << 40), 1 << 40, n)},
+                              env)
+    rt = ct.Table.from_pydict({"k": rng.integers(0, n // 3, n),
+                               "b": rng.integers(0, 99, n)}, env)
+    groupby_aggregate(join_tables(lt, rt, "k", "k", how="inner"), "k",
+                      [("a", "sum"), ("b", "mean")]).to_pandas()
+    fused_calls = len(told)
+    got = groupby_aggregate(lt, "k", [("a", "sum")]).to_pandas()
+    assert fused_calls >= 1 and len(told) > fused_calls
+    assert told == [env.world_size > 1] * len(told)
+    want = lt.to_pandas().groupby("k")["a"].sum()
+    assert np.array_equal(got.sort_values("k")["a_sum"], want.sort_index())

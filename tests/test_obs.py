@@ -620,6 +620,32 @@ def test_ingest_regions_carry_rows_and_bytes(env1):
     assert spans["table.upload"] == {"rows": 512}
 
 
+def test_exchange_spans_carry_rows_and_bytes(env4):
+    """One ``exchange.<route>`` span per exchange, with the logical rows
+    and bytes the always-on counters take, around the launches of the
+    exchange's programs (ISSUE 28)."""
+    from cylon_tpu import obs
+    from cylon_tpu.relational import join_tables
+    left, right = _toy(env4)
+    join_tables(left, right, "k", "k").to_pandas()          # warm
+    rows, nbytes, count = (obs.counter(c) for c in (
+        "exchange_rows_total", "exchange_bytes_total", "exchange_count"))
+    before = rows.value, nbytes.value, count.value
+    rec = trace.arm(capacity=512)
+    join_tables(left, right, "k", "k").to_pandas()
+    events = [e for e in rec.events() if e[2] == "X"]
+    exch = [e for e in events if e[3] == "exchange.flat"]
+    assert len(exch) == count.value - before[2] == 2    # left, right
+    assert sum(e[6]["rows"] for e in exch) == rows.value - before[0] \
+        == left.row_count + right.row_count
+    assert sum(e[6]["bytes"] for e in exch) == nbytes.value - before[1]
+    # the round program is enqueued inside the span
+    rounds = [e for e in events if e[3] == "launch.shuffle__round_fn"]
+    assert len(rounds) == 2 and all(
+        any(x[0] <= r[0] and r[0] + r[1] <= x[0] + x[1] for x in exch)
+        for r in rounds)
+
+
 def test_compile_seconds_by_builder(env1):
     """A forced compile (a row count no other test uses) is attributed to
     the builder whose program was being launched, in

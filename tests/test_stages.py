@@ -73,6 +73,37 @@ def test_every_row_scale_equation_has_a_stage(builder, env4):
     assert _unstaged(traced) == []
 
 
+_CUMULATIVE = {"cumsum", "cumprod", "cummax", "cummin", "cumlogsumexp"}
+
+
+def _long_wide_scans(traced) -> list:
+    """Cumulative equations with a 64-bit operand and more than the 128
+    elements XLA:TPU's scan rewriter leaves alone."""
+    from cylon_tpu.analysis.jaxpr_check import iter_eqns
+    out = []
+    for eqn, _ in iter_eqns(traced):
+        if eqn.primitive.name in _CUMULATIVE:
+            aval = eqn.invars[0].aval
+            if (np.dtype(aval.dtype).itemsize == 8
+                    and aval.shape[eqn.params["axis"]] > 128):
+                out.append((eqn.primitive.name, str(aval.dtype), aval.shape))
+    return out
+
+
+@pytest.mark.parametrize("builder", sorted(DECLS))
+def test_multi_device_programs_hold_no_pile_of_long_wide_scans(builder, env4):
+    """XLA:TPU's scan rewriter dies in-process (SIGSEGV) on a program for
+    more than one device that holds about four long 64-bit scans - three
+    compiled, four died, described ``v5e:2x2`` compiles at 17.8M rows a
+    shard (PERF.md, PR 28).  ``ops/groupby.grouped_reduce``, whose scan
+    count grows with a query's aggregations, writes them in blocks on
+    such a mesh (``blocked_cumsum``): its builders hold none.  No other
+    builder holds more than one."""
+    scans = _long_wide_scans(DECLS[builder].trace(env4.mesh))
+    module = builder.split("[")[0].rpartition(".")[0].rpartition(".")[2]
+    assert len(scans) <= (0 if module in ("fused", "groupby") else 1), scans
+
+
 # ---- (b) the benchmark's two routes at toy size ---------------------------
 
 @pytest.fixture()
