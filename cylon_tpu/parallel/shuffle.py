@@ -11,12 +11,16 @@ complexity moves into static-shape capacity planning (SURVEY.md §7 hard-part
 
   phase A (device): rows → target ranks, per-(src,dst) count matrix
   host:             pick pow2 block capacity c and output capacity
-  phase B (device): stable-sort rows by target → scatter into (W·c) send
-                    blocks → ``lax.all_to_all`` over the mesh axis →
-                    stable compaction of valid rows (order-preserving:
-                    received order is (source rank, source position), the
-                    same contract as the reference's order-preserving
-                    all-to-all, table.cpp:182-190)
+  phase B (device): stable-sort rows by target, so each destination's rows
+                    are ONE contiguous run → copy the runs' windows into
+                    the (W·c) send blocks → ``lax.all_to_all`` over the
+                    mesh axis → copy each received block's valid prefix
+                    to its final place (order-preserving: received order
+                    is (source rank, source position), the same contract
+                    as the reference's order-preserving all-to-all,
+                    table.cpp:182-190).  Both placements are W segment
+                    copies at offsets the count matrix gives: the
+                    exchange's programs hold no XLA scatter.
 
 The count matrix doubles as the row-count sidecar the reference sends in its
 buffer headers.  All collectives ride ICI (mesh axis) — no host round-trip of
@@ -37,6 +41,7 @@ from ..obs import comm as _comm, metrics as _metrics, plan as _plan
 from ..topo import model as _topo
 from ..utils import timing
 from ..utils.cache import jit, program_cache
+from ..utils.stages import stage
 from ..ctx.context import ROW_AXIS
 from ..ops import hashing
 
@@ -84,9 +89,12 @@ def hash_targets(mesh: Mesh, key_datas, key_valids, valid_counts: np.ndarray):
 @program_cache()
 def _count_fn(mesh: Mesh, w: int):
     def per_shard(tgt):
-        counts = jax.ops.segment_sum(
-            jnp.ones(tgt.shape[0], jnp.int32), tgt, num_segments=w + 1)
-        return counts[:w].reshape(1, w)
+        # dense compare-and-reduce, the form ops/groupby._seg_apply takes
+        # for few segments: a segment_sum into W+1 segments is a
+        # colliding scatter-add (~72 ns a row on a v5e, PERF.md)
+        dest = jnp.arange(w, dtype=tgt.dtype)
+        hit = (tgt[None, :] == dest[:, None]).astype(jnp.int32)
+        return jnp.sum(hit, axis=1, dtype=jnp.int32).reshape(1, w)
 
     return jit(shard_map(per_shard, mesh=mesh, in_specs=(P(ROW_AXIS),),
                              out_specs=P(ROW_AXIS)))
@@ -235,92 +243,170 @@ def skew_split_targets(mesh: Mesh, key_datas, key_valids,
 # device memory by ~W× per column (round-1 VERDICT red flag).  The exchange
 # therefore runs in R = ceil(max_count / block) rounds with ``block`` capped
 # near the uniform-case size: round r moves the rows whose within-(src,dst)
-# position is in [r·block, (r+1)·block), and the receiver scatters each
-# round's rows STRAIGHT into their final (source-rank, source-position)
-# slots — no end-of-exchange compaction or re-sort, and peak extra memory
+# position is in [r·block, (r+1)·block), and the receiver copies each
+# round's blocks STRAIGHT to their final (source-rank, source-position)
+# place — no end-of-exchange compaction or re-sort, and peak extra memory
 # stays at W·block ≈ one shard's worth regardless of skew.
+#
+# Rows are stable-sorted by target once (``_prep_fn``), so both placements
+# are segment copies at offsets the replicated count matrix gives
+# (``send_fill``, ``recv_place``) — never an XLA scatter, which runs at
+# 56–82 ns a row on a v5e where a copy runs at memory speed (PERF.md §6,
+# PR 29).  ``exchange_rounds`` is the one round body of the flat engine and
+# of the two-hop route's grouped hops (topo/exchange._tier_round_fn).
 # ---------------------------------------------------------------------------
 
 @program_cache()
 def _prep_fn(mesh: Mesh, w: int):
-    """Per shard: stable order rows by destination once; reused each round.
-    Returns (tgt_s, perm, pos): sorted targets, source permutation, and the
-    row's position within its (me -> dest) stream."""
+    """Per shard: the stable order of the rows by destination — the source
+    permutation ``perm``, computed once and reused by every round.
+    Destination ``d``'s rows are the run ``[offs[d], offs[d] + C[my, d])``
+    of it (``offs`` = exclusive prefix of the count matrix's row ``my``);
+    padding rows (target ``w``) sort last and belong to no run."""
 
-    def per_shard(tgt, counts):
-        cap = tgt.shape[0]
-        my = jax.lax.axis_index(ROW_AXIS)
-        idx = jnp.arange(cap, dtype=jnp.int32)
-        tgt_s, perm = jax.lax.sort((tgt, idx), num_keys=1, is_stable=True)
-        my_counts = counts[my]
-        csum = jnp.cumsum(my_counts)
-        offs = jnp.concatenate([jnp.zeros(1, csum.dtype), csum[:-1]])
-        tgt_safe = jnp.clip(tgt_s, 0, w - 1)
-        pos = idx - offs[tgt_safe].astype(jnp.int32)
-        return tgt_s, perm, pos
+    def per_shard(tgt):
+        idx = jnp.arange(tgt.shape[0], dtype=jnp.int32)
+        _tgt_s, perm = jax.lax.sort((tgt, idx), num_keys=1, is_stable=True)
+        return perm
 
-    return jit(shard_map(per_shard, mesh=mesh,
-                             in_specs=(P(ROW_AXIS), P()),
-                             out_specs=(P(ROW_AXIS),) * 3))
+    return jit(shard_map(per_shard, mesh=mesh, in_specs=(P(ROW_AXIS),),
+                             out_specs=P(ROW_AXIS)))
+
+
+def _excl_prefix(c):
+    return jnp.cumsum(c) - c
+
+
+def _rows(arr, start, n: int):
+    """``arr[start : start + n]`` along the row axis, ``start`` traced."""
+    return jax.lax.dynamic_slice_in_dim(arr, start, n, axis=0)
+
+
+def send_fill(sorted_rows, starts, block: int):
+    """The send buffer of one round: block ``i`` is the window
+    ``sorted_rows[starts[i] : starts[i] + block]`` of the target-sorted
+    rows (``starts[i]`` = the run's offset + the round's ``lo``).  Slots
+    past the run's end carry a neighbour's rows, not zeros: ``recv_place``
+    reads only each block's valid prefix, so no mask pass is spent on them.
+
+    ``block`` rows of padding behind the rows let a window start anywhere
+    in ``[0, cap]`` (a run may end at ``cap``, and ``block`` may exceed
+    ``cap`` by config.pow2ceil's step): XLA clamps a slice's start so the
+    slice fits, silently, and here that only happens to a window past
+    ``cap`` — a later round of a stream that has ended, which holds no
+    valid row.  The padding is a copy's, not the gather's: a gathered row
+    costs 6 ns on a v5e, a copied one a hundredth of it (PERF.md §6,
+    PR 29)."""
+    with stage("exchange_place"):
+        pad = jnp.zeros((block,) + sorted_rows.shape[1:], sorted_rows.dtype)
+        padded = jnp.concatenate([sorted_rows, pad])
+        return jnp.concatenate([_rows(padded, starts[i], block)
+                                for i in range(starts.shape[0])])
+
+
+def recv_place(out, recv, starts, left, block: int):
+    """Copy the received blocks' valid prefixes to their final place:
+    block ``i`` of ``recv`` holds source ``i``'s rows ``lo .. lo+block`` for
+    me, of which the first ``clip(left[i], 0, block)`` exist (``left`` =
+    the stream's count − ``lo``), and they land at ``out[starts[i]:]``
+    (``starts[i]`` = rows of earlier sources + ``lo``).  Everything else
+    of ``out`` stays as it was: the zeros ``_alloc_fn`` wrote past a
+    destination's valid count (repart._rebuild and
+    integrity.verify_exchange rely on "a permutation + zero padding") and
+    the rows of earlier rounds and sources.
+
+    A window is ``block`` wide whatever the valid prefix, so it may pass
+    ``out``'s end (and, in a round past a short stream's end, start beyond
+    it).  XLA would clamp such a slice's start silently; the clamp is
+    taken here instead and the block's rows are read shifted by the same
+    amount (from ``recv`` behind ``block`` rows of padding)."""
+    out_cap = out.shape[0]
+    if block > out_cap:
+        raise ValueError(f"exchange block {block} exceeds the receive "
+                         f"capacity {out_cap}")
+    with stage("exchange_place"):
+        q = jnp.arange(block, dtype=jnp.int32).reshape(
+            (block,) + (1,) * (out.ndim - 1))
+        recv_p = jnp.concatenate(
+            [jnp.zeros((block,) + recv.shape[1:], recv.dtype), recv])
+        for i in range(starts.shape[0]):
+            n = jnp.clip(left[i], 0, block)
+            at = jnp.clip(starts[i], 0, out_cap - block)
+            shift = jnp.clip(starts[i] - at, 0, block)
+            rows = _rows(recv_p, (i + 1) * block - shift, block)
+            keep = (q >= shift) & (q < shift + n)
+            out = jax.lax.dynamic_update_slice_in_dim(
+                out, jnp.where(keep, rows, _rows(out, at, block)), at,
+                axis=0)
+        return out
+
+
+def exchange_rounds(perm, counts, outs, cols, *, block: int, rounds: int,
+                    members, groups=None):
+    """Per shard, inside ``shard_map``: every round of one exchange over
+    the group of ranks I trade with — ``members`` are its global ranks,
+    ascending (all ``w`` ranks for the flat engine, a tier's group for a
+    two-hop hop), ``groups`` the matching ``axis_index_groups``.
+
+    ``rounds > 1`` (skewed counts: some (src,dst) stream exceeds the
+    block) runs ALL rounds inside one compiled program via
+    ``lax.fori_loop`` — one dispatch total; the all_to_all stays
+    unconditional (a static trip count lowers to a scan, identical on
+    every rank: tests/test_trace_safety.py, JX201)."""
+    my = jax.lax.axis_index(ROW_AXIS)
+    offs = _excl_prefix(counts[my])[members]
+    recv_counts = counts[members, my]
+    roffs = _excl_prefix(recv_counts)
+    with stage("gather_rows"):
+        srt = tuple(col[perm] for col in cols)
+
+    def one_round(r, outs):
+        lo = r * jnp.int32(block)
+        new_outs = []
+        for out, rows in zip(outs, srt):
+            send = send_fill(rows, offs + lo, block)
+            recv = jax.lax.all_to_all(send, ROW_AXIS, split_axis=0,
+                                      concat_axis=0, tiled=True,
+                                      axis_index_groups=groups)
+            new_outs.append(recv_place(out, recv, roffs + lo,
+                                       recv_counts - lo, block))
+        return tuple(new_outs)
+
+    if rounds == 1:
+        return one_round(jnp.int32(0), tuple(outs))
+    return jax.lax.fori_loop(0, rounds, one_round, tuple(outs))
+
+
+def round_program(mesh: Mesh, per_shard):
+    """The jitted ``(perm, counts, outs, cols) -> outs`` program around a
+    per-shard round body; ``outs`` is donated (the receive buffers are
+    updated in place)."""
+
+    def fn(perm, counts, outs, cols):
+        n = len(cols)
+        specs_in = (P(ROW_AXIS), P(), (P(ROW_AXIS),) * n,
+                    (P(ROW_AXIS),) * n)
+        sm = shard_map(per_shard, mesh=mesh, in_specs=specs_in,
+                       out_specs=(P(ROW_AXIS),) * n)
+        return sm(perm, counts, outs, cols)
+
+    return jit(fn, donate_argnums=(2,))
 
 
 @program_cache()
 def _round_fn(mesh: Mesh, w: int, block: int, out_cap: int,
               rounds: int = 1):
-    """The exchange round engine: select a round's position window,
-    all-to-all, scatter received rows into their final output slots.
+    """The exchange round engine: fill the send blocks from the
+    target-sorted rows, all-to-all, place the received blocks
+    (``exchange_rounds`` over all ``w`` ranks).  ``out_cap`` is the
+    receive buffers' row count: part of the program's identity only."""
 
-    ``rounds > 1`` (skewed counts: some (src,dst) stream exceeds the
-    block) runs ALL rounds inside one compiled program via
-    ``lax.fori_loop`` — one dispatch total instead of one per round (the
-    round-3 verdict's multi-round host loop; the collective sits inside
-    the loop body, which XLA supports under shard_map)."""
+    def per_shard(perm, counts, outs, cols):
+        return exchange_rounds(perm, counts, outs, cols, block=block,
+                               rounds=rounds,
+                               members=jnp.arange(w, dtype=jnp.int32))
 
-    def one_round(r, tgt_s, perm, pos, counts, outs, cols, my):
-        lo = r * block
-        sel = (tgt_s < w) & (pos >= lo) & (pos < lo + block)
-        slot = jnp.where(sel, jnp.clip(tgt_s, 0, w - 1) * block + (pos - lo),
-                         jnp.int32(w * block))
-        # receiver: slot k = src*block + q holds src's row (lo + q); final
-        # position = (rows from earlier sources) + lo + q
-        recv_counts = counts[:, my]
-        rcsum = jnp.cumsum(recv_counts)
-        roffs = jnp.concatenate([jnp.zeros(1, rcsum.dtype), rcsum[:-1]])
-        k = jnp.arange(w * block, dtype=jnp.int32)
-        src = k // block
-        q = k - src * block
-        valid = (lo + q) < recv_counts[src]
-        fslot = jnp.where(valid, roffs[src].astype(jnp.int32) + lo + q,
-                          jnp.int32(out_cap))
-        new_outs = []
-        for out, col in zip(outs, cols):
-            send = jnp.zeros((w * block,) + col.shape[1:], col.dtype)
-            send = send.at[slot].set(col[perm], mode="drop")
-            recv = jax.lax.all_to_all(send, ROW_AXIS, split_axis=0,
-                                      concat_axis=0, tiled=True)
-            new_outs.append(out.at[fslot].set(recv, mode="drop"))
-        return tuple(new_outs)
-
-    def per_shard(tgt_s, perm, pos, counts, outs, cols):
-        my = jax.lax.axis_index(ROW_AXIS)
-        if rounds == 1:
-            return one_round(jnp.int32(0), tgt_s, perm, pos, counts, outs,
-                             cols, my)
-        return jax.lax.fori_loop(
-            0, rounds,
-            lambda r, o: one_round(jnp.int32(r), tgt_s, perm, pos, counts,
-                                   o, cols, my),
-            tuple(outs))
-
-    def fn(tgt_s, perm, pos, counts, outs, cols):
-        n = len(cols)
-        specs_in = (P(ROW_AXIS),) * 3 + (P(),) \
-            + ((P(ROW_AXIS),) * n,) + ((P(ROW_AXIS),) * n,)
-        sm = shard_map(per_shard, mesh=mesh, in_specs=specs_in,
-                       out_specs=(P(ROW_AXIS),) * n)
-        return sm(tgt_s, perm, pos, counts, outs, cols)
-
-    return jit(fn, donate_argnums=(4,))
+    return round_program(mesh, per_shard)
 
 
 @program_cache()
@@ -344,7 +430,7 @@ def exchange(mesh: Mesh, tgt, counts: np.ndarray, cols: tuple,
     """Run the (possibly multi-round) padded all-to-all for every array in
     ``cols`` (payload-agnostic: callers pre-pack laneable columns into one
     (cap, L) u32 lane matrix — relational/repart._flatten_for_exchange —
-    so the per-round scatter/all_to_all/scatter chain runs once per ARRAY,
+    so the per-round fill/all_to_all/place chain runs once per ARRAY,
     and a whole table is typically one matrix + f64 side arrays).
 
     ``owner`` names the ledger registration of the guarded receive
@@ -541,13 +627,13 @@ def exchange(mesh: Mesh, tgt, counts: np.ndarray, cols: tuple,
                 # the multi-round protocol actually engaged
                 timing.bump("exchange.multiround")
             counts_i = np.asarray(counts, np.int32)
-            tgt_s, perm, pos = _prep_fn(mesh, w)(tgt, counts_i)
+            perm = _prep_fn(mesh, w)(tgt)
             outs = tuple(_alloc_fn(mesh, out_cap, str(c.dtype),
                                    c.shape[1:])() for c in cols)
             # all rounds run in ONE compiled program (fori_loop if
             # rounds>1)
             fn = _round_fn(mesh, w, block, out_cap, max(rounds, 1))
-            outs = fn(tgt_s, perm, pos, counts_i, outs, tuple(cols))
+            outs = fn(perm, counts_i, outs, tuple(cols))
     # integrity audit tier (exec/integrity, docs/robustness.md): the
     # corruption drill first (so the audit below is what catches it),
     # then the always-on conservation laws — pure host math on the
@@ -594,15 +680,14 @@ def _trace_round(mesh):
     one = _unwrap(_round_fn(mesh, w, cap, out_cap, 1))
     i32 = np.int32
 
-    def both(tgt_s, perm, pos, counts, outs, cols):
+    def both(perm, counts, outs, cols):
         # single-round and scan-wrapped multi-round paths in one walk
-        a = one(tgt_s, perm, pos, counts, outs, cols)
-        b = fn(tgt_s, perm, pos, counts, outs, cols)
+        a = one(perm, counts, outs, cols)
+        b = fn(perm, counts, outs, cols)
         return a, b
 
-    args = (S((w * cap,), i32), S((w * cap,), i32), S((w * cap,), i32),
-            S((w, w), i32), (S((w * out_cap,), np.int64),),
-            (S((w * cap,), np.int64),))
+    args = (S((w * cap,), i32), S((w, w), i32),
+            (S((w * out_cap,), np.int64),), (S((w * cap,), np.int64),))
     return jax.make_jaxpr(both)(*args)
 
 
@@ -640,7 +725,7 @@ def _trace_skew_split_targets(mesh):
 def _trace_prep(mesh):
     w, cap, S = _decl_shapes(mesh)
     fn = _unwrap(_prep_fn(mesh, w))
-    return jax.make_jaxpr(fn)(S((w * cap,), np.int32), S((w, w), np.int32))
+    return jax.make_jaxpr(fn)(S((w * cap,), np.int32))
 
 
 from ..analysis.registry import (declare_builder, decl_shapes as _decl_shapes,  # noqa: E402

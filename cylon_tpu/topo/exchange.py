@@ -162,14 +162,13 @@ def _tier_round_fn(mesh: Mesh, w: int, n_slices: int, hop: int,
     Send buffers are ``G·block`` rows (G = group size) — the grouped
     collective moves G·block per rank per round instead of the flat
     engine's W·block, which is where the ~1/R cross-slice wire
-    reduction comes from.  Receive placement is the flat engine's:
-    slot ``k = src_in_group·block + q`` holds group-source
-    ``src_in_group``'s row ``lo + q``, scattered straight to final
-    position (rows from earlier group sources) + lo + q — group order
-    is ascending global rank for both tiers, so the receive order
-    composes to the flat contract.  Multi-round runs under one
-    static-trip fori_loop exactly like the flat engine (the collective
-    stays unconditional — the JX201 invariant)."""
+    reduction comes from.  The round body IS the flat engine's
+    (``shuffle.exchange_rounds``: segment copies into the send blocks,
+    the all-to-all, segment copies to the final place), handed the
+    group's members as destinations and sources: they ascend in GLOBAL
+    rank order for both tiers, so the receive order composes to the
+    flat contract."""
+    from ..parallel import shuffle as shf
     r_ = w // n_slices
     g = r_ if hop == 1 else n_slices
     if hop == 1:
@@ -177,59 +176,17 @@ def _tier_round_fn(mesh: Mesh, w: int, n_slices: int, hop: int,
     else:
         groups = [[s * r_ + j for s in range(n_slices)] for j in range(r_)]
 
-    def one_round(r, tgt_s, perm, pos, counts, outs, cols, my):
-        lo = r * block
-        tgt_c = jnp.clip(tgt_s, 0, w - 1)
-        gidx = (tgt_c % r_) if hop == 1 else (tgt_c // r_)
-        sel = (tgt_s < w) & (pos >= lo) & (pos < lo + block)
-        slot = jnp.where(sel, gidx * block + (pos - lo),
-                         jnp.int32(g * block))
-        # receiver: slot k = src_in_group*block + q; the group's sources
-        # ascend in GLOBAL rank order for both tiers, so earlier-source
-        # offsets reproduce the flat engine's placement
-        if hop == 1:
-            src_ranks = (my // r_) * r_ + jnp.arange(g, dtype=jnp.int32)
-        else:
-            src_ranks = jnp.arange(g, dtype=jnp.int32) * r_ + (my % r_)
-        recv_g = counts[src_ranks, my]
-        rcsum = jnp.cumsum(recv_g)
-        roffs = jnp.concatenate([jnp.zeros(1, rcsum.dtype), rcsum[:-1]])
-        k = jnp.arange(g * block, dtype=jnp.int32)
-        sg = k // block
-        q = k - sg * block
-        valid = (lo + q) < recv_g[sg]
-        fslot = jnp.where(valid, roffs[sg].astype(jnp.int32) + lo + q,
-                          jnp.int32(out_cap))
-        new_outs = []
-        for out, col in zip(outs, cols):
-            send = jnp.zeros((g * block,) + col.shape[1:], col.dtype)
-            send = send.at[slot].set(col[perm], mode="drop")
-            recv = jax.lax.all_to_all(send, ROW_AXIS, split_axis=0,
-                                      concat_axis=0, tiled=True,
-                                      axis_index_groups=groups)
-            new_outs.append(out.at[fslot].set(recv, mode="drop"))
-        return tuple(new_outs)
-
-    def per_shard(tgt_s, perm, pos, counts, outs, cols):
+    def per_shard(perm, counts, outs, cols):
         my = jax.lax.axis_index(ROW_AXIS)
-        if rounds == 1:
-            return one_round(jnp.int32(0), tgt_s, perm, pos, counts, outs,
-                             cols, my)
-        return jax.lax.fori_loop(
-            0, rounds,
-            lambda r, o: one_round(jnp.int32(r), tgt_s, perm, pos, counts,
-                                   o, cols, my),
-            tuple(outs))
+        if hop == 1:
+            members = (my // r_) * r_ + jnp.arange(g, dtype=jnp.int32)
+        else:
+            members = jnp.arange(g, dtype=jnp.int32) * r_ + (my % r_)
+        return shf.exchange_rounds(perm, counts, outs, cols, block=block,
+                                   rounds=rounds, members=members,
+                                   groups=groups)
 
-    def fn(tgt_s, perm, pos, counts, outs, cols):
-        n = len(cols)
-        specs_in = (P(ROW_AXIS),) * 3 + (P(),) \
-            + ((P(ROW_AXIS),) * n,) + ((P(ROW_AXIS),) * n,)
-        sm = shard_map(per_shard, mesh=mesh, in_specs=specs_in,
-                       out_specs=(P(ROW_AXIS),) * n)
-        return sm(tgt_s, perm, pos, counts, outs, cols)
-
-    return jit(fn, donate_argnums=(4,))
+    return shf.round_program(mesh, per_shard)
 
 
 # ---------------------------------------------------------------------------
@@ -267,24 +224,22 @@ def two_hop(mesh: Mesh, plan, tgt, counts: np.ndarray, cols: tuple,
     # hop 1: slice-local alignment over ICI, final target as sidecar
     tgt1 = _hop1_targets_fn(mesh, w, s_)(tgt)
     c1_i = np.asarray(c1, np.int32)
-    tgt1_s, perm1, pos1 = shf._prep_fn(mesh, w)(tgt1, c1_i)
+    perm1 = shf._prep_fn(mesh, w)(tgt1)
     cols1 = tuple(cols) + (tgt,)
     outs1 = tuple(shf._alloc_fn(mesh, cap1, str(c.dtype), c.shape[1:])()
                   for c in cols1)
     outs1 = _tier_round_fn(mesh, w, s_, 1, block1, cap1,
-                           max(rounds1, 1))(tgt1_s, perm1, pos1, c1_i,
-                                            outs1, cols1)
+                           max(rounds1, 1))(perm1, c1_i, outs1, cols1)
 
     # hop 2: aggregated cross-slice delivery over DCN
     vc1 = np.asarray(p.per_gw, np.int32)
     tgt2 = _hop2_targets_fn(mesh, w, cap1)(vc1, outs1[-1])
     c2_i = np.asarray(c2, np.int32)
-    tgt2_s, perm2, pos2 = shf._prep_fn(mesh, w)(tgt2, c2_i)
+    perm2 = shf._prep_fn(mesh, w)(tgt2)
     outs = tuple(shf._alloc_fn(mesh, out_cap, str(c.dtype), c.shape[1:])()
                  for c in cols)
     outs = _tier_round_fn(mesh, w, s_, 2, block2, out_cap,
-                          max(rounds2, 1))(tgt2_s, perm2, pos2, c2_i,
-                                           outs, outs1[:-1])
+                          max(rounds2, 1))(perm2, c2_i, outs, outs1[:-1])
     return outs, counts.sum(axis=0).astype(np.int64)
 
 
@@ -411,14 +366,13 @@ def _trace_tier_round(mesh):
     hop1 = _unwrap(_tier_round_fn(mesh, w, n_slices, 1, block, out_cap, 3))
     hop2 = _unwrap(_tier_round_fn(mesh, w, n_slices, 2, block, out_cap, 1))
 
-    def both(tgt_s, perm, pos, counts, outs, cols):
-        a = hop1(tgt_s, perm, pos, counts, outs, cols)
-        b = hop2(tgt_s, perm, pos, counts, outs, cols)
+    def both(perm, counts, outs, cols):
+        a = hop1(perm, counts, outs, cols)
+        b = hop2(perm, counts, outs, cols)
         return a, b
 
-    args = (S((w * cap,), i32), S((w * cap,), i32), S((w * cap,), i32),
-            S((w, w), i32), (S((w * out_cap,), np.int64),),
-            (S((w * cap,), np.int64),))
+    args = (S((w * cap,), i32), S((w, w), i32),
+            (S((w * out_cap,), np.int64),), (S((w * cap,), np.int64),))
     return jax.make_jaxpr(both)(*args)
 
 

@@ -43,6 +43,7 @@ STAGES = {
     "gather_rows": "row gathers by a permutation or take index",
     "compact": "shrink / slice / compaction by flag, valid counts",
     "hash": "row hashes and partition targets",
+    "exchange_place": "the exchange's send-block fill and receive placement",
     # roots, one per builder family
     "join": "relational/join.py programs (outside the steps above)",
     "groupby": "relational/groupby.py and fused.py programs",

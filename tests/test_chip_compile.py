@@ -267,20 +267,49 @@ def test_fused_compiles_for_four_chips(mesh4, monkeypatch, window, n_sums):
     assert _has_kernel(compiled) == bool(window)
 
 
-def test_shuffle_round_compiles_for_four_chips(mesh4):
-    """One table's exchange round: scatter into the send blocks, the
-    all_to_all, scatter into the receive buffer (one u32 lane matrix of two
-    lanes, 2^21 rows a shard in, the next capacity out)."""
+def _hlo_ops(text: str, opcode: str) -> int:
+    """Instructions of one opcode in compiled HLO text (async pairs count
+    once, by their ``-start``)."""
+    import re
+    return len(re.findall(rf"= \S+ {opcode}(?:-start)?\(", text))
+
+
+@pytest.mark.parametrize("cap,block,out_cap,rounds", [
+    (1 << 21, 17 << 15, 17 << 17, 1),
+    (1 << 23, 17 << 17, 17 << 19, 1),   # dist_join_groupby_8m_x4's shapes
+    (1 << 21, 17 << 13, 17 << 17, 3),
+])
+def test_shuffle_round_compiles_for_four_chips(mesh4, cap, block, out_cap,
+                                               rounds):
+    """One table's exchange rounds: the gather by ``perm``, the send blocks
+    as slices of the target-sorted rows, the all_to_all, each received
+    block's valid prefix copied to its final place (one u32 lane matrix of
+    two lanes; ``out_cap`` the next capacity of config.pow2ceil's family).
+    No scatter is left in the program, and exactly the one all_to_all."""
     from cylon_tpu.ctx.context import ROW_AXIS
     from cylon_tpu.exec import compiler
     from cylon_tpu.parallel import shuffle
-    w, cap, block, out_cap = 4, 1 << 21, 17 << 15, 17 << 17
+    w = 4
     rep, row = NamedSharding(mesh4, P()), NamedSharding(mesh4, P(ROW_AXIS))
     S = jax.ShapeDtypeStruct
-    i32 = S((w * cap,), np.int32, sharding=row)
-    prog = shuffle._round_fn(mesh4, w, block, out_cap, 1)
-    compiled = compiler.aot_compile(
-        prog, i32, i32, i32, S((w, w), np.int32, sharding=rep),
+    prog = shuffle._round_fn(mesh4, w, block, out_cap, rounds)
+    text = compiler.aot_compile(
+        prog, S((w * cap,), np.int32, sharding=row),
+        S((w, w), np.int32, sharding=rep),
         (S((w * out_cap, 2), np.uint32, sharding=row),),
-        (S((w * cap, 2), np.uint32, sharding=row),))
-    assert "all-to-all" in compiled.as_text()
+        (S((w * cap, 2), np.uint32, sharding=row),)).as_text()
+    assert _hlo_ops(text, "all-to-all") == 1
+    assert "scatter" not in text
+
+
+def test_shuffle_count_compiles_for_four_chips(mesh4):
+    """The count sidecar at the cell's shard size: a dense
+    compare-and-reduce, no scatter-add."""
+    from cylon_tpu.ctx.context import ROW_AXIS
+    from cylon_tpu.exec import compiler
+    from cylon_tpu.parallel import shuffle
+    row = NamedSharding(mesh4, P(ROW_AXIS))
+    text = compiler.aot_compile(
+        shuffle._count_fn(mesh4, 4),
+        jax.ShapeDtypeStruct((4 << 23,), np.int32, sharding=row)).as_text()
+    assert "scatter" not in text
