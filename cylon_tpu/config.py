@@ -131,9 +131,6 @@ PALLAS_PROBE = _env_flag("CYLON_TPU_PALLAS_PROBE", False)
 #: that cache is enabled — accelerator processes; CPU runs skip it.
 PREWARM_PIECE_PROGRAMS = _env_flag("CYLON_TPU_PREWARM", True)
 
-#: Round variable capacities up to powers of two to bound recompilation.
-POW2_CAPACITIES = _env_flag("CYLON_TPU_POW2_CAPS", True)
-
 #: Shape-family canonicalization at INGEST (exec/compiler.family_cap):
 #: single-controller tables pad their row capacity to the same pow2 family
 #: buckets the multi-rank distributor already uses, so N tenants with
@@ -314,18 +311,6 @@ def sort_samples(world: int) -> int:
     return max(64, 16 * world)
 
 
-#: Defer inner-join output materialization so a same-key groupby can consume
-#: the pre-expansion sorted state (relational/fused.py); any other access
-#: materializes transparently.  Reference analog: the streaming ops DAG
-#: (cpp/src/cylon/ops/, SURVEY §2 C9).
-DEFER_JOIN = _env_flag("CYLON_TPU_DEFER_JOIN", True)
-
-#: route large dense grouped-reduce gathers through the Pallas windowed
-#: kernel (ops/pallas_gather) on TPU — ~6x the XLA matrix gather at bench
-#: density; span overflows auto-redispatch the plain program
-WINDOWED_GATHER = _env_flag("CYLON_TPU_WINDOWED_GATHER", True)
-
-
 def pow2ceil(n: int) -> int:
     """Bucket a dynamic capacity to the next 2^(b-5) step for n in
     (2^(b-1), 2^b] (exact powers of two below 16Ki): 16 steps per octave,
@@ -335,8 +320,6 @@ def pow2ceil(n: int) -> int:
     (~15 ns/row measured), which dwarfs the marginal compiles (and
     capacity hysteresis amortizes those anyway)."""
     n = max(int(n), 1)
-    if not POW2_CAPACITIES:
-        return n
     if n <= 16384:
         return 1 << (n - 1).bit_length()
     step = 1 << ((n - 1).bit_length() - 5)

@@ -435,7 +435,7 @@ def _distribute(cols: dict[str, Column], env: CylonEnv) -> Table:
     w = env.world_size
     chunk = -(-n // w)  # contiguous rows per rank (last ranks may get fewer)
     # pow2-bucketed capacity: bounds the family of compiled shapes across
-    # ingests of varying row counts (config.POW2_CAPACITIES)
+    # ingests of varying row counts
     cap = config.pow2ceil(chunk)
     # the canonicalization decision is a pure function of (rows, world) —
     # rank-uniform, no vote — recorded on the active plan node (no-op

@@ -4,8 +4,7 @@ PRs 3–4 made the pipeline survive *in-process* faults: the consensus
 retry ladder re-plans at degraded configurations and the HBM ledger
 spills resident state to host RAM.  What neither can cure is a fault
 that poisons the PROCESS — a real XLA ``RESOURCE_EXHAUSTED`` on an
-HBM-poisoning rig, a libtpu compiler crash that exhausted its pad
-ladder — where the only honest remedy is a fresh process, and before
+HBM-poisoning rig, a reported libtpu compiler crash — where the only honest remedy is a fresh process, and before
 this module that meant recomputing every completed piece from zero.
 Following the lineage/checkpoint recovery tradition of the
 MapReduce/Spark line (PAPERS.md), this module adds the missing
@@ -46,7 +45,7 @@ MapReduce/Spark line (PAPERS.md), this module adds the missing
    resume to recompute, never to a wrong answer.
 
 4. **The FINAL ladder rung** (:mod:`cylon_tpu.exec.recovery`): an
-   unrecoverable ``DeviceOOMError`` or exhausted compiler-crash ladder
+   unrecoverable ``DeviceOOMError`` or reported compiler crash
    flushes the session (:func:`flush_for_abort`) and raises a typed
    :class:`~cylon_tpu.status.ResumableAbort` carrying the resume token
    instead of a bare abort.

@@ -291,7 +291,7 @@ class GroupBySink:
         specs = _normalize_aggs(list(self._chunk_aggs))
         h = try_begin_join_groupby(chunk, self.by, specs, 1)
         if h is not None:
-            self._pending.append((h, chunk))
+            self._pending.append(h)
             # one-deep: the next piece's program is enqueued before this
             # pull blocks.  Two-deep was measured SLOWER at the 125M
             # bench (12.91 vs 12.73 s/iter): the extra piece's pinned
@@ -300,30 +300,18 @@ class GroupBySink:
                 self._settle(self._pending.pop(0))
         else:
             self.flush_pending()
-        if h is None:
-            # a crash-exhausted begin must not let groupby_aggregate
-            # re-run the identical (uncached) compile ladder — force the
-            # materialize path first, exactly like _settle
-            chunk.columns  # noqa: B018 — triggers DeferredTable thunk
             self._adopt(
                 groupby_aggregate(chunk, self.by, list(self._chunk_aggs)))
         return None
 
-    def _settle(self, pending) -> None:
-        from ..relational.groupby import groupby_aggregate
+    def _settle(self, h) -> None:
         from ..utils import timing
-        h, chunk = pending
         with timing.sync_region("pipe.consume"):
             # the per-piece host sync of the sink pipeline: its ".block"
             # twin is where the dispatch/block split (bench.py,
             # CYLON_TPU_TIMING=async) charges the device work that every
             # dispatch-only pipe.* marker above it enqueued
             out = h.resolve()
-        if out is None:   # compile ladder exhausted mid-resolve
-            # materialize FIRST: groupby_aggregate would otherwise retry
-            # the identical (crash-exhausted, uncached) pushdown ladder
-            chunk.columns  # noqa: B018 — triggers DeferredTable thunk
-            out = groupby_aggregate(chunk, self.by, list(self._chunk_aggs))
         self._adopt(out)
 
     #: public alias of the consume path — the streaming view's verb

@@ -232,33 +232,20 @@ def classify(e: Exception) -> CylonError | None:
 # compiler-crash classification
 # ---------------------------------------------------------------------------
 
-#: the shape of a compiler-PROCESS death: the helper subprocess's name
-#: and the signal.  A kernel Mosaic *refuses* ("Mosaic failed to
-#: compile") is an invalid program, not a dead compiler — it is NOT in
-#: this set, so it raises instead of giving way to another gather.
-_BASE_CRASH_SIGS = ("tpu_compile_helper", "SIGSEGV")
-
-
-def compiler_crash_signatures() -> tuple:
-    """The compiler-crash message signatures: a TPU compile runs in the
-    ``tpu_compile_helper`` subprocess, and its death surfaces under that
-    name with the signal.  ``CYLON_TPU_CRASH_SIGS`` (``|``-separated)
-    overrides the set entirely, which is how tests prove the pad ladder
-    still engages under a synthetic signature change."""
-    env_sigs = os.environ.get("CYLON_TPU_CRASH_SIGS")
-    if env_sigs is not None:
-        return tuple(s for s in env_sigs.split("|") if s)
-    return _BASE_CRASH_SIGS
+#: the shape of a compiler-PROCESS death: a helper subprocess's name and
+#: the signal.  A kernel Mosaic *refuses* ("Mosaic failed to compile") is
+#: an invalid program, not a dead compiler, and is not in this set.
+_CRASH_SIGS = ("tpu_compile_helper", "SIGSEGV")
 
 
 def is_compiler_crash(e: Exception) -> bool:
-    """True when the XLA compiler process died (SIGSEGV landmines: f64
-    sort payloads and specific gather lane widths, v5e libtpu 2026-07)
-    rather than the program being invalid — matched against
-    :func:`compiler_crash_signatures`, which the pad ladder
-    (``relational/groupby._pad_ladder``) engages on."""
+    """True when the error says the XLA compiler died rather than that the
+    program is invalid.  On the supported installation XLA:TPU compiles
+    in-process, so such a death takes the process and nothing raises
+    this; :func:`_resumable` keeps the classification for a runtime that
+    does surface one."""
     s = str(e)
-    return any(sig in s for sig in compiler_crash_signatures())
+    return any(sig in s for sig in _CRASH_SIGS)
 
 
 # ---------------------------------------------------------------------------
@@ -1118,7 +1105,7 @@ def _resumable(exc, label: str):
     & resume"): when durable checkpointing is armed
     (``CYLON_TPU_CKPT_DIR``) and the fault is one no in-process rung can
     cure — a real :class:`DeviceOOMError` (HBM may be poisoned) or an
-    exhausted compiler-crash ladder — flush the checkpoint session and
+    error that reports the compiler's death — flush the checkpoint session and
     convert into a typed :class:`ResumableAbort` carrying the resume
     token, so a supervisor can relaunch with ``CYLON_TPU_RESUME=1`` and
     fast-forward past every committed piece.  Anything else (or with
@@ -1141,9 +1128,9 @@ def _resumable(exc, label: str):
 
 
 def _attempt(fn, label: str = ""):
-    """(result, fault) — non-fault exceptions propagate (a compiler
-    crash that exhausted its pad ladder takes the FINAL checkpoint rung
-    on the way out when one is armed)."""
+    """(result, fault) — non-fault exceptions propagate (a reported
+    compiler crash takes the FINAL checkpoint rung on the way out when
+    one is armed)."""
     try:
         return fn(), None
     except Exception as e:  # noqa: BLE001 — classify filters

@@ -175,6 +175,7 @@ class QueryPlan:
         self.roots: list[PlanNode] = []
         self.result = None          # the profiled callable's return value
         self.global_phases: dict = {}
+        self.global_seconds = 0.0   # the phase table's unrounded total
         self.comm: dict | None = None
 
     def static_dict(self) -> dict:
@@ -199,11 +200,15 @@ class QueryPlan:
         node's SELF table must equal the process-global phase table
         accumulated over the run (both tables saw the identical region
         durations; only the grouping differs, so equality holds to fp
-        summation order).  Regions fired outside any node land in
-        ``unattributed_s``."""
+        summation order: the totals are sums of UNROUNDED seconds,
+        rounded once — the tables' own entries are rounded to 1e-4 each).
+        Regions fired outside any node land in ``unattributed_s``."""
         per_name: dict = {}
+        node_s = 0.0
 
         def walk(n: PlanNode):
+            nonlocal node_s
+            node_s += n.seconds or 0.0
             for k, v in (n.phases or {}).items():
                 per_name[k] = per_name.get(k, 0.0) + v["s"]
             for c in n.children:
@@ -211,9 +216,7 @@ class QueryPlan:
 
         for r in self.roots:
             walk(r)
-        node_s = sum(per_name.values())
-        glob = {k: v["s"] for k, v in self.global_phases.items()}
-        glob_s = sum(glob.values())
+        glob_s = self.global_seconds
         return {"node_s": round(node_s, 6),
                 "phase_s": round(glob_s, 6),
                 "unattributed_s": round(glob_s - node_s, 6),
@@ -521,6 +524,7 @@ def explain_analyze(fn, *args, reset_timings: bool = True,
             prof.keys_enabled = bool(profile_keys)
             prof.result = fn(*args, **kwargs)
             prof.global_phases = timing.snapshot()
+            prof.global_seconds = timing.total_seconds()
     finally:
         config.BENCH_TIMINGS = prev
     if comm.armed():

@@ -182,8 +182,7 @@ def blocked_cumsum(x):
 
 def grouped_reduce(ops, values_list, vmasks, starts, n_live, key_datas,
                    key_valids, seg_cap: int, key_narrow=None,
-                   value_narrow=None, pad_lanes: int = 0,
-                   gather_parts: int = 1, use_window: int = 0,
+                   value_narrow=None, use_window: int = 0,
                    blocked_scans: bool = False):
     """Grouped-input fast path, fully batched: per-group sums for the
     cumsum-able ops (sum/count/mean/var/std) AND the representative-key
@@ -312,35 +311,11 @@ def grouped_reduce(ops, values_list, vmasks, starts, n_live, key_datas,
         g_next = jnp.concatenate([g[1:], tailv], axis=0)
         return g, g_next
 
-    def gather_pair_multi(cols):
-        """gather_pair split into ``gather_parts`` narrower matrix
-        gathers, columns re-concatenated in order — another shape-shifting
-        variant for the XLA:TPU compiler-crash ladder (specific full-width
-        combinations crash; the narrower parts compile)."""
-        parts = min(gather_parts, len(cols))
-        if parts <= 1:
-            return gather_pair(cols)
-        bounds = np.linspace(0, len(cols), parts + 1).astype(int)
-        gs, gns = [], []
-        for lo, hi in zip(bounds[:-1], bounds[1:]):
-            if hi > lo:
-                g, gn = gather_pair(list(cols[lo:hi]))
-                gs.append(g)
-                gns.append(gn)
-        return (jnp.concatenate(gs, axis=1),
-                jnp.concatenate(gns, axis=1))
-
     win_ok = jnp.ones((), bool)
     windowed = False
     if use_window and u32_cols:
         from . import pallas_gather as pg
         windowed = pg.supported(n + 1, seg_cap, len(u32_cols), use_window)
-    if pad_lanes and not windowed:
-        # XLA:TPU compiler landmine: specific (u32, f64) gather-lane width
-        # combinations SIGSEGV tpu_compile_helper (v5e libtpu 2026-07; e.g.
-        # 7xu32+6xf64 crashes while 8xu32+6xf64 compiles).  Callers retry a
-        # crashed compile with pad_lanes>0 dummy lanes to shift the width.
-        u32_cols = u32_cols + [jnp.zeros(n + 1, jnp.uint32)] * pad_lanes
     g_u = gn_u = g_f = gn_f = None
     if windowed:
         # lane-major stack (a post-hoc transpose would cost ~700 ms; the
@@ -354,9 +329,9 @@ def grouped_reduce(ops, values_list, vmasks, starts, n_live, key_datas,
                 (len(u32_cols), 1))
             gn_u = jnp.concatenate([g_u[:, 1:], tail], axis=1)
     elif u32_cols:
-        g_u, gn_u = gather_pair_multi(u32_cols)
+        g_u, gn_u = gather_pair(u32_cols)
     if f64_cols:
-        g_f, gn_f = gather_pair_multi(f64_cols)
+        g_f, gn_f = gather_pair(f64_cols)
 
     def ucol(li, at_next: bool):
         src = gn_u if at_next else g_u
@@ -412,8 +387,8 @@ CUMSUMMABLE = {"sum", "count", "mean", "var", "std", "sumsq"}
 
 def combine_locally(op: str, values, gids, num_segments, mask=None):
     """Stage 1: per-group intermediates on local rows.  Returns a dict of
-    named intermediate arrays, each of length num_segments and each further
-    reducible by :func:`reduce_intermediates`."""
+    named intermediate arrays, each of length num_segments; sum, sumsq
+    and count reduce further by summing, min and max by min and max."""
     if op == "sum":
         return {"sum": seg_sum(values, gids, num_segments, mask)}
     if op == "count":
@@ -437,18 +412,6 @@ def combine_locally(op: str, values, gids, num_segments, mask=None):
         f = values.astype(_ftype(values))
         return {"sumsq": seg_sum(f * f, gids, num_segments, mask)}
     raise ValueError(f"op {op} has no associative decomposition")
-
-
-_REDUCERS = {"sum": seg_sum, "sumsq": seg_sum, "count": seg_sum,
-             "min": seg_min, "max": seg_max}
-
-
-def reduce_intermediates(inter: dict, gids, num_segments, mask=None):
-    """Stage 4: combine shuffled intermediates keyed by new group ids.
-    min/max of empty pre-groups carry sentinel values; their count=0 keeps
-    them out of the final validity."""
-    return {k: _REDUCERS[k](v, gids, num_segments, mask)
-            for k, v in inter.items()}
 
 
 @staged("segment_reduce")

@@ -38,7 +38,6 @@ from jax.sharding import Mesh
 
 from .. import config
 from ..utils.cache import jit, program_cache
-from ..core.column import Column
 from ..core.dtypes import LogicalType
 from ..core.table import DeferredTable, Table
 from ..ops import groupby as gbk
@@ -54,7 +53,9 @@ shard_map = jax.shard_map
 #: ops whose join pushdown is exact multiplicity algebra
 PUSHDOWN_OPS = {"sum", "count", "mean", "var", "std", "sumsq"}
 
-#: callsite-signature -> last observed kept-group-count bucket
+#: fused callsite-signature (``sig[0]`` the env serial) -> (kept-group
+#: bucket, windowed gather allowed, window): what
+#: :func:`~.groupby.dispatch_at_bucket` settled on
 _SEG_CACHE = BoundedCache()
 
 
@@ -114,8 +115,7 @@ def _col_entry(state: JoinState, name: str):
 @program_cache()
 def _fused_fn(mesh: Mesh, n_l: int, all_live: bool, lspec, rspec,
               vspecs: tuple, key_cols: tuple, key_narrow: tuple,
-              seg_cap: int, ddof: int, pad_lanes: int = 0,
-              gather_parts: int = 1, use_window: int = 0):
+              seg_cap: int, ddof: int, use_window: int = 0):
     """Per-shard fused join+groupby kernel.
 
     ``vspecs``: per aggregation (side, lane_col_idx, op); ``key_cols``:
@@ -202,7 +202,6 @@ def _fused_fn(mesh: Mesh, n_l: int, all_live: bool, lspec, rspec,
         inters, key_out, kval_out, wok = gbk.grouped_reduce(
             ops_list, vals, masks, starts, n_live, key_datas,
             key_valids, seg_cap, key_narrow=key_narrow,
-            pad_lanes=pad_lanes, gather_parts=gather_parts,
             use_window=use_window, blocked_scans=multi_shard())
         l_cnt = inters[-2]["count"]
         r_cnt = inters[-1]["count"]
@@ -247,35 +246,9 @@ def window_for(mesh, seg_cap: int, density: float) -> int:
     hurt."""
     from ..ops import pallas_gather as pg
     on_tpu = next(iter(mesh.devices.flat)).platform == "tpu"
-    if not (on_tpu and config.WINDOWED_GATHER):
-        return 0
-    if density < pg.MIN_DENSITY or seg_cap < (1 << 20):
+    if not on_tpu or density < pg.MIN_DENSITY or seg_cap < (1 << 20):
         return 0
     return pg.pick_window(density)
-
-
-class _PendingFused:
-    """A DISPATCHED (not yet pulled) fused join+groupby.  The first device
-    program is already enqueued; :meth:`resolve` pulls its meta sidecar,
-    handles seg-cap/window mispredicts (redispatching as needed) and
-    builds the result Table — or returns None when the compile ladder is
-    exhausted mid-resolve (caller falls back to the materialize path).
-
-    Purpose: a range-partitioned pipeline consumes one fused groupby per
-    piece, and each meta pull is a full host round trip (device idle
-    meanwhile; not measured on the current runtime).  Begin/
-    resolve lets the consumer enqueue piece i+1's program BEFORE pulling
-    piece i's meta — one-deep software pipelining of dispatch vs pull
-    (the reference's ops-DAG keeps pieces in flight the same way,
-    cpp/src/cylon/ops/execution/execution.hpp:43 RoundRobin)."""
-
-    __slots__ = ("_fn",)
-
-    def __init__(self, fn):
-        self._fn = fn
-
-    def resolve(self):
-        return self._fn()
 
 
 def try_join_groupby_pushdown(table: Table, by: list, specs: list,
@@ -290,8 +263,8 @@ def try_join_groupby_pushdown(table: Table, by: list, specs: list,
 def try_begin_join_groupby(table: Table, by: list, specs: list,
                            ddof: int):
     """Dispatch the fused join+groupby WITHOUT waiting for its meta pull.
-    Returns a :class:`_PendingFused` (resolve() -> Table | None), or None
-    when the fused path does not apply or its first compile crashed."""
+    Returns a :class:`~.groupby.PendingReduce` (resolve() -> Table), or
+    None when the fused path does not apply."""
     if not isinstance(table, DeferredTable) or table.materialized:
         return None
     state = table.op_state
@@ -337,12 +310,12 @@ def try_begin_join_groupby(table: Table, by: list, specs: list,
         key_narrow.append(bool(state.lspec.cols[ent[1]].narrow))
 
     env = table.env
-    from .groupby import _result_table, _shrink
+    from .groupby import (PendingReduce, _result_table, _result_types,
+                          _shrink, dispatch_at_bucket)
     # result typing from the join output schema
     class _C:  # minimal stand-in with .type/.dictionary for _result_types
         def __init__(self, t, dc):
             self.type, self.dictionary = t, dc
-    from .groupby import _result_types
     val_cols = [_C(state.types[state.names.index(c)],
                    state.dicts[state.names.index(c)]) for c, _, _, _ in specs]
     res_types, res_dicts = _result_types(specs, val_cols)
@@ -350,89 +323,36 @@ def try_begin_join_groupby(table: Table, by: list, specs: list,
                   state.dicts[state.names.index(k)]) for k in by]
     res_names = [n for _, _, _, n in specs]
 
-    cap_total = state.cap_l + state.cap_r
     args = (state.vcl, state.vcr, state.idx_s, state.bnd, state.pl_s)
-    sig = (env.serial, tuple(by), tuple(vspecs), state.cap_l, state.cap_r,
-           int(state.vcl.sum()), int(state.vcr.sum()), ddof)
-
-    from .groupby import _FIRST_SEG_CAP, _is_compiler_crash, _pad_ladder
+    live = np.asarray(state.vcl, np.int64) + np.asarray(state.vcr, np.int64)
 
     def call(sc, win):
-        # same compiler-crash ladder as every other grouped_reduce dispatch
-        # site: the windowed Pallas gather first (when eligible), then
-        # dummy gather lanes to shift a SIGSEGV-ing lane width, then a
-        # split gather — a still-crashing spec bails to the materialize path
-        def disp(pad, parts=1, w=0):
-            return _fused_fn(env.mesh, state.cap_l, state.all_live,
-                             state.lspec, state.rspec, tuple(vspecs),
-                             tuple(key_cols), tuple(key_narrow), sc,
-                             ddof, pad, parts, w)(*args)
+        return _fused_fn(env.mesh, state.cap_l, state.all_live, state.lspec,
+                         state.rspec, tuple(vspecs), tuple(key_cols),
+                         tuple(key_narrow), sc, ddof, win)(*args)
 
-        attempts = []
-        if win:
-            attempts.append(("fused+win", lambda: disp(0, 1, win)))
-        attempts += [(f"fused+pad{p}", lambda p=p: disp(p)) for p in (0, 1)]
-        attempts.append(("fused+split2", lambda: disp(0, 2)))
-        return _pad_ladder(("fused", env.serial, tuple(vspecs),
-                            tuple(key_cols), tuple(key_narrow), bool(win)),
-                           attempts)
+    def read_meta(res):
+        # n_groups and the windowed gather's span flag, one pull
+        meta = host_array(res[-1]).astype(np.int64).reshape(-1, 2)
+        return meta[:, 0], bool(np.all(meta[:, 1]))
 
-    # first sight of a large state: dispatch at a modest segment space
-    # (multi-10M-segment programs have pathological XLA:TPU compile
-    # times); the returned n_groups detects a mispredict.  Cache value:
-    # (seg bucket, windowed allowed, window size) — the window is
-    # picked from the MEASURED per-shard group density (min across
-    # shards) and a span overflow (win_ok False) permanently disables
-    # the windowed gather for this callsite.
+    def window(sc, n_groups):
+        # from the MEASURED per-shard group density (min across shards)
+        dens = float((n_groups / np.maximum(live, 1)).min()) \
+            if n_groups.size else 0.0
+        return window_for(env.mesh, sc, dens)
+
     with timing.region("groupby.fused"):
-        pred = _SEG_CACHE.get(sig)
-        if isinstance(pred, tuple):
-            pred_seg, win_allowed, win = pred
-        else:
-            pred_seg, win_allowed, win = pred, True, 0
-        if pred_seg is not None and pred_seg < cap_total:
-            seg_cap = pred_seg
-        elif pred_seg is None and cap_total > _FIRST_SEG_CAP:
-            seg_cap = _FIRST_SEG_CAP
-        else:
-            seg_cap = config.pow2ceil(cap_total)
-        if not win_allowed:
-            win = 0
-        try:
-            res = call(seg_cap, win)     # ENQUEUED; meta not pulled yet
-        except Exception as e:  # noqa: BLE001
-            if _is_compiler_crash(e):
-                return None   # ladder exhausted: materialize path handles it
-            raise
+        h = dispatch_at_bucket(
+            _SEG_CACHE,
+            (env.serial, tuple(by), tuple(vspecs), state.cap_l, state.cap_r,
+             int(state.vcl.sum()), int(state.vcr.sum()), ddof),
+            config.pow2ceil(state.cap_l + state.cap_r), call, read_meta,
+            window)
 
     def _resolve():
-        nonlocal res, seg_cap, win, win_allowed
-        live = np.asarray(state.vcl, np.int64) + np.asarray(state.vcr,
-                                                            np.int64)
         with timing.region("groupby.fused"):
-            try:
-                for _ in range(3):
-                    meta = host_array(res[4]).astype(np.int64).reshape(-1, 2)
-                    n_groups = meta[:, 0]
-                    ng_cap = config.pow2ceil(int(n_groups.max())
-                                             if n_groups.size else 1)
-                    wok = (not win) or bool(np.all(meta[:, 1]))
-                    if ng_cap <= seg_cap and wok:
-                        break
-                    if not wok:
-                        win_allowed = False
-                    seg_cap = max(seg_cap, ng_cap)
-                    dens = float((n_groups / np.maximum(live, 1)).min()) \
-                        if n_groups.size else 0.0
-                    win = window_for(env.mesh, seg_cap, dens) \
-                        if win_allowed else 0
-                    res = call(seg_cap, win)
-            except Exception as e:  # noqa: BLE001
-                if _is_compiler_crash(e):
-                    return None   # caller falls back to materialize path
-                raise
-            _SEG_CACHE.put(sig, (ng_cap, win_allowed, win))
-            key_out, kval_out, res_d, res_v = res[0], res[1], res[2], res[3]
+            (key_out, kval_out, res_d, res_v, _), n_groups = h.resolve()
         out = _result_table(env, by, by_cols, key_out, kval_out, res_names,
                             res_d, res_v, res_types, res_dicts, n_groups)
         out = _shrink(out, n_groups)
@@ -447,4 +367,4 @@ def try_begin_join_groupby(table: Table, by: list, specs: list,
             out.grouped_by = tuple(by)
         return out
 
-    return _PendingFused(_resolve)
+    return PendingReduce(_resolve)

@@ -40,7 +40,9 @@ shard_map = jax.shard_map
 
 _VALID_OPS = gbk.ASSOCIATIVE | gbk.NON_ASSOCIATIVE
 
-#: callsite-signature -> last observed group-count bucket
+#: callsite-signature -> what the site's last dispatch settled on:
+#: (segment bucket, windowed gather allowed, window) - written and read by
+#: :func:`dispatch_at_bucket` alone
 _SEG_CACHE = BoundedCache()
 
 #: optimistic first-dispatch segment space for large-cap groupbys with no
@@ -49,55 +51,88 @@ _SEG_CACHE = BoundedCache()
 #: programs at multi-10M shapes have pathological XLA:TPU compile times
 #: (observed 50+ min), while the dense form compiles in seconds.  A
 #: mispredict (more groups than this) is detected via the returned
-#: n_groups and re-dispatched at the true bucket (see the dispatch
-#: comment in _groupby_aggregate_impl).
+#: n_groups and re-dispatched at the true bucket
+#: (:func:`dispatch_at_bucket`).
 _FIRST_SEG_CAP = 512
 
-#: program-signature -> first ladder attempt index that compiled (see
-#: :func:`_pad_ladder`)
+#: always empty: ``benchmark/lib/checks.py`` still reads it (ROADMAP D10)
 _PAD_CACHE = BoundedCache()
 
 
-def _is_compiler_crash(e: Exception) -> bool:
-    """True when the XLA compiler process died rather than the program
-    being invalid (a kernel Mosaic refuses is invalid, and raises) —
-    delegates to :func:`cylon_tpu.exec.recovery.is_compiler_crash`
-    (``CYLON_TPU_CRASH_SIGS`` overrides the signature set)."""
-    from ..exec.recovery import is_compiler_crash
-    return is_compiler_crash(e)
+class PendingReduce:
+    """A DISPATCHED (not yet pulled) grouped reduce: the device program is
+    enqueued, :meth:`resolve` pulls its meta sidecar and hands back the
+    result.  A range-partitioned pipeline consumes one fused groupby per
+    piece and each meta pull is a host round trip; begin/resolve lets the
+    consumer enqueue piece i+1's program BEFORE pulling piece i's meta —
+    one-deep software pipelining of dispatch against pull (the
+    reference's ops-DAG keeps pieces in flight the same way,
+    cpp/src/cylon/ops/execution/execution.hpp:43 RoundRobin)."""
+
+    __slots__ = ("_fn",)
+
+    def __init__(self, fn):
+        self._fn = fn
+
+    def resolve(self):
+        return self._fn()
 
 
-def _pad_ladder(sig_key, attempts):
-    """Run the first ``attempts`` entry that compiles.  Each entry is a
-    ``(tag, thunk)``; on an XLA:TPU compiler crash (a compile-time SIGSEGV,
-    not a data error) the next variant is tried — dummy gather lanes shift
-    the crashing width, the final entry is the scatter fallback.  The
-    winning index is remembered per program signature so steady state
-    dispatches straight to a compiling variant."""
-    start = min(_PAD_CACHE.get(sig_key, 0), len(attempts) - 1)
-    last = None
-    for idx in range(start, len(attempts)):
-        try:
-            res = attempts[idx][1]()
-            if idx != start:
-                _PAD_CACHE.put(sig_key, idx)
-            return res
-        except Exception as e:  # noqa: BLE001
-            if idx + 1 < len(attempts) and _is_compiler_crash(e):
-                from ..exec import recovery
-                from ..utils.logging import log
-                log.warning(
-                    "TPU compiler crash on groupby variant %r; retrying "
-                    "with %r: %.300s", attempts[idx][0],
-                    attempts[idx + 1][0], e)
-                # a taken rung is a degradation the caller can see
-                recovery._record(f"groupby.pad_ladder.{attempts[idx][0]}",
-                                 "compiler_crash",
-                                 f"rung:{attempts[idx + 1][0]}")
-                last = e
-                continue
-            raise
-    raise last
+def dispatch_at_bucket(cache, sig, cap_full: int, call, read_meta,
+                       window=None) -> PendingReduce:
+    """THE dispatch of a grouped reduce: every reduction, scatter and
+    gather of the program runs over ``seg_cap`` slots, and the true group
+    count is usually far below the row capacity ``cap_full``.  Enqueues
+    ``call(seg_cap, win)`` at the predicted segment bucket — what
+    ``cache[sig]`` remembers of this callsite if that is under
+    ``cap_full``, :data:`_FIRST_SEG_CAP` on first sight of a larger
+    capacity (most groupbys have far fewer groups than rows, and a
+    multi-10M-segment program takes XLA:TPU pathologically long to
+    compile), else ``cap_full`` — and returns a handle whose ``resolve()``
+    pulls ``read_meta(outputs) -> (n_groups per shard, win_ok)`` once per
+    dispatch and re-dispatches until the program fits: at the true bucket
+    when ``n_groups`` (counted from the group ids themselves, so a
+    mispredict is always seen) passes ``seg_cap``, and without the window
+    for good after a span overflow (``win_ok`` false: the windowed
+    gather's output is garbage).  ``window(seg_cap, n_groups) -> int``
+    picks the windowed Pallas gather for a re-dispatch from the measured
+    counts (None or 0: XLA's gather); a first-sight dispatch never has
+    one.  What the site settled on is remembered as ``cache[sig] =
+    (bucket, windowed allowed, window)``.  ``resolve()`` returns
+    ``(outputs, n_groups)``."""
+    bucket, allowed, win = cache.get(sig) or (None, True, 0)
+    if bucket is not None and bucket < cap_full:
+        seg_cap = bucket
+    elif bucket is None and cap_full > _FIRST_SEG_CAP:
+        seg_cap = _FIRST_SEG_CAP
+    else:
+        seg_cap = cap_full
+    res = call(seg_cap, win)        # ENQUEUED; meta not pulled yet
+
+    def resolve():
+        nonlocal res, seg_cap, allowed, win
+        while True:
+            n_groups, win_ok = read_meta(res)
+            bucket = min(config.pow2ceil(int(n_groups.max())
+                                         if n_groups.size else 1), cap_full)
+            win_ok = win_ok or not win
+            if bucket <= seg_cap and win_ok:
+                break
+            allowed = allowed and win_ok
+            seg_cap = max(seg_cap, bucket)
+            win = window(seg_cap, n_groups) if window and allowed else 0
+            res = call(seg_cap, win)
+        cache.put(sig, (bucket, allowed, win))
+        return res, n_groups
+
+    return PendingReduce(resolve)
+
+
+def _n_groups_of(res):
+    """``read_meta`` of a program whose last output is ``n_groups`` alone
+    (no windowed gather, so nothing to overflow)."""
+    return host_array(res[-1]).astype(np.int64), True
+
 
 #: static intermediate-column order per op (mapreduce.hpp:27 analog: MEAN ->
 #: {sum,count}, VAR/STD -> {sum,sumsq,count})
@@ -272,8 +307,7 @@ def _sort_state(vc, by_datas, by_valids, val_datas, val_valids, narrow,
 
 
 def _runs_reduce(specs_ops, val_datas, vmasks, gids, first, mask, vc,
-                 seg_cap, by_datas, by_valids, narrow, vnarrow,
-                 pad_lanes: int = 0, gather_parts: int = 1):
+                 seg_cap, by_datas, by_valids, narrow, vnarrow):
     """Per-op intermediate dicts + representative keys for run-contiguous
     (grouped or freshly sorted) input: every cumsum-able intermediate AND
     the min/max ops' counts ride grouped_reduce's single prefix-diff
@@ -294,8 +328,7 @@ def _runs_reduce(specs_ops, val_datas, vmasks, gids, first, mask, vc,
         [vmasks[b[1]] for b in batch], starts, n_live,
         list(by_datas), list(by_valids), seg_cap, key_narrow=narrow,
         value_narrow=[(bool(vnarrow[b[1]]) if vnarrow else False)
-                      for b in batch], pad_lanes=pad_lanes,
-        gather_parts=gather_parts, blocked_scans=multi_shard())
+                      for b in batch], blocked_scans=multi_shard())
     inters: dict = {}
     for (op, i), d in zip(batch, inters_b):
         inters.setdefault(i, {}).update(d)
@@ -311,8 +344,7 @@ def _runs_reduce(specs_ops, val_datas, vmasks, gids, first, mask, vc,
 
 @program_cache()
 def _combine_fn(mesh: Mesh, ops: tuple, seg_cap: int, grouped: bool,
-                narrow: tuple, vspec=None, val_map: tuple = (),
-                pad_lanes: int = 0, gather_parts: int = 1):
+                narrow: tuple, vspec=None, val_map: tuple = ()):
     """Phase 1 per shard: group keys, reduce each (col, op) into
     intermediate arrays of static length seg_cap (rank-ordered dense
     prefix), gather per-group key representatives.  With ``vspec`` the
@@ -337,7 +369,7 @@ def _combine_fn(mesh: Mesh, ops: tuple, seg_cap: int, grouped: bool,
         if first is not None:
             inters, key_out, kval_out = _runs_reduce(
                 ops, val_datas, vmasks, gids, first, mask, vc, seg_cap,
-                by_datas, by_valids, narrow, (), pad_lanes, gather_parts)
+                by_datas, by_valids, narrow, ())
             inter_out = [tuple(inters[i][k] for k in INTER_NAMES[op])
                          for i, op in enumerate(ops)]
         else:
@@ -355,9 +387,7 @@ def _combine_fn(mesh: Mesh, ops: tuple, seg_cap: int, grouped: bool,
 
 
 @program_cache()
-def _final_fn(mesh: Mesh, ops: tuple, seg_cap: int, ddof: int, narrow: tuple,
-              pad_lanes: int = 0, use_runs: bool = True,
-              gather_parts: int = 1):
+def _final_fn(mesh: Mesh, ops: tuple, seg_cap: int, ddof: int, narrow: tuple):
     """Phase 2 per shard: reduce shuffled intermediates under the new key
     grouping, finalize each op.
 
@@ -371,24 +401,7 @@ def _final_fn(mesh: Mesh, ops: tuple, seg_cap: int, ddof: int, narrow: tuple,
     (mapreduce/mapreduce.hpp:56-76)."""
     from ..ops import lanes
 
-    def per_shard_scatter(vc, by_datas, by_valids, inter_by_op):
-        """Fallback (compiler-crash ladder): dense-rank + per-op segment
-        scatters — the pre-sort-path phase 2."""
-        gids, n_groups, mask, _ = _group_keys(by_datas, by_valids, vc,
-                                              narrow=narrow)
-        key_out, kval_out = _rep_keys(by_datas, by_valids, gids, seg_cap)
-        res_d, res_v = [], []
-        for i, op in enumerate(ops):
-            inter = dict(zip(INTER_NAMES[op], inter_by_op[i]))
-            red = gbk.reduce_intermediates(inter, gids, seg_cap, mask)
-            d, v = gbk.finalize(op, red, ddof)
-            res_d.append(d)
-            res_v.append(v)
-        return key_out, kval_out, tuple(res_d), tuple(res_v), n_groups.reshape(1)
-
     def per_shard(vc, by_datas, by_valids, inter_by_op):
-        if not use_runs:
-            return per_shard_scatter(vc, by_datas, by_valids, inter_by_op)
         flat_arrs, flat_kinds = [], []   # kind: 'sum' | 'min' | 'max'
         for i, op in enumerate(ops):
             for nm, arr in zip(INTER_NAMES[op], inter_by_op[i]):
@@ -411,8 +424,7 @@ def _final_fn(mesh: Mesh, ops: tuple, seg_cap: int, ddof: int, narrow: tuple,
         inters_b, key_out, kval_out, _wok = gbk.grouped_reduce(
             ["sum"] * len(sum_idx), [s_arrs[j] for j in sum_idx],
             [mask] * len(sum_idx), starts, n_live, list(s_by), list(s_byv),
-            seg_cap, key_narrow=narrow, pad_lanes=pad_lanes,
-            gather_parts=gather_parts, blocked_scans=multi_shard())
+            seg_cap, key_narrow=narrow, blocked_scans=multi_shard())
         red_flat = [None] * len(flat_arrs)
         for j, d in zip(sum_idx, inters_b):
             red_flat[j] = d["sum"]
@@ -443,8 +455,7 @@ def _final_fn(mesh: Mesh, ops: tuple, seg_cap: int, ddof: int, narrow: tuple,
 @program_cache()
 def _raw_fn(mesh: Mesh, specs: tuple, seg_cap: int, ddof: int, grouped: bool,
             narrow: tuple, vnarrow: tuple = (), vspec=None,
-            val_map: tuple = (), pad_lanes: int = 0, use_runs: bool = True,
-            gather_parts: int = 1):
+            val_map: tuple = ()):
     """Single-phase per shard over raw (already co-located) rows — used for
     non-associative ops, the local path, and the grouped-input fast path
     (join/sort output: no shuffle, no rank sort).  ``vnarrow``: host-proven
@@ -483,11 +494,11 @@ def _raw_fn(mesh: Mesh, specs: tuple, seg_cap: int, ddof: int, grouped: bool,
         # every cumsum-able aggregation, min/max counts AND the
         # representative keys
         batched: dict[int, dict] = {}
-        if first is not None and use_runs:
+        if first is not None:
             batched, key_out, kval_out = _runs_reduce(
                 tuple(op for op, _ in specs), val_datas, vmasks, gids,
                 first, mask, vc, seg_cap, by_datas, by_valids, narrow,
-                vnarrow, pad_lanes, gather_parts)
+                vnarrow)
         else:
             key_out, kval_out = _rep_keys(by_datas, by_valids, gids, seg_cap)
         res_d, res_v = [], []
@@ -802,45 +813,13 @@ def _groupby_aggregate_impl(table: Table, by, aggs, ddof: int = 1) -> Table:
         cspec = _plan_vspec(uval_cols, by_cols, narrow,
                             sum(len(INTER_NAMES[op]) for op in ops_t))
         cargs = (vc, by_datas, by_valids, uval_datas, uval_valids)
-
-        def combine_call(sc):
-            attempts = ([(f"sort+pad{p}",
-                          lambda p=p: _combine_fn(env.mesh, ops_t, sc,
-                                                  False, narrow, cspec,
-                                                  val_map, p)(*cargs))
-                         for p in (0, 1, 2)]
-                        + [(f"sort+pad{pads}split{parts}",
-                            lambda pads=pads, parts=parts: _combine_fn(
-                                env.mesh, ops_t, sc, False, narrow, cspec,
-                                val_map, pads, parts)(*cargs))
-                           for pads, parts in ((0, 2), (1, 2), (0, 4))]) \
-                if cspec is not None else []
-            attempts.append(
-                ("scatter",
-                 lambda: _combine_fn(env.mesh, ops_t, sc, False, narrow,
-                                     None, val_map)(*cargs)))
-            return _pad_ladder(("combine", env.serial, ops_t, narrow, cspec),
-                               attempts)
-
-        # same first-sight/hysteresis segment-space discipline as the raw
-        # path (multi-10M-segment programs have pathological compile times)
-        seg_key1 = ("combine-seg", env.serial, ops_t, tuple(by), narrow,
-                    cap_full, int(table.valid_counts.sum()))
-        pred1 = _SEG_CACHE.get(seg_key1)
-        if pred1 is not None and pred1 < cap_full:
-            seg_cap = pred1
-        elif pred1 is None and cap_full > _FIRST_SEG_CAP:
-            seg_cap = _FIRST_SEG_CAP
-        else:
-            seg_cap = cap_full
-        key_out, kval_out, inter_out, n_groups = combine_call(seg_cap)
-        n_groups = host_array(n_groups).astype(np.int64)
-        ng_cap1 = min(config.pow2ceil(int(n_groups.max()) if n_groups.size
-                                      else 1), cap_full)
-        if ng_cap1 > seg_cap:
-            key_out, kval_out, inter_out, n_groups = combine_call(ng_cap1)
-            n_groups = host_array(n_groups).astype(np.int64)
-        _SEG_CACHE.put(seg_key1, ng_cap1)
+        (key_out, kval_out, inter_out, _), n_groups = dispatch_at_bucket(
+            _SEG_CACHE,
+            ("combine-seg", env.serial, ops_t, tuple(by), narrow, cap_full,
+             int(table.valid_counts.sum())), cap_full,
+            lambda sc, _win: _combine_fn(env.mesh, ops_t, sc, False, narrow,
+                                         cspec, val_map)(*cargs),
+            _n_groups_of).resolve()
         # intermediate table: keys + flat intermediate columns
         cols = {}
         for n, c, d, v in zip(by, by_cols, key_out, kval_out):
@@ -863,22 +842,9 @@ def _groupby_aggregate_impl(table: Table, by, aggs, ddof: int = 1) -> Table:
             for inames in inames_by_op)
         vc2 = np.asarray(shuffled.valid_counts, np.int32)
         fin_cap = max(shuffled.capacity, 1)
-        fargs = (vc2, s_by_datas, s_by_valids, inter_by_op)
-        fattempts = [(f"sort+pad{p}",
-                      lambda p=p: _final_fn(env.mesh, ops_t, fin_cap, ddof,
-                                            narrow, p)(*fargs))
-                     for p in (0, 1, 2)]
-        for pads, parts in ((0, 2), (1, 2), (0, 4)):
-            fattempts.append(
-                (f"sort+pad{pads}split{parts}",
-                 lambda pads=pads, parts=parts: _final_fn(
-                     env.mesh, ops_t, fin_cap, ddof, narrow, pads, True,
-                     parts)(*fargs)))
-        fattempts.append(
-            ("scatter", lambda: _final_fn(env.mesh, ops_t, fin_cap, ddof,
-                                          narrow, 0, False)(*fargs)))
-        key2, kval2, res_d, res_v, ng2 = _pad_ladder(
-            ("final", env.serial, ops_t, narrow, ddof), fattempts)
+        key2, kval2, res_d, res_v, ng2 = _final_fn(
+            env.mesh, ops_t, fin_cap, ddof, narrow)(
+                vc2, s_by_datas, s_by_valids, inter_by_op)
         ng2 = host_array(ng2).astype(np.int64)
         out = _result_table(env, by, by_cols, key2, kval2, res_names, res_d,
                             res_v, res_types, res_dicts, ng2)
@@ -929,61 +895,15 @@ def _groupby_aggregate_impl(table: Table, by, aggs, ddof: int = 1) -> Table:
                        if op in gbk.ASSOCIATIVE)
         vspec = _plan_vspec(uval_cols, [work.column(n) for n in by], narrow,
                             max(n_inters, 1))
-    # segment-capacity hysteresis: every reduction/scatter/gather in _raw_fn
-    # runs over seg_cap slots, but the true group count is usually far below
-    # row capacity — dispatch at the previous call's observed bucket and
-    # re-dispatch at full capacity only when the observed count exceeds it
-    # (n_groups comes from the gids themselves, so a mispredict is always
-    # detected).  Steady-state pipelines (benchmarks, iterative queries) hit.
-    seg_key = (env.serial, spec_t, tuple(by), grouped, narrow, ddof,
-               cap_full, int(work.valid_counts.sum()))
-    pred = _SEG_CACHE.get(seg_key)
     args = (vc, by_datas, by_valids, uval_datas, uval_valids)
-
-    def raw_call(sc):
-        # widened ladder (round 4): the scatter terminal compiles
-        # pathologically at multi-M segment spaces (observed live: >55 min
-        # at TPC-H SF5 Q18), so give the sort path more width-shifting
-        # chances (pad2, pad1+split2, split4) before surrendering to it
-        attempts = [(f"sort+pad{p}",
-                     lambda p=p: _raw_fn(env.mesh, spec_t, sc, ddof, grouped,
-                                         narrow, vnarrow, vspec, val_map,
-                                         p)(*args))
-                    for p in (0, 1, 2)]
-        for pads, parts in ((0, 2), (1, 2), (0, 4)):
-            attempts.append(
-                (f"sort+pad{pads}split{parts}",
-                 lambda pads=pads, parts=parts: _raw_fn(
-                     env.mesh, spec_t, sc, ddof, grouped, narrow,
-                     vnarrow, vspec, val_map, pads, True, parts)(*args)))
-        attempts.append(
-            ("scatter", lambda: _raw_fn(env.mesh, spec_t, sc, ddof, grouped,
-                                        narrow, vnarrow, None, val_map, 0,
-                                        False)(*args)))
-        return _pad_ladder(("raw", env.serial, spec_t, grouped, narrow,
-                            vnarrow, vspec), attempts)
-
     with timing.region("groupby.raw"):
-        if pred is not None and pred < cap_full:
-            seg_cap = pred
-        elif pred is None and cap_full > _FIRST_SEG_CAP:
-            # first sight of a large-cap groupby: dispatch at a modest
-            # segment space — most groupbys have far fewer groups than
-            # rows, and multi-10M-segment programs have pathological
-            # XLA:TPU compile times (observed: 50+ min at a 33M segment
-            # space that compiles in seconds at 1M).  A mispredict is
-            # detected via n_groups and re-dispatched at the true bucket.
-            seg_cap = _FIRST_SEG_CAP
-        else:
-            seg_cap = cap_full
-        res = raw_call(seg_cap)
-        n_groups = host_array(res[4]).astype(np.int64)
-        ng_cap = min(config.pow2ceil(int(n_groups.max()) if n_groups.size
-                                     else 1), cap_full)
-        if ng_cap > seg_cap:
-            res = raw_call(ng_cap)
-        _SEG_CACHE.put(seg_key, ng_cap)
-        key_out, kval_out, res_d, res_v = res[0], res[1], res[2], res[3]
+        (key_out, kval_out, res_d, res_v, _), n_groups = dispatch_at_bucket(
+            _SEG_CACHE,
+            (env.serial, spec_t, tuple(by), grouped, narrow, ddof, cap_full,
+             int(work.valid_counts.sum())), cap_full,
+            lambda sc, _win: _raw_fn(env.mesh, spec_t, sc, ddof, grouped,
+                                     narrow, vnarrow, vspec, val_map)(*args),
+            _n_groups_of).resolve()
     out = _result_table(env, by, by_cols, key_out, kval_out, res_names, res_d,
                         res_v, res_types, res_dicts, n_groups)
     out = _shrink(out, n_groups)

@@ -846,8 +846,8 @@ def prewarm_packed_join(pl: PackedPiece, pr: PackedPiece, left_on,
          _dicts, _bounds, carry_emit, carry_match,
          all_live) = _packed_statics(pl, pr, left_on, right_on, how,
                                      suffixes, coalesce_keys)
-        slim = (config.DEFER_JOIN and how == "inner" and carry_emit
-                and carry_match and coalesce and allow_defer)
+        slim = (how == "inner" and carry_emit and carry_match
+                and coalesce and allow_defer)
         fn = _packed_count_fn(
             pl.env.mesh, how, narrow, need_nf, pl.spec, pr.spec, kil, kir,
             pl.piece_cap, pr.piece_cap, len(pl.arrs), len(pr.arrs),
@@ -880,8 +880,8 @@ def _join_packed_impl(pl: PackedPiece, pr: PackedPiece, left_on, right_on,
     vcl = np.asarray(pl.lens, np.int32)
     vcr = np.asarray(pr.lens, np.int32)
 
-    defer = (config.DEFER_JOIN and how == "inner" and carry_emit
-             and carry_match and coalesce and allow_defer)
+    defer = (how == "inner" and carry_emit and carry_match and coalesce
+             and allow_defer)
     fn = _packed_count_fn(env.mesh, how, narrow, need_nf, pl.spec, pr.spec,
                           kil, kir, cap_l, cap_r, len(pl.arrs),
                           len(pr.arrs), all_live, carry_emit, carry_match,
@@ -1333,8 +1333,8 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
     # any other access materializes THROUGH the stitch.  The plan-less
     # split=True legs (broadcast join / legacy semi-anti spread) have no
     # plan to reconstruct co-location from and stay eager.
-    defer = (config.DEFER_JOIN and how == "inner" and carry_emit
-             and carry_match and coalesce and allow_defer
+    defer = (how == "inner" and carry_emit and carry_match and coalesce
+             and allow_defer
              and (skew_plan is not None or not skew_split))
     if defer:
         with timing.region("join.sort_count"):

@@ -221,8 +221,8 @@ class NumericOverflowError(CylonError):
 
 class ResumableAbort(CylonError):
     """The retry ladder's FINAL rung (exec/recovery + exec/checkpoint):
-    an unrecoverable fault (real device OOM on an HBM-poisoning rig, an
-    exhausted compiler-crash ladder) arrived while durable checkpointing
+    an unrecoverable fault (real device OOM on an HBM-poisoning rig, a
+    reported compiler crash) arrived while durable checkpointing
     was armed — committed piece state has been flushed, and a FRESH
     process launched with ``CYLON_TPU_RESUME=1`` fast-forwards past the
     committed pieces bit-identically instead of recomputing.  ``token``

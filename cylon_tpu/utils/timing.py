@@ -397,6 +397,11 @@ def reset() -> None:
     _SCOPE_TLS.excluded = 0.0
 
 
+def total_seconds() -> float:
+    """Unrounded seconds of the whole phase table."""
+    return sum(v[0] for v in _ACCUM.values())
+
+
 def snapshot() -> dict:
     """{region: {"s": total_seconds, "n": calls[, "b": bytes_moved]}}
     sorted by cost; ``b`` appears only for phases that attributed

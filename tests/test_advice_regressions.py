@@ -186,17 +186,17 @@ class TestRound3Advice:
         pd.testing.assert_frame_equal(got, exp, check_dtype=False)
 
     def test_compiler_crash_matches_helper_death_messages(self):
-        # a directly-attached TPU surfaces a compiler death under the
-        # helper subprocess's name and/or the signal — the ladder must
-        # engage on each shape
-        from cylon_tpu.relational.groupby import _is_compiler_crash
-        assert _is_compiler_crash(
+        # a runtime that surfaces a compiler death does so under the
+        # helper subprocess's name and/or the signal — the recovery
+        # ladder's final rung (recovery._resumable) classifies each shape
+        from cylon_tpu.exec.recovery import is_compiler_crash
+        assert is_compiler_crash(
             RuntimeError("tpu_compile_helper exited with status 139"))
-        assert _is_compiler_crash(
+        assert is_compiler_crash(
             RuntimeError("Compilation failure: SIGSEGV in subprocess"))
-        assert _is_compiler_crash(RuntimeError(
+        assert is_compiler_crash(RuntimeError(
             "INTERNAL: tpu_compile_helper terminated by SIGSEGV"))
-        assert not _is_compiler_crash(RuntimeError("shape mismatch"))
+        assert not is_compiler_crash(RuntimeError("shape mismatch"))
 
     def test_deferred_materialize_does_not_resort(self, env1, monkeypatch):
         # materializing a deferred join must NOT re-run phase 1 (the sort);

@@ -174,10 +174,10 @@ def test_fused_join_groupby_compiles_for_v5e(mesh1, env1, monkeypatch):
     from cylon_tpu.exec import compiler
     fused_fn, resident, _, _ = _capture_main_path(env1, monkeypatch)
     static, args = resident[-1]
-    assert len(static) == 12                      # ..., seg_cap@7, ..., w
+    assert len(static) == 10                      # ..., seg_cap@7, ddof, w
     seg_cap = static[7]
     assert seg_cap % 256 == 0 and seg_cap > 512, seg_cap
-    prog = fused_fn(mesh1, *static[:11], 1024)
+    prog = fused_fn(mesh1, *static[:9], 1024)
     # steer the kernel off interpret mode: the builder asks the backend
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled = compiler.aot_compile(prog, *_abstract(args, mesh1))
@@ -261,7 +261,7 @@ def test_fused_compiles_for_four_chips(mesh4, monkeypatch, window, n_sums):
     from cylon_tpu.relational import fused
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     prog = fused._fused_fn(mesh4, _ROWS4, False, *_fused_static(n_sums),
-                           3407872, 1, 0, 1, window)
+                           3407872, 1, window)
     compiled = compiler.aot_compile(
         prog, *_fused_args(mesh4, _ROWS4, 1 + n_sums))
     assert _has_kernel(compiled) == bool(window)

@@ -157,11 +157,13 @@ def test_deferred_via_dataframe_api(env4, rng):
                                   check_dtype=False)
 
 
-def test_defer_flag_off_restores_eager_join(env4, rng, monkeypatch):
-    from cylon_tpu import config
-    monkeypatch.setattr(config, "DEFER_JOIN", False)
+def test_defer_flag_off_restores_eager_join(env4, rng):
+    """The eager join as its real callers ask for it
+    (``exec/pipeline.py``: a sink-less chunk join): ``allow_defer=False``."""
     ldf, rdf = _tables(env4, rng)
-    j = _join(env4, ldf, rdf)
+    j = join_tables(ct.Table.from_pandas(ldf, env4),
+                    ct.Table.from_pandas(rdf, env4), "k", "k", how="inner",
+                    allow_defer=False)
     assert not isinstance(j, DeferredTable)
     g = groupby_aggregate(j, "k", [("a", "sum")])
     ej = ldf.merge(rdf, on="k")

@@ -107,7 +107,7 @@ def test_fused_windowed_gather_survives_capacity_padding(env1, rng,
     from functools import partial
     monkeypatch.setattr(fused, "shard_map",
                         partial(jax.shard_map, check_vma=False))
-    win = orig(env1.mesh, *static[:11], 4096)(*args)
+    win = orig(env1.mesh, *static[:9], 4096)(*args)
     n_groups, wok = np.asarray(win[4]).reshape(-1, 2)[0]
     assert wok == 1, "the windowed gather reported a span overflow"
     assert n_groups == np.asarray(plain[4]).reshape(-1, 2)[0][0]
