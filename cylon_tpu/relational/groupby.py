@@ -15,6 +15,8 @@ reference ships ``MapReduceKernel`` intermediates through ArrowAllToAll.
 
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,6 +28,7 @@ from ..core.column import Column
 from ..core.dtypes import LogicalType, from_numpy_dtype, physical_np_dtype
 from ..core.table import Table
 from ..ctx.context import ROW_AXIS
+from ..obs import metrics as _metrics
 from ..ops import groupby as gbk
 from ..ops import pack
 from ..utils.stages import stage, staged
@@ -99,7 +102,15 @@ def dispatch_at_bucket(cache, sig, cap_full: int, call, read_meta,
     counts (None or 0: XLA's gather); a first-sight dispatch never has
     one.  What the site settled on is remembered as ``cache[sig] =
     (bucket, windowed allowed, window)``.  ``resolve()`` returns
-    ``(outputs, n_groups)``."""
+    ``(outputs, n_groups)``.  Two registry counters say how often the
+    kernel engaged: ``grouped_reduce_windowed_dispatches`` (a dispatch
+    enqueued with a window) and ``grouped_reduce_window_overflows`` (a
+    re-dispatch because its span overflowed)."""
+    def enqueue(seg_cap, win):
+        if win:
+            _metrics.counter("grouped_reduce_windowed_dispatches").inc()
+        return call(seg_cap, win)
+
     bucket, allowed, win = cache.get(sig) or (None, True, 0)
     if bucket is not None and bucket < cap_full:
         seg_cap = bucket
@@ -107,7 +118,7 @@ def dispatch_at_bucket(cache, sig, cap_full: int, call, read_meta,
         seg_cap = _FIRST_SEG_CAP
     else:
         seg_cap = cap_full
-    res = call(seg_cap, win)        # ENQUEUED; meta not pulled yet
+    res = enqueue(seg_cap, win)     # ENQUEUED; meta not pulled yet
 
     def resolve():
         nonlocal res, seg_cap, allowed, win
@@ -118,20 +129,51 @@ def dispatch_at_bucket(cache, sig, cap_full: int, call, read_meta,
             win_ok = win_ok or not win
             if bucket <= seg_cap and win_ok:
                 break
+            if not win_ok:
+                _metrics.counter("grouped_reduce_window_overflows").inc()
             allowed = allowed and win_ok
             seg_cap = max(seg_cap, bucket)
             win = window(seg_cap, n_groups) if window and allowed else 0
-            res = call(seg_cap, win)
+            res = enqueue(seg_cap, win)
         cache.put(sig, (bucket, allowed, win))
         return res, n_groups
 
     return PendingReduce(resolve)
 
 
-def _n_groups_of(res):
-    """``read_meta`` of a program whose last output is ``n_groups`` alone
-    (no windowed gather, so nothing to overflow)."""
-    return host_array(res[-1]).astype(np.int64), True
+def _meta_out(n_groups, win_ok, use_window: int):
+    """A shard's meta as ONE output, so a dispatch costs one host pull (a
+    second transfer is another round trip): ``n_groups`` and, from a
+    program asked for the windowed gather, whether it stayed inside its
+    spans (``win_ok`` None: no kernel ran).  A program without the window
+    has nothing to overflow and keeps the one-number output, and with it
+    the program text, it always had."""
+    if not use_window:
+        return n_groups.reshape(1)
+    ok = jnp.ones((), bool) if win_ok is None else win_ok
+    return jnp.stack([n_groups, ok.astype(jnp.int32)]).reshape(2)
+
+
+def _read_meta(world: int, res):
+    """``read_meta`` of a program whose last output is :func:`_meta_out`
+    on each of ``world`` shards: the group counts and whether every
+    shard's windowed gather stayed inside its spans, one pull."""
+    meta = host_array(res[-1]).astype(np.int64).reshape(world, -1)
+    return meta[:, 0], bool(np.all(meta[:, 1:]))
+
+
+def _density_window(mesh, live):
+    """``window`` of a site whose shards hold ``live`` rows each: the
+    fused path's one rule (:func:`~.fused.window_for`) on the MEASURED
+    per-shard group density, the minimum over the shards."""
+    from . import fused
+    live = np.maximum(np.asarray(live, np.int64), 1)
+
+    def window(seg_cap, n_groups):
+        dens = float((n_groups / live).min()) if n_groups.size else 0.0
+        return fused.window_for(mesh, seg_cap, dens)
+
+    return window
 
 
 #: static intermediate-column order per op (mapreduce.hpp:27 analog: MEAN ->
@@ -307,13 +349,16 @@ def _sort_state(vc, by_datas, by_valids, val_datas, val_valids, narrow,
 
 
 def _runs_reduce(specs_ops, val_datas, vmasks, gids, first, mask, vc,
-                 seg_cap, by_datas, by_valids, narrow, vnarrow):
+                 seg_cap, by_datas, by_valids, narrow, vnarrow,
+                 use_window: int = 0):
     """Per-op intermediate dicts + representative keys for run-contiguous
     (grouped or freshly sorted) input: every cumsum-able intermediate AND
     the min/max ops' counts ride grouped_reduce's single prefix-diff
-    gather; only the min/max extrema themselves need segment scatters.
-    Ops outside CUMSUMMABLE/min/max get no intermediate entry (callers'
-    non-associative branches compute their own)."""
+    gather (``use_window``: through the windowed Pallas kernel); only the
+    min/max extrema themselves need segment scatters.  Ops outside
+    CUMSUMMABLE/min/max get no intermediate entry (callers'
+    non-associative branches compute their own).  Returns (inters,
+    key_out, kval_out, win_ok) - ``win_ok`` is grouped_reduce's."""
     my = jax.lax.axis_index(ROW_AXIS)
     n_live = vc[my].astype(jnp.int32)
     starts = gbk.grouped_starts(gids, first, mask, n_live, seg_cap)
@@ -323,12 +368,13 @@ def _runs_reduce(specs_ops, val_datas, vmasks, gids, first, mask, vc,
             batch.append((op, i))
         elif op in ("min", "max"):
             batch.append(("count", i))
-    inters_b, key_out, kval_out, _wok = gbk.grouped_reduce(
+    inters_b, key_out, kval_out, win_ok = gbk.grouped_reduce(
         [b[0] for b in batch], [val_datas[b[1]] for b in batch],
         [vmasks[b[1]] for b in batch], starts, n_live,
         list(by_datas), list(by_valids), seg_cap, key_narrow=narrow,
         value_narrow=[(bool(vnarrow[b[1]]) if vnarrow else False)
-                      for b in batch], blocked_scans=multi_shard())
+                      for b in batch], use_window=use_window,
+        blocked_scans=multi_shard())
     inters: dict = {}
     for (op, i), d in zip(batch, inters_b):
         inters.setdefault(i, {}).update(d)
@@ -339,12 +385,13 @@ def _runs_reduce(specs_ops, val_datas, vmasks, gids, first, mask, vc,
         elif op == "max":
             inters[i]["max"] = gbk.seg_max(val_datas[i], gids, seg_cap,
                                            vmasks[i])
-    return inters, key_out, kval_out
+    return inters, key_out, kval_out, win_ok
 
 
 @program_cache()
 def _combine_fn(mesh: Mesh, ops: tuple, seg_cap: int, grouped: bool,
-                narrow: tuple, vspec=None, val_map: tuple = ()):
+                narrow: tuple, vspec=None, val_map: tuple = (),
+                use_window: int = 0):
     """Phase 1 per shard: group keys, reduce each (col, op) into
     intermediate arrays of static length seg_cap (rank-ordered dense
     prefix), gather per-group key representatives.  With ``vspec`` the
@@ -352,7 +399,8 @@ def _combine_fn(mesh: Mesh, ops: tuple, seg_cap: int, grouped: bool,
     intermediates come from the run-contiguous prefix-diff machinery
     instead of per-op segment scatters.  Sum intermediates are never
     narrowed here — phase 2 sums them AGAIN across shards, so the
-    single-shard rows·max|v| < 2^31 proof does not cover them."""
+    single-shard rows·max|v| < 2^31 proof does not cover them.
+    ``use_window`` and the last output: as :func:`_raw_fn`'s."""
 
     def per_shard(vc, by_datas, by_valids, uval_datas, uval_valids):
         if vspec is not None and not grouped:
@@ -366,10 +414,11 @@ def _combine_fn(mesh: Mesh, ops: tuple, seg_cap: int, grouped: bool,
         val_valids = tuple(uval_valids[j] for j in val_map)
         vmasks = [_value_mask(mask, val_datas[i], val_valids[i])
                   for i in range(len(ops))]
+        win_ok = None
         if first is not None:
-            inters, key_out, kval_out = _runs_reduce(
+            inters, key_out, kval_out, win_ok = _runs_reduce(
                 ops, val_datas, vmasks, gids, first, mask, vc, seg_cap,
-                by_datas, by_valids, narrow, ())
+                by_datas, by_valids, narrow, (), use_window)
             inter_out = [tuple(inters[i][k] for k in INTER_NAMES[op])
                          for i, op in enumerate(ops)]
         else:
@@ -379,7 +428,8 @@ def _combine_fn(mesh: Mesh, ops: tuple, seg_cap: int, grouped: bool,
                 inter = gbk.combine_locally(op, val_datas[i], gids, seg_cap,
                                             vmasks[i])
                 inter_out.append(tuple(inter[k] for k in INTER_NAMES[op]))
-        return key_out, kval_out, tuple(inter_out), n_groups.reshape(1)
+        return (key_out, kval_out, tuple(inter_out),
+                _meta_out(n_groups, win_ok, use_window))
 
     return jit(shard_map(per_shard, mesh=mesh,
                              in_specs=(REP, ROW, ROW, ROW, ROW),
@@ -455,7 +505,7 @@ def _final_fn(mesh: Mesh, ops: tuple, seg_cap: int, ddof: int, narrow: tuple):
 @program_cache()
 def _raw_fn(mesh: Mesh, specs: tuple, seg_cap: int, ddof: int, grouped: bool,
             narrow: tuple, vnarrow: tuple = (), vspec=None,
-            val_map: tuple = ()):
+            val_map: tuple = (), use_window: int = 0):
     """Single-phase per shard over raw (already co-located) rows — used for
     non-associative ops, the local path, and the grouped-input fast path
     (join/sort output: no shuffle, no rank sort).  ``vnarrow``: host-proven
@@ -474,7 +524,12 @@ def _raw_fn(mesh: Mesh, specs: tuple, seg_cap: int, ddof: int, grouped: bool,
     and the representative keys then come from the run machinery's single
     prefix-diff gather (:func:`_runs_reduce`).  The reference's pipeline
     groupby (groupby/pipeline_groupby.cpp) is the moral analog: sort once,
-    reduce runs."""
+    reduce runs.
+
+    ``use_window`` (a window size, 0 = off; a dimension of the program
+    cache, as :func:`~.fused._fused_fn`'s last static): that gather
+    through the windowed Pallas kernel.  The last output is
+    :func:`_meta_out`."""
 
     def per_shard(vc, by_datas, by_valids, uval_datas, uval_valids):
         # uval_*: one array per DISTINCT value column; val_map expands to
@@ -494,11 +549,12 @@ def _raw_fn(mesh: Mesh, specs: tuple, seg_cap: int, ddof: int, grouped: bool,
         # every cumsum-able aggregation, min/max counts AND the
         # representative keys
         batched: dict[int, dict] = {}
+        win_ok = None
         if first is not None:
-            batched, key_out, kval_out = _runs_reduce(
+            batched, key_out, kval_out, win_ok = _runs_reduce(
                 tuple(op for op, _ in specs), val_datas, vmasks, gids,
                 first, mask, vc, seg_cap, by_datas, by_valids, narrow,
-                vnarrow)
+                vnarrow, use_window)
         else:
             key_out, kval_out = _rep_keys(by_datas, by_valids, gids, seg_cap)
         res_d, res_v = [], []
@@ -519,7 +575,8 @@ def _raw_fn(mesh: Mesh, specs: tuple, seg_cap: int, ddof: int, grouped: bool,
                 d, v = gbk.quantile(val_datas[i], gids, seg_cap, q, vmask)
             res_d.append(d)
             res_v.append(v)
-        return key_out, kval_out, tuple(res_d), tuple(res_v), n_groups.reshape(1)
+        return (key_out, kval_out, tuple(res_d), tuple(res_v),
+                _meta_out(n_groups, win_ok, use_window))
 
     return jit(shard_map(per_shard, mesh=mesh,
                              in_specs=(REP, ROW, ROW, ROW, ROW),
@@ -817,9 +874,12 @@ def _groupby_aggregate_impl(table: Table, by, aggs, ddof: int = 1) -> Table:
             _SEG_CACHE,
             ("combine-seg", env.serial, ops_t, tuple(by), narrow, cap_full,
              int(table.valid_counts.sum())), cap_full,
-            lambda sc, _win: _combine_fn(env.mesh, ops_t, sc, False, narrow,
-                                         cspec, val_map)(*cargs),
-            _n_groups_of).resolve()
+            lambda sc, win: _combine_fn(env.mesh, ops_t, sc, False, narrow,
+                                        cspec, val_map, win)(*cargs),
+            partial(_read_meta, env.world_size),
+            # the gather the window serves exists on the sort path alone
+            _density_window(env.mesh, table.valid_counts)
+            if cspec is not None else None).resolve()
         # intermediate table: keys + flat intermediate columns
         cols = {}
         for n, c, d, v in zip(by, by_cols, key_out, kval_out):
@@ -901,9 +961,14 @@ def _groupby_aggregate_impl(table: Table, by, aggs, ddof: int = 1) -> Table:
             _SEG_CACHE,
             (env.serial, spec_t, tuple(by), grouped, narrow, ddof, cap_full,
              int(work.valid_counts.sum())), cap_full,
-            lambda sc, _win: _raw_fn(env.mesh, spec_t, sc, ddof, grouped,
-                                     narrow, vnarrow, vspec, val_map)(*args),
-            _n_groups_of).resolve()
+            lambda sc, win: _raw_fn(env.mesh, spec_t, sc, ddof, grouped,
+                                    narrow, vnarrow, vspec, val_map,
+                                    win)(*args),
+            partial(_read_meta, env.world_size),
+            # the gather the window serves exists on run-contiguous input
+            # alone (grouped, or the sort path)
+            _density_window(env.mesh, work.valid_counts)
+            if grouped or vspec is not None else None).resolve()
     out = _result_table(env, by, by_cols, key_out, kval_out, res_names, res_d,
                         res_v, res_types, res_dicts, n_groups)
     out = _shrink(out, n_groups)

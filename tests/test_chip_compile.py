@@ -313,3 +313,60 @@ def test_shuffle_count_compiles_for_four_chips(mesh4):
         shuffle._count_fn(mesh4, 4),
         jax.ShapeDtypeStruct((4 << 23,), np.int32, sharding=row)).as_text()
     assert "scatter" not in text
+
+
+# ---- the standalone groupby with the window (ISSUE 31) ----------------------
+# relational/groupby's two dispatch sites ask for the windowed gather under
+# the fused path's rule; these are their programs as an eligible callsite
+# runs them: one int64 sum by a narrow int64 key (benchmark cell
+# groupby_sort_25m's query: 3 u32 lanes, padded to 8 for the kernel).
+
+def _groupby_args(mesh, cap: int):
+    from cylon_tpu.ctx.context import ROW_AXIS
+    w = int(mesh.devices.size)
+    rep, row = NamedSharding(mesh, P()), NamedSharding(mesh, P(ROW_AXIS))
+    S = jax.ShapeDtypeStruct
+    col = S((w * cap,), np.int64, sharding=row)
+    return S((w,), np.int32, sharding=rep), (col,), (None,), (col,), (None,)
+
+
+def _groupby_program(mesh, site: str, seg_cap: int, window: int):
+    from cylon_tpu.ops import lanes
+    from cylon_tpu.relational import groupby as rel_gb
+    vspec = lanes.plan_lanes(("int64", "int64"), (False, False), (True, True))
+    if site == "combine":
+        return rel_gb._combine_fn(mesh, ("sum",), seg_cap, False, (True,),
+                                  vspec, (0,), window)
+    return rel_gb._raw_fn(mesh, (("sum", 0.5),), seg_cap, 1, False, (True,),
+                          (False,), vspec, (0,), window)
+
+
+@pytest.mark.parametrize("cap,seg_cap", [
+    (69632, 40960),
+    # groupby_sort_25m's own shapes: 25M rows, ~15.09M groups (about a
+    # minute of XLA:TPU, most of it the 4-operand sort)
+    (25165824, 15204352),
+])
+def test_windowed_raw_groupby_compiles_for_v5e(mesh1, monkeypatch, cap,
+                                               seg_cap):
+    """``groupby__raw_fn`` at its settled segment bucket on one described
+    chip, with the windowed Pallas gather inside (window 1024: what
+    ``pick_window`` gives the cell's density 0.60)."""
+    from cylon_tpu.exec import compiler
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = compiler.aot_compile(
+        _groupby_program(mesh1, "raw", seg_cap, 1024),
+        *_groupby_args(mesh1, cap))
+    assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize("site", ["combine", "raw"])
+def test_windowed_groupby_compiles_for_four_chips(mesh4, monkeypatch, site):
+    """Phase 1 of the distributed associative groupby and the raw route on
+    a mesh of four, with the kernel inside: forms no chip has run yet."""
+    from cylon_tpu.exec import compiler
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = compiler.aot_compile(
+        _groupby_program(mesh4, site, 16384, 1024),
+        *_groupby_args(mesh4, 17408))
+    assert _has_kernel(compiled)

@@ -6,6 +6,7 @@ ladder's final rung keeps, the dense/scatter segment-reduction parity and
 the bounds of the program caches.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pandas as pd
@@ -63,6 +64,13 @@ _DISPATCH_CASES = {
 }
 
 
+def _window_counters():
+    from cylon_tpu.obs import metrics
+    return np.array([
+        metrics.counter("grouped_reduce_windowed_dispatches").value,
+        metrics.counter("grouped_reduce_window_overflows").value])
+
+
 @pytest.mark.parametrize("case", list(_DISPATCH_CASES))
 def test_dispatch_at_bucket(case):
     """The whole policy against a fake program: which (segment space,
@@ -89,6 +97,7 @@ def test_dispatch_at_bucket(case):
         assert n_groups.shape == (2,)
         return win
 
+    before = _window_counters()
     h = rel_gb.dispatch_at_bucket(cache, "site", cap_full, call, read_meta,
                                   window if win else None)
     assert dispatched == want[:1] and pulled == []      # enqueued, not pulled
@@ -97,6 +106,11 @@ def test_dispatch_at_bucket(case):
     assert pulled == list(range(len(want)))             # one pull a dispatch
     assert res == len(want) - 1 and int(n_groups[0]) == metas[-1][0]
     assert cache["site"] == memory and len(cache) == 1
+    # the registry's two counters: dispatches enqueued with a window, and
+    # re-dispatches because a window's span overflowed
+    assert tuple(_window_counters() - before) == (
+        sum(1 for _sc, w in want if w),
+        sum(1 for (_sc, w), (_n, ok) in zip(want, metas) if w and not ok))
 
 
 def _site_query(site, env, rng):
@@ -147,6 +161,117 @@ def test_site_recovers_from_a_forced_mispredict(site, world, request, rng,
         pd.testing.assert_frame_equal(got, exp, check_dtype=False)
         assert len(segs) == want_dispatches, segs
     assert segs[0] > 2          # the remembered true bucket, not first sight
+
+
+def _forced_window(monkeypatch, builder):
+    """The windowed gather forced onto the CPU rig: ``fused.window_for``
+    answers 1024 whatever the platform and the density, the kernel runs in
+    interpret mode (jax 0.9's Pallas interpreter cannot type varying axes
+    inside shard_map, so the programs skip that check) and the site starts
+    from an empty memory.  Returns the log of ``(statics, arguments,
+    outputs)`` per dispatch (``statics[1]`` the segment space,
+    ``statics[-1]`` the window), the list of the kernel's traces and the
+    builder itself."""
+    from functools import partial
+
+    from cylon_tpu.ops import pallas_gather as pg
+    from cylon_tpu.relational import fused
+    from cylon_tpu.relational.common import BoundedCache
+    monkeypatch.setattr(fused, "window_for", lambda mesh, sc, dens: 1024)
+    monkeypatch.setattr(rel_gb, "_SEG_CACHE", BoundedCache())
+    monkeypatch.setattr(rel_gb, "shard_map",
+                        partial(jax.shard_map, check_vma=False))
+    log, traced = [], []
+    real, take = getattr(rel_gb, builder), pg.windowed_take_t
+
+    def spy(mesh, *static):
+        fn = real(mesh, *static)
+
+        def call(*args):
+            log.append((static, args, fn(*args)))
+            return log[-1][2]
+        return call
+
+    def take_spy(mat_t, idx, window, interpret=None):
+        traced.append(window)
+        return take(mat_t, idx, window, interpret)
+
+    monkeypatch.setattr(rel_gb, builder, spy)
+    monkeypatch.setattr(pg, "windowed_take_t", take_spy)
+    return log, traced, real
+
+
+def _sums_match_pandas_at_windows(t, df, log, windows_per_call):
+    """``sum(a) by k`` of ``t``, once per entry of ``windows_per_call``:
+    each answer pandas', each call's dispatches at exactly those
+    windows."""
+    exp = (df.groupby("k", as_index=False).agg(a_sum=("a", "sum"))
+           .sort_values("k").reset_index(drop=True))
+    for want in windows_per_call:
+        log.clear()
+        got = (groupby_aggregate(t, "k", [("a", "sum")]).to_pandas()
+               .sort_values("k").reset_index(drop=True))
+        pd.testing.assert_frame_equal(got, exp, check_dtype=False)
+        assert [static[-1] for static, _a, _o in log] == want, log
+
+
+@pytest.mark.parametrize("site,world", [("raw", "env1"), ("combine", "env4")])
+def test_site_takes_the_window(site, world, request, rng, monkeypatch):
+    """The standalone sites ask for the windowed gather under the fused
+    path's rule.  A table with dead rows behind its live prefix (empty
+    segment slots must point at the END OF THE LIVE PREFIX, PR 22's
+    lesson): first sight at 512 slots, then the true bucket WITH the
+    window, no span overflow, every output the plain program's and
+    pandas'; the second call is one dispatch with the remembered window;
+    the registry counts both."""
+    env = request.getfixturevalue(world)
+    log, traced, real = _forced_window(
+        monkeypatch, "_combine_fn" if site == "combine" else "_raw_fn")
+    n = 66000                      # cap 69632 on one shard: 3632 dead rows
+    df = pd.DataFrame({"k": rng.integers(0, int(n * 0.9), n).astype(np.int64),
+                       "a": rng.integers(0, int(n * 0.9), n).astype(np.int64)})
+    t = ct.Table.from_pandas(df, env)
+    assert t.capacity * env.world_size - n > 1024
+    before = _window_counters()
+    _sums_match_pandas_at_windows(t, df, log, ([0, 1024], [1024]))
+    static, args, win_out = log[-1]
+    seg_cap = static[1]
+    assert seg_cap > 512 and seg_cap % 256 == 0
+    assert traced and set(traced) == {1024}             # the kernel ran
+    meta = np.asarray(win_out[-1]).reshape(env.world_size, 2)
+    assert meta[:, 1].all(), "the windowed gather reported a span overflow"
+    assert list(rel_gb._SEG_CACHE.values()) == [(seg_cap, True, 1024)]
+    assert tuple(_window_counters() - before) == (2, 0)
+    # every output against the plain program's, group by group on each shard
+    plain = real(env.mesh, *static[:-1], 0)(*args)
+    np.testing.assert_array_equal(np.asarray(plain[-1]), meta[:, 0])
+    for a, b in zip(jax.tree.leaves(win_out[:-1]),
+                    jax.tree.leaves(plain[:-1])):
+        a = np.asarray(a).reshape(env.world_size, -1)
+        b = np.asarray(b).reshape(env.world_size, -1)
+        for r in range(env.world_size):
+            np.testing.assert_array_equal(a[r, :meta[r, 0]],
+                                          b[r, :meta[r, 0]])
+
+
+def test_raw_site_window_overflow_falls_back_for_good(env1, rng, monkeypatch):
+    """One huge group among many small ones: the tile of starts that holds
+    it spans more rows than any window.  The site re-dispatches once
+    without the window, the answer is pandas', ``_SEG_CACHE`` remembers
+    that the window is not allowed, the next call is ONE plain dispatch
+    and the registry counted one overflow."""
+    log, traced, _real = _forced_window(monkeypatch, "_raw_fn")
+    k = np.concatenate([np.zeros(40000, np.int64),
+                        np.arange(1, 26001, dtype=np.int64)])
+    rng.shuffle(k)
+    df = pd.DataFrame({"k": k,
+                       "a": rng.integers(0, 99, len(k)).astype(np.int64)})
+    t = ct.Table.from_pandas(df, env1)
+    before = _window_counters()
+    _sums_match_pandas_at_windows(t, df, log, ([0, 1024, 0], [0]))
+    assert traced                                   # the kernel really ran
+    assert list(rel_gb._SEG_CACHE.values()) == [(log[-1][0][1], False, 0)]
+    assert tuple(_window_counters() - before) == (1, 1)
 
 
 class TestCrashClassifier:
