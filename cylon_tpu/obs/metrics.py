@@ -237,7 +237,14 @@ def _get_or_make(name: str, cls, **kw):
     return m
 
 
-def counter(name: str, help: str = "") -> Counter:  # noqa: A002
+def counter(name: str, help: str = "", **labels) -> Counter:  # noqa: A002
+    """``labels``: one counter a label set, registered under the
+    exposition's own spelling of the series, ``name{key="value",...}``
+    (keys sorted) - what :func:`snapshot` and :func:`prometheus_text`
+    show it as."""
+    if labels:
+        name += "{" + ",".join(f'{k}="{v}"'
+                               for k, v in sorted(labels.items())) + "}"
     return _get_or_make(name, Counter, help=help)
 
 
@@ -378,11 +385,16 @@ def prometheus_text(prefix: str = "cylon_tpu") -> str:
     out = []
     with _LOCK:   # registrations are concurrent (serving threads)
         items = sorted(_METRICS.items())
+    typed = set()
     for name, m in items:
-        pn = f"{prefix}_{_prom_name(name)}"
+        # a labelled counter is registered as ``family{labels}``
+        family, brace, labels = name.partition("{")
+        pn = f"{prefix}_{_prom_name(family)}"
         if isinstance(m, Counter):
-            out.append(f"# TYPE {pn} counter")
-            out.append(f"{pn} {m.value}")
+            if pn not in typed:
+                typed.add(pn)
+                out.append(f"# TYPE {pn} counter")
+            out.append(f"{pn}{brace}{labels} {m.value}")
         elif isinstance(m, Gauge):
             out.append(f"# TYPE {pn} gauge")
             out.append(f"{pn} {m.value}")

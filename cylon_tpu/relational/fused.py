@@ -239,16 +239,27 @@ def _fused_fn(mesh: Mesh, n_l: int, all_live: bool, lspec, rspec,
                              out_specs=(ROW, ROW, ROW, ROW, ROW)))
 
 
-def window_for(mesh, seg_cap: int, density: float) -> int:
-    """Windowed-gather request for a dispatch at segment space
-    ``seg_cap`` (0 = plain): TPU only, measured group density above the
-    coverage floor, segment space big enough for the plain gather to
-    hurt."""
+def window_rule(mesh, seg_cap: int, density: float) -> tuple:
+    """THE eligibility rule of the windowed gather, for a dispatch at
+    segment space ``seg_cap``: ``(window, why)`` - the window to ask for
+    (0 = plain) and, where it is 0, which test said so: TPU only
+    (``not_tpu``), measured group density above the coverage floor
+    (``density_below_floor``), segment space big enough for the plain
+    gather to hurt (``segment_space_small``)."""
     from ..ops import pallas_gather as pg
-    on_tpu = next(iter(mesh.devices.flat)).platform == "tpu"
-    if not on_tpu or density < pg.MIN_DENSITY or seg_cap < (1 << 20):
-        return 0
-    return pg.pick_window(density)
+    if next(iter(mesh.devices.flat)).platform != "tpu":
+        return 0, "not_tpu"
+    if density < pg.MIN_DENSITY:
+        return 0, "density_below_floor"
+    if seg_cap < (1 << 20):
+        return 0, "segment_space_small"
+    return pg.pick_window(density), ""
+
+
+def window_for(mesh, seg_cap: int, density: float) -> int:
+    """:func:`window_rule`'s window alone (what ``chip_smoke.py`` and the
+    benchmark hold a callsite's memory to)."""
+    return window_rule(mesh, seg_cap, density)[0]
 
 
 def try_join_groupby_pushdown(table: Table, by: list, specs: list,
@@ -310,8 +321,8 @@ def try_begin_join_groupby(table: Table, by: list, specs: list,
         key_narrow.append(bool(state.lspec.cols[ent[1]].narrow))
 
     env = table.env
-    from .groupby import (PendingReduce, _result_table, _result_types,
-                          _shrink, dispatch_at_bucket)
+    from .groupby import (PendingReduce, _density_window, _result_table,
+                          _result_types, _shrink, dispatch_at_bucket)
     # result typing from the join output schema
     class _C:  # minimal stand-in with .type/.dictionary for _result_types
         def __init__(self, t, dc):
@@ -336,19 +347,13 @@ def try_begin_join_groupby(table: Table, by: list, specs: list,
         meta = host_array(res[-1]).astype(np.int64).reshape(-1, 2)
         return meta[:, 0], bool(np.all(meta[:, 1]))
 
-    def window(sc, n_groups):
-        # from the MEASURED per-shard group density (min across shards)
-        dens = float((n_groups / np.maximum(live, 1)).min()) \
-            if n_groups.size else 0.0
-        return window_for(env.mesh, sc, dens)
-
     with timing.region("groupby.fused"):
         h = dispatch_at_bucket(
             _SEG_CACHE,
             (env.serial, tuple(by), tuple(vspecs), state.cap_l, state.cap_r,
              int(state.vcl.sum()), int(state.vcr.sum()), ddof),
             config.pow2ceil(state.cap_l + state.cap_r), call, read_meta,
-            window)
+            _density_window(env.mesh, live))
 
     def _resolve():
         with timing.region("groupby.fused"):

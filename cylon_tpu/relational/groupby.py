@@ -97,15 +97,14 @@ def dispatch_at_bucket(cache, sig, cap_full: int, call, read_meta,
     when ``n_groups`` (counted from the group ids themselves, so a
     mispredict is always seen) passes ``seg_cap``, and without the window
     for good after a span overflow (``win_ok`` false: the windowed
-    gather's output is garbage).  ``window(seg_cap, n_groups) -> int``
-    picks the windowed Pallas gather for a re-dispatch from the measured
-    counts (None or 0: XLA's gather); a first-sight dispatch never has
-    one.  What the site settled on is remembered as ``cache[sig] =
-    (bucket, windowed allowed, window)``.  ``resolve()`` returns
-    ``(outputs, n_groups)``.  Two registry counters say how often the
-    kernel engaged: ``grouped_reduce_windowed_dispatches`` (a dispatch
-    enqueued with a window) and ``grouped_reduce_window_overflows`` (a
-    re-dispatch because its span overflowed)."""
+    gather's output is garbage).  ``window(seg_cap, n_groups) -> (window,
+    why, density)`` (:func:`_density_window`) picks the windowed Pallas
+    gather for a re-dispatch from the measured counts (None, or window 0:
+    XLA's gather); a first-sight dispatch never has one.  The site's
+    memory is ``cache[sig] = (bucket, windowed allowed, window)``;
+    ``resolve()`` returns ``(outputs, n_groups)``.  Registry counters:
+    ``grouped_reduce_windowed_dispatches``, ``..._window_overflows`` and a
+    plain settled dispatch's reason (:func:`_note_settled`, file's end)."""
     def enqueue(seg_cap, win):
         if win:
             _metrics.counter("grouped_reduce_windowed_dispatches").inc()
@@ -133,9 +132,10 @@ def dispatch_at_bucket(cache, sig, cap_full: int, call, read_meta,
                 _metrics.counter("grouped_reduce_window_overflows").inc()
             allowed = allowed and win_ok
             seg_cap = max(seg_cap, bucket)
-            win = window(seg_cap, n_groups) if window and allowed else 0
+            win = window(seg_cap, n_groups)[0] if window and allowed else 0
             res = enqueue(seg_cap, win)
         cache.put(sig, (bucket, allowed, win))
+        _note_settled(bucket, seg_cap, allowed, win, window, n_groups)
         return res, n_groups
 
     return PendingReduce(resolve)
@@ -163,15 +163,15 @@ def _read_meta(world: int, res):
 
 
 def _density_window(mesh, live):
-    """``window`` of a site whose shards hold ``live`` rows each: the
-    fused path's one rule (:func:`~.fused.window_for`) on the MEASURED
-    per-shard group density, the minimum over the shards."""
+    """``window`` of a site whose shards hold ``live`` rows each: the one
+    rule (:func:`~.fused.window_rule`) on the MEASURED per-shard group
+    density (min over shards): ``(window, why it is 0, that density)``."""
     from . import fused
     live = np.maximum(np.asarray(live, np.int64), 1)
 
     def window(seg_cap, n_groups):
         dens = float((n_groups / live).min()) if n_groups.size else 0.0
-        return fused.window_for(mesh, seg_cap, dens)
+        return (*fused.window_rule(mesh, seg_cap, dens), dens)
 
     return window
 
@@ -1021,3 +1021,45 @@ declare_builder(f"{__name__}._combine_fn", _trace_combine,
 declare_builder(f"{__name__}._shrink_fn", _trace_shrink, tags=("groupby",))
 declare_builder(f"{__name__}._sink_finalize_fn", _trace_sink_finalize,
                 tags=("groupby", "stream"))
+
+
+# ---------------------------------------------------------------------------
+# what a settled dispatch is counted and shown as (host side).  Below
+# everything a program is traced through, and the code above keeps its
+# line numbers: Mosaic's serialized kernel body embeds the lines of the
+# traced frames, so a shifted ``per_shard`` is a new cache key and a cold
+# compile of every windowed program (ROADMAP S1(b)).
+# ---------------------------------------------------------------------------
+
+#: why a settled dispatch ran XLA's gather, one registry counter each,
+#: registered at import so that a snapshot shows the whole family: the
+#: site hands the dispatcher no rule (its program holds no run gather);
+#: a span overflow forbade the window for good; ``fused.window_rule`` said
+#: no (its three words); or the rule would say yes on these counts but the
+#: site remembers window 0 from other data of the same signature
+_PLAIN = {why: _metrics.counter("grouped_reduce_plain_dispatches",
+                                reason=why)
+          for why in ("no_window_rule", "span_overflow", "not_tpu",
+                      "density_below_floor", "segment_space_small",
+                      "remembered_plain")}
+
+
+def _note_settled(bucket, seg_cap, allowed, win, window, n_groups):
+    """One settled dispatch of :func:`dispatch_at_bucket`: a plain one is
+    counted under the reason it is plain - asked of the site's own
+    ``window`` rule at the segment space the program ran at, no second
+    copy of its thresholds - and the groupby plan node (where a profile
+    is on) gets the segment bucket the site remembers, the window, and
+    the group density the rule was given."""
+    from ..obs import plan as _plan
+    why, dens = "no_window_rule", None
+    if window is not None:
+        _w, why, dens = window(seg_cap, n_groups)
+        if not allowed:
+            why = "span_overflow"
+    if not win:
+        _PLAIN[why or "remembered_plain"].inc()
+    node = _plan.current()          # None with no profile on
+    if node is not None and node.op == "groupby":
+        node.annotate(segment_space=int(bucket), window=int(win),
+                      **({} if dens is None else {"density": round(dens, 6)}))

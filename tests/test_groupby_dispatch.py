@@ -61,6 +61,9 @@ _DISPATCH_CASES = {
     "forbidden_window_stays_off_when_the_site_grows":
         ((1 << 20, False, 0), 1 << 22, [(1 << 21, True), (1 << 21, True)],
          4096, [(1 << 20, 0), (1 << 21, 0)], (1 << 21, False, 0)),
+    "warm_plain_site_is_not_asked_again_though_its_rule_now_says_yes":
+        ((1 << 21, True, 0), 1 << 22, [(1 << 21, True)], 4096,
+         [(1 << 21, 0)], (1 << 21, True, 0)),
 }
 
 
@@ -69,6 +72,20 @@ def _window_counters():
     return np.array([
         metrics.counter("grouped_reduce_windowed_dispatches").value,
         metrics.counter("grouped_reduce_window_overflows").value])
+
+
+def _plain_counters() -> dict:
+    """``grouped_reduce_plain_dispatches{reason=...}``, by reason."""
+    import re
+    from cylon_tpu.obs import metrics
+    rx = re.compile(r'^grouped_reduce_plain_dispatches\{reason="(\w+)"\}$')
+    return {m.group(1): v for k, v in metrics.snapshot().items()
+            if (m := rx.match(k))}
+
+
+def _plain_delta(before: dict) -> dict:
+    return {k: v - before[k] for k, v in _plain_counters().items()
+            if v != before[k]}
 
 
 @pytest.mark.parametrize("case", list(_DISPATCH_CASES))
@@ -95,9 +112,9 @@ def test_dispatch_at_bucket(case):
 
     def window(seg_cap, n_groups):
         assert n_groups.shape == (2,)
-        return win
+        return win, "", 0.5
 
-    before = _window_counters()
+    before, plain_before = _window_counters(), _plain_counters()
     h = rel_gb.dispatch_at_bucket(cache, "site", cap_full, call, read_meta,
                                   window if win else None)
     assert dispatched == want[:1] and pulled == []      # enqueued, not pulled
@@ -111,6 +128,13 @@ def test_dispatch_at_bucket(case):
     assert tuple(_window_counters() - before) == (
         sum(1 for _sc, w in want if w),
         sum(1 for (_sc, w), (_n, ok) in zip(want, metas) if w and not ok))
+    # and the third: ONE count a settled dispatch that ran without a
+    # window, under the reason - the site gave no rule, a span overflow
+    # forbade it, or (this fake rule always says yes) the site remembers 0
+    reason = ("no_window_rule" if not win else
+              "span_overflow" if not memory[1] else "remembered_plain")
+    assert _plain_delta(plain_before) == ({} if want[-1][1]
+                                          else {reason: 1})
 
 
 def _site_query(site, env, rng):
@@ -164,7 +188,7 @@ def test_site_recovers_from_a_forced_mispredict(site, world, request, rng,
 
 
 def _forced_window(monkeypatch, builder):
-    """The windowed gather forced onto the CPU rig: ``fused.window_for``
+    """The windowed gather forced onto the CPU rig: ``fused.window_rule``
     answers 1024 whatever the platform and the density, the kernel runs in
     interpret mode (jax 0.9's Pallas interpreter cannot type varying axes
     inside shard_map, so the programs skip that check) and the site starts
@@ -177,7 +201,8 @@ def _forced_window(monkeypatch, builder):
     from cylon_tpu.ops import pallas_gather as pg
     from cylon_tpu.relational import fused
     from cylon_tpu.relational.common import BoundedCache
-    monkeypatch.setattr(fused, "window_for", lambda mesh, sc, dens: 1024)
+    monkeypatch.setattr(fused, "window_rule",
+                        lambda mesh, sc, dens: (1024, ""))
     monkeypatch.setattr(rel_gb, "_SEG_CACHE", BoundedCache())
     monkeypatch.setattr(rel_gb, "shard_map",
                         partial(jax.shard_map, check_vma=False))
@@ -232,8 +257,9 @@ def test_site_takes_the_window(site, world, request, rng, monkeypatch):
                        "a": rng.integers(0, int(n * 0.9), n).astype(np.int64)})
     t = ct.Table.from_pandas(df, env)
     assert t.capacity * env.world_size - n > 1024
-    before = _window_counters()
+    before, plain_before = _window_counters(), _plain_counters()
     _sums_match_pandas_at_windows(t, df, log, ([0, 1024], [1024]))
+    assert _plain_delta(plain_before) == {}     # both settled WITH a window
     static, args, win_out = log[-1]
     seg_cap = static[1]
     assert seg_cap > 512 and seg_cap % 256 == 0
@@ -267,11 +293,12 @@ def test_raw_site_window_overflow_falls_back_for_good(env1, rng, monkeypatch):
     df = pd.DataFrame({"k": k,
                        "a": rng.integers(0, 99, len(k)).astype(np.int64)})
     t = ct.Table.from_pandas(df, env1)
-    before = _window_counters()
+    before, plain_before = _window_counters(), _plain_counters()
     _sums_match_pandas_at_windows(t, df, log, ([0, 1024, 0], [0]))
     assert traced                                   # the kernel really ran
     assert list(rel_gb._SEG_CACHE.values()) == [(log[-1][0][1], False, 0)]
     assert tuple(_window_counters() - before) == (1, 1)
+    assert _plain_delta(plain_before) == {"span_overflow": 2}   # both calls
 
 
 class TestCrashClassifier:

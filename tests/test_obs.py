@@ -207,6 +207,22 @@ class TestExposition:
         metrics.counter("t.prom.dotted").inc()
         assert "cylon_tpu_t_prom_dotted 1" in metrics.prometheus_text()
 
+    def test_labelled_counter_is_one_series_a_label_set(self):
+        """``counter(name, **labels)``: a counter a label set, the same
+        handle on the same labels whatever their order, the family typed
+        once in the exposition and each series spelled as Prometheus
+        spells it."""
+        a = metrics.counter("t_prom_lab", reason="x", site="s")
+        assert metrics.counter("t_prom_lab", site="s", reason="x") is a
+        b = metrics.counter("t_prom_lab", reason="y", site="s")
+        a.set(2)
+        b.set(5)
+        text = metrics.prometheus_text()
+        assert text.count("# TYPE cylon_tpu_t_prom_lab counter") == 1
+        assert 'cylon_tpu_t_prom_lab{reason="x",site="s"} 2' in text
+        assert 'cylon_tpu_t_prom_lab{reason="y",site="s"} 5' in text
+        assert metrics.snapshot()['t_prom_lab{reason="y",site="s"}'] == 5
+
     def test_snapshot_carries_phase_collector(self):
         config.BENCH_TIMINGS = True
         timing.reset()
