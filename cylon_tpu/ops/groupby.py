@@ -128,17 +128,27 @@ def _ftype(values):
 # ---------------------------------------------------------------------------
 
 @staged("segment_starts")
-def grouped_starts(gids, first, mask, n_live, seg_cap: int):
+def grouped_starts(first, mask, n_live, seg_cap: int):
     """First live row position of each group id, for grouped input (each
     group one contiguous run in the live prefix).  Slots past the last
     group hold ``n_live`` — making them both the empty-group sentinel and
     the "next start" of the final group, so every run extent is a
-    consecutive diff of this one array.  ONE scatter."""
-    n = gids.shape[0]
+    consecutive diff of this one array.
+
+    ONE one-operand sort, no scatter.  Rests on the callers' group ids
+    being ``cumsum(first & mask) - 1``: dense and non-decreasing with
+    position over the rows where ``first & mask`` holds, each such
+    position ``< n_live``.  Then group g starts at the g-th smallest
+    start position; every other row carries the fill ``n_live`` and
+    sorts behind them, and a ``seg_cap`` under the group count keeps the
+    first ``seg_cap`` starts.  Ties are among the fill alone, so the sort
+    is NOT stable: stability costs a second (iota) operand on XLA:TPU."""
+    n = first.shape[0]
     pos = jnp.arange(n, dtype=jnp.int32)
-    scat = jnp.where(first & mask, gids, jnp.int32(seg_cap))
-    return jnp.full(seg_cap, n_live, jnp.int32).at[scat].set(pos,
-                                                             mode="drop")
+    srt = jax.lax.sort(jnp.where(first & mask, pos, n_live), is_stable=False)
+    if seg_cap <= n:
+        return srt[:seg_cap]
+    return jnp.concatenate([srt, jnp.full(seg_cap - n, n_live, jnp.int32)])
 
 
 _GROUPED_NEEDS = {"sum": ("sum",), "count": ("count",),

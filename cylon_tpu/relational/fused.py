@@ -162,10 +162,9 @@ def _fused_fn(mesh: Mesh, n_l: int, all_live: bool, lspec, rspec,
         # capacity pad (shape-family padding, N - n_live ~ 1M rows at 32M
         # rows/side, overflowed every window and silently lost the
         # windowed gather)
-        with stage("segment_starts"):
-            starts = jnp.full(seg_cap, n_live, jnp.int32).at[
-                jnp.where(kstart, kgid, jnp.int32(seg_cap))].set(
-                    pos, mode="drop")
+        # kgid is cumsum(kstart) - 1 and every kept row is live: the
+        # invariant grouped_starts' sort rests on
+        starts = gbk.grouped_starts(kstart, keep, n_live, seg_cap)
 
         nl_lanes = lspec.n_lanes
         with stage("unpack"):

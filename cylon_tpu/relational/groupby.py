@@ -361,7 +361,7 @@ def _runs_reduce(specs_ops, val_datas, vmasks, gids, first, mask, vc,
     key_out, kval_out, win_ok) - ``win_ok`` is grouped_reduce's."""
     my = jax.lax.axis_index(ROW_AXIS)
     n_live = vc[my].astype(jnp.int32)
-    starts = gbk.grouped_starts(gids, first, mask, n_live, seg_cap)
+    starts = gbk.grouped_starts(first, mask, n_live, seg_cap)
     batch = []      # (batched op name, spec index)
     for i, op in enumerate(specs_ops):
         if op in gbk.CUMSUMMABLE:
@@ -469,7 +469,7 @@ def _final_fn(mesh: Mesh, ops: tuple, seg_cap: int, ddof: int, narrow: tuple):
             (None,) * len(flat_arrs), narrow, vspec)
         my = jax.lax.axis_index(ROW_AXIS)
         n_live = vc[my].astype(jnp.int32)
-        starts = gbk.grouped_starts(gids, first, mask, n_live, seg_cap)
+        starts = gbk.grouped_starts(first, mask, n_live, seg_cap)
         sum_idx = [j for j, k in enumerate(flat_kinds) if k == "sum"]
         inters_b, key_out, kval_out, _wok = gbk.grouped_reduce(
             ["sum"] * len(sum_idx), [s_arrs[j] for j in sum_idx],

@@ -34,7 +34,7 @@ STAGES = {
     "boundaries": "run/group boundary flags of sorted keys, group ids",
     "liveness": "row-liveness masks and their gather to sorted positions",
     "scan": "cumsum / cummin / cummax geometry scans",
-    "segment_starts": "the scatter of each group's first row position",
+    "segment_starts": "each group's first row position: a one-operand sort",
     "segment_gather": "the lane-matrix gather at the segment starts "
                       "(XLA's, or the windowed Pallas kernel)",
     "segment_reduce": "prefix differences, per-segment sums/extrema, finalize",

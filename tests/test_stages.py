@@ -7,8 +7,10 @@ its declaration's shapes: each row-scale equation sits under a
 ``cylon.<stage>`` whose stage is in the vocabulary — one case a builder;
 (b) the two benchmark routes at toy size: the programs they launch are
 named after their builders (HLO module ``jit_<module>_<builder>``) and the
-sort / scatter / gather instructions carry the expected stage in
-``op_name`` — which is what the profiler hands back as ``tf_op``.
+sort / gather instructions carry the expected stage in
+``op_name`` — which is what the profiler hands back as ``tf_op``; under
+``segment_starts`` no program holds a scatter, and the four grouped
+reduces hold ONE sort there, of one operand and not stable (ISSUE 33).
 """
 
 from __future__ import annotations
@@ -29,23 +31,27 @@ _STAGE = re.compile(r"cylon\.([A-Za-z0-9_]+)")
 DECLS = {d.builder: d for d in registry.collect()}
 
 
-def _stages_of(eqn) -> list:
-    return _STAGE.findall(str(eqn.source_info.name_stack))
+def _staged_eqns(jaxpr, inherited=()):
+    """``(eqn, stages)`` of every leaf equation: ``stages`` are the
+    ``cylon.<stage>`` names of the enclosing equations' name stacks and
+    its own, outermost first."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    for eqn in jaxpr.eqns:
+        here = tuple(inherited) + tuple(
+            _STAGE.findall(str(eqn.source_info.name_stack)))
+        subs = list(_sub_jaxprs(eqn))
+        if not subs:
+            yield eqn, here
+        for sub in subs:
+            yield from _staged_eqns(sub, here)
 
 
-def _unstaged(jaxpr, inherited=()):
+def _unstaged(jaxpr):
     """``(primitive, stages)`` of every leaf equation with a row-scale
     output whose innermost stage (own name stack, else the enclosing
     equation's) is missing or not in the vocabulary."""
     bad = []
-    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
-    for eqn in jaxpr.eqns:
-        here = tuple(inherited) + tuple(_stages_of(eqn))
-        subs = list(_sub_jaxprs(eqn))
-        if subs:
-            for sub in subs:
-                bad += _unstaged(sub, here)
-            continue
+    for eqn, here in _staged_eqns(jaxpr):
         row_scale = any(
             int(np.prod(getattr(v.aval, "shape", ()) or (1,)))
             >= registry.ROW_SCALE_ELEMS for v in eqn.outvars)
@@ -102,6 +108,16 @@ def test_multi_device_programs_hold_no_pile_of_long_wide_scans(builder, env4):
     scans = _long_wide_scans(DECLS[builder].trace(env4.mesh))
     module = builder.split("[")[0].rpartition(".")[0].rpartition(".")[2]
     assert len(scans) <= (0 if module in ("fused", "groupby") else 1), scans
+
+
+@pytest.mark.parametrize("builder", sorted(DECLS))
+def test_no_registered_builder_scatters_under_segment_starts(builder, env4):
+    """``starts`` is a one-operand sort (ops/groupby.grouped_starts, PR
+    33); the scatter it was cost 4.6-4.9 ns a candidate update on v5e."""
+    traced = DECLS[builder].trace(env4.mesh)
+    assert [e.primitive.name for e, st in _staged_eqns(traced)
+            if "segment_starts" in st
+            and e.primitive.name.startswith("scatter")] == []
 
 
 # ---- (b) the benchmark's two routes at toy size ---------------------------
@@ -168,8 +184,9 @@ def test_join_groupby_route_names(env1, launched):
     assert all("jit(join__count_fn)/cylon.join/" in n for n in sorts)
     fused = _hlo(launched["fused__fused_fn"])
     assert fused.startswith("HloModule jit_fused__fused_fn")
-    scatters = [n for n in _op_names(fused, "scatter")]
-    assert scatters and {_innermost(n) for n in scatters} \
+    # `starts` is a one-operand sort since PR 33: the program's only sort
+    assert _op_names(fused, "scatter") == []
+    assert {_innermost(n) for n in _op_names(fused, "sort")} \
         == {"segment_starts"}
     gathers = _op_names(fused, "gather")
     assert gathers and {_innermost(n) for n in gathers} \
@@ -184,15 +201,46 @@ def test_groupby_sort_route_names(env1, launched):
     assert {"groupby__raw_fn", "sort__local_sort_fn"} <= set(launched)
     raw = _hlo(launched["groupby__raw_fn"])
     assert raw.startswith("HloModule jit_groupby__raw_fn")
-    assert {_innermost(n) for n in _op_names(raw, "sort")} == {"sort_keys"}
-    assert {_innermost(n) for n in _op_names(raw, "scatter")} \
-        == {"segment_starts"}
+    assert {_innermost(n) for n in _op_names(raw, "sort")} \
+        == {"sort_keys", "segment_starts"}
+    assert _op_names(raw, "scatter") == []
     assert {_innermost(n) for n in _op_names(raw, "gather")} \
         == {"segment_gather"}
     srt = _hlo(launched["sort__local_sort_fn"])
     assert srt.startswith("HloModule jit_sort__local_sort_fn")
     assert {_innermost(n) for n in _op_names(srt, "sort")} == {"sort_keys"}
     assert all("cylon.sort/" in n for n in _op_names(srt, "sort"))
+
+
+def _grouped_reduce_route(builder, env1, env4):
+    from cylon_tpu.relational import groupby_aggregate, join_tables
+    if builder == "fused__fused_fn":
+        left, right = _tables(env1)
+        return groupby_aggregate(
+            join_tables(left, right, "k", "k", how="inner"), "k",
+            [("a", "sum"), ("b", "sum")])
+    env = env1 if builder == "groupby__raw_fn" else env4
+    return groupby_aggregate(_tables(env)[0], "k", [("a", "sum")])
+
+
+@pytest.mark.parametrize("builder", [
+    "fused__fused_fn", "groupby__raw_fn", "groupby__combine_fn",
+    "groupby__final_fn"])
+def test_segment_starts_is_one_unstable_one_operand_sort(builder, env1,
+                                                         env4, launched):
+    """Neither the scatter nor the stability ``iota`` (a second operand:
+    the sort twice as long on XLA:TPU) can come back unseen."""
+    assert _grouped_reduce_route(builder, env1, env4).row_count > 0
+    prog, args, kwargs = launched[builder]
+    traced = jax.make_jaxpr(prog._fn)(*args, **kwargs)
+    under = [e for e, st in _staged_eqns(traced)
+             if st and st[-1] == "segment_starts"]
+    prims = [e.primitive.name for e in under]
+    assert not [p for p in prims if p.startswith("scatter")], prims
+    sorts = [e for e in under if e.primitive.name == "sort"]
+    assert len(sorts) == 1, prims
+    assert len(sorts[0].invars) == 1
+    assert sorts[0].params["is_stable"] is False
 
 
 def test_short_name_is_module_and_builder():
