@@ -244,9 +244,8 @@ BROADCAST_JOIN_ROWS = int(os.environ.get("CYLON_TPU_BROADCAST_JOIN_ROWS",
 SKEW_SAMPLE = int(os.environ.get("CYLON_TPU_SKEW_SAMPLE", "4096"))
 #: Minimum per-shard sampled share for a key to enter the estimate:
 SKEW_MIN_SHARE = float(os.environ.get("CYLON_TPU_SKEW_MIN_SHARE", "0.01"))
-#: A key is heavy when its weighted global share exceeds FACTOR / world
-#: (1.0 = one full shard's worth of rows):
-SKEW_GLOBAL_FACTOR = float(os.environ.get("CYLON_TPU_SKEW_FACTOR", "1.0"))
+#: (How heavy a key must be is no setting: relational/skew.split_rule,
+#: the owner's projected load against a bound with a chip run behind it.)
 #: At most this many heavy keys split per join:
 SKEW_MAX_KEYS = int(os.environ.get("CYLON_TPU_SKEW_MAX_KEYS", "8"))
 #: Replication guard: skip the split when the BUILD side's heavy rows,
@@ -265,12 +264,6 @@ SKEW_GUARD_ROWS = int(os.environ.get("CYLON_TPU_SKEW_GUARD_ROWS", "65536"))
 #: Master switch (default ARMED — "0" falls back to plain hashing for
 #: inner/left/right/outer; semi/anti keep the legacy round-robin spread):
 SKEW_SPLIT = os.environ.get("CYLON_TPU_SKEW_SPLIT", "1") != "0"
-#: Conservative absolute share floor: a key must hold at least this
-#: fraction of the probe side (in addition to exceeding
-#: SKEW_GLOBAL_FACTOR / world) before the facade will split it — at
-#: large worlds 1/W alone is far too eager for the stitch's extra pass:
-SKEW_SPLIT_SHARE = float(os.environ.get("CYLON_TPU_SKEW_SPLIT_SHARE",
-                                        "0.05"))
 #: Fan-out oversubscription: a key with estimated share s splits over
 #: ceil(s * world * FANOUT_FACTOR) contiguous ranks (clamped to
 #: [2, world] and to the key's exact row count):

@@ -451,7 +451,9 @@ def exchange(mesh: Mesh, tgt, counts: np.ndarray, cols: tuple,
     block = config.pow2ceil(min(max(max_c, 1), exchange_block_cap(total, w)))
     rounds = -(-max_c // block) if max_c else 1
     per_dest = counts.sum(axis=0)
-    out_cap = config.pow2ceil(int(per_dest.max()) if per_dest.size else 1)
+    # the fullest destination's rows set EVERY chip's receive capacity
+    recv_max = int(per_dest.max()) if per_dest.size else 0
+    out_cap = config.pow2ceil(recv_max)
 
     # topology route (cylon_tpu/topo, docs/topology.md): on a
     # multi-slice fabric phase B goes hierarchical — a slice-local ICI
@@ -568,6 +570,11 @@ def exchange(mesh: Mesh, tgt, counts: np.ndarray, cols: tuple,
     _metrics.counter("exchange_rows_total").inc(total)
     _metrics.counter("exchange_bytes_total").inc(total * row_bytes)
     _metrics.counter("exchange_count").inc()
+    # how UNEVEN it was: the fullest destination's rows and the capacity
+    # bucket they set (every chip's receive buffers, and so every later
+    # whole-shard program's shape, are sized by the fullest chip)
+    _metrics.counter("exchange_recv_max_rows_total").inc(recv_max)
+    _metrics.counter("exchange_recv_cap_rows_total").inc(out_cap)
     route = "two_hop" if hplan is not None else "flat"
     topo_t = _topo.topology(mesh)
     tiers = None
@@ -609,7 +616,8 @@ def exchange(mesh: Mesh, tgt, counts: np.ndarray, cols: tuple,
     # trace, flight recorder): what it moved, and how long the host took
     # to enqueue its programs (docs/observability.md)
     with timing.span("exchange." + route, rows=total,
-                     bytes=total * row_bytes):
+                     bytes=total * row_bytes, recv_max=recv_max,
+                     recv_cap=out_cap, block=block, rounds=rounds):
         if hplan is not None:
             # the voted hierarchical route (cylon_tpu/topo/exchange): the
             # plan hash is consensus-adopted BEFORE the first hierarchical

@@ -96,8 +96,8 @@ def _hash_args(cols):
 
 def _heavy_keys(table: Table, key_names: list, env):
     """Host-side heavy-hitter estimate from a small device sample: key
-    HASHES whose weighted global share exceeds SKEW_GLOBAL_FACTOR/world
-    (a single key owning a full shard's worth of rows).  Returns a small
+    HASHES whose owner's projected load passes ``skew.split_rule``'s
+    bound (the one threshold of every heavy-key route).  Returns a small
     np uint32 array or None.  Reference analog: the sampled partition
     machinery (table.cpp:620-689) applied to skew (SURVEY.md §7 hard-part
     4).  A hash collision only widens the split to an extra (light) key —
@@ -126,8 +126,8 @@ def _heavy_keys(table: Table, key_names: list, env):
         keep = cnt / lv.size > config.SKEW_MIN_SHARE
         for u, c in zip(uniq[keep], cnt[keep]):
             shares[u] = shares.get(u, 0.0) + c * weight
-    thresh = config.SKEW_GLOBAL_FACTOR / w
-    heavy = [(u, sh) for u, sh in shares.items() if sh > thresh]
+    from .skew import split_rule
+    heavy = [(u, sh) for u, sh in shares.items() if split_rule(sh, w)[2]]
     if not heavy:
         return None
     heavy.sort(key=lambda x: -x[1])

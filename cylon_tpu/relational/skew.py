@@ -10,8 +10,8 @@ deterministic, rank-coherent PLAN:
    (:func:`cylon_tpu.relational.common.sample_key_rows` — evenly spaced
    per-shard positions, shard-weighted) feeds the weighted Misra-Gries
    sketch (:mod:`cylon_tpu.obs.sketch`); key-hash classes whose
-   estimated share exceeds ``max(SKEW_GLOBAL_FACTOR / W,
-   CYLON_TPU_SKEW_SPLIT_SHARE)`` become candidate heavy keys, each named
+   owner's projected load ``1 + share * (W - 1)`` passes ``2 - 1/W``
+   (:func:`split_rule`) become candidate heavy keys, each named
    by the FULL sampled key tuple (values + validity bits) so every
    later predicate runs in sort-OPERAND space (``pack.key_operands`` +
    ``rows_cmp_splitters``) — equality and order agree bit-for-bit with
@@ -357,10 +357,12 @@ def detect(probe: Table, key_names, env) -> SkewPlan | None:
     if sampled is None:
         return None
     values, valids, hashes, weights, _total = sampled
+    _DETECT_JOINS.inc()
     mg = MisraGries(k=max(4 * config.SKEW_MAX_KEYS, 8))
     mg.update(hashes, weights)
-    thresh = max(config.SKEW_GLOBAL_FACTOR / w, config.SKEW_SPLIT_SHARE)
-    heavy = [(hv, sh) for hv, sh, _err in mg.shares() if sh > thresh]
+    est = mg.shares()
+    _annotate_rule(est, w)
+    heavy = [(hv, sh) for hv, sh, _e in est if split_rule(sh, w)[2]]
     if not heavy:
         return None
     heavy = heavy[:config.SKEW_MAX_KEYS]
@@ -947,3 +949,54 @@ declare_builder(f"{__name__}._out_ltcount_fn", _trace_out_ltcount,
                 tags=("skew", "join"))
 declare_builder(f"{__name__}._stitch_pos_fn", _trace_stitch_pos,
                 tags=("skew", "join"))
+
+
+# ---------------------------------------------------------------------------
+# THE rule that decides a split (ISSUE 34, ROADMAP X13; docs/skew.md "The
+# rule").  Host-side code of this file goes HERE, at its end: the programs
+# above keep their line numbers (PERF.md, PR 30's lesson).
+# ---------------------------------------------------------------------------
+
+def split_rule(share: float, w: int) -> tuple:
+    """``(owner_load, bound, split)`` for a key holding ``share`` of the
+    probe rows on ``w`` chips - the one statement of the threshold, in the
+    terms it protects.  Hash partitioning sends the key whole to one chip,
+    which then holds its balanced ``1/w`` of the other rows plus the whole
+    key: ``owner_load = 1 + share * (w - 1)`` times the balanced rows.
+    Every whole-shard program after the exchange is compiled at the
+    FULLEST chip's receive capacity, so that load is what the whole mesh
+    pays.  The key is split when the load passes ``bound = 2 - 1/w``: the
+    key BY ITSELF is then more than one chip's balanced rows (``share >
+    1/w``; 1.75x at w = 4, 1.875x at w = 8).  The bound is no setting;
+    docs/skew.md has the chip measurement behind it (PR 34: at a load of
+    1.34 the split plan took twice the unsplit plan's time).
+    :func:`detect` asks it of every sketched key, the legacy semi/anti
+    spread (``relational/join._heavy_keys``) of its own estimate."""
+    w = int(w)
+    load = 1.0 + float(share) * (w - 1)
+    bound = 2.0 - 1.0 / w
+    return load, bound, load > bound
+
+
+def _annotate_rule(est: list, w: int) -> None:
+    """Tell the join's plan node what the rule saw of the hottest sketched
+    key (the sketch lists heaviest first), so that an unsplit join says
+    why.  A no-op outside ``obs.explain*``."""
+    if not est:
+        return
+    from ..obs import plan as _plan
+    share = float(est[0][1])
+    load, bound, _split = split_rule(share, w)
+    _plan.annotate(skew_top_share=round(share, 6),
+                   skew_owner_load=round(load, 6),
+                   skew_owner_load_bound=round(bound, 6))
+
+
+#: registered at import, so that a snapshot shows all three whether or not
+#: a join ever split: joins that ran the detector (took the sample), joins
+#: that voted a plan, and the keys those plans split
+from ..obs import metrics as _metrics  # noqa: E402
+
+_DETECT_JOINS = _metrics.counter("skew_detect_joins")
+_metrics.counter("skew_split_joins")
+_metrics.counter("skew_split_keys")

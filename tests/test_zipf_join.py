@@ -72,9 +72,10 @@ def _node(qplan, op: str):
 @pytest.mark.parametrize("world", ["env1", "env4"])
 def test_zipf_join_groupby_equals_numpy(world, route, s, request):
     """At s = 1.1 the hottest key holds ~13% of the probe rows, at 1.5
-    over a third: on four devices the first stays under the skew
-    detector's threshold (25% at world 4: route ``hash``, armed, no key
-    split), the second is split (``skew_split``, one heavy key)."""
+    over a third: on four devices the first key's owner is projected to
+    hold 1.39x the balanced rows, under the measured bound of
+    ``skew.split_rule`` (PR 34: route ``hash``, armed, no key split),
+    the second's over twice (``skew_split``, one heavy key)."""
     env = request.getfixturevalue(world)
     left, right = _host_tables(s)
     hot_share = np.bincount(left["k"]).max() / ROWS
@@ -100,10 +101,13 @@ def test_zipf_join_groupby_equals_numpy(world, route, s, request):
     if env.world_size == 1:
         assert join.attrs["route"] == "colocated"
     elif s == 1.1:
+        assert join.attrs["skew_owner_load"] < join.attrs[
+            "skew_owner_load_bound"] < 2.0
         assert join.attrs["route"] == "hash"
         assert join.attrs["skew_split_armed"] is True
         assert join.attrs["skew_split_keys"] == 0
     else:
+        assert join.attrs["skew_owner_load"] > 2.0
         assert join.attrs["route"] == "skew_split"
         assert join.attrs["skew_plan"]["keys"] == 1
     if route == "fused_pushdown":
