@@ -5,14 +5,18 @@ TPU-native replacement for the reference's local join layer
 ``do_sorted_join``, hash_join.cpp:22-85).  The reference's default algorithm
 is SORT (join_config.hpp:37); a pointer-chasing hash build/probe doesn't map
 to XLA, so the sort path is *the* design here (SURVEY.md §7 hard-part 2),
-engineered around the measured v5e cost model: ``lax.sort`` is cheap
-(~7 ns/row), random gathers are expensive (~20 ns/row/lane), segment
-reductions with large segment counts are expensive — prefix scans are cheap.
+engineered around the measured v5e cost model: ``lax.sort`` costs its
+operand count (1.1-1.25 ns a row an operand: ledger, PRs 29-34), random
+gathers are expensive (~20 ns/row/lane), segment reductions with large
+segment counts are expensive — prefix scans are cheap.
 
   1. ``join_sort_state``: ONE stable sort of the concatenated (left ++
      right) packed key tuples (u32 lanes, :mod:`.pack`).  Stability makes
      left rows precede right rows within every equal-key run, so the sorted
-     order itself encodes the merge.
+     order itself encodes the merge.  What rides that sort beside the keys
+     and the row index is said in ONE place, :class:`PayloadLayout`: the
+     two sides' payload lanes share operands and a key column's lanes are
+     read back from the sorted key operands, so every datum moves once.
   2. ``join_carry``: per-position geometry from *segmented scans* only
      (no segment reductions, no group-space gather; row liveness is a
      position compare against the live prefix, ``live_sides``):
@@ -40,9 +44,10 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..utils.stages import stage, staged
-from .pack import KeyOps, concat_keyops, neighbor_flags
+from .pack import KeyOps, concat_keyops, key_operand_slots, neighbor_flags
 
 
 class JoinCarry(NamedTuple):
@@ -56,14 +61,143 @@ class JoinCarry(NamedTuple):
     un: jax.Array      # outer only: 1 = unmatched right row (else zeros)
 
 
-def join_sort_state(ko_l: KeyOps, ko_r: KeyOps, payloads: tuple = ()):
+class PayloadLayout(NamedTuple):
+    """What rides THE sort beside the key operands and ``idx`` - the one
+    statement of the join's payload layout (static, hashable: it travels
+    with the compiled programs beside ``lspec`` / ``rspec`` and in
+    ``fused.JoinState``).  Two rules, both read off the specs:
+
+    1. *The two sides share payload operands.*  Left rows are ``[0, n_l)``
+       of the concat and right rows the rest, so physical payload ``j`` is
+       ``concatenate([left lane, right lane])`` and the sort carries
+       ``max`` of the two sides' lane counts where one operand a lane a
+       side would be half zeros.  Every consumer reads a left lane only at
+       left rows and a right lane only at right rows (``fused.value_of``
+       masks by side, ``join_take`` gathers the emit side at the owning
+       row and the match side at ``mpos`` under ``matched``), so after the
+       sort left lane j and right lane j ARE the same array.
+    2. *A key column's lanes are not sorted twice.*  Where a left output
+       column is a join key whose lanes are bit-equal to its sort
+       operands (:func:`payload_layout`), the lane stays out of the
+       payloads and is the sorted key operand itself (on right rows that
+       array holds the right row's key; nobody reads a left lane there).
+
+    ``pl_s``, the tuple the count programs return and every consumer
+    holds, is the PHYSICAL arrays: the sorted key operands named in
+    ``kept_keys`` (as sorted: int32 / uint32), then the ``n_payloads``
+    shared payload arrays.  :func:`payload_operands` builds the sort's
+    payloads, :func:`payload_lanes` turns ``pl_s`` back into (left lanes,
+    right lanes); nobody else slices it."""
+    n_keys: int = 0    # key operands of the sort, liveness flag included
+    nl: int = 0        # left lanes a consumer reads (0: the side does not ride)
+    nr: int = 0        # right lanes
+    alias: tuple = ()  # per left lane: the key operand that IS it, or -1
+
+    @property
+    def kept_keys(self) -> tuple:
+        """Sort-operand indices of the sorted keys ``pl_s`` leads with."""
+        return tuple(sorted({a for a in self.alias if a >= 0}))
+
+    @property
+    def riding(self) -> tuple:
+        """Left lanes that ride as payload (not aliased to a key)."""
+        return tuple(j for j, a in enumerate(self.alias) if a < 0)
+
+    @property
+    def n_payloads(self) -> int:
+        return max(len(self.riding), self.nr)
+
+    @property
+    def n_arrays(self) -> int:
+        """Length of ``pl_s``."""
+        return len(self.kept_keys) + self.n_payloads
+
+    @property
+    def sort_operands(self) -> int:
+        """Operands handed to the join's ``lax.sort``: keys, idx, payloads."""
+        return self.n_keys + 1 + self.n_payloads
+
+
+def payload_layout(lspec, rspec, key_cols: tuple, key_dtypes: tuple,
+                   need_nf: tuple, narrow: tuple,
+                   all_live: bool) -> PayloadLayout:
+    """The layout for a join whose left / right lane matrices (``lspec`` /
+    ``rspec``: :class:`~.lanes.LaneSpec`, None where that side does not
+    ride) go through the sort of a key tuple of physical ``key_dtypes``
+    packed with ``need_nf`` / ``narrow`` (:func:`.pack.key_operands`; no
+    liveness operand when ``all_live``).  ``key_cols[i]``: the left lane
+    column that IS key column i (None: no left output column is).
+
+    Rule 2 takes a key's lanes from its sorted operands where the two are
+    bit-equal, decided on the physical dtype alone: integer kinds (a string
+    key is its int32 dictionary codes, and rides as them, so it is taken
+    like any int32) with no null-flag operand (a nullable key's operand is
+    zeroed under the flag while the lane keeps the raw datum) - one lane =
+    the narrow / 32-bit operand, or ``lo`` of a wide pair; two lanes = the
+    ``(hi, lo)`` pair.  Floats decline (their operands are canonicalised),
+    and so does a two-lane column beside a narrow operand."""
+    kinds, slots = key_operand_slots(key_dtypes, need_nf, narrow,
+                                     row_mask=not all_live)
+    nl = lspec.n_lanes if lspec is not None else 0
+    alias = [-1] * nl
+    for ci, dt, nf, ops in zip(key_cols if nl else (), key_dtypes, need_nf,
+                               slots):
+        if ci is None or nf or np.dtype(dt).kind not in "iu":
+            continue
+        col = lspec.cols[ci]
+        if np.dtype(col.dtype) != np.dtype(dt):
+            continue
+        if len(col.lanes) == 1:
+            alias[col.lanes[0]] = ops[-1]
+        elif len(col.lanes) == len(ops) == 2:
+            alias[col.lanes[0]], alias[col.lanes[1]] = ops
+    return PayloadLayout(len(kinds), nl,
+                         rspec.n_lanes if rspec is not None else 0,
+                         tuple(alias))
+
+
+@staged("pack")
+def payload_operands(layout: PayloadLayout, lmat, rmat, n_l: int,
+                     n_r: int) -> tuple:
+    """The sort's payload operands, (n_l + n_r,) uint32 each: physical
+    payload j is left riding lane j over the left rows and right lane j
+    over the right rows, zeros where a side has no such lane.  ``lmat`` /
+    ``rmat``: the sides' (n, L) lane matrices (not read, so None will do,
+    where ``layout`` has no lane of that side)."""
+    n = layout.n_payloads
+    left = [lmat[:, j] for j in layout.riding]
+    right = [rmat[:, j] for j in range(layout.nr)]
+    left += [jnp.zeros(n_l, jnp.uint32)] * (n - len(left))
+    right += [jnp.zeros(n_r, jnp.uint32)] * (n - len(right))
+    return tuple(jnp.concatenate(pair) for pair in zip(left, right))
+
+
+@staged("unpack")
+def payload_lanes(layout: PayloadLayout, pl_s: tuple) -> tuple:
+    """``(left lanes, right lanes)`` of a sorted state's physical arrays
+    ``pl_s`` (:class:`PayloadLayout`): ``layout.nl`` and ``layout.nr``
+    (N,) uint32 arrays, a left lane valid at left rows and a right lane at
+    right rows only."""
+    kept = layout.kept_keys
+    keys, pay = pl_s[:len(kept)], pl_s[len(kept):]
+    ride = iter(pay)
+    left = tuple(
+        jax.lax.bitcast_convert_type(keys[kept.index(a)], jnp.uint32)
+        if a >= 0 else next(ride) for a in layout.alias)
+    return left, tuple(pay[:layout.nr])
+
+
+def join_sort_state(ko_l: KeyOps, ko_r: KeyOps, payloads: tuple = (),
+                    keep: tuple = ()):
     """THE sort: stable lexicographic sort of the concatenated key tuples.
 
-    Returns ``(bnd, idx_s, sorted_payloads)`` — bnd/idx_s (n_l + n_r,)
-    int32.  ``idx_s[p]`` is the concat-row index occupying sorted position
+    Returns ``(bnd, idx_s, pl_s)`` — bnd/idx_s (n_l + n_r,) int32.
+    ``idx_s[p]`` is the concat-row index occupying sorted position
     p (values < n_l are left rows); ``bnd[p]`` = 1 iff position p starts a
     new key group (p=0 -> 0).  Stability ⇒ within a group, left rows come
-    first, each side in source order.
+    first, each side in source order.  (XLA's stable-sort expansion adds
+    no tie-break operand of its own here: it reuses an operand that
+    already is an iota, and ``idx`` is one.)
 
     Invariant every consumer of the state relies on (:func:`live_sides`):
     *a liveness operand leads the sort ⇔ ``n_live`` is not None ⇔ padding
@@ -73,10 +207,13 @@ def join_sort_state(ko_l: KeyOps, ko_r: KeyOps, payloads: tuple = ()):
     rows sort first whatever their keys (int64 max included) and
     ``n_live`` is the two sides' valid counts summed — a per-shard scalar.
 
-    ``payloads``: optional (n_l+n_r,) arrays carried through the sort —
-    moving data as sort payload costs ~2 ns/row/operand vs ~20 ns/row for
-    a later gather, so callers ride small column sets along.
-    """
+    ``payloads``: optional (n_l+n_r,) arrays carried through the sort
+    (:func:`payload_operands`) — every operand is one more pass of the
+    sort's network, 1.1-1.25 ns a row on v5e (ledger, PRs 29-34), against
+    ~20 ns/row for a later gather, so callers ride small column sets
+    along.  ``keep``: key-operand indices whose SORTED arrays lead
+    ``pl_s`` (:attr:`PayloadLayout.kept_keys`); the sorted payloads
+    follow."""
     cat = concat_keyops(ko_l, ko_r)
     n = cat.n
     idx = jnp.arange(n, dtype=jnp.int32)
@@ -86,7 +223,8 @@ def join_sort_state(ko_l: KeyOps, ko_r: KeyOps, payloads: tuple = ()):
     nk = len(cat.ops)
     idx_s = sorted_all[nk]
     bnd = neighbor_flags(sorted_all[:nk], cat.kinds)
-    return bnd, idx_s, tuple(sorted_all[nk + 1:])
+    return (bnd, idx_s,
+            tuple(sorted_all[i] for i in keep) + tuple(sorted_all[nk + 1:]))
 
 
 @staged("liveness")

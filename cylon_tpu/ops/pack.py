@@ -185,31 +185,43 @@ def key_operands(datas, validities=None, row_mask=None, descendings=None,
     return KeyOps(tuple(ops), tuple(kinds))
 
 
-def key_operand_kinds(dtypes, need_null_flags, narrow32) -> tuple:
-    """Static operand KIND tuple that :func:`key_operands` (with a
-    ``row_mask``, ascending keys) produces for this key structure —
-    liveness flag, then per column an optional null flag plus the value
-    operand kind(s).  This is :func:`_sort_value`'s packing rules in
-    dtype space only (no arrays built): keep the two in lockstep — the
-    Pallas probe's eligibility gate and exec/pipeline's static operand
-    counts both read this."""
-    kinds = ["i"]
+def key_operand_slots(dtypes, need_null_flags, narrow32,
+                      row_mask: bool = True) -> tuple:
+    """Static ``(kinds, slots)`` of the operand list :func:`key_operands`
+    (ascending keys) produces for this key structure: ``kinds`` the
+    operand KIND tuple - liveness flag (with a ``row_mask``), then per
+    column an optional null flag plus the value operand kind(s) -
+    and ``slots[i]`` the positions of column i's VALUE operand(s) in it
+    (one, or ``(hi, lo)`` for a wide 64-bit integer).  This is
+    :func:`_sort_value`'s packing rules in dtype space only (no arrays
+    built): keep the two in lockstep - the Pallas probe's eligibility
+    gate, exec/pipeline's static operand counts and the join's payload
+    layout (ops/join.payload_layout) all read this."""
+    kinds = ["i"] if row_mask else []
+    slots = []
     for dt, nf, nrw in zip(dtypes, need_null_flags, narrow32):
         if nf:
             kinds.append("i")
         d = np.dtype(dt)
         if d.kind == "b":
-            kinds.append("i")
+            val = ("i",)
         elif d.kind in "iu":
             # wide 64-bit values split into a native (hi, lo) lane pair
-            kinds.extend(("i",) if (d.itemsize <= 4 or nrw) else ("i", "i"))
+            val = ("i",) if (d.itemsize <= 4 or nrw) else ("i", "i")
         elif d.kind == "f":
             # f32 sorts via the order-preserving uint32 bitcast ('i');
             # f64 keeps native NaN-aware float compares ('f')
-            kinds.append("i" if d.itemsize <= 4 else "f")
+            val = ("i" if d.itemsize <= 4 else "f",)
         else:
             raise TypeError(f"unsortable dtype {dt}")
-    return tuple(kinds)
+        slots.append(tuple(range(len(kinds), len(kinds) + len(val))))
+        kinds.extend(val)
+    return tuple(kinds), tuple(slots)
+
+
+def key_operand_kinds(dtypes, need_null_flags, narrow32) -> tuple:
+    """:func:`key_operand_slots`' kinds alone, liveness flag included."""
+    return key_operand_slots(dtypes, need_null_flags, narrow32)[0]
 
 
 def concat_keyops(a: KeyOps, b: KeyOps) -> KeyOps:

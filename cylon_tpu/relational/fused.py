@@ -69,14 +69,17 @@ class JoinState(NamedTuple):
     and ``pl_s`` holds the sorted WINDOW lanes, so the groupby pushdown
     consumes range pieces without any piece ever materializing; columns
     the aggregation never reads are never unpacked).  The fused kernel is
-    agnostic: ``plan``/``lspec``/``rspec`` are self-consistent in both."""
+    agnostic: ``plan``/``lspec``/``rspec``/``layout`` are self-consistent
+    in both."""
     vcl: np.ndarray      # left per-shard valid counts
     vcr: np.ndarray      # right per-shard valid counts
     idx_s: jax.Array     # (N,) concat-row index at each sorted position
     bnd: jax.Array       # (N,) key-boundary flags of the sorted state
-    pl_s: tuple          # sorted payload lanes: left lanes ++ right lanes
+    pl_s: tuple          # the sort's physical arrays: ``layout``'s kept
+                         # sorted keys, then the shared payload operands
     lspec: lanes.LaneSpec
     rspec: lanes.LaneSpec
+    layout: joink.PayloadLayout   # pl_s -> (left lanes, right lanes)
     plan: tuple          # output plan entries parallel to names
     names: tuple
     types: tuple
@@ -113,13 +116,14 @@ def _col_entry(state: JoinState, name: str):
 
 
 @program_cache()
-def _fused_fn(mesh: Mesh, n_l: int, all_live: bool, lspec, rspec,
+def _fused_fn(mesh: Mesh, n_l: int, all_live: bool, lspec, rspec, layout,
               vspecs: tuple, key_cols: tuple, key_narrow: tuple,
               seg_cap: int, ddof: int, use_window: int = 0):
     """Per-shard fused join+groupby kernel.
 
     ``vspecs``: per aggregation (side, lane_col_idx, op); ``key_cols``:
-    left lane-col index per groupby key.  Live rows form a sorted PREFIX
+    left lane-col index per groupby key; ``layout``: what ``pl_s`` holds
+    (ops/join.PayloadLayout).  Live rows form a sorted PREFIX
     (the row-liveness operand sorts padding last), so liveness is a
     position compare — no gather (ops/join.live_sides, the one statement
     of that rule)."""
@@ -166,10 +170,10 @@ def _fused_fn(mesh: Mesh, n_l: int, all_live: bool, lspec, rspec,
         # invariant grouped_starts' sort rests on
         starts = gbk.grouped_starts(kstart, keep, n_live, seg_cap)
 
-        nl_lanes = lspec.n_lanes
+        pl_l, pl_r = joink.payload_lanes(layout, pl_s)
         with stage("unpack"):
-            lmat = jnp.stack(pl_s[:nl_lanes], axis=1)
-            rmat = jnp.stack(pl_s[nl_lanes:], axis=1)
+            lmat = jnp.stack(pl_l, axis=1)
+            rmat = jnp.stack(pl_r, axis=1)
         ldat, lval = lanes.unpack_lanes(lspec, lmat)
         rdat, rval = lanes.unpack_lanes(rspec, rmat)
 
@@ -338,8 +342,9 @@ def try_begin_join_groupby(table: Table, by: list, specs: list,
 
     def call(sc, win):
         return _fused_fn(env.mesh, state.cap_l, state.all_live, state.lspec,
-                         state.rspec, tuple(vspecs), tuple(key_cols),
-                         tuple(key_narrow), sc, ddof, win)(*args)
+                         state.rspec, state.layout, tuple(vspecs),
+                         tuple(key_cols), tuple(key_narrow), sc, ddof,
+                         win)(*args)
 
     def read_meta(res):
         # n_groups and the windowed gather's span flag, one pull

@@ -30,6 +30,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from .. import config
+from ..obs import metrics as _metrics
 from ..obs import plan as _plan
 from ..utils.cache import jit, program_cache
 from ..core.column import Column
@@ -41,7 +42,6 @@ from ..ops import pack
 from ..status import InvalidError
 from ..utils import timing
 from ..utils.host import host_array
-from ..utils.stages import stage
 from .common import (PAD_L, PAD_R, REP, ROW, BoundedCache, build_table,
                      check_same_env,
                      sample_positions,
@@ -273,9 +273,10 @@ def _shuffle_for_join(lwork: Table, rwork: Table, left_on, right_on,
 
 def _sorted_state(vcl, vcr, l_datas, l_valids, r_datas, r_valids,
                   narrow: tuple, payloads: tuple = (),
-                  all_live: bool = False):
-    """Per-shard single-sort join state (bnd, idx_s, n_live, sorted
-    payloads).
+                  all_live: bool = False, keep: tuple = ()):
+    """Per-shard single-sort join state (bnd, idx_s, n_live, ``pl_s`` =
+    the ``keep`` sorted key operands then the sorted payloads,
+    ops/join.PayloadLayout).
 
     Both sides must build structurally identical operand lists, so the
     null-flag presence per key column is the union of the two sides' and the
@@ -296,7 +297,7 @@ def _sorted_state(vcl, vcr, l_datas, l_valids, r_datas, r_valids,
     ko_r = pack.key_operands(list(r_datas), list(r_valids), row_mask=mask_r,
                              pad_key=PAD_R, need_null_flags=need_nf,
                              narrow32=narrow)
-    bnd, idx_s, pl_s = joink.join_sort_state(ko_l, ko_r, payloads)
+    bnd, idx_s, pl_s = joink.join_sort_state(ko_l, ko_r, payloads, keep)
     return bnd, idx_s, None if all_live else live_count(vcl, vcr), pl_s
 
 
@@ -339,38 +340,36 @@ def _semi_flag_fn(mesh: Mesh, narrow: tuple, all_live: bool, anti: bool):
 @program_cache()
 def _count_fn(mesh: Mesh, how: str, narrow: tuple,
               lspec: lanes.LaneSpec | None = None,
-              rspec: lanes.LaneSpec | None = None, all_live: bool = False,
-              slim: bool = False):
+              rspec: lanes.LaneSpec | None = None,
+              layout: joink.PayloadLayout = joink.PayloadLayout(),
+              all_live: bool = False, slim: bool = False):
     """Phase 1: sort once; return per-shard exact counts + carried state.
 
     With ``lspec``/``rspec`` (inner/left joins over fully-laneable output
     columns), that side's u32 lane matrix RIDES THE SORT as payload
-    operands — ~1.7 ns/row/lane (measured) vs ~15 ns/row for the gathers
-    the materialize phase would otherwise pay: ``rspec`` kills the
-    dependent ``idx_s[mpos]`` + right lane-matrix gathers, ``lspec`` folds
-    the left values into the meta-stack gather that phase 2 already does.
-    Payload layout: left (emit) lanes first, then right (match) lanes."""
+    operands — 1.1-1.25 ns a row an operand on v5e (ledger, PRs 29-34) vs
+    ~15 ns/row for the gathers the materialize phase would otherwise pay:
+    ``rspec`` kills the dependent ``idx_s[mpos]`` + right lane-matrix
+    gathers, ``lspec`` folds the left values into the meta-stack gather
+    that phase 2 already does.  ``layout`` (ops/join.PayloadLayout, built
+    on the host by ``ops/join.payload_layout`` from these specs) says which
+    operands carry them: the two sides share operands and a key column's
+    lanes come back from the sorted key."""
+    assert (layout.nl, layout.nr) == tuple(
+        0 if sp is None else sp.n_lanes for sp in (lspec, rspec))
 
     def per_shard(vcl, vcr, l_datas, l_valids, r_datas, r_valids,
                   lg_cols, lg_valids, rg_cols, rg_valids):
         cap_l = l_datas[0].shape[0]
         cap_r = r_datas[0].shape[0]
-        payloads = ()
-        if lspec is not None:
-            lmat = lanes.pack_lanes(lspec, lg_cols, lg_valids)
-            with stage("pack"):
-                zr = jnp.zeros(cap_r, jnp.uint32)
-                payloads += tuple(jnp.concatenate([lmat[:, j], zr])
-                                  for j in range(lspec.n_lanes))
-        if rspec is not None:
-            rmat = lanes.pack_lanes(rspec, rg_cols, rg_valids)
-            with stage("pack"):
-                zl = jnp.zeros(cap_l, jnp.uint32)
-                payloads += tuple(jnp.concatenate([zl, rmat[:, j]])
-                                  for j in range(rspec.n_lanes))
+        lmat = None if lspec is None \
+            else lanes.pack_lanes(lspec, lg_cols, lg_valids)
+        rmat = None if rspec is None \
+            else lanes.pack_lanes(rspec, rg_cols, rg_valids)
+        payloads = joink.payload_operands(layout, lmat, rmat, cap_l, cap_r)
         bnd, idx_s, n_live, pl_s = _sorted_state(
             vcl, vcr, l_datas, l_valids, r_datas, r_valids, narrow, payloads,
-            all_live)
+            all_live, layout.kept_keys)
         n, carry = joink.join_carry(bnd, idx_s, n_live, cap_l, how)
         if slim:
             # deferred-join state: only what the fused consumer needs
@@ -381,9 +380,7 @@ def _count_fn(mesh: Mesh, how: str, narrow: tuple,
             return (n.reshape(1), idx_s, bnd) + pl_s
         return (n.reshape(1),) + tuple(carry) + pl_s
 
-    n_pl = (lspec.n_lanes if lspec is not None else 0) + \
-        (rspec.n_lanes if rspec is not None else 0)
-    n_out = (3 + n_pl) if slim else (7 + n_pl)
+    n_out = (3 if slim else 7) + layout.n_arrays
     return jit(shard_map(per_shard, mesh=mesh,
                              in_specs=(REP, REP, ROW, ROW, ROW, ROW, ROW,
                                        ROW, ROW, ROW),
@@ -424,26 +421,27 @@ def _un_count_fn(mesh: Mesh):
 @program_cache()
 def _materialize_fn(mesh: Mesh, how: str, out_cap: int, cap_l: int,
                     plan: tuple, lspec: lanes.LaneSpec,
-                    rspec: lanes.LaneSpec, carry_emit: bool = False,
-                    carry_match: bool = False):
+                    rspec: lanes.LaneSpec,
+                    layout: joink.PayloadLayout = joink.PayloadLayout()):
     """Phase 2.  ``plan`` entries (static):
     ("l", i, needs_valid) — output column = left lane-matrix column i;
     ("r", j, needs_valid) — right lane-matrix column j;
     ("k", i, j, needs_valid) — coalesce left col i with right col j.
 
-    ``carry_match``: the right lane matrix arrived pre-sorted as sort
-    payload (phase 1) — right values come from ONE (out, Lr) gather of the
-    sorted lanes at the match positions instead of idx_s[mpos] + a second
-    lane-matrix gather.  ``carry_emit``: the left lane matrix arrived the
-    same way and rides join_take's meta-stack gather — no separate left
-    gather at all.  Both only for how in (inner, left)."""
+    ``layout`` (ops/join.PayloadLayout) says what phase 1's ``pl_s``
+    holds.  A right side that rode the sort (``carry_match``): right
+    values come from ONE (out, Lr) gather of the sorted lanes at the match
+    positions instead of idx_s[mpos] + a second lane-matrix gather.  A
+    left side that rode (``carry_emit``): its lanes ride join_take's
+    meta-stack gather — no separate left gather at all.  Both only for how
+    in (inner, left)."""
 
+    carry_emit, carry_match = layout.nl > 0, layout.nr > 0
     l_f64 = any(not c.lanes for c in lspec.cols)
     r_f64 = any(not c.lanes for c in rspec.cols)
 
     def per_shard(carry, pl_s, l_cols, l_valids, r_cols, r_valids):
-        n_e = lspec.n_lanes if carry_emit else 0
-        pl_e, pl_m = pl_s[:n_e], pl_s[n_e:]
+        pl_e, pl_m = joink.payload_lanes(layout, pl_s)
         tk = joink.join_take(joink.JoinCarry(*carry), cap_l, how, out_cap,
                              extra=pl_e, carry_emit=carry_emit,
                              carry_match=carry_match,
@@ -558,15 +556,16 @@ def _window_keys(spec: lanes.LaneSpec, mat, f64w, key_idx: tuple):
 @program_cache()
 def _packed_count_fn(mesh: Mesh, how: str, narrow: tuple, need_nf: tuple,
                      lspec: lanes.LaneSpec, rspec: lanes.LaneSpec,
+                     layout: joink.PayloadLayout,
                      kil: tuple, kir: tuple, cap_l: int, cap_r: int,
                      n_arrs_l: int, n_arrs_r: int, all_live: bool,
-                     carry_emit: bool, carry_match: bool,
                      slim: bool = False):
     """Phase 1 over packed windows: slice both windows, unpack only the
     KEY columns, sort once, return per-shard exact counts + carried state.
-    With ``carry_emit``/``carry_match`` the window's OWN lanes ride the
-    sort as payload — there is no separate pack step at all (the windows
-    already are lane matrices)."""
+    The window's OWN lanes ride the sort as payload where ``layout``
+    (ops/join.PayloadLayout, as in :func:`_count_fn`) says that side rides
+    — there is no separate pack step at all (the windows already are lane
+    matrices)."""
 
     def per_shard(vcl, vcr, sl, sr, *arrs):
         arrs_l, arrs_r = arrs[:n_arrs_l], arrs[n_arrs_l:]
@@ -583,25 +582,18 @@ def _packed_count_fn(mesh: Mesh, how: str, narrow: tuple, need_nf: tuple,
         ko_r = pack.key_operands(r_datas, r_valids, row_mask=mask_r,
                                  pad_key=PAD_R, need_null_flags=need_nf,
                                  narrow32=narrow)
-        payloads = ()
-        if carry_emit:
-            zr = jnp.zeros(cap_r, jnp.uint32)
-            payloads += tuple(jnp.concatenate([mat_l[:, j], zr])
-                              for j in range(lspec.n_lanes))
-        if carry_match:
-            zl = jnp.zeros(cap_l, jnp.uint32)
-            payloads += tuple(jnp.concatenate([zl, mat_r[:, j]])
-                              for j in range(rspec.n_lanes))
-        bnd, idx_s, pl_s = joink.join_sort_state(ko_l, ko_r, payloads)
+        # a side that does not ride has no lane in ``layout``: its window
+        # is handed over all the same and nothing of it is read
+        payloads = joink.payload_operands(layout, mat_l, mat_r, cap_l, cap_r)
+        bnd, idx_s, pl_s = joink.join_sort_state(ko_l, ko_r, payloads,
+                                                 layout.kept_keys)
         n_live = None if all_live else live_count(vcl, vcr)
         n, carry = joink.join_carry(bnd, idx_s, n_live, cap_l, how)
         if slim:
             return (n.reshape(1), idx_s, bnd) + pl_s
         return (n.reshape(1),) + tuple(carry) + pl_s
 
-    n_pl = (lspec.n_lanes if carry_emit else 0) + \
-        (rspec.n_lanes if carry_match else 0)
-    n_out = (3 + n_pl) if slim else (7 + n_pl)
+    n_out = (3 if slim else 7) + layout.n_arrays
     in_specs = (REP, REP, REP, REP) + (ROW,) * (n_arrs_l + n_arrs_r)
     return jit(shard_map(per_shard, mesh=mesh, in_specs=in_specs,
                              out_specs=(ROW,) * n_out))
@@ -611,8 +603,8 @@ def _packed_count_fn(mesh: Mesh, how: str, narrow: tuple, need_nf: tuple,
 def _packed_materialize_fn(mesh: Mesh, how: str, out_cap: int, cap_l: int,
                            cap_r: int, plan: tuple,
                            lspec: lanes.LaneSpec, rspec: lanes.LaneSpec,
+                           layout: joink.PayloadLayout,
                            n_arrs_l: int, n_arrs_r: int,
-                           carry_emit: bool, carry_match: bool,
                            donate: tuple = ()):
     """Phase 2 over packed windows.  Carried sides unpack from the sorted
     payload lanes exactly like :func:`_materialize_fn`; non-carried sides
@@ -631,6 +623,7 @@ def _packed_materialize_fn(mesh: Mesh, how: str, out_cap: int, cap_l: int,
     dispatch over the state: the speculative-capacity dispatch and any
     fused consumer sharing the state via JoinState must not donate."""
 
+    carry_emit, carry_match = layout.nl > 0, layout.nr > 0
     l_f64 = any(not c.lanes for c in lspec.cols)
     r_f64 = any(not c.lanes for c in rspec.cols)
 
@@ -648,8 +641,7 @@ def _packed_materialize_fn(mesh: Mesh, how: str, out_cap: int, cap_l: int,
     def per_shard(carry, pl_s, sl, sr, *arrs):
         arrs_l, arrs_r = arrs[:n_arrs_l], arrs[n_arrs_l:]
         my = jax.lax.axis_index(ROW_AXIS)
-        n_e = lspec.n_lanes if carry_emit else 0
-        pl_e, pl_m = pl_s[:n_e], pl_s[n_e:]
+        pl_e, pl_m = joink.payload_lanes(layout, pl_s)
         tk = joink.join_take(joink.JoinCarry(*carry), cap_l, how, out_cap,
                              extra=pl_e, carry_emit=carry_emit,
                              carry_match=carry_match,
@@ -702,6 +694,25 @@ def _packed_materialize_fn(mesh: Mesh, how: str, out_cap: int, cap_l: int,
     jit_kwargs = {"donate_argnums": tuple(donate)} if donate else {}
     return jit(shard_map(per_shard, mesh=mesh, in_specs=in_specs,
                              out_specs=(ROW, ROW)), **jit_kwargs)
+
+
+#: registered at import, so that a snapshot shows both at 0 before any
+#: join: the operands handed to the join's ``lax.sort`` summed over the
+#: count programs dispatched, and those dispatches (static numbers of the
+#: layout, bumped on the host; benchmark metric join_sort_operands_per_join)
+_SORT_OPERANDS = _metrics.counter("join_sort_operands")
+_SORT_DISPATCHES = _metrics.counter("join_sort_dispatches")
+
+
+def _note_sort(layout: joink.PayloadLayout) -> None:
+    """One count program was dispatched with ``layout``: bump the two
+    registry counters and, under ``obs.explain*``, say on the join's plan
+    node what its one sort carries."""
+    _SORT_OPERANDS.inc(layout.sort_operands)
+    _SORT_DISPATCHES.inc()
+    _plan.annotate(sort_operands=layout.sort_operands,
+                   payload_operands=layout.n_payloads,
+                   aliased_key_lanes=layout.nl - len(layout.riding))
 
 
 def _fits32_meta(dtype, bounds) -> bool:
@@ -824,9 +835,13 @@ def _packed_statics(pl: PackedPiece, pr: PackedPiece, left_on, right_on,
     carry_match = can_carry(pr.spec) and pr.spec.n_lanes <= 8
     all_live = bool((pl.lens == pl.piece_cap).all()
                     and (pr.lens == pr.piece_cap).all())
+    # the window's own spec holds every column, the keys among them
+    layout = joink.payload_layout(
+        pl.spec if carry_emit else None, pr.spec if carry_match else None,
+        kil, tuple(pl.spec.cols[i].dtype for i in kil), need_nf, narrow,
+        all_live)
     return (kil, kir, need_nf, narrow, coalesce, tuple(plan), tuple(names),
-            tuple(types), tuple(dicts), tuple(bounds), carry_emit,
-            carry_match, all_live)
+            tuple(types), tuple(dicts), tuple(bounds), layout, all_live)
 
 
 def prewarm_packed_join(pl: PackedPiece, pr: PackedPiece, left_on,
@@ -843,15 +858,14 @@ def prewarm_packed_join(pl: PackedPiece, pr: PackedPiece, left_on,
         return
     try:
         (kil, kir, need_nf, narrow, coalesce, _plan, _names, _types,
-         _dicts, _bounds, carry_emit, carry_match,
-         all_live) = _packed_statics(pl, pr, left_on, right_on, how,
-                                     suffixes, coalesce_keys)
-        slim = (how == "inner" and carry_emit and carry_match
+         _dicts, _bounds, layout, all_live) = _packed_statics(
+            pl, pr, left_on, right_on, how, suffixes, coalesce_keys)
+        slim = (how == "inner" and layout.nl and layout.nr
                 and coalesce and allow_defer)
         fn = _packed_count_fn(
-            pl.env.mesh, how, narrow, need_nf, pl.spec, pr.spec, kil, kir,
-            pl.piece_cap, pr.piece_cap, len(pl.arrs), len(pr.arrs),
-            all_live, carry_emit, carry_match, slim)
+            pl.env.mesh, how, narrow, need_nf, pl.spec, pr.spec, layout,
+            kil, kir, pl.piece_cap, pr.piece_cap, len(pl.arrs),
+            len(pr.arrs), all_live, bool(slim))
         vcl = np.asarray(pl.lens, np.int32)
         vcr = np.asarray(pr.lens, np.int32)
         from ..exec.compiler import aot_compile
@@ -874,19 +888,19 @@ def _join_packed_impl(pl: PackedPiece, pr: PackedPiece, left_on, right_on,
     memory.touch(pl.reg)
     memory.touch(pr.reg)
     (kil, kir, need_nf, narrow, coalesce, plan, names, types, dicts,
-     bounds, carry_emit, carry_match, all_live) = _packed_statics(
+     bounds, layout, all_live) = _packed_statics(
         pl, pr, left_on, right_on, how, suffixes, coalesce_keys)
     cap_l, cap_r = pl.piece_cap, pr.piece_cap
     vcl = np.asarray(pl.lens, np.int32)
     vcr = np.asarray(pr.lens, np.int32)
 
-    defer = (how == "inner" and carry_emit and carry_match and coalesce
-             and allow_defer)
+    defer = bool(how == "inner" and layout.nl and layout.nr and coalesce
+                 and allow_defer)
     fn = _packed_count_fn(env.mesh, how, narrow, need_nf, pl.spec, pr.spec,
-                          kil, kir, cap_l, cap_r, len(pl.arrs),
-                          len(pr.arrs), all_live, carry_emit, carry_match,
-                          defer)
+                          layout, kil, kir, cap_l, cap_r, len(pl.arrs),
+                          len(pr.arrs), all_live, defer)
     args = (vcl, vcr, pl.starts, pr.starts) + pl.arrs + pr.arrs
+    _note_sort(layout)
 
     if defer:
         with timing.region("join.sort_count"):
@@ -910,7 +924,7 @@ def _join_packed_impl(pl: PackedPiece, pr: PackedPiece, left_on, right_on,
                 # fused consumer that drains the deferred state (TS108)
                 mfn = _packed_materialize_fn(
                     env.mesh, how, out_cap, cap_l, cap_r, plan, pl.spec,
-                    pr.spec, len(pl.arrs), len(pr.arrs), True, True,
+                    pr.spec, layout, len(pl.arrs), len(pr.arrs),
                     donate=(0,) if config.DONATE_BUFFERS else ())
                 out_d, out_v = mfn(carry, pl_s, pl.starts, pr.starts,
                                    *pl.arrs, *pr.arrs)
@@ -922,7 +936,8 @@ def _join_packed_impl(pl: PackedPiece, pr: PackedPiece, left_on, right_on,
         from .fused import JoinState
         state = JoinState(
             vcl=vcl, vcr=vcr, idx_s=idx_s_s, bnd=bnd_s, pl_s=pl_s,
-            lspec=pl.spec, rspec=pr.spec, plan=plan, names=names,
+            lspec=pl.spec, rspec=pr.spec, layout=layout, plan=plan,
+            names=names,
             types=types, dicts=dicts, key_names=tuple(left_on),
             cap_l=cap_l, cap_r=cap_r, all_live=all_live)
         out = DeferredTable(
@@ -946,8 +961,7 @@ def _join_packed_impl(pl: PackedPiece, pr: PackedPiece, left_on, right_on,
     def mat_fn(cap, donate=()):
         return _packed_materialize_fn(
             env.mesh, how, cap, cap_l, cap_r, plan, pl.spec, pr.spec,
-            len(pl.arrs), len(pr.arrs), carry_emit, carry_match,
-            donate=donate)
+            layout, len(pl.arrs), len(pr.arrs), donate=donate)
 
     # phase-1 state (carry + sorted payload lanes) dies with this piece:
     # its LAST materialize dispatch donates it so the output reuses the
@@ -1227,6 +1241,9 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
         side_list.append(col)
         return len(side_list) - 1
 
+    #: left key name -> its left lane column, where it is an output column
+    l_key_lane: dict = {}
+
     plan, names, types, dicts, bounds = [], [], [], [], []
 
     def merged_bounds(a: Column, b: Column):
@@ -1244,8 +1261,8 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
             # inner/left every output row has a live left key (and for right
             # a live right key) — one lane set instead of two
             if how in ("inner", "left"):
-                plan.append(("l", lane_col(l_cols_list, col),
-                             col.validity is not None))
+                l_key_lane[n] = lane_col(l_cols_list, col)
+                plan.append(("l", l_key_lane[n], col.validity is not None))
             elif how == "right":
                 plan.append(("r", lane_col(r_cols_list, rcol),
                              rcol.validity is not None))
@@ -1256,6 +1273,8 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
                              lane_col(r_cols_list, rcol), needs_valid))
         else:
             needs_valid = col.validity is not None or how in ("right", "outer")
+            if n in key_set_l:
+                l_key_lane[n] = len(l_cols_list)
             plan.append(("l", lane_col(l_cols_list, col), needs_valid))
             bounds.append(col.bounds)
             n = n + suffixes[0] if n in overlap else n
@@ -1280,10 +1299,11 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
 
     # ride a side's lane matrix through the phase-1 sort when every one of
     # its output columns is laneable (no f64 side channels) and the lane
-    # count is small — payload operands cost ~1.7 ns/row vs ~15 ns/row
-    # gathers.  carry_match (right side) kills the dependent idx_s[mpos] +
-    # right lane-matrix gathers; carry_emit (left side) folds the left
-    # values into the meta-stack gather join_take already performs.
+    # count is small — a sort operand costs 1.1-1.25 ns a row on v5e
+    # (ledger, PRs 29-34) vs ~15 ns/row gathers.  carry_match (right side)
+    # kills the dependent idx_s[mpos] + right lane-matrix gathers;
+    # carry_emit (left side) folds the left values into the meta-stack
+    # gather join_take already performs.
     def _can_carry(spec, col_list, budget: int) -> bool:
         # laneless f64 columns do not disqualify (carry-LITE: laneable
         # columns ride the sort, f64 columns keep their take-index
@@ -1309,6 +1329,13 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
                   *count_l_args, *count_r_args)
     cl_spec = lspec if carry_emit else None
     cr_spec = rspec if carry_match else None
+    # what rides the sort, said once (ops/join.PayloadLayout): the two
+    # sides share operands, a left key column is the sorted key itself
+    layout = joink.payload_layout(
+        cl_spec, cr_spec, tuple(l_key_lane.get(n) for n in left_on),
+        tuple(d.dtype for d in l_datas),
+        tuple((lv is not None) or (rv is not None)
+              for lv, rv in zip(l_valids, r_valids)), narrow, all_live)
 
     # ---- deferred materialization (reference ops-DAG slot, C9) -----------
     # Inner joins whose output columns fully ride the phase-1 sort can hand
@@ -1338,8 +1365,9 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
              and (skew_plan is not None or not skew_split))
     if defer:
         with timing.region("join.sort_count"):
-            res = _count_fn(env.mesh, how, narrow, cl_spec, cr_spec,
+            res = _count_fn(env.mesh, how, narrow, cl_spec, cr_spec, layout,
                             all_live, slim=True)(*count_args)
+        _note_sort(layout)
         counts_dev, idx_s_s, bnd_s = res[0], res[1], res[2]
         pl_s = tuple(res[3:])
         counts = host_array(counts_dev).astype(np.int64)
@@ -1354,8 +1382,7 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
                 carry = _carry_fn(env.mesh, how, lwork.capacity, all_live)(
                     vcl, vcr, idx_s_s, bnd_s)
                 fn = _materialize_fn(env.mesh, how, out_cap, lwork.capacity,
-                                     tuple(plan), lspec, rspec, carry_emit,
-                                     carry_match)
+                                     tuple(plan), lspec, rspec, layout)
                 out_d, out_v = fn(carry, pl_s, *l_gather_args,
                                   *r_gather_args)
             return {nme: Column(d, t, v, dc, bounds=b)
@@ -1417,7 +1444,7 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
             d_counts, d_cap = counts, out_cap
         state = JoinState(
             vcl=vcl, vcr=vcr, idx_s=idx_s_s, bnd=bnd_s, pl_s=pl_s,
-            lspec=lspec, rspec=rspec, plan=tuple(plan),
+            lspec=lspec, rspec=rspec, layout=layout, plan=tuple(plan),
             names=tuple(names), types=tuple(types), dicts=tuple(dicts),
             key_names=tuple(left_on),
             cap_l=lwork.capacity, cap_r=rwork.capacity, all_live=all_live,
@@ -1435,10 +1462,11 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
         return out
 
     with timing.region("join.sort_count"):
-        res = _count_fn(env.mesh, how, narrow, cl_spec, cr_spec,
+        res = _count_fn(env.mesh, how, narrow, cl_spec, cr_spec, layout,
                         all_live)(*count_args)
         counts_dev, carry = res[0], res[1:7]
         pl_s = tuple(res[7:])
+    _note_sort(layout)
 
     mat_args = (carry, pl_s, *l_gather_args, *r_gather_args)
 
@@ -1448,16 +1476,14 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
             # speculative dispatch at the predicted capacity BEFORE the
             # blocking count pull — the sync overlaps device work
             fn = _materialize_fn(env.mesh, how, predicted, lwork.capacity,
-                                 tuple(plan), lspec, rspec, carry_emit,
-                                 carry_match)
+                                 tuple(plan), lspec, rspec, layout)
             out_d, out_v = fn(*mat_args)
         counts = host_array(counts_dev).astype(np.int64)
         out_cap = config.pow2ceil(int(counts.max()) if counts.size else 1)
         _CAP_CACHE.put(cache_key, out_cap)
         if out_d is None or out_cap > predicted:
             fn = _materialize_fn(env.mesh, how, out_cap, lwork.capacity,
-                                 tuple(plan), lspec, rspec, carry_emit,
-                                 carry_match)
+                                 tuple(plan), lspec, rspec, layout)
             out_d, out_v = fn(*mat_args)
     out = build_table(names, out_d, out_v, types, dicts, counts, env,
                       bounds=bounds)
@@ -1539,7 +1565,7 @@ def _trace_semi_flag(mesh):
 
 def _trace_count(mesh):
     _w, _S, vc, keys, valids = _decl_args(mesh)
-    fn = _unwrap(_count_fn(mesh, "inner", (False,), None, None, False, False))
+    fn = _unwrap(_count_fn(mesh, "inner", (False,)))
     return jax.make_jaxpr(fn)(vc, vc, keys, valids, keys, valids,
                               (), (), (), ())
 
@@ -1563,9 +1589,11 @@ def _trace_packed_count(mesh):
     w, S, vc, _keys, _valids = _decl_args(mesh)
     spec = _packed_decl_spec()
     cap = 512
+    layout = joink.payload_layout(spec, spec, (0,), ("int32",), (False,),
+                                  (False,), False)
     fn = _unwrap(_packed_count_fn(mesh, "inner", (False,), (False,), spec,
-                                  spec, (0,), (0,), cap, cap, 1, 1, False,
-                                  True, True, False))
+                                  spec, layout, (0,), (0,), cap, cap, 1, 1,
+                                  False))
     st = S((w,), np.int32)
     mat = S((w * 1024, spec.n_lanes), np.uint32)
     return jax.make_jaxpr(fn)(vc, vc, st, st, mat, mat)
@@ -1577,8 +1605,8 @@ def _trace_packed_materialize(mesh):
     cap = 512
     plan = (("l", 0, False), ("l", 1, False), ("r", 1, False))
     fn = _unwrap(_packed_materialize_fn(mesh, "inner", 1024, cap, cap,
-                                        plan, spec, spec, 1, 1, False,
-                                        False))
+                                        plan, spec, spec,
+                                        joink.PayloadLayout(), 1, 1))
     carry = tuple(S((w * 2 * cap,), np.int32) for _ in range(6))
     st = S((w,), np.int32)
     mat = S((w * 1024, spec.n_lanes), np.uint32)

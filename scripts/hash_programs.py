@@ -139,7 +139,9 @@ def hash_all(log: list, tests_dir: str) -> dict:
     try:
         for world, mesh in meshes.items():
             rows, static = 1 << 14, tcc._fused_static(2)
-            fargs = tcc._fused_args(mesh, rows, 3)
+            # (a tree from before PR 35 counts lanes: three u32 arrays)
+            fargs = tcc._fused_args(mesh, rows,
+                                    static[2] if len(static) > 5 else 3)
             gargs = tcc._groupby_args(mesh, 17408)
             out[f"{world}dev fused 512 plain"] = text_hash(
                 fused._fused_fn(mesh, rows, False, *static, 512, 1), fargs)

@@ -100,7 +100,12 @@ def main():
 
     l_gather_args = (tuple(c.data for c in l_cols_list),
                      tuple(c.validity for c in l_cols_list))
-    fn1 = rj._count_fn(env.mesh, "inner", narrow, lspec, rspec,
+    from cylon_tpu.ops import join as joink
+    layout = joink.payload_layout(lspec, rspec, (0,),
+                                  tuple(str(d.dtype) for d in l_datas),
+                                  (False,), narrow, True)
+    print("sort operands:", layout.sort_operands)
+    fn1 = rj._count_fn(env.mesh, "inner", narrow, lspec, rspec, layout,
                        all_live=True)
     res = timed("join phase1 (sort+carry+count)", fn1, vcl, vcr, l_datas,
                 l_valids, r_datas, r_valids, *l_gather_args, *r_gather_args)
@@ -112,7 +117,7 @@ def main():
 
     plan = (("l", 0, False), ("l", 1, False), ("r", 0, False))
     fn2 = rj._materialize_fn(env.mesh, "inner", out_cap, lwork.capacity,
-                             plan, lspec, rspec, True, True)
+                             plan, lspec, rspec, layout)
     mat_args = (carry, pl_s, *l_gather_args, *r_gather_args)
     timed("join phase2 (materialize)", fn2, *mat_args)
 

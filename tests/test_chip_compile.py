@@ -174,10 +174,10 @@ def test_fused_join_groupby_compiles_for_v5e(mesh1, env1, monkeypatch):
     from cylon_tpu.exec import compiler
     fused_fn, resident, _, _ = _capture_main_path(env1, monkeypatch)
     static, args = resident[-1]
-    assert len(static) == 10                      # ..., seg_cap@7, ddof, w
-    seg_cap = static[7]
+    assert len(static) == 11                      # ..., seg_cap@8, ddof, w
+    seg_cap = static[8]
     assert seg_cap % 256 == 0 and seg_cap > 512, seg_cap
-    prog = fused_fn(mesh1, *static[:9], 1024)
+    prog = fused_fn(mesh1, *static[:10], 1024)
     # steer the kernel off interpret mode: the builder asks the backend
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled = compiler.aot_compile(prog, *_abstract(args, mesh1))
@@ -212,7 +212,10 @@ def test_packed_piece_join_compiles_for_v5e(mesh1, env1, monkeypatch):
 _ROWS4 = 17 << 19
 
 
-def _fused_args(mesh, n_side: int, n_lanes: int = 3):
+def _fused_args(mesh, n_side: int, layout):
+    """vcl, vcr, idx_s, bnd and ``pl_s`` as ``layout`` lays it out: the
+    kept sorted key (int32: a narrow key's operand), then the payload
+    operands the two sides share."""
     from cylon_tpu.ctx.context import ROW_AXIS
     w = int(mesh.devices.size)
     rep, row = NamedSharding(mesh, P()), NamedSharding(mesh, P(ROW_AXIS))
@@ -220,21 +223,33 @@ def _fused_args(mesh, n_side: int, n_lanes: int = 3):
     vc = S((w,), np.int32, sharding=rep)
     idx = S((w * 2 * n_side,), np.int32, sharding=row)
     lane = S((w * 2 * n_side,), np.uint32, sharding=row)
-    return vc, vc, idx, idx, (lane,) * n_lanes
+    return vc, vc, idx, idx, (idx,) * len(layout.kept_keys) \
+        + (lane,) * layout.n_payloads
 
 
-def _fused_static(n_sums: int = 2):
-    """Lane specs and aggregations of the benchmark's query, all int64
-    within int32 bounds: left (k, a), right (b); sum(a), sum(b) by k -
-    or, for four sums, left (k, a, c), right (b, d)."""
-    from cylon_tpu.ops import lanes
+def _join_specs(n_sums: int = 2):
+    """Lane specs and payload layout of the benchmark's join, all int64
+    within int32 bounds, tables under capacity: left (k, a), right (b) -
+    or, for four sums, left (k, a, c), right (b, d).  The key's lane is
+    the sorted key operand; the others share one operand a pair."""
+    from cylon_tpu.ops import join as joink, lanes
     nl = n_sums // 2
     lspec = lanes.plan_lanes(("int64",) * (1 + nl), (False,) * (1 + nl),
                              (True,) * (1 + nl))
     rspec = lanes.plan_lanes(("int64",) * nl, (False,) * nl, (True,) * nl)
+    layout = joink.payload_layout(lspec, rspec, (0,), ("int64",), (False,),
+                                  (True,), False)
+    assert layout.sort_operands == 3 + nl and layout.n_arrays == 1 + nl
+    return lspec, rspec, layout
+
+
+def _fused_static(n_sums: int = 2):
+    """:func:`_join_specs` and the aggregations of the benchmark's query:
+    sum(a), sum(b) by k (or the four sums)."""
+    nl = n_sums // 2
     vspecs = tuple(("l", 1 + i, "sum") for i in range(nl)) \
         + tuple(("r", i, "sum") for i in range(nl))
-    return (lspec, rspec, vspecs, (0,), (True,))
+    return _join_specs(n_sums) + (vspecs, (0,), (True,))
 
 
 @pytest.mark.parametrize("n_sums", [2, 4])
@@ -244,10 +259,10 @@ def test_first_sight_compiles_for_four_chips(mesh4, n_sums):
     meets this program first."""
     from cylon_tpu.exec import compiler
     from cylon_tpu.relational import fused
-    prog = fused._fused_fn(mesh4, _ROWS4, False, *_fused_static(n_sums),
-                           512, 1)
+    static = _fused_static(n_sums)
+    prog = fused._fused_fn(mesh4, _ROWS4, False, *static, 512, 1)
     compiled = compiler.aot_compile(
-        prog, *_fused_args(mesh4, _ROWS4, 1 + n_sums))
+        prog, *_fused_args(mesh4, _ROWS4, static[2]))
     assert not _has_kernel(compiled)
 
 
@@ -260,11 +275,41 @@ def test_fused_compiles_for_four_chips(mesh4, monkeypatch, window, n_sums):
     from cylon_tpu.exec import compiler
     from cylon_tpu.relational import fused
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    prog = fused._fused_fn(mesh4, _ROWS4, False, *_fused_static(n_sums),
-                           3407872, 1, window)
+    static = _fused_static(n_sums)
+    prog = fused._fused_fn(mesh4, _ROWS4, False, *static, 3407872, 1, window)
     compiled = compiler.aot_compile(
-        prog, *_fused_args(mesh4, _ROWS4, 1 + n_sums))
+        prog, *_fused_args(mesh4, _ROWS4, static[2]))
     assert _has_kernel(compiled) == bool(window)
+
+
+@pytest.mark.parametrize("world,cap", [(1, 1 << 16), (4, _ROWS4)])
+def test_join_count_compiles_with_one_sort_of_four(topo, world, cap):
+    """``join__count_fn`` (slim: the deferred join's) in the shared-operand
+    layout (ISSUE 35) at the benchmark's schema, on one described chip at
+    the rehearsal's rows and on four at the cell's 8,912,896 a side: the
+    optimised text holds ONE sort, of the layout's 4 operands - liveness,
+    key, ``idx``, the operand ``a`` and ``b`` share - so the stable sort's
+    expansion added no tie-break ``iota`` of its own (``idx`` is one)."""
+    import re
+    from cylon_tpu.ctx.context import ROW_AXIS
+    from cylon_tpu.exec import compiler
+    from cylon_tpu.relational import join
+    mesh = Mesh(np.array(topo.devices[:world]), (ROW_AXIS,))
+    rep, row = NamedSharding(mesh, P()), NamedSharding(mesh, P(ROW_AXIS))
+    S = jax.ShapeDtypeStruct
+    lspec, rspec, layout = _join_specs(2)
+    vc = S((world,), np.int32, sharding=rep)
+    col = S((world * cap,), np.int64, sharding=row)
+    prog = join._count_fn(mesh, "inner", (True,), lspec, rspec, layout,
+                          False, True)
+    text = compiler.aot_compile(
+        prog, vc, vc, (col,), (None,), (col,), (None,), (col, col),
+        (None, None), (col,), (None,)).as_text()
+    sorts = re.findall(r"^.* = (.*?) sort\(", text, re.M)
+    assert len(sorts) == 1, sorts
+    results = re.findall(r"[su]32\[\d+\]", sorts[0])
+    assert results == ["s32[%d]" % (2 * cap)] * 3 + ["u32[%d]" % (2 * cap)]
+    assert len(results) == layout.sort_operands == 4
 
 
 def _hlo_ops(text: str, opcode: str) -> int:

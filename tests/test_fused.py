@@ -294,7 +294,7 @@ def test_first_sight_counts_what_the_settled_program_counts(world, request):
 
         def call(*args):
             out = prog(*args)
-            seen.append((static[7], np.asarray(out[4]).reshape(-1, 2)[:, 0]))
+            seen.append((static[8], np.asarray(out[4]).reshape(-1, 2)[:, 0]))
             return out
         return call
 
@@ -337,13 +337,19 @@ def test_fused_program_keeps_wide_scans_from_the_rewriter(world, request):
     lspec = lanes.plan_lanes(("int64", "int64"), (False, False),
                              (True, False))
     rspec = lanes.plan_lanes(("int64",), (False,), (False,))
-    fn = fused._fused_fn(env.mesh, cap, False, lspec, rspec,
+    from cylon_tpu.ops import join as joink
+    # key k narrow -> the sorted key operand; a's (hi, lo) share two
+    # operands with b's
+    layout = joink.payload_layout(lspec, rspec, (0,), ("int64",), (False,),
+                                  (True,), False)
+    assert layout.n_arrays == 3 and layout.sort_operands == 5
+    fn = fused._fused_fn(env.mesh, cap, False, lspec, rspec, layout,
                          (("l", 1, "sum"), ("r", 0, "sum"), ("l", 1, "mean")),
                          (0,), (True,), 512, 1)
     S = jax.ShapeDtypeStruct
     vc = S((w,), np.int64)
     row = S((w * 2 * cap,), np.int32)
-    pl = tuple(S((w * 2 * cap,), np.uint32) for _ in range(5))
+    pl = (row,) + tuple(S((w * 2 * cap,), np.uint32) for _ in range(2))
     traced = jax.make_jaxpr(registry.unwrap(fn))(vc, vc, row, row, pl)
     scans = _cumsums(traced)
     long_ = sorted(dt for dt, n in scans if n > 128)

@@ -170,7 +170,7 @@ def test_site_recovers_from_a_forced_mispredict(site, world, request, rng,
     run, ref, mod, builder = _site_query(site, env, rng)
     segs = []
     real = getattr(mod, builder)
-    seg_at = 7 if site == "fused" else 1      # seg_cap among the statics
+    seg_at = 8 if site == "fused" else 1      # seg_cap among the statics
 
     def spy(mesh, *static, **kw):
         segs.append(static[seg_at])

@@ -592,3 +592,317 @@ def test_join_carry_equals_gather_formulation(how, seed):
     assert int(total) == ref_total
     for name, got, exp in zip(joink.JoinCarry._fields, carry, ref):
         np.testing.assert_array_equal(np.asarray(got), exp, err_msg=name)
+
+
+# ---- the payload layout of the join's one sort (ISSUE 35) ------------------
+# ops/join.PayloadLayout: the two sides' lanes share sort operands, and a
+# left key column's lanes are the sorted key operands themselves.  Every
+# case runs the real programs against pandas; ``_old_layout_build`` is the
+# layout until PR 34, kept here as the reference the new one has to equal
+# bit for bit: one operand a lane a side, left lanes then right lanes.
+
+def _spy_builder(monkeypatch, module, name, log):
+    """Record (static args, call args, outputs) of every program a cached
+    builder hands out."""
+    orig = getattr(module, name)
+
+    def builder(mesh, *static, **kw):
+        fn = orig(mesh, *static, **kw)
+
+        def call(*args):
+            out = fn(*args)
+            log.append((static, kw, args, out))
+            return out
+        return call
+    monkeypatch.setattr(module, name, builder)
+    return orig
+
+
+def _old_layout_build(mesh, how, narrow, lspec, rspec, all_live, l_f64,
+                      r_f64, out_cap, plan, count_args, mat_cols):
+    """(idx_s, bnd, left lanes, right lanes, out_d, out_v) per shard, with
+    every lane of either side an operand of its own, zeros over the other
+    side's rows - ``_count_fn`` + ``_materialize_fn`` as PR 34 had them."""
+    import jax
+    import jax.numpy as jnp
+    from cylon_tpu.ops import join as joink, lanes
+    from cylon_tpu.relational import join as rj
+    from cylon_tpu.relational.common import REP, ROW
+
+    def per_shard(vcl, vcr, l_datas, l_valids, r_datas, r_valids, lg_cols,
+                  lg_valids, rg_cols, rg_valids, l_cols, r_cols):
+        cap_l, cap_r = l_datas[0].shape[0], r_datas[0].shape[0]
+        payloads = ()
+        if lspec is not None:
+            lmat = lanes.pack_lanes(lspec, lg_cols, lg_valids)
+            zr = jnp.zeros(cap_r, jnp.uint32)
+            payloads += tuple(jnp.concatenate([lmat[:, j], zr])
+                              for j in range(lspec.n_lanes))
+        if rspec is not None:
+            rmat = lanes.pack_lanes(rspec, rg_cols, rg_valids)
+            zl = jnp.zeros(cap_l, jnp.uint32)
+            payloads += tuple(jnp.concatenate([zl, rmat[:, j]])
+                              for j in range(rspec.n_lanes))
+        bnd, idx_s, n_live, pl_s = rj._sorted_state(
+            vcl, vcr, l_datas, l_valids, r_datas, r_valids, narrow,
+            payloads, all_live)
+        n_e = lspec.n_lanes if lspec is not None else 0
+        pl_e, pl_m = pl_s[:n_e], pl_s[n_e:]
+        _, carry = joink.join_carry(bnd, idx_s, n_live, cap_l, how)
+        tk = joink.join_take(carry, cap_l, how, out_cap, extra=pl_e,
+                             carry_emit=True, carry_match=True,
+                             emit_idx=l_f64, match_idx=r_f64)
+        ldat, lval = lanes.unpack_lanes(lspec, jnp.stack(tk.extra, axis=1))
+        ldat = list(ldat)
+        for i, d in lanes.gather_laneless(lspec, l_cols, tk.l_take).items():
+            ldat[i] = d
+        rrows = jnp.stack(pl_m, axis=1)[tk.mpos]
+        rdat, rval = lanes.unpack_lanes(rspec, rrows)
+        rdat = list(rdat)
+        for i, d in lanes.gather_laneless(rspec, r_cols, tk.r_take).items():
+            rdat[i] = d
+        out_d, out_v = rj._plan_outputs(plan, ldat, lval, tk.valid, rdat,
+                                        rval, tk.matched)
+        return idx_s, bnd, pl_e, pl_m, out_d, out_v
+
+    fn = jax.jit(jax.shard_map(per_shard, mesh=mesh,
+                               in_specs=(REP, REP) + (ROW,) * 10,
+                               out_specs=ROW))
+    return fn(*count_args, *mat_cols)
+
+
+_KEYS = ("narrow", "wide", "nullable", "string")
+#: left lanes rule 2 reads back from the sorted key operands, by key kind
+_ALIASED = {"narrow": 1, "wide": 2, "nullable": 0, "string": 1}
+_LANES = ("fewer", "equal", "more")          # left lanes against right
+
+
+def _layout_frames(rng, key, lanes_, f64, n_l, n_r):
+    """Left (k, optional a*, f) and right (k, b*, optional g) frames: int32
+    payload columns counted so that the left side rides fewer, as many, or
+    more lanes than the right (key lanes and the validity lane included)."""
+    key_lanes = {"narrow": 1, "wide": 2, "nullable": 2, "string": 1}[key]
+    n_a, n_b = {"fewer": (0, key_lanes + 2),
+                "equal": (1, key_lanes + 1), "more": (3, 1)}[lanes_]
+
+    def keys(n):
+        if key == "string":
+            pool = np.asarray(["ant", "bee", "cat", "dog", "elk", "fox",
+                               "gnu"], object)
+            return pool[rng.integers(0, len(pool), n)]
+        hi = (1 << 40) if key == "wide" else 12
+        ks = rng.integers(0, 12, n).astype(np.int64) * (hi // 12)
+        if key == "nullable":
+            return pd.array([None if rng.random() < 0.2 else int(k)
+                             for k in ks], dtype="Int64")
+        return ks
+
+    def frame(n, names, fname):
+        d = {"k": keys(n)}
+        for c in names:
+            d[c] = rng.integers(-99, 99, n).astype(np.int32)
+        if fname:
+            d[fname] = rng.random(n)
+        return pd.DataFrame(d)
+
+    return (frame(n_l, [f"a{i}" for i in range(n_a)],
+                  "f" if f64 == "left" else None),
+            frame(n_r, [f"b{i}" for i in range(n_b)],
+                  "g" if f64 == "right" else None))
+
+
+def _layout_cases():
+    """how x key x lane counts in full; the f64 side, all_live and the
+    world rotate over them so that every value meets every other."""
+    import itertools
+    worlds = (("env1", True), ("env1", False), ("env8", False))
+    cases = []
+    for i, (how, key, lanes_) in enumerate(itertools.product(
+            ("inner", "left"), _KEYS, _LANES)):
+        f64 = ("none", "left", "right")[(i + i // 3) % 3]
+        envname, full = worlds[(i + i // 6) % 3]
+        cases.append(pytest.param(
+            how, key, lanes_, f64, envname, full,
+            id=f"{how}-{key}-{lanes_}-f64_{f64}-{envname}-"
+               f"{'full' if full else 'ragged'}"))
+    return cases
+
+
+def _masked(d, v, slot_ok):
+    """A result column as compared: zeros where no row or a null is."""
+    ok = slot_ok if v is None else slot_ok & np.asarray(v)
+    return np.where(ok, np.asarray(d), 0), ok
+
+
+@pytest.mark.parametrize("how,key,lanes_,f64,envname,full", _layout_cases())
+def test_payload_layout(request, rng, monkeypatch, how, key, lanes_, f64,
+                        envname, full):
+    """The join through the shared-operand layout: equal to pandas, and
+    ``idx_s``, ``bnd``, every lane at its own side's rows and every result
+    column bit-equal to the old layout's build."""
+    import jax.numpy as jnp
+    from cylon_tpu.relational import join as rj
+    from cylon_tpu.ops import join as joink
+    env = request.getfixturevalue(envname)
+    # at world 1 a table of a power of two rows is at capacity (all_live)
+    n_l, n_r = (64, 32) if full else (61, 37)
+    ldf, rdf = _layout_frames(rng, key, lanes_, f64, n_l, n_r)
+    lt = ct.Table.from_pandas(ldf, env)
+    rt = ct.Table.from_pandas(rdf, env)
+    counts, mats = [], []
+    real_count = _spy_builder(monkeypatch, rj, "_count_fn", counts)
+    _spy_builder(monkeypatch, rj, "_materialize_fn", mats)
+    got = join_tables(lt, rt, "k", "k", how=how)
+    exp = ldf.merge(rdf, on="k", how=how)
+    assert_table_matches(got, exp, sort_by=list(exp.columns))
+    monkeypatch.undo()
+
+    (cstatic, ckw, cargs, cres), = counts
+    _how, narrow, lspec, rspec, layout, all_live = cstatic[:6]
+    assert all_live == full and lspec is not None and rspec is not None
+    nl, nr = lspec.n_lanes, rspec.n_lanes
+    assert {"fewer": nl < nr, "equal": nl == nr, "more": nl > nr}[lanes_]
+    # rule 2 by key kind; rule 1: the sides share what is left
+    aliased = _ALIASED[key]
+    assert nl - len(layout.riding) == aliased
+    assert layout.n_payloads == max(nl - aliased, nr)
+    assert layout.sort_operands == layout.n_keys + 1 + layout.n_payloads
+    assert layout.n_keys == (not full) + (key == "nullable") \
+        + (2 if key == "wide" else 1)
+
+    # the state the real count program builds, against the old layout's
+    res = real_count(env.mesh, *cstatic[:7], slim=True)(*cargs)
+    n_rows, idx_s, bnd, pl_s = res[0], res[1], res[2], tuple(res[3:])
+    assert len(pl_s) == layout.n_arrays
+    (mstatic, _mkw, margs, (new_d, new_v)), = mats[-1:]
+    out_cap, cap_l, plan = mstatic[1], mstatic[2], mstatic[3]
+    l_f64 = any(not c.lanes for c in lspec.cols)
+    r_f64 = any(not c.lanes for c in rspec.cols)
+    assert (l_f64, r_f64) == (f64 == "left", f64 == "right")
+    o_idx, o_bnd, o_le, o_ri, old_d, old_v = _old_layout_build(
+        env.mesh, how, narrow, lspec, rspec, all_live, l_f64, r_f64,
+        out_cap, plan, cargs, (margs[2], margs[4]))
+    np.testing.assert_array_equal(np.asarray(idx_s), np.asarray(o_idx))
+    np.testing.assert_array_equal(np.asarray(bnd), np.asarray(o_bnd))
+    le, ri = joink.payload_lanes(layout, pl_s)
+    assert (len(le), len(ri)) == (nl, nr)
+    left_row = np.asarray(idx_s) < cap_l
+    for new, old in zip(le, o_le):
+        assert new.dtype == jnp.uint32
+        np.testing.assert_array_equal(np.asarray(new)[left_row],
+                                      np.asarray(old)[left_row])
+    for new, old in zip(ri, o_ri):
+        np.testing.assert_array_equal(np.asarray(new)[~left_row],
+                                      np.asarray(old)[~left_row])
+    slot = np.arange(env.world_size * out_cap)
+    slot_ok = slot % out_cap < np.asarray(n_rows)[slot // out_cap]
+    assert len(new_d) == len(old_d) == len(plan)
+    for nd, nv, od, ov in zip(new_d, new_v, old_d, old_v):
+        assert (nv is None) == (ov is None)
+        a, a_ok = _masked(nd, nv, slot_ok)
+        b, b_ok = _masked(od, ov, slot_ok)
+        np.testing.assert_array_equal(a_ok, b_ok)
+        np.testing.assert_array_equal(a, b)
+
+
+def _key_frames(rng, key, n_l=300, n_r=200):
+    ldf, rdf = _layout_frames(rng, key, "equal", "none", n_l, n_r)
+    return ldf.rename(columns={"a0": "a"}), rdf
+
+
+@pytest.mark.parametrize("key", _KEYS)
+@pytest.mark.parametrize("how", ["inner", "left"])
+def test_payload_layout_packed_piece(env1, rng, monkeypatch, how, key):
+    """The packed-piece producer (the range pipeline's join entry) through
+    the same layout: pandas' rows, and per piece ``idx_s``, ``bnd`` and the
+    lanes equal to the old layout's sort of the same windows."""
+    import jax
+    import jax.numpy as jnp
+    from cylon_tpu.exec import pipelined_join
+    from cylon_tpu.ops import join as joink, pack
+    from cylon_tpu.relational import join as rj
+    from cylon_tpu.relational.common import (PAD_L, PAD_R, REP, ROW,
+                                             live_mask)
+    ldf, rdf = _key_frames(rng, key)
+    lt = ct.Table.from_pandas(ldf, env1)
+    rt = ct.Table.from_pandas(rdf, env1)
+    pieces = []
+    real = _spy_builder(monkeypatch, rj, "_packed_count_fn", pieces)
+    got = pipelined_join(lt, rt, "k", "k", how=how, n_chunks=2)
+    exp = ldf.merge(rdf, on="k", how=how)
+    assert_table_matches(got, exp, sort_by=list(exp.columns))
+    monkeypatch.undo()
+    assert pieces
+    for static, _kw, args, _out in pieces:
+        (_how, narrow, need_nf, lspec, rspec, layout, kil, kir, cap_l,
+         cap_r, n_al, n_ar, all_live) = static[:13]
+        aliased = _ALIASED[key]
+        assert layout.nl - len(layout.riding) == aliased
+        assert layout.n_payloads == max(layout.nl - aliased, layout.nr)
+        res = real(env1.mesh, *static[:13], True)(*args)
+        idx_s, bnd, pl_s = res[1], res[2], tuple(res[3:])
+
+        def old(vcl, vcr, sl, sr, *arrs):
+            mat_l, f64_l = rj._window(lspec, arrs[:n_al], sl[0], cap_l)
+            mat_r, f64_r = rj._window(rspec, arrs[n_al:], sr[0], cap_r)
+            ko = [pack.key_operands(
+                *rj._window_keys(spec, mat, f64, ki),
+                row_mask=None if all_live else live_mask(vc, cap),
+                pad_key=pad, need_null_flags=need_nf, narrow32=narrow)
+                for spec, mat, f64, ki, vc, cap, pad in (
+                    (lspec, mat_l, f64_l, kil, vcl, cap_l, PAD_L),
+                    (rspec, mat_r, f64_r, kir, vcr, cap_r, PAD_R))]
+            pay = tuple(jnp.concatenate(
+                [mat_l[:, j], jnp.zeros(cap_r, jnp.uint32)])
+                for j in range(lspec.n_lanes)) + tuple(jnp.concatenate(
+                    [jnp.zeros(cap_l, jnp.uint32), mat_r[:, j]])
+                    for j in range(rspec.n_lanes))
+            return joink.join_sort_state(*ko, pay)
+
+        o_bnd, o_idx, o_pl = jax.jit(jax.shard_map(
+            old, mesh=env1.mesh, in_specs=(REP,) * 4 + (ROW,) * (n_al + n_ar),
+            out_specs=ROW))(*args)
+        np.testing.assert_array_equal(np.asarray(idx_s), np.asarray(o_idx))
+        np.testing.assert_array_equal(np.asarray(bnd), np.asarray(o_bnd))
+        le, ri = joink.payload_lanes(layout, pl_s)
+        left_row = np.asarray(idx_s) < cap_l
+        assert len(le + ri) == len(o_pl)
+        for i, (new, old_lane) in enumerate(zip(le + ri, o_pl)):
+            rows = left_row if i < lspec.n_lanes else ~left_row
+            np.testing.assert_array_equal(np.asarray(new)[rows],
+                                          np.asarray(old_lane)[rows])
+
+
+@pytest.mark.parametrize("key,operands,num_keys,was", [
+    ("narrow", 4, 2, 6), ("wide", 5, 3, 8)])
+def test_count_program_holds_one_sort_of_the_layouts_operands(
+        env1, rng, monkeypatch, key, operands, num_keys, was):
+    """The benchmark cells' schema (left k, a; right k, b; int64; tables
+    not at capacity; inner join on k, deferred): ``join__count_fn`` holds
+    ONE stable sort of 4 operands (6 until PR 34), and with a key that
+    does not narrow 5 (was 8)."""
+    import jax
+    from cylon_tpu.analysis import registry
+    from cylon_tpu.analysis.jaxpr_check import iter_eqns
+    from cylon_tpu.relational import join as rj
+    hi = (1 << 40) if key == "wide" else 900
+    mk = lambda top: rng.integers(0, top, 1000).astype(np.int64)  # noqa: E731
+    lt = ct.Table.from_pydict({"k": mk(hi), "a": mk(900)}, env1)
+    rt = ct.Table.from_pydict({"k": mk(hi), "b": mk(900)}, env1)
+    assert (lt.valid_counts < lt.capacity).all()
+    counts = []
+    real = _spy_builder(monkeypatch, rj, "_count_fn", counts)
+    join_tables(lt, rt, "k", "k", how="inner")
+    monkeypatch.undo()
+    (static, kw, args, _res), = counts
+    layout = static[4]
+    assert kw == {"slim": True} and layout.sort_operands == operands
+    # the old layout's count: every lane of either side, and the key again
+    assert layout.n_keys + 1 + layout.nl + layout.nr == was
+    traced = jax.make_jaxpr(registry.unwrap(real(env1.mesh, *static, **kw)))(
+        *args)
+    sorts = [e for e, _ in iter_eqns(traced) if e.primitive.name == "sort"]
+    assert len(sorts) == 1
+    assert len(sorts[0].invars) == operands
+    assert sorts[0].params["num_keys"] == num_keys
+    assert sorts[0].params["is_stable"]

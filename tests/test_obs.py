@@ -662,6 +662,88 @@ def test_exchange_spans_carry_rows_and_bytes(env4):
         for r in rounds)
 
 
+@pytest.mark.parametrize("how,fused", [("inner", True), ("left", False)])
+def test_join_sort_counters_and_plan_fields(env1, how, fused):
+    """The join's one sort, as the registry and the plan node say it
+    (ISSUE 35): ``join_sort_operands`` / ``join_sort_dispatches`` take one
+    count program's static numbers a join, on the host; the ``join`` node
+    shows ``sort_operands``, ``payload_operands``, ``aliased_key_lanes``.
+    ``_toy``'s schema is the benchmark cells': liveness + narrow key + idx
+    + one operand shared by ``a`` and ``b``, the key's lane aliased."""
+    from cylon_tpu.relational import groupby_aggregate, join_tables
+    assert {"join_sort_operands", "join_sort_dispatches"} \
+        <= set(metrics.snapshot())           # registered at import
+    left, right = _toy(env1, n=4000)         # under capacity, as the cells
+    ops, joins = (obs.counter(c) for c in (
+        "join_sort_operands", "join_sort_dispatches"))
+    before = ops.value, joins.value
+
+    def query():
+        j = join_tables(left, right, "k", "k", how=how)
+        if fused:
+            return groupby_aggregate(j, "k", [("a", "sum"), ("b", "sum")])
+        return j.to_pandas()
+
+    plan = obs.explain_analyze(query)
+    assert (ops.value - before[0], joins.value - before[1]) == (4, 1)
+    node, = [n for n in plan.to_dict()["roots"] if n["op"] == "join"]
+    assert {k: node["attrs"][k] for k in (
+        "sort_operands", "payload_operands", "aliased_key_lanes")} \
+        == {"sort_operands": 4, "payload_operands": 1,
+            "aliased_key_lanes": 1}
+
+
+def test_benchmark_reads_join_sort_operands_per_join(tmp_path, monkeypatch,
+                                                     capfd):
+    """The yardstick's side of the two counters: ``benchmark/metrics/
+    join_sort_operands_per_join.json`` through ``run.py``'s own ``main`` on
+    the join cell's 65,536-row twin (the benchmark's test helpers; the CPU
+    has no device plane, so the trace reduction is stood in for as in
+    ``benchmark/tests/test_zipf.py``).  The twin's tables are AT capacity
+    (65,536 rows), so its sort has no liveness operand: 3.0, where the
+    cells' 32,000,000 rows padded to 32,505,856 read 4.0."""
+    import importlib.util
+    bench_tests = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "tests")
+    spec = importlib.util.spec_from_file_location(
+        "_bench_test_helpers", os.path.join(bench_tests, "helpers.py"))
+    helpers = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(helpers)
+    monkeypatch.setattr(sys, "path", list(sys.path))
+    bench_dir = helpers.copy_with_tiny_cells(tmp_path)
+    name = "join_sort_operands_per_join"
+    with open(os.path.join(bench_dir, "metrics", name + ".json")) as f:
+        m = json.load(f)
+    assert m["args"] == {"counter": "^join_sort_operands$",
+                         "per": "^join_sort_dispatches$"}
+    with open(os.path.join(bench_dir, "metrics", "tiny_" + name + ".json"),
+              "w") as f:
+        json.dump(dict(m, name="tiny_" + name,
+                       workloads=["tiny_join_groupby_32m"]), f)
+    run = helpers.load_run(bench_dir)
+    helpers.steer_to_cpu(run, monkeypatch)
+    monkeypatch.setattr(run, "_traced_queries", lambda one, n, spans, d: (
+        [one() for _ in range(n)],
+        {"n_queries": n, "n_chips": 1, "busy_s": 0.9, "window_s": 1.0,
+         "idle_share": 0.1, "op_seconds": [], "gap_seconds": []})[1])
+    before = tuple(obs.counter(c).value for c in (
+        "join_sort_operands", "join_sort_dispatches"))
+    capfd.readouterr()
+    rc = run.main(["--workload", "tiny_join_groupby_32m", "--seed",
+                   str(2**31 + 35), "--seconds", "0.5", "--trace", "1"])
+    out = capfd.readouterr()
+    assert rc == 0, out.err[-3000:]
+    line = helpers.last_json_line(out.out)
+    assert line["correct"] is True, line["compared"]
+    ops, joins = (obs.counter(c).value - b for c, b in zip(
+        ("join_sort_operands", "join_sort_dispatches"), before))
+    assert joins > 0 and ops == 3 * joins
+    # the reader sums the whole process's registry: other tests' joins too
+    assert line["metrics"]["tiny_" + name]["unit"] == "count"
+    if before == (0, 0):
+        assert line["metrics"]["tiny_" + name]["value"] == 3.0
+
+
 def test_compile_seconds_by_builder(env1):
     """A forced compile (a row count no other test uses) is attributed to
     the builder whose program was being launched, in

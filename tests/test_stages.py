@@ -193,6 +193,37 @@ def test_join_groupby_route_names(env1, launched):
         == {"segment_gather"}
 
 
+def test_payload_layout_helpers_are_staged(env1, launched):
+    """ops/join's layout helpers at the cells' schema (ISSUE 35): the
+    payload operand the two sides share is built under ``pack``, the lanes
+    come back out of the sorted arrays under ``unpack`` (the aliased key's
+    bitcast among them), and no row-scale equation of either program is
+    left without a stage."""
+    from cylon_tpu.relational import groupby_aggregate, join_tables
+    left, right = _tables(env1, n=4000)          # under capacity
+    groupby_aggregate(join_tables(left, right, "k", "k", how="inner"),
+                      "k", [("a", "sum"), ("b", "sum")])
+    traced = {}
+    for builder in ("join__count_fn", "fused__fused_fn"):
+        prog, args, kwargs = launched[builder]
+        traced[builder] = jax.make_jaxpr(prog._fn)(*args, **kwargs)
+        assert _unstaged(traced[builder]) == []
+    n = left.capacity + right.capacity
+    sort, = [e for e, _ in _staged_eqns(traced["join__count_fn"])
+             if e.primitive.name == "sort"]
+    assert len(sort.invars) == 4
+    shared = [st for e, st in _staged_eqns(traced["join__count_fn"])
+              if e.primitive.name == "concatenate"
+              and e.outvars[0] is sort.invars[3]]
+    assert len(shared) == 1 and shared[0][-1] == "pack"
+    casts = [st for e, st in _staged_eqns(traced["fused__fused_fn"])
+             if e.primitive.name == "bitcast_convert_type"
+             and e.invars[0].aval.shape == (n,)
+             and str(e.invars[0].aval.dtype) == "int32"
+             and str(e.outvars[0].aval.dtype) == "uint32"]
+    assert casts and all(st[-1] == "unpack" for st in casts)
+
+
 def test_groupby_sort_route_names(env1, launched):
     from cylon_tpu.relational import groupby_aggregate, sort_table
     left, _ = _tables(env1)
@@ -287,17 +318,19 @@ def _join_programs(mesh, cap):
     spec = lanes.plan_lanes(("int32", "int32"), (False, False))
     mat = S((w * 2 * cap, spec.n_lanes), np.uint32)
 
+    from cylon_tpu.ops import join as joink
+    layout = joink.payload_layout(spec, spec, (0,), ("int32",), (False,),
+                                  (False,), False)
+
     def packed(slim):
         return rj._packed_count_fn(mesh, "left", (False,), (False,), spec,
-                                   spec, (0,), (0,), cap, cap, 1, 1, False,
-                                   True, True, slim)
+                                   spec, layout, (0,), (0,), cap, cap, 1, 1,
+                                   False, slim)
 
     return {
-        "count_full": (rj._count_fn(mesh, "outer", (False,), None, None,
-                                    False, False),
+        "count_full": (rj._count_fn(mesh, "outer", (False,)),
                        table_args + ((), (), (), ())),
-        "count_slim": (rj._count_fn(mesh, "inner", (False,), None, None,
-                                    False, True),
+        "count_slim": (rj._count_fn(mesh, "inner", (False,), slim=True),
                        table_args + ((), (), (), ())),
         "carry": (rj._carry_fn(mesh, "right", cap, False),
                   (vc, vc, cat, cat)),
