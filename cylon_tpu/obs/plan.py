@@ -7,7 +7,11 @@ route it chose (broadcast vs hash vs skew-split vs pipelined), chunk
 counts, piece caps, spill/donation flags.  With no profile active the
 whole facade is one thread-local load per operator call: no node, no
 allocation, no timing, no device work (the PR 10 overhead contract,
-asserted in tests/test_explain.py).
+asserted in tests/test_explain.py) — beside the operator call's own
+boundary span ``cylon.op.<op>`` (``utils/timing.span``: one
+``TraceAnnotation``, no clock read with nothing armed; tests/test_obs.py),
+which is how a trace or the flight recorder's ring knows where an
+operator call begins and ends.
 
 :func:`explain` runs a query and returns the static tree;
 :func:`explain_analyze` additionally attaches measurements per node:
@@ -45,6 +49,8 @@ from __future__ import annotations
 
 import contextlib
 import threading
+
+from ..utils import timing as _timing
 
 __all__ = ["PlanNode", "QueryPlan", "node", "annotate", "active",
            "current", "explain", "explain_analyze", "record_exchange",
@@ -276,42 +282,53 @@ def _event_counters() -> tuple:
 
 
 class _NodeCtx:
-    """The per-operator context manager: cheap no-op when no profile is
-    active; otherwise push + (analyze mode) a node-scoped attribution
-    scope whose table becomes the node's self-time phase breakdown."""
+    """The per-operator context manager.  Always: the operator call is a
+    ``cylon.op.<op>`` boundary span of the program's own
+    (``utils/timing.span``: one ``TraceAnnotation`` with nothing armed, no
+    clock read), nested operators open their own and a reader takes the
+    outermost; it closes whatever the operator raised.  With a profile
+    active besides: push + (analyze mode) a node-scoped attribution scope
+    whose table becomes the node's self-time phase breakdown."""
 
-    __slots__ = ("_op", "_attrs", "_node", "_prof")
+    __slots__ = ("_op", "_attrs", "_node", "_prof", "_span")
 
     def __init__(self, op: str, attrs: dict):
         self._op = op
         self._attrs = attrs
         self._node = None
         self._prof = None
+        self._span = None
 
     def __enter__(self):
+        self._span = _timing.span("op." + self._op)
+        self._span.__enter__()
         prof = getattr(_TLS, "profile", None)
         if prof is None:
             return _NOOP
         self._prof = prof
         n = self._node = push_node(self._op, self._attrs, prof)
         if prof.mode == "analyze":
-            from ..utils import timing
-            n._scope_cm = timing.attribution_scope(f"plan:{self._op}")
+            n._scope_cm = _timing.attribution_scope(f"plan:{self._op}")
             n._scope = n._scope_cm.__enter__()
             n._ev0 = _event_counters()
         return n
 
     def __exit__(self, exc_type, exc, tb):
+        try:
+            if self._node is not None:
+                self._close_node(exc_type, exc, tb)
+        finally:
+            self._span.__exit__(exc_type, exc, tb)
+        return False
+
+    def _close_node(self, exc_type, exc, tb) -> None:
         n = self._node
-        if n is None:
-            return False
         if n._scope_cm is not None:
             n._scope_cm.__exit__(exc_type, exc, tb)
             sc, n._scope, n._scope_cm = n._scope, None, None
-            from ..utils import timing
             n.phases = sc.snapshot()
             n.seconds = sc.total_seconds()
-            dispatch, block = timing.split_snapshot(n.phases)
+            dispatch, block = _timing.split_snapshot(n.phases)
             n.dispatch_s = sum(dispatch.values())
             n.block_s = sum(block.values())
             ev1 = _event_counters()
@@ -325,7 +342,6 @@ class _NodeCtx:
             if outer is not None:
                 outer.absorb(sc)
         pop_node(n)
-        return False
 
 
 def node(op: str, **attrs) -> _NodeCtx:

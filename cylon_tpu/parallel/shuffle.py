@@ -445,173 +445,179 @@ def exchange(mesh: Mesh, tgt, counts: np.ndarray, cols: tuple,
     exchange alongside the source table — the W·block bound applies to the
     per-round send/recv buffers).
     """
-    w = counts.shape[0]
-    max_c = int(counts.max()) if counts.size else 1
-    total = int(counts.sum()) if counts.size else 1
-    block = config.pow2ceil(min(max(max_c, 1), exchange_block_cap(total, w)))
-    rounds = -(-max_c // block) if max_c else 1
-    per_dest = counts.sum(axis=0)
-    # the fullest destination's rows set EVERY chip's receive capacity
-    recv_max = int(per_dest.max()) if per_dest.size else 0
-    out_cap = config.pow2ceil(recv_max)
+    # host.exchange_plan: block / round / capacity arithmetic on the count
+    # sidecar, the receive guard and the always-on totals - host work
+    # between the count pull and the exchange's first launch
+    with timing.span("host.exchange_plan"):
+        w = counts.shape[0]
+        max_c = int(counts.max()) if counts.size else 1
+        total = int(counts.sum()) if counts.size else 1
+        block = config.pow2ceil(min(max(max_c, 1),
+                                    exchange_block_cap(total, w)))
+        rounds = -(-max_c // block) if max_c else 1
+        per_dest = counts.sum(axis=0)
+        # the fullest destination's rows set EVERY chip's receive capacity
+        recv_max = int(per_dest.max()) if per_dest.size else 0
+        out_cap = config.pow2ceil(recv_max)
 
-    # topology route (cylon_tpu/topo, docs/topology.md): on a
-    # multi-slice fabric phase B goes hierarchical — a slice-local ICI
-    # alignment hop, then ONE aggregated cross-slice DCN hop — bit- and
-    # order-equal to the flat plan by the slice-major layout.  The
-    # route choice is deterministic from the cached topology plan
-    # (rank-uniform by construction), and on a single-slice topology
-    # ``hier_plan`` is one cached lookup returning None: the flat path
-    # below is byte-identical to the pre-topology engine — zero extra
-    # collectives, zero host syncs (the chaos --multislice unarmed-leg
-    # contract).
-    hplan = _topo.hier_plan(mesh)
-    hprep = None
-    if hplan is not None:
-        # derive the two-hop schedule (hop count matrices, blocks,
-        # gateway capacity) ONCE per exchange — the guard sizing, tier
-        # accounting and dispatch below all read this object
-        from ..topo import exchange as _topo_exchange
-        hprep = _topo_exchange.prepare(hplan, counts)
-
-    # Receive-side memory guard (accelerators only; ``guard=True`` from
-    # hash-shuffle callers): the multi-round protocol bounds SEND
-    # buffers, but the receiving shard still materializes every row
-    # routed to it (out_cap is per-DEST).  A catastrophic route (skew
-    # the heavy-key split didn't model, e.g. hash clustering) is known
-    # from the COUNT SIDECAR before any allocation — raising an
-    # OOM-shaped error here FAILS FAST AND CLEAN instead of submitting a
-    # doomed multi-GB alloc, which this rig never recovers from (a real
-    # device OOM poisons the process, docs/DESIGN.md).  Receive
-    # concentration is not curable downstream — the streaming pipeline
-    # shuffles the same full tables — so the REMEDY is the heavy-key
-    # split (on by default); this guard is the backstop for routes the
-    # split didn't model.  CPU meshes skip it (host RAM is typically far
-    # above any HBM-sized budget); sort/repartition exchanges are
-    # unguarded likewise.
-    on_accel = mesh.devices.flat[0].platform != "cpu" \
-        or config.EXCHANGE_RECV_GUARD_CPU
-    row_bytes = sum(int(np.dtype(c.dtype).itemsize)
-                    * int(np.prod(c.shape[1:], dtype=np.int64))
-                    for c in cols)
-    if guard:
-        # The raise/proceed decision is itself rank-coherent: every rank
-        # evaluates its local predicate (deterministic from the replicated
-        # count sidecar, OR a rank-selective injected fault) and any
-        # consensus runs BEFORE phase B's first collective is dispatched —
-        # "no rank-local control flow after a collective has been
-        # entered" (docs/robustness.md).  A rank whose guard did not fire
-        # still raises when any peer's did, so no rank ever enters the
-        # exchange alone.  The consensus poll itself runs ONLY when the
-        # predicate can differ from OK somewhere — over_budget is
-        # rank-uniform (replicated counts) and `armed` is rank-uniform by
-        # construction (recovery.probe) — so the un-injected happy path
-        # adds no collective and no host sync to the exchange.
-        from ..exec import recovery, scheduler
+        # topology route (cylon_tpu/topo, docs/topology.md): on a
+        # multi-slice fabric phase B goes hierarchical — a slice-local ICI
+        # alignment hop, then ONE aggregated cross-slice DCN hop — bit- and
+        # order-equal to the flat plan by the slice-major layout.  The
+        # route choice is deterministic from the cached topology plan
+        # (rank-uniform by construction), and on a single-slice topology
+        # ``hier_plan`` is one cached lookup returning None: the flat path
+        # below is byte-identical to the pre-topology engine — zero extra
+        # collectives, zero host syncs (the chaos --multislice unarmed-leg
+        # contract).
+        hplan = _topo.hier_plan(mesh)
+        hprep = None
         if hplan is not None:
-            # two-hop peak receive: the hop-1 gateway buffers (payload
-            # + the int32 final-target sidecar lane) are still alive —
-            # as hop 2's inputs — while the final buffers fill, so the
-            # guard sizes against the SUM of the tiers (deterministic
-            # host math on the replicated sidecar)
-            need = _topo_exchange.recv_guard_bytes(hplan, hprep, out_cap,
-                                                   row_bytes)
-        else:
-            need = out_cap * row_bytes
-        # HBM-ledger consult (exec/memory): the predicted receive is an
-        # allocation ON TOP of the resident balance the ledger tracks —
-        # and unlike the static receive budget, ledger pressure is
-        # CURABLE: cold spillable owners (packed piece sources — sink
-        # partials and receive buffers are accounting-only) evict to
-        # host BEFORE the allocation.  Routed through the serving tier's
-        # facade (scheduler.free_pressure, lint rule TS109); still
-        # single-controller only (the underlying try_free no-ops in
-        # multiprocess sessions, where eviction is taken exclusively on
-        # the consensus'd admission path), and the raise/consensus
-        # predicate below stays EXACTLY the replicated count-sidecar
-        # one: a ledger balance read is rank-uniform only up to GC
-        # release timing, so gating the consensus poll on it would risk
-        # the very desync this guard exists to prevent.
-        scheduler.free_pressure(need)
-        over_budget = bool(
-            on_accel
-            and need > config.EXCHANGE_RECV_BUDGET_BYTES)
-        kind, armed = recovery.probe("shuffle.recv_guard")
-        local_fault = over_budget or kind is not None
-        if ((over_budget or armed)
-                and recovery.guard_consensus(mesh, local_fault)):
-            from ..status import PredictedResourceExhausted
-            if kind is not None and kind != "predicted":
-                # rank-selective simulation of a non-guard fault at this
-                # site (e.g. device_oom): raise the REQUESTED kind; peer
-                # ranks raise the predicted shape below and the ladder's
-                # code consensus re-aligns the branches
-                raise recovery.make_fault(kind, "shuffle.recv_guard")
-            hop1 = ("" if hplan is None else
-                    f" (two-hop route: {out_cap} final rows + "
-                    f"{hprep.cap1} gateway rows incl. the target "
-                    "sidecar — both tiers live at once)")
-            raise PredictedResourceExhausted(
-                f"RESOURCE_EXHAUSTED (predicted): exchange receive "
-                f"allocation {need} B at {row_bytes} B/row{hop1} exceeds "
-                f"CYLON_TPU_EXCHANGE_RECV_BUDGET "
-                f"({config.EXCHANGE_RECV_BUDGET_BYTES} B); one destination "
-                "shard would materialize the bulk of the table",
-                site="shuffle.recv_guard")
+            # derive the two-hop schedule (hop count matrices, blocks,
+            # gateway capacity) ONCE per exchange — the guard sizing, tier
+            # accounting and dispatch below all read this object
+            from ..topo import exchange as _topo_exchange
+            hprep = _topo_exchange.prepare(hplan, counts)
 
-    # always-on exchange totals (host arithmetic on the already-pulled
-    # count sidecar — no device work, no sync): the registry counters
-    # the armed comm matrix's row/column sums must reconcile against
-    # (obs/comm, docs/observability.md).  The counters record the
-    # LOGICAL exchange — each row delivered once — whichever route
-    # carried it, so flat and hierarchical runs of the same workload
-    # stay comparable; the tier counters below say which interconnect
-    # the journey used.
-    _metrics.counter("exchange_rows_total").inc(total)
-    _metrics.counter("exchange_bytes_total").inc(total * row_bytes)
-    _metrics.counter("exchange_count").inc()
-    # how UNEVEN it was: the fullest destination's rows and the capacity
-    # bucket they set (every chip's receive buffers, and so every later
-    # whole-shard program's shape, are sized by the fullest chip)
-    _metrics.counter("exchange_recv_max_rows_total").inc(recv_max)
-    _metrics.counter("exchange_recv_cap_rows_total").inc(out_cap)
-    route = "two_hop" if hplan is not None else "flat"
-    topo_t = _topo.topology(mesh)
-    tiers = None
-    if topo_t.n_slices > 1:
-        # always-on per-tier counters on MULTI-SLICE topologies only
-        # (host numpy on the replicated sidecar; single-slice rigs skip
-        # on one cached field load): payload rows/bytes split by which
-        # tier the row's journey crosses, plus the PADDED wire volume
-        # and (src, dst, round) message count each tier's links carry —
-        # the DCN message count is the two-hop route's exactly-1/R
-        # acceptance instrument (docs/topology.md, bench --slices).
-        from ..topo import exchange as _topo_exchange
-        ici_rows, dcn_rows = _topo.tier_split(counts, topo_t)
-        traffic = _topo_exchange.tier_traffic(
-            topo_t, counts, row_bytes, route, prep=hprep,
-            flat_block_rounds=(block, rounds) if hplan is None else None)
-        _metrics.counter("exchange_ici_rows_total").inc(ici_rows)
-        _metrics.counter("exchange_dcn_rows_total").inc(dcn_rows)
-        _metrics.counter("exchange_ici_bytes_total").inc(
-            ici_rows * row_bytes)
-        _metrics.counter("exchange_dcn_bytes_total").inc(
-            dcn_rows * row_bytes)
-        _metrics.counter("exchange_ici_wire_bytes_total").inc(
-            traffic["wire_ici"])
-        _metrics.counter("exchange_dcn_wire_bytes_total").inc(
-            traffic["wire_dcn"])
-        _metrics.counter("exchange_ici_messages_total").inc(
-            traffic["msgs_ici"])
-        _metrics.counter("exchange_dcn_messages_total").inc(
-            traffic["msgs_dcn"])
-        tiers = {"slice_ids": topo_t.slice_ids(), "route": route,
-                 **traffic}
-    if _comm.armed() or _plan.active():
-        # per-(src,dst) matrix + plan-node attribution (armed runs /
-        # active EXPLAIN ANALYZE only — the happy path skips on two
-        # cached loads)
-        _plan.record_exchange(counts, row_bytes, site=owner, tiers=tiers)
+        # Receive-side memory guard (accelerators only; ``guard=True`` from
+        # hash-shuffle callers): the multi-round protocol bounds SEND
+        # buffers, but the receiving shard still materializes every row
+        # routed to it (out_cap is per-DEST).  A catastrophic route (skew
+        # the heavy-key split didn't model, e.g. hash clustering) is known
+        # from the COUNT SIDECAR before any allocation — raising an
+        # OOM-shaped error here FAILS FAST AND CLEAN instead of submitting a
+        # doomed multi-GB alloc, which this rig never recovers from (a real
+        # device OOM poisons the process, docs/DESIGN.md).  Receive
+        # concentration is not curable downstream — the streaming pipeline
+        # shuffles the same full tables — so the REMEDY is the heavy-key
+        # split (on by default); this guard is the backstop for routes the
+        # split didn't model.  CPU meshes skip it (host RAM is typically far
+        # above any HBM-sized budget); sort/repartition exchanges are
+        # unguarded likewise.
+        on_accel = mesh.devices.flat[0].platform != "cpu" \
+            or config.EXCHANGE_RECV_GUARD_CPU
+        row_bytes = sum(int(np.dtype(c.dtype).itemsize)
+                        * int(np.prod(c.shape[1:], dtype=np.int64))
+                        for c in cols)
+        if guard:
+            # The raise/proceed decision is itself rank-coherent: every rank
+            # evaluates its local predicate (deterministic from the replicated
+            # count sidecar, OR a rank-selective injected fault) and any
+            # consensus runs BEFORE phase B's first collective is dispatched:
+            # "no rank-local control flow after a collective has been
+            # entered" (docs/robustness.md).  A rank whose guard did not fire
+            # still raises when any peer's did, so no rank ever enters the
+            # exchange alone.  The consensus poll itself runs ONLY when the
+            # predicate can differ from OK somewhere — over_budget is
+            # rank-uniform (replicated counts) and `armed` is rank-uniform by
+            # construction (recovery.probe) — so the un-injected happy path
+            # adds no collective and no host sync to the exchange.
+            from ..exec import recovery, scheduler
+            if hplan is not None:
+                # two-hop peak receive: the hop-1 gateway buffers (payload
+                # + the int32 final-target sidecar lane) are still alive —
+                # as hop 2's inputs — while the final buffers fill, so the
+                # guard sizes against the SUM of the tiers (deterministic
+                # host math on the replicated sidecar)
+                need = _topo_exchange.recv_guard_bytes(hplan, hprep, out_cap,
+                                                       row_bytes)
+            else:
+                need = out_cap * row_bytes
+            # HBM-ledger consult (exec/memory): the predicted receive is an
+            # allocation ON TOP of the resident balance the ledger tracks —
+            # and unlike the static receive budget, ledger pressure is
+            # CURABLE: cold spillable owners (packed piece sources — sink
+            # partials and receive buffers are accounting-only) evict to
+            # host BEFORE the allocation.  Routed through the serving tier's
+            # facade (scheduler.free_pressure, lint rule TS109); still
+            # single-controller only (the underlying try_free no-ops in
+            # multiprocess sessions, where eviction is taken exclusively on
+            # the consensus'd admission path), and the raise/consensus
+            # predicate below stays EXACTLY the replicated count-sidecar
+            # one: a ledger balance read is rank-uniform only up to GC
+            # release timing, so gating the consensus poll on it would risk
+            # the very desync this guard exists to prevent.
+            scheduler.free_pressure(need)
+            over_budget = bool(
+                on_accel
+                and need > config.EXCHANGE_RECV_BUDGET_BYTES)
+            kind, armed = recovery.probe("shuffle.recv_guard")
+            local_fault = over_budget or kind is not None
+            if ((over_budget or armed)
+                    and recovery.guard_consensus(mesh, local_fault)):
+                from ..status import PredictedResourceExhausted
+                if kind is not None and kind != "predicted":
+                    # rank-selective simulation of a non-guard fault at this
+                    # site (e.g. device_oom): raise the REQUESTED kind; peer
+                    # ranks raise the predicted shape below and the ladder's
+                    # code consensus re-aligns the branches
+                    raise recovery.make_fault(kind, "shuffle.recv_guard")
+                hop1 = ("" if hplan is None else
+                        f" (two-hop route: {out_cap} final rows + "
+                        f"{hprep.cap1} gateway rows incl. the target "
+                        "sidecar — both tiers live at once)")
+                raise PredictedResourceExhausted(
+                    f"RESOURCE_EXHAUSTED (predicted): exchange receive "
+                    f"allocation {need} B at {row_bytes} B/row{hop1} exceeds "
+                    f"CYLON_TPU_EXCHANGE_RECV_BUDGET "
+                    f"({config.EXCHANGE_RECV_BUDGET_BYTES} B); one "
+                    "destination "
+                    "shard would materialize the bulk of the table",
+                    site="shuffle.recv_guard")
+
+        # always-on exchange totals (host arithmetic on the already-pulled
+        # count sidecar — no device work, no sync): the registry counters
+        # the armed comm matrix's row/column sums must reconcile against
+        # (obs/comm, docs/observability.md).  The counters record the
+        # LOGICAL exchange — each row delivered once — whichever route
+        # carried it, so flat and hierarchical runs of the same workload
+        # stay comparable; the tier counters below say which interconnect
+        # the journey used.
+        _metrics.counter("exchange_rows_total").inc(total)
+        _metrics.counter("exchange_bytes_total").inc(total * row_bytes)
+        _metrics.counter("exchange_count").inc()
+        # how UNEVEN it was: the fullest destination's rows and the capacity
+        # bucket they set (every chip's receive buffers, and so every later
+        # whole-shard program's shape, are sized by the fullest chip)
+        _metrics.counter("exchange_recv_max_rows_total").inc(recv_max)
+        _metrics.counter("exchange_recv_cap_rows_total").inc(out_cap)
+        route = "two_hop" if hplan is not None else "flat"
+        topo_t = _topo.topology(mesh)
+        tiers = None
+        if topo_t.n_slices > 1:
+            # always-on per-tier counters on MULTI-SLICE topologies only
+            # (host numpy on the replicated sidecar; single-slice rigs skip
+            # on one cached field load): payload rows/bytes split by which
+            # tier the row's journey crosses, plus the PADDED wire volume
+            # and (src, dst, round) message count each tier's links carry —
+            # the DCN message count is the two-hop route's exactly-1/R
+            # acceptance instrument (docs/topology.md, bench --slices).
+            from ..topo import exchange as _topo_exchange
+            ici_rows, dcn_rows = _topo.tier_split(counts, topo_t)
+            traffic = _topo_exchange.tier_traffic(
+                topo_t, counts, row_bytes, route, prep=hprep,
+                flat_block_rounds=(block, rounds) if hplan is None else None)
+            _metrics.counter("exchange_ici_rows_total").inc(ici_rows)
+            _metrics.counter("exchange_dcn_rows_total").inc(dcn_rows)
+            _metrics.counter("exchange_ici_bytes_total").inc(
+                ici_rows * row_bytes)
+            _metrics.counter("exchange_dcn_bytes_total").inc(
+                dcn_rows * row_bytes)
+            _metrics.counter("exchange_ici_wire_bytes_total").inc(
+                traffic["wire_ici"])
+            _metrics.counter("exchange_dcn_wire_bytes_total").inc(
+                traffic["wire_dcn"])
+            _metrics.counter("exchange_ici_messages_total").inc(
+                traffic["msgs_ici"])
+            _metrics.counter("exchange_dcn_messages_total").inc(
+                traffic["msgs_dcn"])
+            tiers = {"slice_ids": topo_t.slice_ids(), "route": route,
+                     **traffic}
+        if _comm.armed() or _plan.active():
+            # per-(src,dst) matrix + plan-node attribution (armed runs /
+            # active EXPLAIN ANALYZE only — the happy path skips on two
+            # cached loads)
+            _plan.record_exchange(counts, row_bytes, site=owner, tiers=tiers)
     # the exchange's host side on every sink of timing.span (profiler
     # trace, flight recorder): what it moved, and how long the host took
     # to enqueue its programs (docs/observability.md)
@@ -642,33 +648,34 @@ def exchange(mesh: Mesh, tgt, counts: np.ndarray, cols: tuple,
             # rounds>1)
             fn = _round_fn(mesh, w, block, out_cap, max(rounds, 1))
             outs = fn(perm, counts_i, outs, tuple(cols))
-    # integrity audit tier (exec/integrity, docs/robustness.md): the
-    # corruption drill first (so the audit below is what catches it),
-    # then the always-on conservation laws — pure host math on the
-    # already-pulled sidecar, zero device work — then, ARMED only
-    # (CYLON_TPU_AUDIT=1), fingerprint conservation across the route:
-    # the XOR content fingerprint of the valid input rows must equal
-    # the delivered outputs', whichever route carried them
-    from ..exec import integrity as _integrity, recovery as _recovery
-    if _recovery.maybe_inject("exchange.corrupt",
-                              intercept=("corrupt",)) == "corrupt":
-        _recovery._record("exchange.corrupt", "corrupt", "flipped")
-        outs = _integrity.flip_one(mesh, outs, per_dest)
-    _integrity.conserve_exchange(counts, per_dest, total, row_bytes,
-                                 site=owner)
-    if _integrity.armed():
-        _integrity.verify_exchange(mesh, tgt, cols, outs, per_dest,
-                                   site=owner)
-    if guard:
-        # HBM-ledger accounting of the receive allocation (exec/memory):
-        # one registration PER buffer, each anchored to its own array, so
-        # the balance tracks exactly the buffers still alive (the lane
-        # matrix usually dies at rebuild; f64 side arrays live on as the
-        # table's columns).  Non-spillable — an exchange output has no
-        # cheap re-entry path.
-        from ..exec import memory
-        for arr in outs:
-            memory.register(owner, (arr,), anchor=arr)
+    with timing.span("host.exchange_close"):
+        # integrity audit tier (exec/integrity, docs/robustness.md): the
+        # corruption drill first (so the audit below is what catches it),
+        # then the always-on conservation laws — pure host math on the
+        # already-pulled sidecar, zero device work — then, ARMED only
+        # (CYLON_TPU_AUDIT=1), fingerprint conservation across the route:
+        # the XOR content fingerprint of the valid input rows must equal
+        # the delivered outputs', whichever route carried them
+        from ..exec import integrity as _integrity, recovery as _recovery
+        if _recovery.maybe_inject("exchange.corrupt",
+                                  intercept=("corrupt",)) == "corrupt":
+            _recovery._record("exchange.corrupt", "corrupt", "flipped")
+            outs = _integrity.flip_one(mesh, outs, per_dest)
+        _integrity.conserve_exchange(counts, per_dest, total, row_bytes,
+                                     site=owner)
+        if _integrity.armed():
+            _integrity.verify_exchange(mesh, tgt, cols, outs, per_dest,
+                                       site=owner)
+        if guard:
+            # HBM-ledger accounting of the receive allocation (exec/memory):
+            # one registration PER buffer, each anchored to its own array, so
+            # the balance tracks exactly the buffers still alive (the lane
+            # matrix usually dies at rebuild; f64 side arrays live on as the
+            # table's columns).  Non-spillable — an exchange output has no
+            # cheap re-entry path.
+            from ..exec import memory
+            for arr in outs:
+                memory.register(owner, (arr,), anchor=arr)
     return outs, per_dest.astype(np.int64)
 
 

@@ -10,6 +10,8 @@ space), per-shard liveness masks, and result-table assembly.
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -146,6 +148,20 @@ def col_arrays(cols: list[Column]):
     """Split columns into parallel (datas, valids) tuples; valids entries may
     be None (all-valid) — None is an empty pytree so it passes through jit."""
     return tuple(c.data for c in cols), tuple(c.validity for c in cols)
+
+
+@functools.lru_cache(maxsize=2)
+def all_valid(cap: int) -> np.ndarray:
+    """The all-true validity stand-in of ``cap`` rows for a key column that
+    has none, built once per capacity and kept (read-only).  A fresh
+    ``np.ones(cap, bool)`` on every distributed join is a 32 MiB block at
+    2^25 rows, which glibc serves from heap it kept (~2.4 ms) or from
+    fresh ``mmap`` pages (~33 ms of page faults) by the process's
+    allocation history, not its seed: the four-chip cells' second speed
+    (PERF.md §6, PR 39)."""
+    ones = np.ones(cap, bool)
+    ones.setflags(write=False)
+    return ones
 
 
 def promote_key_pair(a: Column, b: Column) -> tuple[Column, Column]:
@@ -400,7 +416,7 @@ def sample_keys(table: Table, key_names: list, m: int | None = None,
     cap = cols[0].data.shape[0]
     datas = tuple(c.data for c in cols)
     valids = tuple(c.validity if c.validity is not None
-                   else np.ones(cap, bool) for c in cols)
+                   else all_valid(cap) for c in cols)
     outs = _key_sample_fn(env.mesh, m, len(cols))(
         np.asarray(table.valid_counts, np.int32), *datas, *valids)
     vals0 = host_array(outs[0]).reshape(w, m)
@@ -442,6 +458,7 @@ def sample_key_rows(table: Table, key_names: list, m: int | None = None):
     device program + one host pull — no collective (the plan decision
     stays rank-uniform because the pull allgathers)."""
     from .. import config
+    from ..utils import timing
     from ..utils.host import host_array
 
     env = table.env
@@ -452,28 +469,34 @@ def sample_key_rows(table: Table, key_names: list, m: int | None = None):
     if m is None:
         m = config.SKEW_SAMPLE
     m = min(max(int(table.capacity), 1), int(m))
-    cols = [table.column(n) for n in key_names]
-    cap = cols[0].data.shape[0]
-    nk = len(cols)
-    datas = tuple(c.data for c in cols)
-    valids = tuple(c.validity if c.validity is not None
-                   else np.ones(cap, bool) for c in cols)
-    outs = _key_sample_fn(env.mesh, m, nk, True)(
-        np.asarray(table.valid_counts, np.int32), *datas, *valids)
+    # host.skew_operands: the sampler's operands, built on the host - a key
+    # column with no validity gets an all-true stand-in of the column's
+    # whole capacity (one byte a row; :func:`all_valid` keeps it)
+    with timing.span("host.skew_operands"):
+        cols = [table.column(n) for n in key_names]
+        cap = cols[0].data.shape[0]
+        nk = len(cols)
+        datas = tuple(c.data for c in cols)
+        valids = tuple(c.validity if c.validity is not None
+                       else all_valid(cap) for c in cols)
+        sample = _key_sample_fn(env.mesh, m, nk, True)
+        vc32 = np.asarray(table.valid_counts, np.int32)
+    outs = sample(vc32, *datas, *valids)
     vals = [host_array(o).reshape(w * m) for o in outs[:nk]]
     vls = [host_array(o).reshape(w * m) for o in outs[nk:2 * nk]]
     hashes = host_array(outs[-2]).reshape(w * m)
     live = host_array(outs[-1]).reshape(w, m)
-    vc = np.asarray(table.valid_counts, np.float64)
-    keep = live.reshape(-1)
-    if not keep.any():
-        return None
-    # each shard contributes its true row share split evenly over its
-    # samples (the sample_keys weighting) — scaled to absolute rows
-    per_shard_w = np.repeat(
-        np.where(vc > 0, vc / np.maximum(m, 1), 0.0), m)
-    return ([v[keep] for v in vals], [v[keep] for v in vls],
-            hashes[keep], per_shard_w[keep], total)
+    with timing.span("host.skew_weigh"):
+        vc = np.asarray(table.valid_counts, np.float64)
+        keep = live.reshape(-1)
+        if not keep.any():
+            return None
+        # each shard contributes its true row share split evenly over its
+        # samples (the sample_keys weighting) — scaled to absolute rows
+        per_shard_w = np.repeat(
+            np.where(vc > 0, vc / np.maximum(m, 1), 0.0), m)
+        return ([v[keep] for v in vals], [v[keep] for v in vls],
+                hashes[keep], per_shard_w[keep], total)
 
 
 def _trace_key_sample(mesh):

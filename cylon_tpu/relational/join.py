@@ -1221,148 +1221,156 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
         from .repart import filter_table
         return filter_table(lwork, flag)
 
-    cache_key = (env.serial, how, narrow, lwork.capacity, rwork.capacity,
-                 int(lwork.valid_counts.sum()), int(rwork.valid_counts.sum()),
-                 tuple(left_on), tuple(right_on),
-                 tuple(lwork.column_names), tuple(rwork.column_names))
-    predicted = _CAP_CACHE.get(cache_key)
+    # host.join_plan: the output plan's loops, the lane specs and what
+    # rides the sort - host work between the last exchange (or the call's
+    # start) and the count program's launch
+    with timing.span("host.join_plan"):
+        cache_key = (env.serial, how, narrow, lwork.capacity, rwork.capacity,
+                     int(lwork.valid_counts.sum()),
+                     int(rwork.valid_counts.sum()),
+                     tuple(left_on), tuple(right_on),
+                     tuple(lwork.column_names), tuple(rwork.column_names))
+        predicted = _CAP_CACHE.get(cache_key)
 
-    # ---- output plan -----------------------------------------------------
-    coalesce = coalesce_keys and left_on == right_on
-    key_set_l, key_set_r = set(left_on), set(right_on)
-    overlap = (set(lwork.column_names) & set(rwork.column_names)) - (
-        key_set_l if coalesce else set())
+        # ---- output plan -------------------------------------------------
+        coalesce = coalesce_keys and left_on == right_on
+        key_set_l, key_set_r = set(left_on), set(right_on)
+        overlap = (set(lwork.column_names) & set(rwork.column_names)) - (
+            key_set_l if coalesce else set())
 
-    # lane-matrix column lists per side (keys first, then gathered columns)
-    l_cols_list: list[Column] = []
-    r_cols_list: list[Column] = []
+        # lane-matrix column lists per side (keys first, then gathered columns)
+        l_cols_list: list[Column] = []
+        r_cols_list: list[Column] = []
 
-    def lane_col(side_list, col) -> int:
-        side_list.append(col)
-        return len(side_list) - 1
+        def lane_col(side_list, col) -> int:
+            side_list.append(col)
+            return len(side_list) - 1
 
-    #: left key name -> its left lane column, where it is an output column
-    l_key_lane: dict = {}
+        #: left key name -> its left lane column, where it is an output column
+        l_key_lane: dict = {}
 
-    plan, names, types, dicts, bounds = [], [], [], [], []
+        plan, names, types, dicts, bounds = [], [], [], [], []
 
-    def merged_bounds(a: Column, b: Column):
-        if a.bounds is None or b.bounds is None:
-            return None
-        return (min(a.bounds[0], b.bounds[0]), max(a.bounds[1], b.bounds[1]))
+        def merged_bounds(a: Column, b: Column):
+            if a.bounds is None or b.bounds is None:
+                return None
+            return (min(a.bounds[0], b.bounds[0]),
+                    max(a.bounds[1], b.bounds[1]))
 
-    for n in lwork.column_names:
-        col = lwork.column(n)
-        if coalesce and n in key_set_l:
-            ki = left_on.index(n)
-            rcol = rwork.column(right_on[ki])
-            bounds.append(merged_bounds(col, rcol))
-            # the coalesced key only needs BOTH sides for outer joins; for
-            # inner/left every output row has a live left key (and for right
-            # a live right key) — one lane set instead of two
-            if how in ("inner", "left"):
-                l_key_lane[n] = lane_col(l_cols_list, col)
-                plan.append(("l", l_key_lane[n], col.validity is not None))
-            elif how == "right":
-                plan.append(("r", lane_col(r_cols_list, rcol),
-                             rcol.validity is not None))
+        for n in lwork.column_names:
+            col = lwork.column(n)
+            if coalesce and n in key_set_l:
+                ki = left_on.index(n)
+                rcol = rwork.column(right_on[ki])
+                bounds.append(merged_bounds(col, rcol))
+                # the coalesced key only needs BOTH sides for outer joins; for
+                # inner/left every output row has a live left key (and for
+                # right
+                # a live right key) — one lane set instead of two
+                if how in ("inner", "left"):
+                    l_key_lane[n] = lane_col(l_cols_list, col)
+                    plan.append(("l", l_key_lane[n], col.validity is not None))
+                elif how == "right":
+                    plan.append(("r", lane_col(r_cols_list, rcol),
+                                 rcol.validity is not None))
+                else:
+                    needs_valid = (col.validity is not None
+                                   or rcol.validity is not None)
+                    plan.append(("k", lane_col(l_cols_list, col),
+                                 lane_col(r_cols_list, rcol), needs_valid))
             else:
                 needs_valid = (col.validity is not None
-                               or rcol.validity is not None)
-                plan.append(("k", lane_col(l_cols_list, col),
-                             lane_col(r_cols_list, rcol), needs_valid))
-        else:
-            needs_valid = col.validity is not None or how in ("right", "outer")
-            if n in key_set_l:
-                l_key_lane[n] = len(l_cols_list)
-            plan.append(("l", lane_col(l_cols_list, col), needs_valid))
+                               or how in ("right", "outer"))
+                if n in key_set_l:
+                    l_key_lane[n] = len(l_cols_list)
+                plan.append(("l", lane_col(l_cols_list, col), needs_valid))
+                bounds.append(col.bounds)
+                n = n + suffixes[0] if n in overlap else n
+            names.append(n)
+            types.append(col.type)
+            dicts.append(col.dictionary)
+        for n in rwork.column_names:
+            if coalesce and n in key_set_r:
+                continue
+            col = rwork.column(n)
+            needs_valid = col.validity is not None or how in ("left", "outer")
+            plan.append(("r", lane_col(r_cols_list, col), needs_valid))
+            names.append(n + suffixes[1] if n in overlap else n)
+            types.append(col.type)
+            dicts.append(col.dictionary)
             bounds.append(col.bounds)
-            n = n + suffixes[0] if n in overlap else n
-        names.append(n)
-        types.append(col.type)
-        dicts.append(col.dictionary)
-    for n in rwork.column_names:
-        if coalesce and n in key_set_r:
-            continue
-        col = rwork.column(n)
-        needs_valid = col.validity is not None or how in ("left", "outer")
-        plan.append(("r", lane_col(r_cols_list, col), needs_valid))
-        names.append(n + suffixes[1] if n in overlap else n)
-        types.append(col.type)
-        dicts.append(col.dictionary)
-        bounds.append(col.bounds)
 
-    # host-known bounds narrow 64-bit lanes to one u32 lane each
-    from .common import table_lane_spec
-    lspec = table_lane_spec(l_cols_list)
-    rspec = table_lane_spec(r_cols_list)
+        # host-known bounds narrow 64-bit lanes to one u32 lane each
+        from .common import table_lane_spec
+        lspec = table_lane_spec(l_cols_list)
+        rspec = table_lane_spec(r_cols_list)
 
-    # ride a side's lane matrix through the phase-1 sort when every one of
-    # its output columns is laneable (no f64 side channels) and the lane
-    # count is small — a sort operand costs 1.1-1.25 ns a row on v5e
-    # (ledger, PRs 29-34) vs ~15 ns/row gathers.  carry_match (right side)
-    # kills the dependent idx_s[mpos] + right lane-matrix gathers;
-    # carry_emit (left side) folds the left values into the meta-stack
-    # gather join_take already performs.
-    def _can_carry(spec, col_list, budget: int) -> bool:
-        # laneless f64 columns do not disqualify (carry-LITE: laneable
-        # columns ride the sort, f64 columns keep their take-index
-        # gathers); there must be at least one laneable data column
-        return bool(how in ("inner", "left") and col_list
-                    and any(c.lanes for c in spec.cols)
-                    and spec.n_lanes <= budget)
+        # ride a side's lane matrix through the phase-1 sort when every one of
+        # its output columns is laneable (no f64 side channels) and the lane
+        # count is small — a sort operand costs 1.1-1.25 ns a row on v5e
+        # (ledger, PRs 29-34) vs ~15 ns/row gathers.  carry_match (right side)
+        # kills the dependent idx_s[mpos] + right lane-matrix gathers;
+        # carry_emit (left side) folds the left values into the meta-stack
+        # gather join_take already performs.
+        def _can_carry(spec, col_list, budget: int) -> bool:
+            # laneless f64 columns do not disqualify (carry-LITE: laneable
+            # columns ride the sort, f64 columns keep their take-index
+            # gathers); there must be at least one laneable data column
+            return bool(how in ("inner", "left") and col_list
+                        and any(c.lanes for c in spec.cols)
+                        and spec.n_lanes <= budget)
 
-    carry_match = _can_carry(rspec, r_cols_list, 8)
-    carry_emit = _can_carry(lspec, l_cols_list, 6)
+        carry_match = _can_carry(rspec, r_cols_list, 8)
+        carry_emit = _can_carry(lspec, l_cols_list, 6)
 
-    l_gather_args = (tuple(c.data for c in l_cols_list),
-                     tuple(c.validity for c in l_cols_list))
-    r_gather_args = (tuple(c.data for c in r_cols_list),
-                     tuple(c.validity for c in r_cols_list))
-    all_live = bool((vcl == lwork.capacity).all()
-                    and (vcr == rwork.capacity).all())
-    # phase 1 only consumes the columns that ride the sort; keep the
-    # rest out of the trace (no needless retraces)
-    count_l_args = l_gather_args if carry_emit else ((), ())
-    count_r_args = r_gather_args if carry_match else ((), ())
-    count_args = (vcl, vcr, l_datas, l_valids, r_datas, r_valids,
-                  *count_l_args, *count_r_args)
-    cl_spec = lspec if carry_emit else None
-    cr_spec = rspec if carry_match else None
-    # what rides the sort, said once (ops/join.PayloadLayout): the two
-    # sides share operands, a left key column is the sorted key itself
-    layout = joink.payload_layout(
-        cl_spec, cr_spec, tuple(l_key_lane.get(n) for n in left_on),
-        tuple(d.dtype for d in l_datas),
-        tuple((lv is not None) or (rv is not None)
-              for lv, rv in zip(l_valids, r_valids)), narrow, all_live)
+        l_gather_args = (tuple(c.data for c in l_cols_list),
+                         tuple(c.validity for c in l_cols_list))
+        r_gather_args = (tuple(c.data for c in r_cols_list),
+                         tuple(c.validity for c in r_cols_list))
+        all_live = bool((vcl == lwork.capacity).all()
+                        and (vcr == rwork.capacity).all())
+        # phase 1 only consumes the columns that ride the sort; keep the
+        # rest out of the trace (no needless retraces)
+        count_l_args = l_gather_args if carry_emit else ((), ())
+        count_r_args = r_gather_args if carry_match else ((), ())
+        count_args = (vcl, vcr, l_datas, l_valids, r_datas, r_valids,
+                      *count_l_args, *count_r_args)
+        cl_spec = lspec if carry_emit else None
+        cr_spec = rspec if carry_match else None
+        # what rides the sort, said once (ops/join.PayloadLayout): the two
+        # sides share operands, a left key column is the sorted key itself
+        layout = joink.payload_layout(
+            cl_spec, cr_spec, tuple(l_key_lane.get(n) for n in left_on),
+            tuple(d.dtype for d in l_datas),
+            tuple((lv is not None) or (rv is not None)
+                  for lv, rv in zip(l_valids, r_valids)), narrow, all_live)
 
-    # ---- deferred materialization (reference ops-DAG slot, C9) -----------
-    # Inner joins whose output columns fully ride the phase-1 sort can hand
-    # the pre-expansion sorted state to a fused downstream consumer
-    # (groupby pushdown, relational/fused.py) — the output expansion (two
-    # ~15 ns/slot gathers over every output row, the dominant join cost)
-    # never runs for join->groupby-on-the-join-keys pipelines.  Any other
-    # access materializes transparently (core.table.DeferredTable).  Phase 1
-    # runs SLIM (no carry outputs, ~5 N-length HBM buffers freed) — a later
-    # materialization rebuilds the carry from the held (idx_s, bnd) with
-    # prefix scans only (_carry_fn) — the sort never runs twice.
-    # allow_defer default: colocated (pipelined chunk) joins only defer
-    # when the caller says a fused consumer will drain each chunk's state
-    # immediately (pipelined_join with a sink).  The sink-less concat path
-    # would retain every chunk's slim state simultaneously alongside the
-    # resident build side — the HBM headroom the pipeline exists to keep.
-    if allow_defer is None:
-        allow_defer = not assume_colocated
-    # the adaptive skew-split route (skew_plan) defers exactly like the
-    # plain co-located join — the fused consumer combines the heavy
-    # keys' per-shard partials (fused.py + skew.combine_heavy_partials),
-    # any other access materializes THROUGH the stitch.  The plan-less
-    # split=True legs (broadcast join / legacy semi-anti spread) have no
-    # plan to reconstruct co-location from and stay eager.
-    defer = (how == "inner" and carry_emit and carry_match and coalesce
-             and allow_defer
-             and (skew_plan is not None or not skew_split))
+        # ---- deferred materialization (reference ops-DAG slot, C9) -------
+        # Inner joins whose output columns fully ride the phase-1 sort can hand
+        # the pre-expansion sorted state to a fused downstream consumer
+        # (groupby pushdown, relational/fused.py) — the output expansion (two
+        # ~15 ns/slot gathers over every output row, the dominant join cost)
+        # never runs for join->groupby-on-the-join-keys pipelines.  Any other
+        # access materializes transparently (core.table.DeferredTable).  Phase
+        # 1 runs SLIM (no carry outputs, ~5 N-length HBM buffers freed) — a
+        # later materialization rebuilds the carry from the held (idx_s, bnd) with
+        # prefix scans only (_carry_fn) — the sort never runs twice.
+        # allow_defer default: colocated (pipelined chunk) joins only defer
+        # when the caller says a fused consumer will drain each chunk's state
+        # immediately (pipelined_join with a sink).  The sink-less concat path
+        # would retain every chunk's slim state simultaneously alongside the
+        # resident build side — the HBM headroom the pipeline exists to keep.
+        if allow_defer is None:
+            allow_defer = not assume_colocated
+        # the adaptive skew-split route (skew_plan) defers exactly like the
+        # plain co-located join — the fused consumer combines the heavy
+        # keys' per-shard partials (fused.py + skew.combine_heavy_partials),
+        # any other access materializes THROUGH the stitch.  The plan-less
+        # split=True legs (broadcast join / legacy semi-anti spread) have no
+        # plan to reconstruct co-location from and stay eager.
+        defer = (how == "inner" and carry_emit and carry_match and coalesce
+                 and allow_defer
+                 and (skew_plan is not None or not skew_split))
     if defer:
         with timing.region("join.sort_count"):
             res = _count_fn(env.mesh, how, narrow, cl_spec, cr_spec, layout,

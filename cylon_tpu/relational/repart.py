@@ -140,6 +140,7 @@ def shuffle_table(table: Table, key_names,
     if env.world_size == 1:
         return table
     from ..obs import plan as _plan
+    from ..utils import timing
     with _plan.node("shuffle", keys=tuple(key_names), owner=owner) as pn:
         if pn:
             pn.set(rows_in=table.row_count, rows_out=table.row_count)
@@ -148,12 +149,16 @@ def shuffle_table(table: Table, key_names,
         tgt = shuffle.hash_targets(env.mesh, datas, valids,
                                    table.valid_counts)
         counts = shuffle.count_targets(env.mesh, tgt)
-        flat, recipe = _flatten_for_exchange(table)
+        # the lane pack / unpack programs are plain ``jit`` calls (no
+        # ``launch.*`` span): their enqueue is host work of the turn
+        with timing.span("host.exchange_pack"):
+            flat, recipe = _flatten_for_exchange(table)
         # hash shuffles run under join/groupby/setops OOM fallbacks: the
         # receive-budget guard may preempt a doomed allocation
         new_flat, new_valid = shuffle.exchange(env.mesh, tgt, counts, flat,
                                                guard=True, owner=owner)
-        return _rebuild(recipe, new_flat, new_valid, env)
+        with timing.span("host.exchange_unpack"):
+            return _rebuild(recipe, new_flat, new_valid, env)
 
 
 def exchange_by_targets(table: Table, tgt, counts: np.ndarray) -> Table:

@@ -341,6 +341,7 @@ def detect(probe: Table, key_names, env) -> SkewPlan | None:
     construction."""
     from ..obs.sketch import MisraGries
     from ..ops.hashing import partition_of
+    from ..utils import timing
     from .common import sample_key_rows
 
     # every eligible join's decision sequence starts here: clear the
@@ -358,11 +359,13 @@ def detect(probe: Table, key_names, env) -> SkewPlan | None:
         return None
     values, valids, hashes, weights, _total = sampled
     _DETECT_JOINS.inc()
-    mg = MisraGries(k=max(4 * config.SKEW_MAX_KEYS, 8))
-    mg.update(hashes, weights)
-    est = mg.shares()
-    _annotate_rule(est, w)
-    heavy = [(hv, sh) for hv, sh, _e in est if split_rule(sh, w)[2]]
+    with timing.span("host.skew_detect"):
+        # the detector's verdict on the pulled sample: pure host work
+        mg = MisraGries(k=max(4 * config.SKEW_MAX_KEYS, 8))
+        mg.update(hashes, weights)
+        est = mg.shares()
+        _annotate_rule(est, w)
+        heavy = [(hv, sh) for hv, sh, _e in est if split_rule(sh, w)[2]]
     if not heavy:
         return None
     heavy = heavy[:config.SKEW_MAX_KEYS]

@@ -20,6 +20,11 @@ device — ``launch.<builder>`` (analysis/runtime.tag_program: the enqueue of
 every program_cache program) and ``pull.<kind>`` (utils/host: every
 sanctioned host pull) — go through :func:`span`, the same thing without the
 tables: they nest inside the operator regions and must not be summed twice.
+So do the operator call itself, ``op.<op>`` (obs/plan._NodeCtx: every
+``plan.node``), and the named stretches of host work between a pull's return
+and the next launch, ``host.<step>`` (:data:`HOST_STEPS`): what is in no
+``launch.*`` / ``pull.*`` span of an ``op.*`` span is a TURN of the host, and
+``benchmark/readers/trace_round_trips.py`` reads all of it off the spans.
 
 JAX dispatch is async — a region covering only device work would time the
 dispatch, not the execution.  Regions are therefore placed around phases
@@ -69,6 +74,15 @@ from .. import config
 
 #: every region is also a ``cylon.<name>`` span of jax's profiler
 ANNOTATION_PREFIX = "cylon."
+
+#: the closed vocabulary of ``span("host.<step>")``: the stretches of an
+#: operator call between a pull's return and the next launch in which the
+#: host does real work (docs/observability.md, "A round trip, both ends").
+#: Held by tests/test_obs.py over the source and over a profiled query,
+#: not by a check at run time: :func:`span` stays as it is.
+HOST_STEPS = ("skew_operands", "skew_weigh", "skew_detect", "exchange_pack",
+              "exchange_plan", "exchange_close", "exchange_unpack",
+              "join_plan")
 
 #: name -> [total_seconds, call_count]
 _ACCUM: dict[str, list] = {}

@@ -27,7 +27,7 @@ import pytest
 
 from cylon_tpu import config, obs
 from cylon_tpu.obs import metrics, rank_report, trace
-from cylon_tpu.status import (ExecutionError, InvalidError,
+from cylon_tpu.status import (CylonKeyError, ExecutionError, InvalidError,
                               PredictedResourceExhausted)
 from cylon_tpu.utils import timing
 
@@ -505,6 +505,63 @@ class TestUnarmedContract:
                         ("cylon.t.two", {"session": "tenantA", "bytes": 5})]
         assert timing.snapshot() == {}      # BENCH_TIMINGS off: no table
 
+    def test_operator_call_and_host_step_read_no_clock(self, env1,
+                                                       tmp_path,
+                                                       monkeypatch):
+        """The contract restated for ``cylon.op.<op>`` (every
+        ``plan.node``) and ``cylon.host.<step>`` (ISSUE 39): with nothing
+        armed each constructs ONE ``TraceAnnotation``, reads no clock and
+        writes nothing - on the facade alone and over a whole join ->
+        groupby.  ``timing.span`` is the parent's, byte for byte: the
+        digest is of its source, to be changed knowingly."""
+        import hashlib
+        import inspect
+        import types
+        from cylon_tpu.obs import plan
+        from cylon_tpu.relational import groupby_aggregate, join_tables
+        assert hashlib.sha256(inspect.getsource(timing.span).encode()) \
+            .hexdigest()[:16] == "65809b1d6c3e31c4"
+        left, right = _toy(env1, n=512)
+
+        def query():
+            return groupby_aggregate(join_tables(left, right, "k", "k"),
+                                     "k", [("a", "sum")]).row_count
+
+        query()                                   # compiled, caches warm
+        monkeypatch.chdir(tmp_path)
+        made, reads = [], []
+
+        class Spy(timing.TraceAnnotation):
+            def __init__(self, name, **kw):
+                made.append(name)
+                super().__init__(name, **kw)
+
+        def perf_counter():
+            reads.append(1)
+            return time.perf_counter()
+
+        monkeypatch.setattr(timing, "TraceAnnotation", Spy)
+        monkeypatch.setattr(timing, "time", types.SimpleNamespace(
+            perf_counter=perf_counter))
+        before = metrics.snapshot()
+        with plan.node("join", how="inner") as pn:
+            assert not pn                          # no profile: the no-op
+            with timing.span("host.join_plan"):
+                pass
+        assert made == ["cylon.op.join", "cylon.host.join_plan"]
+        assert reads == []
+        assert query() > 0
+        assert reads == []                         # the whole query: none
+        ops = [n for n in made if n.startswith("cylon.op.")]
+        hosts = [n for n in made if n.startswith("cylon.host.")]
+        assert ops[1:] == ["cylon.op.join", "cylon.op.groupby"]
+        assert hosts[1:] == ["cylon.host.join_plan"]
+        # nothing armed, nothing written: no ring, no table, no file, and
+        # the registry gained no name
+        assert trace.recorder() is None and timing.snapshot() == {}
+        assert os.listdir(tmp_path) == []
+        assert set(metrics.snapshot()) == set(before)
+
     def test_autoarm_needs_env(self, monkeypatch):
         monkeypatch.delenv("CYLON_TPU_TRACE", raising=False)
         trace.autoarm()
@@ -660,6 +717,185 @@ def test_exchange_spans_carry_rows_and_bytes(env4):
     assert len(rounds) == 2 and all(
         any(x[0] <= r[0] and r[0] + r[1] <= x[0] + x[1] for x in exch)
         for r in rounds)
+
+
+# ---------------------------------------------------------------------------
+# a round trip, both ends: the operator call and the named turns (ISSUE 39)
+# ---------------------------------------------------------------------------
+
+def _host_step_literals():
+    """Every ``span("host.<step>")`` literal under ``cylon_tpu/``, and any
+    ``host.`` span name built at run time (there must be none)."""
+    import re
+    literal = re.compile(r"""\bspan\(\s*["']host\.([A-Za-z0-9_.]*)["']""")
+    built = re.compile(r"""\bspan\(\s*(?:f["']host\.|["']host\.["']\s*\+)""")
+    found, dynamic = set(), []
+    for root, _dirs, names in os.walk(os.path.join(REPO, "cylon_tpu")):
+        for name in names:
+            if name.endswith(".py"):
+                with open(os.path.join(root, name), encoding="utf-8") as f:
+                    text = f.read()
+                found |= set(literal.findall(text))
+                dynamic += built.findall(text)
+    return found, dynamic
+
+
+def test_op_and_host_spans_on_the_profilers_clock(env4, profiled):
+    """A distributed ``join_tables`` + ``groupby_aggregate`` under
+    ``jax.profiler``: the operator calls are ``cylon.op.<op>`` spans (the
+    exchange's ``op.shuffle`` inside ``op.join``), the named turns
+    ``cylon.host.<step>``; every name written, and every literal in the
+    source, is of ``timing.HOST_STEPS`` - and the distributed join writes
+    them all, so the vocabulary holds no dead name.  (The rig's four
+    devices stand for the issue's two: the module's programs are compiled
+    for them already.)"""
+    from cylon_tpu.relational import groupby_aggregate, join_tables
+    left, right = _toy(env4)
+
+    def query():
+        g = groupby_aggregate(join_tables(left, right, "k", "k"), "k",
+                              [("a", "sum"), ("b", "sum")])
+        assert g.row_count > 0
+
+    spans, ring = profiled(query)
+    names = [s[0] for s in spans]
+    vocabulary = {"cylon.host." + s for s in timing.HOST_STEPS}
+    assert {n for n in names if n.startswith("cylon.host.")} == vocabulary
+    assert _host_step_literals() == (set(timing.HOST_STEPS), [])
+    assert [n for n in names if n.startswith("cylon.op.")] == [
+        "cylon.op.join", "cylon.op.shuffle", "cylon.op.shuffle",
+        "cylon.op.groupby"]
+    for inner, outer in (
+            ("cylon.op.shuffle", "cylon.op.join"),
+            ("cylon.host.skew_detect", "cylon.join.shuffle"),
+            ("cylon.host.exchange_plan", "cylon.op.shuffle"),
+            ("cylon.launch.shuffle__round_fn", "cylon.op.shuffle"),
+            ("cylon.host.skew_operands", "cylon.join.shuffle"),
+            ("cylon.host.join_plan", "cylon.op.join"),
+            ("cylon.launch.fused__fused_fn", "cylon.op.groupby")):
+        assert _inside(spans, inner, outer), (inner, outer)
+    # a host step is a stretch of a turn: no launch and no pull inside it
+    bounds = [s for s in spans
+              if s[0].startswith(("cylon.launch.", "cylon.pull."))]
+    for h in (s for s in spans if s[0] in vocabulary - {
+            # the lane pack / unpack ARE enqueues (plain jit, untagged)
+            "cylon.host.exchange_pack", "cylon.host.exchange_unpack"}):
+        assert not any(h[1] <= b[1] and b[2] <= h[2] for b in bounds), h
+    # at most 12 named turns a four-chip query (ISSUE 39's budget)
+    assert sum(n.startswith("cylon.host.") for n in names) == 12
+    # one source, two sinks: the ring holds them beside launch.* / pull.*
+    assert {"op.join", "op.shuffle", "op.groupby", "launch.join__count_fn",
+            "pull.host_array"} | {"host." + s for s in timing.HOST_STEPS} \
+        <= set(ring)
+
+
+@pytest.mark.parametrize("nested", [False, True])
+def test_operator_that_raises_leaves_no_span_open(env4, monkeypatch, nested):
+    """``cylon.op.<op>`` closes whatever the operator raised: every
+    annotation entered is exited, innermost first, and the ring holds the
+    closed spans - nested, the exchange's ``op.shuffle`` (where the error
+    was raised) inside ``op.join``."""
+    from cylon_tpu.parallel import shuffle
+    from cylon_tpu.relational import join_tables
+    left, right = _toy(env4)
+    log = []
+
+    class Spy(timing.TraceAnnotation):
+        def __init__(self, name, **kw):
+            self._name = name
+            super().__init__(name, **kw)
+
+        def __enter__(self):
+            log.append(("in", self._name))
+            return super().__enter__()
+
+        def __exit__(self, *exc):
+            log.append(("out", self._name))
+            return super().__exit__(*exc)
+
+    def boom(*a, **kw):
+        raise InvalidError("boom")
+
+    monkeypatch.setattr(timing, "TraceAnnotation", Spy)
+    if nested:
+        monkeypatch.setattr(shuffle, "exchange", boom)
+    rec = trace.arm(capacity=256)
+    with pytest.raises((InvalidError, CylonKeyError)):
+        join_tables(left, right, "k" if nested else "no_such_column", "k")
+    stack = []
+    for what, name in log:
+        if what == "in":
+            stack.append(name)
+        else:
+            assert stack.pop() == name             # innermost first
+    assert stack == []                             # none left open
+    entered = [n for w, n in log if w == "in"]
+    assert entered[0] == "cylon.op.join"
+    ring = {e[3]: (e[0], e[0] + e[1]) for e in rec.events() if e[2] == "X"}
+    assert "op.join" in ring
+    if nested:
+        assert "cylon.op.shuffle" in entered
+        (a, b), (c, d) = ring["op.shuffle"], ring["op.join"]
+        assert c <= a and b <= d
+
+
+def test_validity_stand_in_is_built_once(env4):
+    """What the matched round trips found (PERF.md §6, PR 39): the skew
+    sampler's all-true validity stand-in was a fresh ``np.ones(cap, bool)``
+    on every distributed join.  ``common.all_valid`` builds it once per
+    capacity: a second join asks for the same read-only block."""
+    from cylon_tpu.relational import common, join_tables
+    left, right = _toy(env4)
+    assert join_tables(left, right, "k", "k").row_count > 0
+    before = common.all_valid.cache_info()
+    assert join_tables(left, right, "k", "k").row_count > 0
+    after = common.all_valid.cache_info()
+    assert after.misses == before.misses and after.hits > before.hits
+    ones = common.all_valid(left.column("k").data.shape[0])
+    assert ones.dtype == bool and ones.all() and not ones.flags.writeable
+
+
+def test_ring_tiles_every_operator_call(env4, tmp_path, monkeypatch):
+    """Armed, the ring holds ``op.*`` and ``host.*`` beside ``launch.*`` /
+    ``pull.*``, and ``scripts/round_trips_report.py`` reads the export:
+    per outermost operator call launch + pull + turn IS the call's span
+    (turn is what is in neither), the named steps inside it."""
+    import importlib.util
+    from cylon_tpu.relational import groupby_aggregate, join_tables
+    left, right = _toy(env4)
+
+    def query():
+        return groupby_aggregate(join_tables(left, right, "k", "k"), "k",
+                                 [("a", "sum"), ("b", "sum")]).row_count
+
+    query()
+    path = str(tmp_path / "p1.ring.json")
+    rec = trace.arm(path=path, capacity=4096)
+    for _ in range(3):
+        query()
+    names = {e[3] for e in rec.events()}
+    assert {"op.join", "op.shuffle", "op.groupby", "host.skew_operands",
+            "host.skew_detect", "host.exchange_plan",
+            "launch.shuffle__round_fn",
+            "pull.host_array"} <= names
+    assert trace.export() == path
+    monkeypatch.setattr(sys, "path", list(sys.path))
+    spec = importlib.util.spec_from_file_location(
+        "_round_trips_report", os.path.join(REPO, "scripts",
+                                            "round_trips_report.py"))
+    report = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(report)
+    calls = report.ring_calls(path)
+    assert [c["op"] for c in calls] == ["op.join", "op.groupby"] * 3
+    for c in calls:
+        assert c["launch_s"] + c["pull_s"] + c["turn_s"] == pytest.approx(
+            c["op_s"], rel=1e-9)
+        assert c["turn_s"] > 0 and c["pull_s"] > 0
+    join = calls[0]["by_name"]
+    assert join["launch.shuffle__round_fn"] > 0
+    assert 0 < join["host.skew_detect"] < calls[0]["turn_s"]
+    assert report.tag_of(path) == "p1"
+    assert any("op.join x3" in ln for ln in report.ring_lines("p1", calls))
 
 
 @pytest.mark.parametrize("how,fused", [("inner", True), ("left", False)])
