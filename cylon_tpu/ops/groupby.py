@@ -24,6 +24,8 @@ std, nunique, quantile/median (+ first/last index helpers).
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -171,14 +173,19 @@ def blocked_cumsum(x):
     """``jnp.cumsum`` of a 1-D array written as scans of at most
     :data:`_SCAN_BLOCK` elements: inside each block of 128, over the
     block totals (recursively), then one add - the two-level form
-    XLA:TPU's scan rewriter gives a long scan itself.  For a 64-bit
-    accumulator on a mesh of more than one device: there the rewriter
-    dies (SIGSEGV, a use after free inside the compiler, in-process)
-    rewriting the (hi, lo) variadic reduce-windows a long 64-bit scan
-    lowers to, once a program holds about four of them (described
-    ``v5e:2x2`` compiles, PERF.md PR 28); a scan it does not rewrite
-    cannot meet that.  Integer sums are equal bit for bit; a float sum is
-    reassociated as the rewriter would."""
+    XLA:TPU's scan rewriter gives a long scan itself.  For the 64-bit
+    scans that are left on a mesh of more than one device - the
+    ``pair64`` form of an integer sum (no bounds, or bounds past int32;
+    the sums of partial sums in ``_final_fn``) and the float64 prefixes:
+    there the rewriter dies (SIGSEGV, a use after free inside the
+    compiler, in-process) rewriting the (hi, lo) variadic reduce-windows
+    a long 64-bit scan lowers to, once a program holds about four of them
+    (described ``v5e:2x2`` compiles, PERF.md PR 28); a scan it does not
+    rewrite cannot meet that.  It buys no time: a sum in blocks costs the
+    flat pair's 1.05 ns a row (PERF.md §5), which is why a sum whose
+    VALUES fit int32 does not come here at all
+    (:func:`carried_cumsum32`).  Integer sums are equal bit for bit; a
+    float sum is reassociated as the rewriter would."""
     n = x.shape[0]
     if n <= _SCAN_BLOCK:
         return jnp.cumsum(x)
@@ -190,9 +197,82 @@ def blocked_cumsum(x):
     return (inner + before[:, None]).reshape(-1)[:n]
 
 
+#: how the prefix of an integer ``sum`` is scanned and how many lanes it
+#: rides, by what the host has proven of the value column (decided in
+#: relational/groupby.sum_scan_form, from dtype and ``Column.bounds``
+#: alone): ``sum32`` - rows * max|v| fits int32, so the SUM does: one int32
+#: scan, one lane; ``val32`` - each VALUE fits int32: 32-bit scans
+#: (:func:`carried_cumsum32`), the exact int64 prefix in two lanes;
+#: ``pair64`` - nothing proven: the int64 scan, two lanes
+SUM_FORMS = ("sum32", "val32", "pair64")
+
+#: block sizes of :func:`carried_cumsum32` that beat its flat form by more
+#: than 5 ms a sum at the cells' 65M rows on v5e, largest first: 22.1 and
+#: 26.2 ms against 34.9 (32 rows: 31.3; 16: 46.0 - the blocks are laid
+#: out rows-minor and short ones scan worse; PERF.md PR 40)
+VAL32_BLOCKS = (128, 64)
+
+
+class SumScan(NamedTuple):
+    """The static descriptor of one integer ``sum``'s prefix scan: a word
+    of :data:`SUM_FORMS` and, for ``val32``, the rows a block of
+    :func:`carried_cumsum32` - the largest of :data:`VAL32_BLOCKS` whose
+    block sums the bounds prove to fit int32, 1 (flat) where none does.
+    What the program caches key on; never the bounds themselves."""
+    form: str
+    block: int = 1
+
+    def __str__(self):
+        return self.form if self.block == 1 else f"{self.form}/{self.block}"
+
+
+def carried_cumsum32(x, block: int = 1):
+    """The exact int64 ``cumsum`` of an int32 array as its two 32-bit
+    words ``(hi, lo)``, int32 each - the (hi, lo) u32 lanes of the
+    prefix, bit for bit - in int32 scans and no 64-bit operation.
+
+    Flat (``block`` 1): ``lo = cumsum(x)`` wrapping mod 2^32 IS the low
+    word; step p carries out of it exactly when the wrapped ``lo[p]`` is
+    below ``u32(x[p])``, and a negative x[p] (sign-extended: 2^32 - 1 in
+    the high word) borrows one, so ``hi = cumsum(carry - neg)``: two
+    row-length scans.  In blocks (the caller has proven ``block *
+    max|x|`` to fit int32): an int32 ``cumsum`` inside each block is
+    exact, the flat form runs over the N/block block totals alone, and
+    ``before[block] + local`` is one 32-bit add with the same carry rule:
+    ONE row-length scan.  Integer addition is associative, so however the
+    compiler trees the scans the words are those of
+    ``cumsum(x.astype(int64))``.
+
+    Why not the int64 scan: XLA:TPU lowers it to a VARIADIC two-operand
+    (hi, lo) ``reduce-window`` at 1.05 ns a row - this is 0.34 / 0.40 in
+    blocks of 128 / 64 and 0.54 flat (PERF.md PR 40) - every row-length
+    64-bit value compiles for a minute at the cells' 65M rows against
+    seconds, and on a mesh it is the scan the rewriter faults on
+    (:func:`blocked_cumsum`)."""
+    def words(t):
+        lo = jnp.cumsum(t, dtype=jnp.int32)
+        step = (_u32(lo) < _u32(t)).astype(jnp.int32) \
+            - (t < 0).astype(jnp.int32)
+        return jnp.cumsum(step, dtype=jnp.int32), lo, step
+
+    if block <= 1:
+        return words(x)[:2]
+    n = x.shape[0]
+    m = -(-n // block)
+    inner = jnp.cumsum(jnp.pad(x, (0, m * block - n)).reshape(m, block),
+                       axis=1, dtype=jnp.int32)
+    total = inner[:, -1]
+    hi_t, lo_t, step = words(total)
+    blo, bhi = (lo_t - total)[:, None], (hi_t - step)[:, None]  # exclusive
+    lo = blo + inner                                            # wraps
+    hi = bhi + (_u32(lo) < _u32(blo)).astype(jnp.int32) \
+        - (inner < 0).astype(jnp.int32)
+    return hi.reshape(-1)[:n], lo.reshape(-1)[:n]
+
+
 def grouped_reduce(ops, values_list, vmasks, starts, n_live, key_datas,
                    key_valids, seg_cap: int, key_narrow=None,
-                   value_narrow=None, use_window: int = 0,
+                   sum_forms=None, use_window: int = 0,
                    blocked_scans: bool = False):
     """Grouped-input fast path, fully batched: per-group sums for the
     cumsum-able ops (sum/count/mean/var/std) AND the representative-key
@@ -203,13 +283,20 @@ def grouped_reduce(ops, values_list, vmasks, starts, n_live, key_datas,
     PS[starts[g]], with PS the zero-padded exclusive prefix of x and
     starts[n_groups..] = n_live — so a single (seg_cap, L) gather of the
     stacked prefix lanes at ``starts`` + a consecutive diff replaces every
-    per-column reduction pass (gathers are the dominant groupby cost on
-    TPU, ~15 ns/row; splitting an i64 prefix into (hi, lo) u32 lanes is
-    elementwise ~1 ns/row).  Key columns and their validity ride the same
-    gather as passthrough lanes; ``key_narrow[i]`` (host-known bounds fit
-    int32) rides a 64-bit key as ONE lane; ``value_narrow[i]`` (host-proven
-    n·max|v| fits int32 — a BOOLEAN so compiled-fn caches key on it, not on
-    raw data bounds) narrows the i-th op's integer SUM prefix to one lane.
+    per-column reduction pass (the gather is ~15 ns a slot through XLA,
+    a sixth of that through the windowed kernel; splitting an i64 prefix
+    into (hi, lo) u32 lanes is elementwise, but SCANNING it in 64 bits is
+    not cheap: 1.05 ns a row a sum, 3-6 x an int32 scan).  Key columns and
+    their validity ride the same gather as passthrough lanes;
+    ``key_narrow[i]`` (host-known bounds fit int32) rides a 64-bit key as
+    ONE lane; ``sum_forms[i]`` (a :class:`SumScan`, or None - a WORD and
+    a block size, so compiled-fn caches key on the decision, not on raw
+    data bounds) says how the i-th op's integer SUM prefix is scanned:
+    ``sum32`` one int32 scan and one lane, ``val32`` the 32-bit scans of
+    :func:`carried_cumsum32` and the same two lanes as ``pair64``, which
+    is the int64 scan and what None means.  Counts are int32 scans
+    whatever it says; float / ``sumsq`` / ``mean`` / ``var`` prefixes do
+    not read it.
 
     ``use_window`` (a window size, 0 = off) routes the u32 matrix gather
     through the Pallas windowed kernel (ops/pallas_gather) — ~6x the XLA
@@ -219,8 +306,9 @@ def grouped_reduce(ops, values_list, vmasks, starts, n_live, key_datas,
     the DISPATCH layer must re-run with use_window=0).
 
     ``blocked_scans``: the program is compiled for more than one device
-    (relational/common.multi_shard), so every 64-bit prefix sum is a
-    :func:`blocked_cumsum`."""
+    (relational/common.multi_shard), so every 64-bit prefix sum that is
+    left (``pair64``, float64) is a :func:`blocked_cumsum`; the 32-bit
+    forms hold nothing it guards against."""
     from . import lanes as lanes_mod
     n = key_datas[0].shape[0]
 
@@ -250,23 +338,35 @@ def grouped_reduce(ops, values_list, vmasks, starts, n_live, key_datas,
                 recipes.append(("prefix", islot, name, "f64",
                                 (len(f64_cols) - 1,), None))
             return
-        # a count never passes the row count (< 2^31): its prefix is an
-        # int32 scan, one lane, not an (hi, lo) pair narrowed afterwards
-        acc = jnp.int32 if name == "count" else acc_i
-        with stage("scan"):
-            ps = jnp.concatenate([jnp.zeros(1, acc),
-                                  prefix_sum(src.astype(acc))])
-        narrow = name == "count" or (
-            name == "sum" and value_narrow is not None
-            and bool(value_narrow[islot]))
-        narrow = narrow or np.dtype(ps.dtype).itemsize == 4
+        form = sum_forms[islot] if name == "sum" and sum_forms else None
+        word = form.form if form else "pair64"
+        # a count never passes the row count (< 2^31), a ``sum32`` sum
+        # never passes int32: the prefix is an int32 scan, one lane, not
+        # an (hi, lo) pair narrowed afterwards
+        narrow = name == "count" or word == "sum32" \
+            or np.dtype(acc_i).itemsize == 4
+        acc = jnp.int32 if narrow else acc_i
+        if word == "val32" and not narrow:
+            # the exact int64 prefix, scanned in 32 bits: its words ARE
+            # the two lanes
+            with stage("scan"):
+                words = [jnp.concatenate([jnp.zeros(1, jnp.int32), w])
+                         for w in carried_cumsum32(src.astype(jnp.int32),
+                                                   form.block)]
+        else:
+            with stage("scan"):
+                ps = jnp.concatenate([jnp.zeros(1, acc),
+                                      prefix_sum(src.astype(acc))])
+            words = [ps]
         with stage("pack"):
-            ls = lanes_mod._to_lanes(ps, narrow)   # 1 lane narrow, else (hi, lo)
+            # 1 lane narrow, else (hi, lo)
+            ls = [l for w in words for l in lanes_mod._to_lanes(w, narrow)]
         u32_cols.extend(ls)
         recipes.append(("prefix", islot, name, "u32",
                         tuple(range(len(u32_cols) - len(ls),
                                     len(u32_cols))),
-                        ("int32" if np.dtype(ps.dtype).itemsize == 4
+                        ("int32" if name == "count"
+                         or np.dtype(acc_i).itemsize == 4
                          else "int64", narrow)))
 
     @staged("pack")

@@ -29,6 +29,7 @@ transparently) unless every condition holds.
 
 from __future__ import annotations
 
+from types import SimpleNamespace
 from typing import NamedTuple
 
 import jax
@@ -84,6 +85,7 @@ class JoinState(NamedTuple):
     names: tuple
     types: tuple
     dicts: tuple
+    bounds: tuple        # host-known (lo, hi) per output column, or None
     key_names: tuple     # join-key output column names (== left_on)
     cap_l: int
     cap_r: int
@@ -118,12 +120,15 @@ def _col_entry(state: JoinState, name: str):
 @program_cache()
 def _fused_fn(mesh: Mesh, n_l: int, all_live: bool, lspec, rspec, layout,
               vspecs: tuple, key_cols: tuple, key_narrow: tuple,
-              seg_cap: int, ddof: int, use_window: int = 0):
+              seg_cap: int, ddof: int, use_window: int = 0,
+              sum_forms: tuple = ()):
     """Per-shard fused join+groupby kernel.
 
     ``vspecs``: per aggregation (side, lane_col_idx, op); ``key_cols``:
     left lane-col index per groupby key; ``layout``: what ``pl_s`` holds
-    (ops/join.PayloadLayout).  Live rows form a sorted PREFIX
+    (ops/join.PayloadLayout); ``sum_forms``: per aggregation, how an
+    integer ``sum`` is scanned (relational/groupby.sum_scan_form's
+    descriptor; empty = ``pair64`` everywhere).  Live rows form a sorted PREFIX
     (the row-liveness operand sorts padding last), so liveness is a
     position compare — no gather (ops/join.live_sides, the one statement
     of that rule)."""
@@ -205,6 +210,7 @@ def _fused_fn(mesh: Mesh, n_l: int, all_live: bool, lspec, rspec, layout,
         inters, key_out, kval_out, wok = gbk.grouped_reduce(
             ops_list, vals, masks, starts, n_live, key_datas,
             key_valids, seg_cap, key_narrow=key_narrow,
+            sum_forms=sum_forms,
             use_window=use_window, blocked_scans=multi_shard())
         l_cnt = inters[-2]["count"]
         r_cnt = inters[-1]["count"]
@@ -325,17 +331,22 @@ def try_begin_join_groupby(table: Table, by: list, specs: list,
 
     env = table.env
     from .groupby import (PendingReduce, _density_window, _result_table,
-                          _result_types, _shrink, dispatch_at_bucket)
+                          _result_types, _shrink, _sum_forms,
+                          dispatch_at_bucket)
     # result typing from the join output schema
-    class _C:  # minimal stand-in with .type/.dictionary for _result_types
-        def __init__(self, t, dc):
-            self.type, self.dictionary = t, dc
-    val_cols = [_C(state.types[state.names.index(c)],
-                   state.dicts[state.names.index(c)]) for c, _, _, _ in specs]
+    def col(name):
+        # a stand-in with .type / .dictionary / .bounds that keeps no
+        # reference to ``state``: its device arrays go when the query does
+        i = state.names.index(name)
+        return SimpleNamespace(type=state.types[i],
+                               dictionary=state.dicts[i],
+                               bounds=state.bounds[i])
+    val_cols = [col(c) for c, _, _, _ in specs]
     res_types, res_dicts = _result_types(specs, val_cols)
-    by_cols = [_C(state.types[state.names.index(k)],
-                  state.dicts[state.names.index(k)]) for k in by]
+    by_cols = [col(k) for k in by]
     res_names = [n for _, _, _, n in specs]
+    # the prefixes run over the whole concatenated state
+    sum_forms = _sum_forms(specs, val_cols, state.cap_l + state.cap_r)
 
     args = (state.vcl, state.vcr, state.idx_s, state.bnd, state.pl_s)
     live = np.asarray(state.vcl, np.int64) + np.asarray(state.vcr, np.int64)
@@ -344,7 +355,7 @@ def try_begin_join_groupby(table: Table, by: list, specs: list,
         return _fused_fn(env.mesh, state.cap_l, state.all_live, state.lspec,
                          state.rspec, state.layout, tuple(vspecs),
                          tuple(key_cols), tuple(key_narrow), sc, ddof,
-                         win)(*args)
+                         win, sum_forms)(*args)
 
     def read_meta(res):
         # n_groups and the windowed gather's span flag, one pull

@@ -372,8 +372,8 @@ def _runs_reduce(specs_ops, val_datas, vmasks, gids, first, mask, vc,
         [b[0] for b in batch], [val_datas[b[1]] for b in batch],
         [vmasks[b[1]] for b in batch], starts, n_live,
         list(by_datas), list(by_valids), seg_cap, key_narrow=narrow,
-        value_narrow=[(bool(vnarrow[b[1]]) if vnarrow else False)
-                      for b in batch], use_window=use_window,
+        sum_forms=[(vnarrow[b[1]] if vnarrow else None)
+                   for b in batch], use_window=use_window,
         blocked_scans=multi_shard())
     inters: dict = {}
     for (op, i), d in zip(batch, inters_b):
@@ -390,17 +390,20 @@ def _runs_reduce(specs_ops, val_datas, vmasks, gids, first, mask, vc,
 
 @program_cache()
 def _combine_fn(mesh: Mesh, ops: tuple, seg_cap: int, grouped: bool,
-                narrow: tuple, vspec=None, val_map: tuple = (),
-                use_window: int = 0):
+                narrow: tuple, vnarrow: tuple = (), vspec=None,
+                val_map: tuple = (), use_window: int = 0):
     """Phase 1 per shard: group keys, reduce each (col, op) into
     intermediate arrays of static length seg_cap (rank-ordered dense
     prefix), gather per-group key representatives.  With ``vspec`` the
     value/key columns ride the rank sort (see :func:`_sort_state`) and the
     intermediates come from the run-contiguous prefix-diff machinery
-    instead of per-op segment scatters.  Sum intermediates are never
-    narrowed here — phase 2 sums them AGAIN across shards, so the
-    single-shard rows·max|v| < 2^31 proof does not cover them.
-    ``use_window`` and the last output: as :func:`_raw_fn`'s."""
+    instead of per-op segment scatters.  Sum intermediates never ride ONE
+    lane here — phase 2 sums them AGAIN across shards, so the
+    single-shard rows·max|v| < 2^31 proof does not cover them — which is
+    about the lane, not the scan: ``vnarrow`` (:func:`_raw_fn`'s, asked of
+    :func:`sum_scan_form` with ``two_lanes``) scans a sum whose values fit
+    int32 in 32 bits.  ``use_window`` and the last output: as
+    :func:`_raw_fn`'s."""
 
     def per_shard(vc, by_datas, by_valids, uval_datas, uval_valids):
         if vspec is not None and not grouped:
@@ -418,7 +421,7 @@ def _combine_fn(mesh: Mesh, ops: tuple, seg_cap: int, grouped: bool,
         if first is not None:
             inters, key_out, kval_out, win_ok = _runs_reduce(
                 ops, val_datas, vmasks, gids, first, mask, vc, seg_cap,
-                by_datas, by_valids, narrow, (), use_window)
+                by_datas, by_valids, narrow, vnarrow, use_window)
             inter_out = [tuple(inters[i][k] for k in INTER_NAMES[op])
                          for i, op in enumerate(ops)]
         else:
@@ -508,11 +511,12 @@ def _raw_fn(mesh: Mesh, specs: tuple, seg_cap: int, ddof: int, grouped: bool,
             val_map: tuple = (), use_window: int = 0):
     """Single-phase per shard over raw (already co-located) rows — used for
     non-associative ops, the local path, and the grouped-input fast path
-    (join/sort output: no shuffle, no rank sort).  ``vnarrow``: host-proven
-    boolean per value column (rows·max|v| fits int32 — derived from
-    ``Column.bounds``, reduced to a bool so this cache keys on the
-    decision, not on per-batch data bounds), letting the grouped path
-    narrow integer sum-prefix lanes.
+    (join/sort output: no shuffle, no rank sort).  ``vnarrow``: per spec,
+    how an integer ``sum`` of that value column is scanned and how many
+    lanes its prefix rides — :func:`sum_scan_form`'s descriptor, derived
+    from the column's dtype and ``Column.bounds`` and reduced to one of
+    three words and a block size so this cache keys on the decision, not
+    on per-batch data bounds (``ops/groupby.SumScan``).
 
     ``vspec`` (non-grouped inputs only): a :class:`~.lanes.LaneSpec` over
     (value columns per spec ++ key columns) — the SORT PATH
@@ -870,12 +874,15 @@ def _groupby_aggregate_impl(table: Table, by, aggs, ddof: int = 1) -> Table:
         cspec = _plan_vspec(uval_cols, by_cols, narrow,
                             sum(len(INTER_NAMES[op]) for op in ops_t))
         cargs = (vc, by_datas, by_valids, uval_datas, uval_valids)
+        # the partial sums are summed again across shards: two lanes
+        cforms = _sum_forms(specs, val_cols, cap_full, two_lanes=True)
         (key_out, kval_out, inter_out, _), n_groups = dispatch_at_bucket(
             _SEG_CACHE,
             ("combine-seg", env.serial, ops_t, tuple(by), narrow, cap_full,
              int(table.valid_counts.sum())), cap_full,
             lambda sc, win: _combine_fn(env.mesh, ops_t, sc, False, narrow,
-                                        cspec, val_map, win)(*cargs),
+                                        cforms, cspec, val_map,
+                                        win)(*cargs),
             partial(_read_meta, env.world_size),
             # the gather the window serves exists on the sort path alone
             _density_window(env.mesh, table.valid_counts)
@@ -905,6 +912,11 @@ def _groupby_aggregate_impl(table: Table, by, aggs, ddof: int = 1) -> Table:
         key2, kval2, res_d, res_v, ng2 = _final_fn(
             env.mesh, ops_t, fin_cap, ddof, narrow)(
                 vc2, s_by_datas, s_by_valids, inter_by_op)
+        # phase 2 sums partial sums, whose bounds nobody knows
+        _SUM_SCANS["pair64"].inc(sum(
+            nm not in ("min", "max") and np.dtype(a.dtype).kind in "iu"
+            for op, arrs in zip(ops_t, inter_by_op)
+            for nm, a in zip(INTER_NAMES[op], arrs)))
         ng2 = host_array(ng2).astype(np.int64)
         out = _result_table(env, by, by_cols, key2, kval2, res_names, res_d,
                             res_v, res_types, res_dicts, ng2)
@@ -937,14 +949,8 @@ def _groupby_aggregate_impl(table: Table, by, aggs, ddof: int = 1) -> Table:
     spec_t = tuple((op, q) for _, op, q, _ in specs)
     cap_full = max(work.capacity, 1)
 
-    def sum_fits_i32(col: Column) -> bool:
-        b = col.bounds
-        if b is None or col.data.dtype.kind not in ("i", "u"):
-            return False
-        m = max(abs(int(b[0])), abs(int(b[1])))
-        return m * cap_full < (1 << 31)
-
-    vnarrow = tuple(sum_fits_i32(work.column(c)) for c, _, _, _ in specs)
+    vnarrow = _sum_forms(specs, [work.column(c) for c, _, _, _ in specs],
+                         cap_full)
 
     # sort-path lane spec (non-grouped inputs): value + key columns ride the
     # rank sort as u32 lanes when all are laneable and the lane count is
@@ -995,7 +1001,7 @@ def _decl_args(mesh, cap=1024):
 
 def _trace_combine(mesh):
     _w, _S, vc, keys, valids, vals = _decl_args(mesh)
-    fn = _unwrap(_combine_fn(mesh, ("sum",), 256, False, (False,),
+    fn = _unwrap(_combine_fn(mesh, ("sum",), 256, False, (False,), (),
                              None, (0,)))
     return jax.make_jaxpr(fn)(vc, keys, valids, vals, valids)
 
@@ -1063,3 +1069,60 @@ def _note_settled(bucket, seg_cap, allowed, win, window, n_groups):
     if node is not None and node.op == "groupby":
         node.annotate(segment_space=int(bucket), window=int(win),
                       **({} if dens is None else {"density": round(dens, 6)}))
+
+
+# ---------------------------------------------------------------------------
+# how an integer ``sum`` is scanned (host side; ``ops/groupby.SUM_FORMS``)
+# ---------------------------------------------------------------------------
+
+#: one count an integer ``sum`` a dispatched groupby, by the form its
+#: prefix scan took; registered at import so that a snapshot shows the
+#: whole family
+_SUM_SCANS = {form: _metrics.counter("grouped_sum_scans", form=form)
+              for form in gbk.SUM_FORMS}
+
+
+def sum_scan_form(dtype, bounds, rows: int,
+                  two_lanes: bool = False) -> gbk.SumScan:
+    """THE rule of how a grouped integer ``sum`` over a value column of
+    physical ``dtype`` with host-known ``bounds`` (``Column.bounds``: (lo,
+    hi) or None) is scanned over ``rows`` rows a shard, read from what
+    the column says of itself and nothing else: ``sum32`` where rows *
+    max|v| fits int32 (so every prefix does; not with ``two_lanes``: the
+    sums are summed again, and one shard's proof does not cover the
+    lane); ``val32`` where each value does, in the largest blocks of
+    ``ops/groupby.VAL32_BLOCKS`` whose sums fit int32 too (flat where none
+    does: a column that uses int32's width); ``pair64`` where nothing is
+    proven - no bounds (a derived column), bounds past int32 (a uint64 or
+    int64 column that uses its width, a decimal whose scaled bounds pass)
+    or not an integer at all (whose prefixes never read it)."""
+    if bounds is None or np.dtype(dtype).kind not in ("i", "u"):
+        return gbk.SumScan("pair64")
+    lo, hi = int(bounds[0]), int(bounds[1])
+    top = max(abs(lo), abs(hi))
+    if top * rows < (1 << 31) and not two_lanes:
+        return gbk.SumScan("sum32")
+    if lo >= -(1 << 31) and hi < (1 << 31):
+        return gbk.SumScan("val32", next(
+            (b for b in gbk.VAL32_BLOCKS if b * top < (1 << 31)), 1))
+    return gbk.SumScan("pair64")
+
+
+def _sum_forms(specs, val_cols, rows: int, two_lanes: bool = False) -> tuple:
+    """:func:`sum_scan_form` of each spec's value column (anything with
+    ``.type`` and ``.bounds``), and the one place the scans are counted
+    and shown: a count a spec whose op is an integer ``sum``, by form, and
+    ``sum_scan=`` those descriptors on the groupby plan node."""
+    from ..obs import plan as _plan
+    forms, summed = [], []
+    for (_c, op, _q, _n), col in zip(specs, val_cols):
+        dt = physical_np_dtype(col.type)
+        form = sum_scan_form(dt, col.bounds, rows, two_lanes)
+        forms.append(form)
+        if op == "sum" and dt.kind in ("i", "u", "b"):
+            _SUM_SCANS[form.form].inc()
+            summed.append(str(form))
+    node = _plan.current()          # None with no profile on
+    if summed and node is not None and node.op == "groupby":
+        node.annotate(sum_scan=tuple(summed))
+    return tuple(forms)

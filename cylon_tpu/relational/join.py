@@ -938,7 +938,8 @@ def _join_packed_impl(pl: PackedPiece, pr: PackedPiece, left_on, right_on,
             vcl=vcl, vcr=vcr, idx_s=idx_s_s, bnd=bnd_s, pl_s=pl_s,
             lspec=pl.spec, rspec=pr.spec, layout=layout, plan=plan,
             names=names,
-            types=types, dicts=dicts, key_names=tuple(left_on),
+            types=types, dicts=dicts, bounds=bounds,
+            key_names=tuple(left_on),
             cap_l=cap_l, cap_r=cap_r, all_live=all_live)
         out = DeferredTable(
             env, None, None, materialize_cols,
@@ -1454,7 +1455,7 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
             vcl=vcl, vcr=vcr, idx_s=idx_s_s, bnd=bnd_s, pl_s=pl_s,
             lspec=lspec, rspec=rspec, layout=layout, plan=tuple(plan),
             names=tuple(names), types=tuple(types), dicts=tuple(dicts),
-            key_names=tuple(left_on),
+            bounds=tuple(bounds), key_names=tuple(left_on),
             cap_l=lwork.capacity, cap_r=rwork.capacity, all_live=all_live,
             skew_plan=skew_plan,
             pre_thunk=pre_table if skew_plan is not None else None)

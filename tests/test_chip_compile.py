@@ -174,10 +174,11 @@ def test_fused_join_groupby_compiles_for_v5e(mesh1, env1, monkeypatch):
     from cylon_tpu.exec import compiler
     fused_fn, resident, _, _ = _capture_main_path(env1, monkeypatch)
     static, args = resident[-1]
-    assert len(static) == 11                      # ..., seg_cap@8, ddof, w
+    assert len(static) == 12        # ..., seg_cap@8, ddof, w, sum_forms
     seg_cap = static[8]
     assert seg_cap % 256 == 0 and seg_cap > 512, seg_cap
-    prog = fused_fn(mesh1, *static[:10], 1024)
+    assert [str(f) for f in static[11]] == ["val32/128"] * 2  # under 2^24
+    prog = fused_fn(mesh1, *static[:10], 1024, *static[11:])
     # steer the kernel off interpret mode: the builder asks the backend
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled = compiler.aot_compile(prog, *_abstract(args, mesh1))
@@ -252,34 +253,63 @@ def _fused_static(n_sums: int = 2):
     return _join_specs(n_sums) + (vspecs, (0,), (True,))
 
 
-@pytest.mark.parametrize("n_sums", [2, 4])
-def test_first_sight_compiles_for_four_chips(mesh4, n_sums):
+def _scan(shown: str):
+    """``ops/groupby.SumScan`` from the way it prints: ``val32/64``."""
+    from cylon_tpu.ops.groupby import SumScan
+    word, _, block = shown.partition("/")
+    return SumScan(word, int(block or 1))
+
+
+def _wide_scans(compiled) -> list:
+    """The ``reduce-window`` instructions of the optimised text that scan
+    an (hi, lo) PAIR - what a 64-bit ``cumsum`` is on XLA:TPU."""
+    import re
+    return re.findall(r"(?m)^.* = \(.+\) reduce-window\(", compiled.as_text())
+
+
+@pytest.mark.parametrize("n_sums,form", [(2, "pair64"), (4, "pair64"),
+                                         (2, "val32/128")])
+def test_first_sight_compiles_for_four_chips(mesh4, n_sums, form):
     """The first dispatch of a fused callsite: 512 segment slots, always
     XLA's gather (relational/groupby._FIRST_SEG_CAP).  Every four-chip run
-    meets this program first."""
+    meets this program first - with the sums scanned as the cells' bounded
+    columns are (``val32`` in blocks of 128, their values being under
+    2^24: no 64-bit scan in the program at all) and as an unbounded
+    column's (``pair64``, in blocks)."""
     from cylon_tpu.exec import compiler
     from cylon_tpu.relational import fused
     static = _fused_static(n_sums)
-    prog = fused._fused_fn(mesh4, _ROWS4, False, *static, 512, 1)
+    prog = fused._fused_fn(mesh4, _ROWS4, False, *static, 512, 1,
+                           sum_forms=(_scan(form),) * n_sums)
     compiled = compiler.aot_compile(
         prog, *_fused_args(mesh4, _ROWS4, static[2]))
     assert not _has_kernel(compiled)
+    assert bool(_wide_scans(compiled)) == (form == "pair64")
 
 
-@pytest.mark.parametrize("window,n_sums", [(4096, 2), (0, 2), (4096, 4)])
-def test_fused_compiles_for_four_chips(mesh4, monkeypatch, window, n_sums):
+@pytest.mark.parametrize("window,n_sums,form", [
+    (4096, 2, "val32/128"), (0, 2, "val32/128"), (4096, 4, "val32"),
+    (4096, 4, "pair64")])
+def test_fused_compiles_for_four_chips(mesh4, monkeypatch, window, n_sums,
+                                       form):
     """The settled dispatch at segment space 3,407,872 (density 0.2): with
     the windowed Pallas gather inside, as an eligible callsite runs it
     (the cell's two sums, and four), and with XLA's gather, as one below
-    the density floor does."""
+    the density floor does.  The cells' sums are ``val32`` (ISSUE 40):
+    32-bit scans - in blocks of 128, or flat as a column that uses int32's
+    width gets them - and no (hi, lo) pair scan left for PR 28's rewriter
+    fault to meet; four ``pair64`` sums are what the fault was found on
+    and stay in blocks."""
     from cylon_tpu.exec import compiler
     from cylon_tpu.relational import fused
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     static = _fused_static(n_sums)
-    prog = fused._fused_fn(mesh4, _ROWS4, False, *static, 3407872, 1, window)
+    prog = fused._fused_fn(mesh4, _ROWS4, False, *static, 3407872, 1, window,
+                           sum_forms=(_scan(form),) * n_sums)
     compiled = compiler.aot_compile(
         prog, *_fused_args(mesh4, _ROWS4, static[2]))
     assert _has_kernel(compiled) == bool(window)
+    assert bool(_wide_scans(compiled)) == (form == "pair64")
 
 
 @pytest.mark.parametrize("world,cap", [(1, 1 << 16), (4, _ROWS4)])
@@ -375,15 +405,18 @@ def _groupby_args(mesh, cap: int):
     return S((w,), np.int32, sharding=rep), (col,), (None,), (col,), (None,)
 
 
-def _groupby_program(mesh, site: str, seg_cap: int, window: int):
+def _groupby_program(mesh, site: str, seg_cap: int, window: int,
+                     form: str = "val32/64"):
+    """``form``: how the sum is scanned; ``val32`` in blocks of 64 is what
+    the cell's bounded column gets (relational/groupby.sum_scan_form)."""
     from cylon_tpu.ops import lanes
     from cylon_tpu.relational import groupby as rel_gb
     vspec = lanes.plan_lanes(("int64", "int64"), (False, False), (True, True))
     if site == "combine":
         return rel_gb._combine_fn(mesh, ("sum",), seg_cap, False, (True,),
-                                  vspec, (0,), window)
+                                  (_scan(form),), vspec, (0,), window)
     return rel_gb._raw_fn(mesh, (("sum", 0.5),), seg_cap, 1, False, (True,),
-                          (False,), vspec, (0,), window)
+                          (_scan(form),), vspec, (0,), window)
 
 
 @pytest.mark.parametrize("cap,seg_cap", [
@@ -403,15 +436,19 @@ def test_windowed_raw_groupby_compiles_for_v5e(mesh1, monkeypatch, cap,
         _groupby_program(mesh1, "raw", seg_cap, 1024),
         *_groupby_args(mesh1, cap))
     assert _has_kernel(compiled)
+    assert not _wide_scans(compiled)
 
 
-@pytest.mark.parametrize("site", ["combine", "raw"])
-def test_windowed_groupby_compiles_for_four_chips(mesh4, monkeypatch, site):
+@pytest.mark.parametrize("site,form", [("combine", "val32/64"),
+                                       ("raw", "val32"), ("raw", "pair64")])
+def test_windowed_groupby_compiles_for_four_chips(mesh4, monkeypatch, site,
+                                                  form):
     """Phase 1 of the distributed associative groupby and the raw route on
     a mesh of four, with the kernel inside: forms no chip has run yet."""
     from cylon_tpu.exec import compiler
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled = compiler.aot_compile(
-        _groupby_program(mesh4, site, 16384, 1024),
+        _groupby_program(mesh4, site, 16384, 1024, form),
         *_groupby_args(mesh4, 17408))
     assert _has_kernel(compiled)
+    assert bool(_wide_scans(compiled)) == (form == "pair64")

@@ -101,13 +101,13 @@ def test_fused_windowed_gather_survives_capacity_padding(env1, rng,
                       [("a", "sum"), ("b", "sum")]).to_pandas()
     monkeypatch.undo()
     static, args, plain = calls[-1]
-    assert static[-1] == 0 and static[8] % pg.TILE == 0
+    assert static[10] == 0 and static[8] % pg.TILE == 0
     # interpret mode on the CPU; jax 0.9's Pallas interpreter cannot type
     # varying axes inside shard_map, so this one program skips that check
     from functools import partial
     monkeypatch.setattr(fused, "shard_map",
                         partial(jax.shard_map, check_vma=False))
-    win = orig(env1.mesh, *static[:10], 4096)(*args)
+    win = orig(env1.mesh, *static[:10], 4096, *static[11:])(*args)
     n_groups, wok = np.asarray(win[4]).reshape(-1, 2)[0]
     assert wok == 1, "the windowed gather reported a span overflow"
     assert n_groups == np.asarray(plain[4]).reshape(-1, 2)[0][0]
