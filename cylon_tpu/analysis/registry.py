@@ -35,6 +35,7 @@ BUILDER_MODULES = (
     "cylon_tpu.exec.recovery",
     "cylon_tpu.exec.integrity",
     "cylon_tpu.stream.window",
+    "cylon_tpu.series",
 )
 
 #: default bound on distinct compiled programs per builder per session

@@ -27,14 +27,6 @@ from .column import Column
 from .dtypes import LogicalType, from_numpy_dtype, physical_np_dtype
 
 
-def _sorted_dictionary(indices: np.ndarray, values: np.ndarray):
-    """Re-code onto a sorted unique dictionary (code order == lexical)."""
-    uniq, remap = np.unique(values, return_inverse=True)
-    codes = remap.astype(np.int32)[np.clip(indices, 0, len(values) - 1)] \
-        if len(values) else indices.astype(np.int32)
-    return codes, uniq
-
-
 def column_from_arrow(arr) -> Column:
     """pyarrow Array/ChunkedArray -> host Column (no pandas round trip)."""
     import pyarrow as pa
@@ -55,17 +47,13 @@ def column_from_arrow(arr) -> Column:
             return column_from_arrow(inner)
         idx = np.asarray(arr.indices.fill_null(0))
         vals = np.asarray(arr.dictionary, dtype=object)
-        vals = np.asarray([v if isinstance(v, str) else str(v)
-                           for v in vals], dtype=object)
-        codes, uniq = _sorted_dictionary(idx, vals)
-        return Column(codes, LogicalType.STRING, validity, uniq)
+        return Column.from_dictionary(idx, vals, validity)
 
     if pa.types.is_string(t) or pa.types.is_large_string(t):
         enc = pc.dictionary_encode(arr.fill_null(""))
         idx = np.asarray(enc.indices.fill_null(0))
-        vals = np.asarray(enc.dictionary, dtype=object)
-        codes, uniq = _sorted_dictionary(idx, vals)
-        return Column(codes, LogicalType.STRING, validity, uniq)
+        return Column.from_dictionary(
+            idx, np.asarray(enc.dictionary, dtype=object), validity)
 
     if pa.types.is_timestamp(t) or pa.types.is_date(t):
         arr = arr.cast(pa.timestamp("ns"))

@@ -201,6 +201,48 @@ class Column:
         return Column(arr, lt, bounds=bounds)
 
     @staticmethod
+    def from_scaled_ints(values: np.ndarray, scale: int,
+                         precision: int | None = None,
+                         validity: np.ndarray | None = None) -> "Column":
+        """The typed way in for DECIMAL: a HOST column from the UNSCALED
+        integers (``value * 10**scale``, e.g. cents for scale 2) - no
+        Python object a row, so 30M values cost what an int64 column
+        costs.  ``precision`` is the declared one (``decimal(15,2)`` -> 15;
+        default: the digits of the largest value, as the object path
+        sets it); bounds are taken from the data as for an integer
+        column, which is what lets arithmetic on the column prove its
+        results fit (series.Series._decimal_arith)."""
+        data = np.ascontiguousarray(values, dtype=np.int64)
+        if validity is not None:
+            data = np.where(validity, data, 0)
+        bounds = (int(data.min()), int(data.max())) if data.size else None
+        digits = len(str(max(abs(bounds[0]), abs(bounds[1])))) \
+            if bounds else 1
+        if precision is not None and digits > precision:
+            raise CylonTypeError(
+                f"decimal({precision},{scale}) cannot hold a value of "
+                f"{digits} digits")
+        return Column(data, LogicalType.DECIMAL, validity,
+                      DecimalScale(precision or digits, scale),
+                      bounds=bounds)
+
+    @staticmethod
+    def from_dictionary(codes: np.ndarray, values,
+                        validity: np.ndarray | None = None) -> "Column":
+        """A HOST STRING column from dictionary codes and their value table
+        (pandas ``Categorical`` codes / categories, an Arrow dictionary):
+        re-coded onto the SORTED unique values - code order == lexical
+        order, the invariant of every string column - at the cost of one
+        int32 gather a row, with no string touched a row."""
+        values = np.asarray([str(v) for v in values], dtype=object)
+        uniq, remap = np.unique(values, return_inverse=True)
+        codes = np.asarray(codes)
+        if len(values):
+            codes = remap.astype(np.int32)[np.clip(codes, 0, len(values) - 1)]
+        return Column(codes.astype(np.int32, copy=False), LogicalType.STRING,
+                      validity, np.asarray(uniq, dtype=object))
+
+    @staticmethod
     def _decimal_from_objects(arr: np.ndarray, mask: np.ndarray) -> "Column":
         """Object array of decimal.Decimal -> scaled-int64 DECIMAL column
         (exact for precision <= 18; reference: decimal128 comparators)."""
@@ -225,15 +267,11 @@ class Column:
                     raise CylonTypeError(
                         "mixed decimal column; cast uniformly before "
                         "ingest") from e
-        validity = ~mask if mask.any() else None
-        bounds = ((int(data.min()), int(data.max())) if len(data) else None)
         # tight precision (actual digit count): leaves headroom for later
         # 10^Δ rescales against finer-scaled partners (the 18 cap is the
         # int64 representation's, not each column's)
-        max_abs = int(np.abs(data).max()) if len(data) else 0
-        prec = max(len(str(max_abs)), 1)
-        return Column(data, LogicalType.DECIMAL, validity,
-                      DecimalScale(prec, scale), bounds=bounds)
+        return Column.from_scaled_ints(data, scale,
+                                       validity=~mask if mask.any() else None)
 
     @staticmethod
     def _list_passthrough(arr: np.ndarray, mask: np.ndarray) -> "Column":

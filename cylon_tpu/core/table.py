@@ -75,13 +75,19 @@ class Table:
     # -- construction ------------------------------------------------------
     @staticmethod
     def from_pydict(data: Mapping[str, np.ndarray], env: CylonEnv | None = None) -> "Table":
+        """Values are host arrays, or already-typed HOST columns
+        (``Column.from_scaled_ints``, ``Column.from_dictionary``), which
+        pass as they are."""
         env = env or default_env()
-        arrays = {k: np.asarray(v) for k, v in data.items()}
+        arrays = {k: v if isinstance(v, Column) else np.asarray(v)
+                  for k, v in data.items()}
         with timing.region(
                 "table.from_pydict",
                 rows=len(next(iter(arrays.values()))) if arrays else 0,
-                bytes=sum(int(a.nbytes) for a in arrays.values())):
-            cols = {k: Column.from_numpy(a) for k, a in arrays.items()}
+                bytes=sum(int((a.data if isinstance(a, Column) else a).nbytes)
+                          for a in arrays.values())):
+            cols = {k: a if isinstance(a, Column) else Column.from_numpy(a)
+                    for k, a in arrays.items()}
             return _ingest(cols, env)
 
     @staticmethod
@@ -358,6 +364,12 @@ def _column_from_series(s) -> Column:
     the plain to_numpy path (object/str columns dictionary-encode with a
     pd.isna mask in Column._encode_strings)."""
     import pandas as pd
+    if isinstance(s.dtype, pd.CategoricalDtype) \
+            and s.cat.categories.dtype.kind in ("O", "U", "T"):
+        codes = s.cat.codes.to_numpy()
+        return Column.from_dictionary(
+            codes, s.cat.categories,
+            (codes >= 0) if (codes < 0).any() else None)
     npdt = getattr(s.dtype, "numpy_dtype", None)
     if npdt is not None and npdt.kind in ("i", "u", "f", "b"):
         mask = np.asarray(s.isna(), bool)

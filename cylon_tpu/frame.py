@@ -72,8 +72,7 @@ class DataFrame:
         elif isinstance(data, DataFrame):
             self._table = data._table
         elif isinstance(data, Mapping):
-            self._table = Table.from_pydict(
-                {k: np.asarray(v) for k, v in data.items()}, env)
+            self._table = Table.from_pydict(dict(data), env)
         elif isinstance(data, (list, tuple)):
             # list of columns (PyCylon accepts list-of-lists)
             cols = {f"{i}": np.asarray(c) for i, c in enumerate(data)}

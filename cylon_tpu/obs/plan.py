@@ -290,7 +290,7 @@ class _NodeCtx:
     active besides: push + (analyze mode) a node-scoped attribution scope
     whose table becomes the node's self-time phase breakdown."""
 
-    __slots__ = ("_op", "_attrs", "_node", "_prof", "_span")
+    __slots__ = ("_op", "_attrs", "_node", "_prof", "_span", "_live")
 
     def __init__(self, op: str, attrs: dict):
         self._op = op
@@ -299,9 +299,17 @@ class _NodeCtx:
         self._prof = None
         self._span = None
 
+    def span_args(self, **args) -> None:
+        """Arguments of the open ``cylon.op.<op>`` span that the operator
+        learns while it runs (``rows_out`` of a filter): on the profiler's
+        span and in the flight recorder's, like those given at entry."""
+        ann, given = self._live
+        ann.set_metadata(**args)
+        given.update(args)
+
     def __enter__(self):
         self._span = _timing.span("op." + self._op)
-        self._span.__enter__()
+        self._live = self._span.__enter__()
         prof = getattr(_TLS, "profile", None)
         if prof is None:
             return _NOOP

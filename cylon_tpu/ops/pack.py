@@ -52,6 +52,20 @@ from ..utils.stages import stage, staged
 NULL_FIRST = 0
 NULL_LAST = 2
 
+#: The most operands (keys, index and payload lanes together) one
+#: ``lax.sort`` is given where the caller has another way to move its
+#: payload.  XLA:TPU compiles a sort in time that grows with its OPERAND
+#: count and hardly with its rows (described v5e compiles, PR 41: 2 keys of
+#: 3 / 4 / 6 / 8 / 10 operands 41 / 62 / 89 / 138 / 213 s at 4M rows, 116 s
+#: for 8 at 65,536; TPC-H Q3's 13-operand sort of 57,344 rows 639 s), while
+#: what riding saves at run time shrinks with the rows - so past this count
+#: the join's sides stop riding (relational/join.py: their columns are
+#: gathered at the take index), and a sort or a groupby rides the row index
+#: alone and moves its lane matrix by ONE gather at the permutation
+#: (relational/sort.py, relational/groupby._sort_state).  7 is the widest
+#: sort of the benchmark's accepted cells.
+SORT_OPERAND_BUDGET = 7
+
 
 def sort_operand_nbytes(dtypes, need_nf, narrow, rows: int,
                         row_mask: bool = True) -> int:

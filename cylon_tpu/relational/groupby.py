@@ -306,15 +306,15 @@ def _sort_state(vc, by_datas, by_valids, val_datas, val_valids, narrow,
                              list(val_valids) + list(by_valids))
             if vspec.n_lanes else None)
     # laneless (f64) columns cannot ride the sort — any f64 bitcast or sort
-    # payload SIGSEGVs the XLA:TPU compiler — so a u32 row-index payload
-    # lane rides instead and ONE (cap, K) f64 matrix gather at the sorted
-    # permutation moves all of them after the sort (batched: ~6 ns/row/col
-    # at K=5 vs ~16 ns/row/col for separate 1-D gathers, measured v5e)
+    # payload SIGSEGVs the XLA:TPU compiler — so a u32 row-index lane rides
+    # and ONE (cap, K) matrix gather at the sorted permutation moves them
+    # (~6 ns/row/col at K=5 against ~16 for 1-D gathers, measured v5e); so
+    # do the lanes of a sort past pack.SORT_OPERAND_BUDGET (``wide``)
     laneless = tuple(i for i, c in enumerate(vspec.cols) if not c.lanes)
-    extra = ((jnp.arange(cap, dtype=jnp.uint32),) if laneless else ())
-    nk = len(ko.ops)
-    nl = vspec.n_lanes
-    lane_ops = tuple(vmat[:, j] for j in range(nl)) if vmat is not None else ()
+    nk, nl = len(ko.ops), vspec.n_lanes
+    wide = nk + nl + bool(laneless) > pack.SORT_OPERAND_BUDGET
+    extra = (jnp.arange(cap, dtype=jnp.uint32),) if laneless or wide else ()
+    lane_ops = tuple(vmat[:, j] for j in range(0 if wide else nl))
     with stage("sort_keys"):
         sorted_all = jax.lax.sort(ko.ops + lane_ops + extra,
                                   num_keys=nk, is_stable=False)
@@ -330,9 +330,9 @@ def _sort_state(vc, by_datas, by_valids, val_datas, val_valids, narrow,
         n_groups = (jnp.max(jnp.where(mask, gid, -1)) + 1).astype(jnp.int32)
         gids = jnp.where(mask, gid, cap)
     if nl:
-        smat = jnp.stack(sorted_all[nk:nk + nl], axis=1)
-        sdatas, svalids = lanes.unpack_lanes(vspec, smat)
-        sdatas, svalids = list(sdatas), list(svalids)
+        smat = (_lanes_at(vmat, sorted_all[-1]) if wide
+                else jnp.stack(sorted_all[nk:nk + nl], axis=1))
+        sdatas, svalids = map(list, lanes.unpack_lanes(vspec, smat))
     else:
         sdatas = [None] * len(vspec.cols)
         svalids = [None] * len(vspec.cols)
@@ -620,9 +620,9 @@ def _result_types(specs, val_cols):
         if op in ("count", "nunique"):
             types.append(LogicalType.INT64)
             dicts.append(None)
-        elif col.type == LogicalType.STRING:  # min/max of strings = codes
-            types.append(LogicalType.STRING)
-            dicts.append(col.dictionary)
+        elif col.type in (LogicalType.STRING, LogicalType.DECIMAL):
+            types.append(col.type)  # a string's min/max: its code; a decimal
+            dicts.append(_kept_dictionary(c, op, col))   # keeps its scale
         else:
             src = physical_np_dtype(col.type)
             types.append(from_numpy_dtype(gbk.np_result_dtype(op, src)))
@@ -1126,3 +1126,31 @@ def _sum_forms(specs, val_cols, rows: int, two_lanes: bool = False) -> tuple:
     if summed and node is not None and node.op == "groupby":
         node.annotate(sum_scan=tuple(summed))
     return tuple(forms)
+
+
+def _kept_dictionary(name: str, op: str, col: Column):
+    """The ``Column.dictionary`` an aggregate of a STRING or DECIMAL value
+    column keeps (:func:`_result_types`; down here so that no line above
+    moves).  A decimal's scaled integers sum, and order, as the decimals do
+    at the same scale: ``sum`` / ``min`` / ``max`` stay DECIMAL - a sum with
+    all of int64's 18 digits - and every other op raises (``mean`` / ``var``
+    / ``std`` / quantiles of the scaled integers would be off by the scale
+    and are not exact)."""
+    if col.type == LogicalType.DECIMAL:
+        if op not in ("sum", "min", "max"):
+            raise InvalidError(
+                f"agg {op!r} of decimal column {name!r} is not scale-exact "
+                "(sum / min / max are); cast the column to float64 first")
+        if op == "sum":
+            from ..core.column import DecimalScale
+            return DecimalScale(18, col.dictionary.scale)
+    return col.dictionary
+
+
+@staged("gather_rows")
+def _lanes_at(vmat, perm):
+    """A lane matrix at a sort's permutation (``perm``: the uint32 row index
+    that rode it) - :func:`_sort_state`'s one gather where the lanes would
+    take the sort past ``pack.SORT_OPERAND_BUDGET``.  (At the file's end:
+    Mosaic embeds the line numbers of the traced frames above.)"""
+    return vmat[perm.astype(jnp.int32)]

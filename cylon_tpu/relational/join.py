@@ -1323,28 +1323,10 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
 
         carry_match = _can_carry(rspec, r_cols_list, 8)
         carry_emit = _can_carry(lspec, l_cols_list, 6)
-
-        l_gather_args = (tuple(c.data for c in l_cols_list),
-                         tuple(c.validity for c in l_cols_list))
-        r_gather_args = (tuple(c.data for c in r_cols_list),
-                         tuple(c.validity for c in r_cols_list))
         all_live = bool((vcl == lwork.capacity).all()
                         and (vcr == rwork.capacity).all())
-        # phase 1 only consumes the columns that ride the sort; keep the
-        # rest out of the trace (no needless retraces)
-        count_l_args = l_gather_args if carry_emit else ((), ())
-        count_r_args = r_gather_args if carry_match else ((), ())
-        count_args = (vcl, vcr, l_datas, l_valids, r_datas, r_valids,
-                      *count_l_args, *count_r_args)
-        cl_spec = lspec if carry_emit else None
-        cr_spec = rspec if carry_match else None
-        # what rides the sort, said once (ops/join.PayloadLayout): the two
-        # sides share operands, a left key column is the sorted key itself
-        layout = joink.payload_layout(
-            cl_spec, cr_spec, tuple(l_key_lane.get(n) for n in left_on),
-            tuple(d.dtype for d in l_datas),
-            tuple((lv is not None) or (rv is not None)
-                  for lv, rv in zip(l_valids, r_valids)), narrow, all_live)
+        need_nf = tuple((lv is not None) or (rv is not None)
+                        for lv, rv in zip(l_valids, r_valids))
 
         # ---- deferred materialization (reference ops-DAG slot, C9) -------
         # Inner joins whose output columns fully ride the phase-1 sort can hand
@@ -1372,6 +1354,36 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
         defer = (how == "inner" and carry_emit and carry_match and coalesce
                  and allow_defer
                  and (skew_plan is not None or not skew_split))
+        if not defer:
+            # an eager join's sides ride only as far as the sort stays
+            # within pack.SORT_OPERAND_BUDGET (its compile time grows with
+            # every operand): the keys' operands and the index come first,
+            # the two sides share what is left; a side that does not fit is
+            # gathered at the take index
+            room = pack.SORT_OPERAND_BUDGET - 1 - len(pack.key_operand_slots(
+                tuple(d.dtype for d in l_datas), need_nf, narrow,
+                row_mask=not all_live)[0])
+            carry_match = carry_match and rspec.n_lanes <= room
+            carry_emit = carry_emit and lspec.n_lanes <= room
+
+        l_gather_args = (tuple(c.data for c in l_cols_list),
+                         tuple(c.validity for c in l_cols_list))
+        r_gather_args = (tuple(c.data for c in r_cols_list),
+                         tuple(c.validity for c in r_cols_list))
+        # phase 1 only consumes the columns that ride the sort; keep the
+        # rest out of the trace (no needless retraces)
+        count_l_args = l_gather_args if carry_emit else ((), ())
+        count_r_args = r_gather_args if carry_match else ((), ())
+        count_args = (vcl, vcr, l_datas, l_valids, r_datas, r_valids,
+                      *count_l_args, *count_r_args)
+        cl_spec = lspec if carry_emit else None
+        cr_spec = rspec if carry_match else None
+        # what rides the sort, said once (ops/join.PayloadLayout): the two
+        # sides share operands, a left key column is the sorted key itself
+        layout = joink.payload_layout(
+            cl_spec, cr_spec, tuple(l_key_lane.get(n) for n in left_on),
+            tuple(d.dtype for d in l_datas), need_nf, narrow, all_live)
+
     if defer:
         with timing.region("join.sort_count"):
             res = _count_fn(env.mesh, how, narrow, cl_spec, cr_spec, layout,

@@ -462,22 +462,36 @@ def _filter_mat_fn(mesh: Mesh, cap: int, out_cap: int, spec):
 def filter_table(table: Table, flag) -> Table:
     """Keep rows whose boolean flag is set (flag: device bool array with the
     table's row layout).  Row order preserved; distribution keeps each row on
-    its shard (like the reference's local filter ops)."""
-    from .common import rebuild_like
+    its shard (like the reference's local filter ops).  Plan node ``filter``
+    (``cylon.op.filter``: ``columns``, ``rows_in``, ``rows_out``).  The
+    kept rows are a subset, so every column keeps its host-known bounds
+    (widened to 0, which the output's padding rows may hold)."""
+    from ..obs import plan as _plan
+    from .common import build_table, table_lane_spec
     env = table.env
     cap = max(table.capacity, 1)
     vc = np.asarray(table.valid_counts, np.int32)
-    counts = host_array(_filter_count_fn(env.mesh, cap)(vc, flag)
-                        ).astype(np.int64)
-    out_cap = config.pow2ceil(int(counts.max()) if counts.size else 1)
     items = list(table.columns.items())
-    datas = tuple(c.data for _, c in items)
-    valids = tuple(c.validity for _, c in items)
-    from .common import table_lane_spec
-    spec = table_lane_spec([c for _, c in items])
-    out_d, out_v = _filter_mat_fn(env.mesh, cap, out_cap, spec)(vc, flag,
-                                                                datas, valids)
-    return rebuild_like(items, out_d, out_v, counts, env)
+    ctx = _plan.node("filter", columns=len(items))
+    with ctx as pn:
+        counts = host_array(_filter_count_fn(env.mesh, cap)(vc, flag)
+                            ).astype(np.int64)
+        rows = {"rows_in": int(vc.sum()), "rows_out": int(counts.sum())}
+        ctx.span_args(**rows)
+        if pn:
+            pn.set(**rows)
+        out_cap = config.pow2ceil(int(counts.max()) if counts.size else 1)
+        cols = [c for _, c in items]
+        out_d, out_v = _filter_mat_fn(
+            env.mesh, cap, out_cap, table_lane_spec(cols))(
+                vc, flag, tuple(c.data for c in cols),
+                tuple(c.validity for c in cols))
+        return build_table(
+            [n for n, _ in items], out_d, out_v, [c.type for c in cols],
+            [c.dictionary for c in cols], counts, env,
+            bounds=[None if c.bounds is None else
+                    (min(c.bounds[0], 0), max(c.bounds[1], 0))
+                    for c in cols])
 
 
 # ---------------------------------------------------------------------------

@@ -46,11 +46,6 @@ shard_map = jax.shard_map
 #: 0 = scale with the world size, config.sort_samples)
 DEFAULT_SAMPLES = 0
 
-#: max payload lanes ridden through the local sort; wider tables switch to
-#: one lane-matrix gather at the permutation
-CARRY_LANE_BUDGET = 16
-
-
 def _norm_dirs(by, ascending):
     if isinstance(ascending, bool):
         return tuple(not ascending for _ in by)
@@ -77,6 +72,7 @@ def _local_sort_fn(mesh: Mesh, descendings: tuple, nulls_position: int,
     fresh shuffle outputs): XLA reuses them for the sorted output
     instead of holding input + output live together."""
     from ..ops import lanes
+    n_index = 1 if f64_idx else 0     # the row index rides for f64 columns
 
     def per_shard(vc, datas, valids):
         by_datas = [datas[i] for i in by_idx]
@@ -87,11 +83,12 @@ def _local_sort_fn(mesh: Mesh, descendings: tuple, nulls_position: int,
                                descendings=list(descendings),
                                nulls_position=nulls_position, pad_key=PAD_L,
                                narrow32=narrow or None)
-        if vspec.n_lanes > CARRY_LANE_BUDGET or vspec.n_lanes == 0:
+        if (len(ko.ops) + vspec.n_lanes + n_index
+                > pack.SORT_OPERAND_BUDGET or vspec.n_lanes == 0):
             # wide tables (or all-f64, nothing laneable): ONE lane-matrix
             # gather at the permutation (plus f64 side gathers inside
             # gather_columns) beats both per-column gathers and an
-            # overloaded sort
+            # overloaded sort, whose compile grows with every operand
             perm = sortk.sort_permutation(ko)
             return lanes.gather_columns(vspec, list(datas), list(valids),
                                         perm)

@@ -252,11 +252,34 @@ def generate_pandas(scale: float = 0.01, seed: int = 0) -> dict:
             "part": part, "partsupp": partsupp}
 
 
-def generate_tables(scale: float = 0.01, env=None, seed: int = 0) -> dict:
-    """Device-resident DataFrames for all six tables."""
+#: the money columns of the spec (``decimal(15,2)``), by table
+MONEY = {"customer": ("c_acctbal",), "orders": ("o_totalprice",),
+         "lineitem": ("l_extendedprice", "l_discount", "l_tax"),
+         "partsupp": ("ps_supplycost",)}
+
+
+def generate_tables(scale: float = 0.01, env=None, seed: int = 0,
+                    money: str = "float") -> dict:
+    """Device-resident DataFrames for all eight tables.  ``money="decimal"``
+    holds :data:`MONEY`'s columns as the spec's ``decimal(15,2)`` - the
+    float path's values to the cent, as scaled int64 through the typed
+    ingest (``Column.from_scaled_ints``), no Python object a value."""
+    from .core.column import Column
+    from .core.table import Table, _column_from_series
     from .frame import DataFrame
+    if money not in ("float", "decimal"):
+        raise ValueError(f"money: {money!r} is neither float nor decimal")
     pdfs = generate_pandas(scale, seed)
-    return {k: DataFrame(v, env=env) for k, v in pdfs.items()}
+    if money == "float":
+        return {k: DataFrame(v, env=env) for k, v in pdfs.items()}
+    out = {}
+    for k, v in pdfs.items():
+        cols = {str(c): Column.from_scaled_ints(
+                    np.rint(v[c].to_numpy() * 100).astype(np.int64), 2, 15)
+                if c in MONEY.get(k, ()) else _column_from_series(v[c])
+                for c in v.columns}
+        out[k] = DataFrame(Table.from_host_columns(cols, env))
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -338,13 +361,14 @@ def q6_pandas(pdfs: dict, date_lo: str = "1994-01-01",
 # ---------------------------------------------------------------------------
 
 def q3(dfs: dict, env=None, segment: str = "BUILDING",
-       date: str = "1995-03-15"):
+       date: str = "1995-03-15", limit: int | None = 10):
     """SELECT l_orderkey, sum(l_extendedprice*(1-l_discount)) AS revenue,
     o_orderdate, o_shippriority FROM customer, orders, lineitem WHERE
     c_mktsegment = :segment AND c_custkey = o_custkey AND l_orderkey =
     o_orderkey AND o_orderdate < :date AND l_shipdate > :date GROUP BY
     l_orderkey, o_orderdate, o_shippriority ORDER BY revenue DESC,
-    o_orderdate LIMIT 10."""
+    o_orderdate LIMIT :limit (None: every group).  ``1 - l_discount`` is
+    written so that it holds for float64 and DECIMAL money alike."""
     cust = dfs["customer"]
     orders = dfs["orders"]
     line = dfs["lineitem"]
@@ -356,11 +380,13 @@ def q3(dfs: dict, env=None, segment: str = "BUILDING",
 
     co = c.merge(o, left_on="c_custkey", right_on="o_custkey", env=env)
     col = co.merge(l, left_on="o_orderkey", right_on="l_orderkey", env=env)
-    col["revenue"] = col["l_extendedprice"] * (1.0 - col["l_discount"])
+    col["revenue"] = col["l_extendedprice"] * (1 - col["l_discount"])
     g = (col.groupby(["l_orderkey", "o_orderdate", "o_shippriority"],
                      env=env)[["revenue"]].sum())
     out = g.sort_values(["revenue", "o_orderdate"],
-                        ascending=[False, True], env=env).head(10)
+                        ascending=[False, True], env=env)
+    if limit is not None:
+        out = out.head(limit)
     return out[["l_orderkey", "revenue", "o_orderdate", "o_shippriority"]]
 
 
@@ -412,7 +438,7 @@ def q5(dfs: dict, env=None, region: str = "ASIA",
     # l_suppkey = s_suppkey AND c_nationkey = s_nationkey (two-column key)
     j = col.merge(sup, left_on=["l_suppkey", "c_nationkey"],
                   right_on=["s_suppkey", "s_nationkey"], env=env)
-    j["revenue"] = j["l_extendedprice"] * (1.0 - j["l_discount"])
+    j["revenue"] = j["l_extendedprice"] * (1 - j["l_discount"])
     g = j.groupby(["n_name"], env=env)[["revenue"]].sum()
     return g.sort_values("revenue", ascending=False,
                          env=env)[["n_name", "revenue"]]

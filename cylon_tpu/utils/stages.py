@@ -101,3 +101,11 @@ def staged(name: str):
         return scoped
 
     return deco
+
+
+# Added BELOW the functions: Mosaic's serialized kernel bodies embed the line
+# numbers of the traced Python frames - ``scoped`` above among them - so a
+# line moved up there is a cold compile of every windowed program (PERF.md,
+# PR 30's lesson).
+STAGES["expr"] = ("elementwise column expressions: arithmetic, compares, "
+                  "mask logic (series._expr_fn)")

@@ -220,15 +220,20 @@ def span(name: str, **args):
     exchange's launches) — which nest inside the operator regions:
     entered into the phase/scope tables they would be counted twice in
     every sum over a table (a session's fair-share clock, a plan node's
-    seconds, bench.py's dispatch total)."""
-    with _annotation(name, _scope(), args):
+    seconds, bench.py's dispatch total).
+
+    Yields ``(annotation, args)``: what is known only inside the span (a
+    filter's ``rows_out``) is added to both - ``set_metadata`` on the
+    one, ``update`` on the other (obs/plan._NodeCtx.span_args)."""
+    ann = _annotation(name, _scope(), args)
+    with ann:
         tr = _TRACE[0]
         if tr is None:
-            yield
+            yield ann, args
             return
         t0 = time.perf_counter()
         try:
-            yield
+            yield ann, args
         finally:
             tr.span(name, t0, time.perf_counter() - t0, args or None)
 

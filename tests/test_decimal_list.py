@@ -78,7 +78,10 @@ class TestDecimal:
         with pytest.raises(CylonTypeError):
             d["m"] >= Decimal("0.065")   # finer than the column scale
         with pytest.raises(CylonTypeError):
-            d["m"] + 1                   # no decimal arithmetic
+            d["m"] + 1.0                 # a float operand is lossy
+        assert (d["m"] + 1).to_numpy().tolist() == [
+            Decimal("1.05"), Decimal("1.06"), Decimal("1.07"),
+            Decimal("1.08")]             # scale-exact since PR 41
 
     def test_groupby_on_decimal_keys(self, env4, rng):
         df = pd.DataFrame({"m": _dec(rng.integers(0, 8, 500) / 4),

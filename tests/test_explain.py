@@ -105,6 +105,33 @@ class TestPlanTree:
         s = next(r for r in qp.roots if r.op == "sort")
         assert s.rows_out == qp.result.row_count
 
+    @pytest.mark.parametrize("world", [1, 4])
+    def test_filter_opens_a_plan_node(self, world, env1, env4):
+        """``filter_table`` is plan node ``filter`` (``cylon.op.filter``):
+        ``columns``, ``rows_in``, ``rows_out``; the span carries the rows
+        too, which it learns inside (``_NodeCtx.span_args``)."""
+        import cylon_tpu as ct
+        from cylon_tpu.obs import trace
+        lt, _ = _tables(env1 if world == 1 else env4)
+        df = ct.DataFrame.from_table(lt)
+        kept = int((lt.to_pandas()["k"] < 100).sum())
+        qp = obs.explain_analyze(lambda: df[df["k"] < 100])
+        (node,) = [r for r in qp.roots if r.op == "filter"]
+        assert node.attrs["columns"] == lt.column_count
+        assert node.rows_in == lt.row_count and node.rows_out == kept
+        assert qp.result.table.row_count == kept
+        rec = trace.arm(capacity=64)
+        try:
+            df[df["k"] < 100]
+            spans = {e[3]: e[6] for e in rec.events()}
+        finally:
+            trace.disarm()
+        assert spans["op.filter"] == {"rows_in": lt.row_count,
+                                      "rows_out": kept}
+        assert {"launch.repart__filter_count_fn",
+                "launch.repart__filter_mat_fn",
+                "launch.series__expr_fn"} <= set(spans)
+
     def test_pipelined_tree_has_piece_children(self, env4):
         from cylon_tpu.exec import pipelined_join
         lt, rt = _tables(env4, n=6000)

@@ -752,6 +752,9 @@ def test_payload_layout(request, rng, monkeypatch, how, key, lanes_, f64,
     counts, mats = [], []
     real_count = _spy_builder(monkeypatch, rj, "_count_fn", counts)
     _spy_builder(monkeypatch, rj, "_materialize_fn", mats)
+    # the layout is what is tested, at every width: an eager join's operand
+    # budget (test_eager_join_rides_within_the_sort_operand_budget) is lifted
+    monkeypatch.setattr(rj.pack, "SORT_OPERAND_BUDGET", 64)
     got = join_tables(lt, rt, "k", "k", how=how)
     exp = ldf.merge(rdf, on="k", how=how)
     assert_table_matches(got, exp, sort_by=list(exp.columns))
@@ -803,6 +806,48 @@ def test_payload_layout(request, rng, monkeypatch, how, key, lanes_, f64,
         b, b_ok = _masked(od, ov, slot_ok)
         np.testing.assert_array_equal(a_ok, b_ok)
         np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("how,n_a,n_b,wide_key,rides", [
+    ("left", 2, 2, False, (True, True)),    # 2 keys + index + 3 <= 7
+    ("left", 2, 5, False, (True, False)),   # the right's 6 lanes do not fit
+    ("left", 5, 2, False, (False, True)),
+    ("inner", 5, 5, True, (False, False)),  # eager: the names differ
+    ("left", 1, 1, True, (True, True)),     # 3 keys + index + 3
+])
+def test_eager_join_rides_within_the_sort_operand_budget(
+        env1, rng, monkeypatch, how, n_a, n_b, wide_key, rides):
+    """An eager join's sides ride the sort only while keys + index + lanes
+    stay within ``pack.SORT_OPERAND_BUDGET`` (XLA:TPU's compile time of a
+    sort grows with its operands); a side that does not fit is gathered at
+    the take index, and the result is pandas' either way.  A join that
+    defers to the fused consumer rides as before (test_payload_layout,
+    TestPaddedShards)."""
+    from cylon_tpu.relational import join as rj
+    n = 61                                  # ragged: a liveness operand
+    scale = (1 << 40) if wide_key else 1
+
+    def frame(key, names):
+        d = {key: rng.integers(0, 12, n).astype(np.int64) * scale}
+        for c in names:
+            d[c] = rng.integers(-99, 99, n).astype(np.int32)
+        return pd.DataFrame(d)
+
+    ldf = frame("k", [f"a{i}" for i in range(n_a)])
+    rdf = frame("k" if how == "left" else "kr",
+                [f"b{i}" for i in range(n_b)])
+    counts = []
+    _spy_builder(monkeypatch, rj, "_count_fn", counts)
+    lt, rt = ct.Table.from_pandas(ldf, env1), ct.Table.from_pandas(rdf, env1)
+    got = join_tables(lt, rt, "k", rdf.columns[0], how=how)
+    monkeypatch.undo()
+    exp = ldf.merge(rdf, left_on="k", right_on=rdf.columns[0], how=how)
+    assert_table_matches(got, exp, sort_by=list(exp.columns))
+    (cstatic, _kw, _args, _res), = counts
+    lspec, rspec, layout = cstatic[2:5]
+    assert (lspec is not None, rspec is not None) == rides
+    assert layout.sort_operands <= rj.pack.SORT_OPERAND_BUDGET
+    assert layout.sort_operands == layout.n_keys + 1 + layout.n_payloads
 
 
 def _key_frames(rng, key, n_l=300, n_r=200):

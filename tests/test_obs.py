@@ -512,15 +512,16 @@ class TestUnarmedContract:
         ``plan.node``) and ``cylon.host.<step>`` (ISSUE 39): with nothing
         armed each constructs ONE ``TraceAnnotation``, reads no clock and
         writes nothing - on the facade alone and over a whole join ->
-        groupby.  ``timing.span`` is the parent's, byte for byte: the
-        digest is of its source, to be changed knowingly."""
+        groupby.  ``timing.span`` is PR 41's, byte for byte (it yields its
+        annotation and arguments, for ``_NodeCtx.span_args``): the digest
+        is of its source, to be changed knowingly."""
         import hashlib
         import inspect
         import types
         from cylon_tpu.obs import plan
         from cylon_tpu.relational import groupby_aggregate, join_tables
         assert hashlib.sha256(inspect.getsource(timing.span).encode()) \
-            .hexdigest()[:16] == "65809b1d6c3e31c4"
+            .hexdigest()[:16] == "a4430b1221e83b64"
         left, right = _toy(env1, n=512)
 
         def query():

@@ -428,3 +428,103 @@ def test_tpch_out_of_core_disk_tier_bit_equal(env4, monkeypatch, tmp_path):
     assert st["disk_events"] > 0 and st["bytes_to_disk"] > 0, st
     assert recovery.recovery_events() == []   # degraded, not escalated
     pd.testing.assert_frame_equal(capped, base)   # bit-equal
+
+
+# ---------------------------------------------------------------------------
+# PR 41: the spec's DECIMAL money, exact
+# ---------------------------------------------------------------------------
+
+def _plain_q3_q5(pdfs, limit=10):
+    """Q3 and Q5 written out on integer cents with direct indexing (keys
+    are dense): nothing of the engine, nothing of ``q*_pandas``."""
+    c, o, l = pdfs["customer"], pdfs["orders"], pdfs["lineitem"]
+    cents = np.rint(l.l_extendedprice.to_numpy() * 100).astype(np.int64)
+    disc = np.rint(l.l_discount.to_numpy() * 100).astype(np.int64)
+    rev = cents * (100 - disc)                                  # scale 4
+    okey = l.l_orderkey.to_numpy()
+    odate = o.o_orderdate.to_numpy()
+    ocust = o.o_custkey.to_numpy()
+    d = np.datetime64("1995-03-15")
+    seg = (c.c_mktsegment.to_numpy() == "BUILDING")[ocust]
+    rows = np.flatnonzero((l.l_shipdate.to_numpy() > d)
+                          & ((odate < d) & seg)[okey])
+    sums = np.zeros(len(o), np.int64)
+    np.add.at(sums, okey[rows], rev[rows])
+    keys = np.unique(okey[rows])
+    order = np.lexsort((keys, odate[keys], -sums[keys]))[:limit]
+    q3 = (keys[order], sums[keys][order], odate[keys][order])
+    lo, hi = np.datetime64("1994-01-01"), np.datetime64("1995-01-01")
+    asia = (tpch.NATION_REGION == list(tpch.REGIONS).index("ASIA"))
+    cnat = np.where((odate >= lo) & (odate < hi),
+                    c.c_nationkey.to_numpy()[ocust], -1)[okey]
+    snat = pdfs["supplier"].s_nationkey.to_numpy()[l.l_suppkey.to_numpy()]
+    rows = np.flatnonzero((cnat == snat) & asia[np.maximum(cnat, 0)])
+    by_nation = np.zeros(25, np.int64)
+    np.add.at(by_nation, cnat[rows], rev[rows])
+    nations = np.flatnonzero(np.bincount(cnat[rows], minlength=25))
+    order = np.argsort(-by_nation[nations], kind="stable")
+    return q3, (tpch.NATIONS[nations][order], by_nation[nations][order])
+
+
+def _scaled(series, scale=4):
+    return [int(v.scaleb(scale)) for v in series]
+
+
+@pytest.mark.parametrize("seed", [1, 5])
+def test_q3_q5_on_decimal_tables_are_exact(env, seed):
+    """``money="decimal"`` at SF 0.01: every cell equals the plain
+    reference written out above (exact scaled integers at scale 4), and
+    the float path's answer to 1e-9 relative; ``revenue`` is DECIMAL at
+    scale 4 and its sums scan as ``val32`` (the derived bounds hold)."""
+    from cylon_tpu import LogicalType
+    from cylon_tpu.obs import metrics
+    pdfs = tpch.generate_pandas(scale=0.01, seed=seed)
+    dd = tpch.generate_tables(0.01, env, seed=seed, money="decimal")
+    for name, cols in tpch.MONEY.items():
+        for c in cols:
+            col = dd[name].table.column(c)
+            assert col.type == LogicalType.DECIMAL
+            assert (col.dictionary.precision, col.dictionary.scale) == (15, 2)
+    scans = 'grouped_sum_scans{form="%s"}'
+    before = metrics.snapshot()
+    r3, r5 = tpch.q3(dd, env=env), tpch.q5(dd, env=env)
+    after = metrics.snapshot()
+    assert after[scans % "val32"] - before[scans % "val32"] == 2
+    # across shards the second phase sums partial sums, whose bounds
+    # nobody knows: one pair64 scan a query there, none on one device
+    assert after[scans % "pair64"] - before[scans % "pair64"] == (
+        0 if env.world_size == 1 else 2)
+    for r in (r3, r5):
+        col = r.table.column("revenue")
+        assert col.type == LogicalType.DECIMAL and col.dictionary.scale == 4
+    g3, g5 = r3.to_pandas(), r5.to_pandas()
+    (k3, s3, d3), (n5, s5) = _plain_q3_q5(pdfs)
+    assert list(g3.l_orderkey) == list(k3)
+    assert _scaled(g3.revenue) == list(s3)
+    assert list(g3.o_orderdate.to_numpy().astype("datetime64[ns]")) \
+        == list(d3.astype("datetime64[ns]"))
+    assert list(g5.n_name) == list(n5) and _scaled(g5.revenue) == list(s5)
+    f3, f5 = tpch.q3_pandas(pdfs), tpch.q5_pandas(pdfs)
+    np.testing.assert_allclose(g3.revenue.astype(float), f3.revenue,
+                               rtol=1e-9)
+    np.testing.assert_allclose(g5.revenue.astype(float), f5.revenue,
+                               rtol=1e-9)
+    assert list(g3.l_orderkey) == list(f3.l_orderkey)
+    assert list(g5.n_name) == list(f5.n_name)
+
+
+def test_q3_limit(env1):
+    """``limit=None`` is every group, in Q3's order; the default is 10."""
+    pdfs = tpch.generate_pandas(scale=0.01, seed=2)
+    dd = tpch.generate_tables(0.01, env1, seed=2, money="decimal")
+    every = tpch.q3(dd, limit=None).to_pandas()
+    (k, s, _d), _ = _plain_q3_q5(pdfs, limit=None)
+    assert len(every) == len(k) > 10
+    assert list(every.l_orderkey) == list(k)
+    assert _scaled(every.revenue) == list(s)
+    assert len(tpch.q3(dd)) == 10 and len(tpch.q3(dd, limit=3)) == 3
+
+
+def test_money_argument():
+    with pytest.raises(ValueError):
+        tpch.generate_tables(0.001, money="cents")
