@@ -123,10 +123,9 @@ def _from_lanes(lanes, dtype: str, narrow: bool = False):
     return lanes[0].astype(jdt)
 
 
-@staged("pack")
-def pack_lanes(spec: LaneSpec, datas, valids):
-    """(n, spec.n_lanes) uint32 lane matrix from parallel column arrays
-    (laneless f64 columns contribute only their validity bit).
+def _lane_list(spec: LaneSpec, datas, valids) -> list:
+    """The ``spec.n_lanes`` u32 lane arrays of parallel column arrays, in
+    lane order (laneless f64 columns contribute only their validity bit).
     ``valids[i]`` may be None for columns planned with valid_bit == -1."""
     n = datas[0].shape[0]
     lanes = [None] * spec.n_lanes
@@ -142,27 +141,60 @@ def pack_lanes(spec: LaneSpec, datas, valids):
             vlanes[slot] = vlanes[slot] | (vb << jnp.uint32(col.valid_bit % 32))
     for i, vl in enumerate(vlanes):
         lanes[spec.valid_lane0 + i] = vl
-    return jnp.stack(lanes, axis=1)
+    return lanes
 
 
-@staged("unpack")
-def unpack_lanes(spec: LaneSpec, mat):
-    """Inverse of :func:`pack_lanes`: (datas, valids) tuples — laneless
-    (f64) columns yield None data (moved separately); valids entries are
-    None for columns planned without validity."""
+@staged("pack")
+def pack_lanes(spec: LaneSpec, datas, valids):
+    """(n, spec.n_lanes) uint32 lane matrix from parallel column arrays
+    (:func:`_lane_list`, a lane a COLUMN of the matrix)."""
+    return jnp.stack(_lane_list(spec, datas, valids), axis=1)
+
+
+@staged("pack")
+def pack_lane_rows(spec: LaneSpec, datas, valids, multiple: int = 1):
+    """The same lanes LANE-MAJOR: an (L, n) matrix, a lane a ROW, with the
+    zero rows that bring L to a multiple of ``multiple`` inside the one
+    stack (the windowed take wants a sublane multiple; a ``jnp.pad`` after
+    the stack would copy the whole operand again).  An axis-0 stack is a
+    plain concat; a transpose of the (n, L) matrix costs what its gather
+    does (ops/pallas_gather)."""
+    lanes = _lane_list(spec, datas, valids)
+    pad = -len(lanes) % multiple
+    zero = [jnp.zeros_like(lanes[0])] * pad
+    return jnp.stack(lanes + zero, axis=0)
+
+
+def _unpack(spec: LaneSpec, lane):
+    """(datas, valids) of the lanes ``lane(i)`` hands out: laneless (f64)
+    columns yield None data (moved separately); valids entries are None
+    for columns planned without validity."""
     datas, valids = [], []
     for col in spec.cols:
         if col.lanes:
-            datas.append(_from_lanes([mat[:, li] for li in col.lanes],
+            datas.append(_from_lanes([lane(li) for li in col.lanes],
                                      col.dtype, col.narrow))
         else:
             datas.append(None)
         if col.valid_bit >= 0:
-            vl = mat[:, spec.valid_lane0 + col.valid_bit // 32]
+            vl = lane(spec.valid_lane0 + col.valid_bit // 32)
             valids.append(((vl >> jnp.uint32(col.valid_bit % 32)) & 1) != 0)
         else:
             valids.append(None)
     return tuple(datas), tuple(valids)
+
+
+@staged("unpack")
+def unpack_lanes(spec: LaneSpec, mat):
+    """Inverse of :func:`pack_lanes` (:func:`_unpack` of the columns of the
+    (n, L) matrix)."""
+    return _unpack(spec, lambda li: mat[:, li])
+
+
+@staged("unpack")
+def unpack_lane_rows(spec: LaneSpec, mat_t):
+    """Inverse of :func:`pack_lane_rows`: a lane is a row of ``mat_t``."""
+    return _unpack(spec, lambda li: mat_t[li])
 
 
 def slice_lanes(spec: LaneSpec, mat, start, window: int):

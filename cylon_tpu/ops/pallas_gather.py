@@ -215,3 +215,39 @@ def windowed_take_t(mat_t, idx, window: int, interpret: bool | None = None):
     idx2 = idx.reshape(G, 8, TILE // 8)
     out = _pallas_take(mat_t, idx2, ws, window, interpret)[:L]
     return out, ok
+
+
+# Added BELOW the kernel and its wrapper: Mosaic's serialized kernel body
+# embeds the line numbers of the traced frames above (PERF.md, PR 30's
+# lesson), so nothing up there moves.
+
+@staged("gather_rows")
+def take_rows_t(mat_t, idx, window: int, interpret: bool | None = None):
+    """:func:`windowed_take_t`'s rows for a caller that moves whole ROWS
+    by a monotone take index (a filter's kept rows keep their order) and
+    has MEASURED every tile's span before it dispatched: no ``ok`` to
+    pull, and the operations carry stage ``gather_rows`` - ``segment_gather``
+    keeps meaning the grouped reduce.  ``mat_t`` arrives at the DMA tiling
+    (``ops/lanes.pack_lane_rows(..., 8)``, a row count that is a multiple
+    of 128), so the wrapper's ``jnp.pad`` copies nothing."""
+    L, M = mat_t.shape
+    if L % 8 or M % 128:
+        raise ValueError(f"take_rows_t wants an (8k, 128m) matrix, got "
+                         f"{mat_t.shape}")
+    return windowed_take_t.__wrapped__(mat_t, idx, window, interpret)[0]
+
+
+def max_tile_span(srt, last):
+    """The most source rows any :data:`TILE` of a windowed take at the
+    sorted index ``min(srt, last)`` has to hold: the tile's last index
+    less its 128-floored first, plus one - what :func:`windowed_take_t`'s
+    ``ok`` compares with the window, read before a row is moved (a window
+    start clamped to the matrix's end only ever serves).  ``last`` is the
+    last real index: slots past it are padding that rides at ``last``,
+    and a tile of padding alone spans its own 128."""
+    n = srt.shape[0]
+    if n % TILE:
+        return jnp.int32(n)        # no tile grid: never eligible
+    heads = jnp.minimum(srt[::TILE], last)
+    lasts = jnp.minimum(srt[TILE - 1::TILE], last)
+    return jnp.max(lasts - (heads // 128) * 128 + 1).astype(jnp.int32)

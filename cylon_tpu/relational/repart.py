@@ -30,7 +30,9 @@ from ..ctx.context import CylonEnv
 from ..ops import sort as sortk
 from ..parallel import shuffle
 from ..status import InvalidError
+from ..obs import metrics as _metrics
 from ..utils.host import host_array
+from ..utils.stages import stage
 from .common import ROW, REP, build_table, col_arrays, live_mask, \
     unify_dictionaries_many
 
@@ -434,38 +436,117 @@ def tail(table: Table, n: int) -> Table:
 # row filter (reference: compute.pyx filter path — table[bool_mask])
 # ---------------------------------------------------------------------------
 
+#: one count a filter's materialize dispatch, by the path its rows took and
+#: - where XLA's gather moved them - the test that said so: the words of
+#: ``fused.window_rule``, a table with no u32 lane to stack, a shard
+#: capacity off the DMA tiling, or a measured tile span past the window.
+#: Registered at import so that a snapshot shows the whole family.
+_FILTER_WINDOWED = _metrics.counter("filter_dispatches", path="windowed")
+_FILTER_PLAIN = {why: _metrics.counter("filter_dispatches", path="plain",
+                                       reason=why)
+                 for why in ("not_tpu", "density_below_floor",
+                             "segment_space_small", "laneless_only",
+                             "unsupported_shape", "span_overflow")}
+
+
+def _last_kept(srt, n_kept):
+    """Position of the shard's last kept row in the sorted take index
+    ``srt`` (0 where it keeps nothing): what the padding slots ride at."""
+    return jnp.where(n_kept > 0, srt[jnp.maximum(n_kept - 1, 0)],
+                     jnp.int32(0))
+
+
 @program_cache()
 def _filter_count_fn(mesh: Mesh, cap: int):
+    """``(meta, srt)``: per shard ``meta = [kept rows, widest tile span]``
+    - ONE pulled array - and the kept rows' positions in source order, the
+    fill ``cap`` behind them: ``ops/groupby.grouped_starts``' one-operand
+    unstable sort (the k-th kept row is the k-th smallest kept position),
+    handed on the device to whichever materialize program runs.  The span
+    is :func:`~cylon_tpu.ops.pallas_gather.max_tile_span`'s, so the host
+    knows whether the window serves before a row is moved."""
+    from ..ops import groupby as groupbyk, pallas_gather as pg
+
     def per_shard(vc, flag):
         mask = live_mask(vc, cap)
-        return jnp.sum(flag & mask).astype(jnp.int32).reshape(1)
+        n_kept = jnp.sum(flag & mask).astype(jnp.int32)
+        with stage("compact"):      # not the grouped reduce's segment_starts
+            srt = groupbyk.grouped_starts.__wrapped__(
+                flag, mask, jnp.int32(cap), cap)
+            span = pg.max_tile_span(srt, _last_kept(srt, n_kept))
+        return jnp.stack([n_kept, span]), srt
 
     return jit(shard_map(per_shard, mesh=mesh, in_specs=(REP, ROW),
-                             out_specs=ROW))
+                             out_specs=(ROW, ROW)))
 
 
 @program_cache()
-def _filter_mat_fn(mesh: Mesh, cap: int, out_cap: int, spec):
-    from ..ops import lanes
+def _filter_mat_fn(mesh: Mesh, cap: int, out_cap: int, spec, window: int):
+    """Rows at the take index ``srt[:out_cap]``, padding slots clamped to
+    the last kept position (monotone, inside the last real tile's window;
+    ``valid_counts`` masks them).  ``window`` > 0: the u32 lanes stacked as
+    ROWS and moved by the windowed Pallas take - a filter keeps rows in
+    source order -, f64 side columns by XLA's gather at the same index;
+    0: everything by ``lanes.gather_columns`` (XLA's gather)."""
+    from ..ops import lanes, pallas_gather as pg
 
-    def per_shard(vc, flag, datas, valids):
-        mask = live_mask(vc, cap)
-        idx, _ = sortk.compact_by_flag(flag & mask, out_cap)
-        # ONE lane-matrix gather for all columns (+ f64 side gathers)
-        return lanes.gather_columns(spec, list(datas), list(valids), idx)
+    def per_shard(kept, srt, datas, valids):
+        n_kept = kept[jax.lax.axis_index(shuffle.ROW_AXIS)]
+        with stage("compact"):
+            idx = jnp.minimum(srt[:out_cap], _last_kept(srt, n_kept))
+        if not window:
+            # ONE lane-matrix gather for all columns (+ f64 side gathers)
+            return lanes.gather_columns(spec, list(datas), list(valids), idx)
+        mat_t = lanes.pack_lane_rows(spec, list(datas), list(valids), 8)
+        out_d, out_v = lanes.unpack_lane_rows(
+            spec, pg.take_rows_t(mat_t, idx, window))
+        out_d = list(out_d)
+        for i, d in lanes.gather_laneless(spec, datas, idx).items():
+            out_d[i] = d
+        return tuple(out_d), out_v
 
     return jit(shard_map(per_shard, mesh=mesh,
                              in_specs=(REP, ROW, ROW, ROW),
                              out_specs=(ROW, ROW)))
 
 
+def filter_window(mesh: Mesh, cap: int, out_cap: int, n_lanes: int,
+                  density: float, max_span: int) -> tuple:
+    """``(window, why)`` of one filter's materialize dispatch, from what the
+    host knows when it dispatches: the grouped reduce's rule on the
+    output's capacity and the kept density of the fullest shard
+    (``fused.window_rule``: TPU, density >= 0.10, ``out_cap`` >= 2^20),
+    then what the kernel can take (a u32 lane to stack, the DMA tiling)
+    and the widest tile span the count program measured.  0 = XLA's
+    gather, ``why`` the test that said so."""
+    from ..ops import pallas_gather as pg
+    from . import fused
+    window, why = fused.window_rule(mesh, out_cap, density)
+    if not window:
+        return 0, why
+    if not n_lanes:
+        return 0, "laneless_only"
+    if cap % 128 or not pg.supported(cap, out_cap, n_lanes, window):
+        return 0, "unsupported_shape"
+    if max_span > window:
+        return 0, "span_overflow"
+    return window, ""
+
+
 def filter_table(table: Table, flag) -> Table:
     """Keep rows whose boolean flag is set (flag: device bool array with the
     table's row layout).  Row order preserved; distribution keeps each row on
     its shard (like the reference's local filter ops).  Plan node ``filter``
-    (``cylon.op.filter``: ``columns``, ``rows_in``, ``rows_out``).  The
-    kept rows are a subset, so every column keeps its host-known bounds
-    (widened to 0, which the output's padding rows may hold)."""
+    (``cylon.op.filter``: ``columns``, ``rows_in``, ``rows_out``; ``path``,
+    ``window``, ``density``, ``max_tile_span``).  The kept rows are a
+    subset, so every column keeps its host-known bounds (widened to 0, which
+    the output's padding rows may hold).
+
+    Two programs, one pull: the count program sorts the kept positions and
+    returns the counts with the widest tile span; the materialize program
+    moves the rows at that index - by the windowed Pallas take where
+    :func:`filter_window` says the window serves (``filter_dispatches``
+    counts the paths), by XLA's gather elsewhere."""
     from ..obs import plan as _plan
     from .common import build_table, table_lane_spec
     env = table.env
@@ -474,18 +555,27 @@ def filter_table(table: Table, flag) -> Table:
     items = list(table.columns.items())
     ctx = _plan.node("filter", columns=len(items))
     with ctx as pn:
-        counts = host_array(_filter_count_fn(env.mesh, cap)(vc, flag)
-                            ).astype(np.int64)
+        meta, srt = _filter_count_fn(env.mesh, cap)(vc, flag)
+        meta = host_array(meta).astype(np.int64).reshape(-1, 2)
+        counts, max_span = meta[:, 0], int(meta[:, 1].max())
         rows = {"rows_in": int(vc.sum()), "rows_out": int(counts.sum())}
         ctx.span_args(**rows)
-        if pn:
-            pn.set(**rows)
-        out_cap = config.pow2ceil(int(counts.max()) if counts.size else 1)
+        # (a capacity outside pow2ceil's family bounds its own output)
+        out_cap = min(config.pow2ceil(int(counts.max())), cap)
         cols = [c for _, c in items]
-        out_d, out_v = _filter_mat_fn(
-            env.mesh, cap, out_cap, table_lane_spec(cols))(
-                vc, flag, tuple(c.data for c in cols),
-                tuple(c.validity for c in cols))
+        spec = table_lane_spec(cols)
+        full = int(counts.argmax())
+        density = float(counts[full]) / max(int(vc[full]), 1)
+        window, why = filter_window(env.mesh, cap, out_cap, spec.n_lanes,
+                                    density, max_span)
+        (_FILTER_PLAIN[why] if why else _FILTER_WINDOWED).inc()
+        if pn:
+            pn.set(**rows, path="plain" if why else "windowed",
+                   window=window, density=round(density, 6),
+                   max_tile_span=max_span)
+        out_d, out_v = _filter_mat_fn(env.mesh, cap, out_cap, spec, window)(
+            counts.astype(np.int32), srt, tuple(c.data for c in cols),
+            tuple(c.validity for c in cols))
         return build_table(
             [n for n, _ in items], out_d, out_v, [c.type for c in cols],
             [c.dictionary for c in cols], counts, env,

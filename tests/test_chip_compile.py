@@ -452,3 +452,55 @@ def test_windowed_groupby_compiles_for_four_chips(mesh4, monkeypatch, site,
         *_groupby_args(mesh4, 17408))
     assert _has_kernel(compiled)
     assert bool(_wide_scans(compiled)) == (form == "pair64")
+
+
+# ---- the filter (ISSUE 43) --------------------------------------------------
+# Q3's ``lineitem`` filter at the TPC-H cell's size: a 30,408,704-row shard,
+# 16,252,928 output slots, 15 columns in 18 lanes (12 narrow int64 / code
+# columns, 3 two-lane dates) - 24 rows after the pad to a sublane multiple,
+# three times the widest stack the grouped reduce gives the kernel.  XLA:TPU
+# compiles these in seconds (a one-operand sort, no wide sort).
+
+def _filter_programs(mesh, cap, out_cap, window, n_narrow=12, n_wide=3):
+    from cylon_tpu.analysis.registry import unwrap
+    from cylon_tpu.ctx.context import ROW_AXIS
+    from cylon_tpu.ops import lanes
+    from cylon_tpu.relational import repart
+    w = int(mesh.devices.size)
+    n = n_narrow + n_wide
+    spec = lanes.plan_lanes(("int64",) * n, (False,) * n,
+                            (True,) * n_narrow + (False,) * n_wide)
+    rep, row = NamedSharding(mesh, P()), NamedSharding(mesh, P(ROW_AXIS))
+    S = jax.ShapeDtypeStruct
+    vc = S((w,), np.int32, sharding=rep)
+    count = jax.jit(unwrap(repart._filter_count_fn(mesh, cap))).lower(
+        vc, S((w * cap,), np.bool_, sharding=row)).compile()
+    cols = tuple(S((w * cap,), np.int64, sharding=row) for _ in range(n))
+    mat = jax.jit(unwrap(repart._filter_mat_fn(
+        mesh, cap, out_cap, spec, window))).lower(
+            vc, S((w * cap,), np.int32, sharding=row), cols,
+            (None,) * n).compile()
+    return spec, count, mat
+
+
+@pytest.mark.parametrize("world,cap,out_cap,window", [
+    (1, 30408704, 16252928, 1024), (1, 7602176, 1179648, 4096),
+    (1, 30408704, 16252928, 0), (4, 1 << 21, 1 << 20, 2048)])
+def test_filter_programs_compile_for_v5e(topo, monkeypatch, world, cap,
+                                         out_cap, window):
+    """``repart__filter_count_fn`` (ONE one-operand sort, no scatter) and
+    ``repart__filter_mat_fn`` with the windowed take inside at the lane
+    width of Q3's ``lineitem`` - and with XLA's gather, whose program holds
+    no scatter and no sort either since the index comes sorted."""
+    import re
+    from cylon_tpu.ctx.context import ROW_AXIS
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices[:world]), (ROW_AXIS,))
+    spec, count, mat = _filter_programs(mesh, cap, out_cap, window)
+    assert spec.n_lanes == 18
+    text = count.as_text()
+    sorts = re.findall(r"(?m)^.* = (\S+) sort\(", text)
+    assert len(sorts) == 1 and sorts[0].startswith("s32["), sorts
+    assert " scatter(" not in text
+    assert _has_kernel(mat) == bool(window)
+    assert " sort(" not in mat.as_text() and " scatter(" not in mat.as_text()
