@@ -302,10 +302,10 @@ class _NodeCtx:
     def span_args(self, **args) -> None:
         """Arguments of the open ``cylon.op.<op>`` span that the operator
         learns while it runs (``rows_out`` of a filter): on the profiler's
-        span and in the flight recorder's, like those given at entry."""
-        ann, given = self._live
-        ann.set_metadata(**args)
-        given.update(args)
+        span and in the flight recorder's, like those given at entry
+        (``utils/timing.set_args``, which the phase regions use too: the
+        sort's ``recv_max`` / ``recv_cap``)."""
+        _timing.set_args(self._live, **args)
 
     def __enter__(self):
         self._span = _timing.span("op." + self._op)

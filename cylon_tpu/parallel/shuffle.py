@@ -623,7 +623,8 @@ def exchange(mesh: Mesh, tgt, counts: np.ndarray, cols: tuple,
     # to enqueue its programs (docs/observability.md)
     with timing.span("exchange." + route, rows=total,
                      bytes=total * row_bytes, recv_max=recv_max,
-                     recv_cap=out_cap, block=block, rounds=rounds):
+                     recv_cap=out_cap, block=block, rounds=rounds,
+                     site=owner):
         if hplan is not None:
             # the voted hierarchical route (cylon_tpu/topo/exchange): the
             # plan hash is consensus-adopted BEFORE the first hierarchical

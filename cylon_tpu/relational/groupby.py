@@ -876,17 +876,18 @@ def _groupby_aggregate_impl(table: Table, by, aggs, ddof: int = 1) -> Table:
         cargs = (vc, by_datas, by_valids, uval_datas, uval_valids)
         # the partial sums are summed again across shards: two lanes
         cforms = _sum_forms(specs, val_cols, cap_full, two_lanes=True)
-        (key_out, kval_out, inter_out, _), n_groups = dispatch_at_bucket(
-            _SEG_CACHE,
-            ("combine-seg", env.serial, ops_t, tuple(by), narrow, cap_full,
-             int(table.valid_counts.sum())), cap_full,
-            lambda sc, win: _combine_fn(env.mesh, ops_t, sc, False, narrow,
-                                        cforms, cspec, val_map,
-                                        win)(*cargs),
-            partial(_read_meta, env.world_size),
-            # the gather the window serves exists on the sort path alone
-            _density_window(env.mesh, table.valid_counts)
-            if cspec is not None else None).resolve()
+        with timing.region("groupby.combine"):
+            (key_out, kval_out, inter_out, _), n_groups = dispatch_at_bucket(
+                _SEG_CACHE,
+                ("combine-seg", env.serial, ops_t, tuple(by), narrow,
+                 cap_full, int(table.valid_counts.sum())), cap_full,
+                lambda sc, win: _combine_fn(env.mesh, ops_t, sc, False,
+                                            narrow, cforms, cspec, val_map,
+                                            win)(*cargs),
+                partial(_read_meta, env.world_size),
+                # the gather the window serves exists on the sort path alone
+                _density_window(env.mesh, table.valid_counts)
+                if cspec is not None else None).resolve()
         # intermediate table: keys + flat intermediate columns
         cols = {}
         for n, c, d, v in zip(by, by_cols, key_out, kval_out):
@@ -900,24 +901,26 @@ def _groupby_aggregate_impl(table: Table, by, aggs, ddof: int = 1) -> Table:
                                   None, None)
                 inames.append(cn)
             inames_by_op.append(inames)
-        inter_table = _shrink(Table(cols, env, n_groups), n_groups)
         # phase 2: shuffle intermediates by key hash, final combine
-        shuffled = shuffle_table(inter_table, by)
+        with timing.region("groupby.shuffle"):
+            inter_table = _shrink(Table(cols, env, n_groups), n_groups)
+            shuffled = shuffle_table(inter_table, by, owner="groupby.recv")
         s_by_datas, s_by_valids = col_arrays([shuffled.column(n) for n in by])
         inter_by_op = tuple(
             tuple(shuffled.column(cn).data for cn in inames)
             for inames in inames_by_op)
         vc2 = np.asarray(shuffled.valid_counts, np.int32)
         fin_cap = max(shuffled.capacity, 1)
-        key2, kval2, res_d, res_v, ng2 = _final_fn(
-            env.mesh, ops_t, fin_cap, ddof, narrow)(
-                vc2, s_by_datas, s_by_valids, inter_by_op)
-        # phase 2 sums partial sums, whose bounds nobody knows
-        _SUM_SCANS["pair64"].inc(sum(
-            nm not in ("min", "max") and np.dtype(a.dtype).kind in "iu"
-            for op, arrs in zip(ops_t, inter_by_op)
-            for nm, a in zip(INTER_NAMES[op], arrs)))
-        ng2 = host_array(ng2).astype(np.int64)
+        with timing.region("groupby.final"):
+            key2, kval2, res_d, res_v, ng2 = _final_fn(
+                env.mesh, ops_t, fin_cap, ddof, narrow)(
+                    vc2, s_by_datas, s_by_valids, inter_by_op)
+            # phase 2 sums partial sums, whose bounds nobody knows
+            _SUM_SCANS["pair64"].inc(sum(
+                nm not in ("min", "max") and np.dtype(a.dtype).kind in "iu"
+                for op, arrs in zip(ops_t, inter_by_op)
+                for nm, a in zip(INTER_NAMES[op], arrs)))
+            ng2 = host_array(ng2).astype(np.int64)
         out = _result_table(env, by, by_cols, key2, kval2, res_names, res_d,
                             res_v, res_types, res_dicts, ng2)
         out = _shrink(out, ng2)

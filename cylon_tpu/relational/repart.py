@@ -163,17 +163,17 @@ def shuffle_table(table: Table, key_names,
             return _rebuild(recipe, new_flat, new_valid, env)
 
 
-def exchange_by_targets(table: Table, tgt, counts: np.ndarray) -> Table:
+def exchange_by_targets(table: Table, tgt, counts: np.ndarray,
+                        owner: str = "shuffle.recv") -> Table:
     """Exchange with caller-computed per-row targets (range partition etc.)."""
     flat, recipe = _flatten_for_exchange(table)
-    new_flat, new_valid = shuffle.exchange(table.env.mesh, tgt, counts, flat)
-    return _rebuild(recipe, new_flat, new_valid, table.env)
+    out = shuffle.exchange(table.env.mesh, tgt, counts, flat, owner=owner)
+    return _rebuild(recipe, *out, table.env)
 
 
 # ---------------------------------------------------------------------------
 # repartition (reference table.cpp:1481, repartition.hpp:94 index math)
 # ---------------------------------------------------------------------------
-
 @program_cache()
 def _range_targets_fn(mesh: Mesh, cap: int):
     def per_shard(vc, offs, bounds, _probe):

@@ -25,6 +25,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from .. import config
+from ..obs import metrics as _metrics
 from ..utils.cache import jit, program_cache
 from ..core.column import Column
 from ..core.table import Table
@@ -45,6 +46,9 @@ shard_map = jax.shard_map
 #: samples per shard for splitter selection (reference SortOptions.num_samples;
 #: 0 = scale with the world size, config.sort_samples)
 DEFAULT_SAMPLES = 0
+
+#: one count a sort that took the ``sample_sort`` route (world > 1, rows > 0)
+_SAMPLE_SORTS = _metrics.counter("sort_sample_sorts")
 
 def _norm_dirs(by, ascending):
     if isinstance(ascending, bool):
@@ -174,16 +178,19 @@ def _pick_splitters(sample_ops, live, w: int):
     balance, so numpy's NaN-last lexsort is fine here."""
     ops_np = [host_array(o) for o in sample_ops]
     live_np = host_array(live)
-    n_live = int(live_np.sum())
-    # lexicographic argsort over (liveness, op_0, op_1, ...)
-    cols = [~live_np] + [o for o in ops_np]
-    order = np.lexsort(tuple(reversed(cols)))  # last key primary -> reverse
-    take = []
-    for j in range(1, w):
-        pos = min(max((n_live * j) // w, 0), max(n_live - 1, 0))
-        take.append(order[pos])
-    take = np.asarray(take, np.int64)
-    return tuple(o[take] for o in ops_np)
+    # host.sort_splitters: the one host decision between two device
+    # programs of a sort - numpy over the W*m pulled sample rows
+    with timing.span("host.sort_splitters"):
+        n_live = int(live_np.sum())
+        # lexicographic argsort over (liveness, op_0, op_1, ...)
+        cols = [~live_np] + [o for o in ops_np]
+        order = np.lexsort(tuple(reversed(cols)))  # last key primary
+        take = []
+        for j in range(1, w):
+            pos = min(max((n_live * j) // w, 0), max(n_live - 1, 0))
+            take.append(order[pos])
+        take = np.asarray(take, np.int64)
+        return tuple(o[take] for o in ops_np)
 
 
 #: max u32 order lanes per string key (64 prefix bytes).  Past this the
@@ -388,16 +395,22 @@ def _sort_table_impl(table: Table, by: list, ascending,
             pn.annotate(route="sample_sort", num_samples=m,
                         splitters=w - 1)
             _plan.profile_keys(pn, table, by)
+        _SAMPLE_SORTS.inc()
         with timing.region("sort.sample"):
             sample_ops, live = _sample_fn(env.mesh, m, descendings, npos,
                                           narrow_keys)(
                 vc, by_datas, by_valids)
             splitters = _pick_splitters(sample_ops, live, w)
-        with timing.region("sort.exchange"):
+        with timing.region("sort.exchange", samples=m) as open_region:
             tgt = _target_fn(env.mesh, descendings, npos, narrow_keys)(
                 vc, by_datas, by_valids, splitters)
             counts = shuffle.count_targets(env.mesh, tgt)
-            table = exchange_by_targets(table, tgt, counts)
+            table = exchange_by_targets(table, tgt, counts, owner="sort.recv")
+            # how even ``m`` samples a shard made the range partition: the
+            # fullest chip's rows, and the capacity every chip sorts at
+            timing.set_args(open_region,
+                            recv_max=int(table.valid_counts.max()),
+                            recv_cap=int(table.capacity))
 
     # ---- local sort per shard -------------------------------------------
     out = local_sort_table(table, by, ascending, nulls_position)

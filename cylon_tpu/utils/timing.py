@@ -82,7 +82,7 @@ ANNOTATION_PREFIX = "cylon."
 #: not by a check at run time: :func:`span` stays as it is.
 HOST_STEPS = ("skew_operands", "skew_weigh", "skew_detect", "exchange_pack",
               "exchange_plan", "exchange_close", "exchange_unpack",
-              "join_plan")
+              "join_plan", "sort_splitters")
 
 #: name -> [total_seconds, call_count]
 _ACCUM: dict[str, list] = {}
@@ -238,6 +238,16 @@ def span(name: str, **args):
             tr.span(name, t0, time.perf_counter() - t0, args or None)
 
 
+def set_args(live, **args) -> None:
+    """Arguments an open :func:`span` / :func:`region` learns while it runs
+    (``live`` is what the ``with`` yielded): ``set_metadata`` on the
+    profiler's annotation, ``update`` on the flight recorder's arguments,
+    like those given at entry."""
+    ann, given = live
+    ann.set_metadata(**args)
+    given.update(args)
+
+
 @contextlib.contextmanager
 def region(name: str, block=None, **args):
     """Time a named region (when ``config.BENCH_TIMINGS`` — or always,
@@ -253,21 +263,22 @@ def region(name: str, block=None, **args):
     running that is a ``TraceMe`` that checks one flag.  ``args`` (small
     scalars: ``bytes=``, ``rows=``) go to the annotation and to the
     flight recorder's span; the active scope's tag goes in as
-    ``session``."""
+    ``session``.  Yields what :func:`span` yields, for :func:`set_args`."""
     sc = _scope()
     if sc is not None:
         sc.last = name
     else:
         _LAST_REGION[0] = name
-    with _annotation(name, sc, args):
+    ann = _annotation(name, sc, args)
+    with ann:
         if not config.BENCH_TIMINGS and sc is None and _TRACE[0] is None:
-            yield
+            yield ann, args
             return
         t0 = time.perf_counter()
         ex0 = sc._excluded if sc is not None else 0.0
         gex0 = getattr(_SCOPE_TLS, "excluded", 0.0)
         try:
-            yield
+            yield ann, args
         finally:
             if block is not None and not config.TIMING_ASYNC:
                 import jax

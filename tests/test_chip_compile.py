@@ -504,3 +504,78 @@ def test_filter_programs_compile_for_v5e(topo, monkeypatch, world, cap,
     assert " scatter(" not in text
     assert _has_kernel(mat) == bool(window)
     assert " sort(" not in mat.as_text() and " scatter(" not in mat.as_text()
+
+
+# ---- the distributed groupby -> sort (ISSUE 44) ------------------------------
+# What benchmark cell groupby_sort_25m_x4 launches on a mesh of four beside
+# phase 1 (``_combine_fn``, above): phase 2 of the two-phase groupby, whose
+# sums scan as ``pair64`` - partial sums have no bounds - in blocks of 128
+# (PR 28: XLA:TPU's scan rewriter dies on long 64-bit scans of a
+# multi-device program), and the sample sort's three builders with a
+# two-operand int64 key.  A small shard: what the compiler refuses it
+# refuses at any size, and its time grows with the rows.
+
+_GS_SHARD = 17408
+
+
+def _dist_sort_program(mesh, which: str, cap: int):
+    """``(program, abstract args)`` of one builder of the cell's query: an
+    int64 ``sum`` by a narrow int64 key, then a sort by the sum."""
+    from cylon_tpu.ctx.context import ROW_AXIS
+    from cylon_tpu.ops import lanes, pack
+    from cylon_tpu.relational import groupby as rel_gb, sort as rel_sort
+    w = int(mesh.devices.size)
+    rep, row = NamedSharding(mesh, P()), NamedSharding(mesh, P(ROW_AXIS))
+    S = jax.ShapeDtypeStruct
+    vc = S((w,), np.int32, sharding=rep)
+    col = S((w * cap,), np.int64, sharding=row)
+    if which == "final":
+        return (rel_gb._final_fn(mesh, ("sum",), cap, 1, (True,)),
+                (vc, (col,), (None,), ((col,),)))
+    desc, npos, narrow = (False,), pack.NULL_LAST, (False,)
+    if which == "sample":
+        return (rel_sort._sample_fn(mesh, 64, desc, npos, narrow),
+                (vc, (col,), (None,)))
+    if which == "target":
+        splitters = (S((w - 1,), np.int32, sharding=rep),
+                     S((w - 1,), np.uint32, sharding=rep))
+        return (rel_sort._target_fn(mesh, desc, npos, narrow),
+                (vc, (col,), (None,), splitters))
+    # the result of phase 2: the key and the sum, both wide by then
+    vspec = lanes.plan_lanes(("int64", "int64"), (False, False),
+                             (False, False))
+    return (rel_sort._local_sort_fn(mesh, desc, npos, narrow, vspec, (),
+                                    (1,), False),
+            (vc, (col, col), (None, None)))
+
+
+@pytest.mark.parametrize("which", ["final", "sample", "target",
+                                   "local_sort"])
+def test_dist_groupby_sort_compiles_for_four_chips(mesh4, which):
+    """``groupby__final_fn``, ``sort__sample_fn``, ``sort__target_fn`` and
+    ``sort__local_sort_fn`` for four described chips.  Phase 2's 64-bit
+    scans are all in blocks: no (hi, lo) pair ``reduce-window`` runs the
+    length of a shard, the form the rewriter dies on."""
+    import re
+    from cylon_tpu.exec import compiler
+    from cylon_tpu.ops import groupby as gbk
+    program, args = _dist_sort_program(mesh4, which, _GS_SHARD)
+    compiled = compiler.aot_compile(program, *args)
+    text = compiled.as_text()
+    assert not _has_kernel(compiled)
+    sorts = re.findall(r"(?m)^.* = (.+) sort\(", text)
+    if which == "final":
+        wide = _wide_scans(compiled)
+        assert wide                       # pair64: the sums ARE 64-bit scans
+        for line in wide:
+            shapes = re.findall(r"[su]32\[([\d,]+)\]", line.split(
+                " reduce-window(")[0])
+            assert shapes and all(
+                max(int(d) for d in s.split(",")) * gbk._SCAN_BLOCK
+                <= 2 * _GS_SHARD for s in shapes), line
+    elif which == "local_sort":
+        # ONE sort of eight operands, the one-chip cell's: liveness, the
+        # key's (hi, lo), four payload lanes, XLA's own index for stability
+        assert len(sorts) == 1 and sorts[0].count("[") == 8, sorts
+    else:
+        assert not sorts and " all-to-all(" not in text

@@ -82,15 +82,18 @@ def test_chip_smoke_phases_on_cpu_mesh(capsys):
     lives in main(), which must refuse this rig and print no result."""
     import cylon_tpu as ct
     from cylon_tpu.ctx.context import CPUMeshConfig
-    from cylon_tpu.exec import compiler, memory, recovery
+    from cylon_tpu.exec import checkpoint, compiler, memory, recovery
     sys.path.insert(0, REPO)
     try:
         import chip_smoke
     finally:
         sys.path.remove(REPO)
     compiler.install_listener()
+    # what ``check_not_degraded`` reads is the process's: a file that ran
+    # earlier in this xdist worker may have spilled or checkpointed
     recovery.reset_events()
     memory.reset_stats()
+    checkpoint.reset_stats()
     env = ct.CylonEnv(config=CPUMeshConfig(world_size=1))
     inp = chip_smoke.make_inputs(65536, seed=0)
     ref, join_rows = chip_smoke.reference(inp)

@@ -747,9 +747,10 @@ def test_op_and_host_spans_on_the_profilers_clock(env4, profiled):
     exchange's ``op.shuffle`` inside ``op.join``), the named turns
     ``cylon.host.<step>``; every name written, and every literal in the
     source, is of ``timing.HOST_STEPS`` - and the distributed join writes
-    them all, so the vocabulary holds no dead name.  (The rig's four
-    devices stand for the issue's two: the module's programs are compiled
-    for them already.)"""
+    them all but the sort's own (``sort_splitters``: the test below), so
+    the vocabulary holds no dead name.  (The rig's four devices stand for
+    the issue's two: the module's programs are compiled for them
+    already.)"""
     from cylon_tpu.relational import groupby_aggregate, join_tables
     left, right = _toy(env4)
 
@@ -760,7 +761,8 @@ def test_op_and_host_spans_on_the_profilers_clock(env4, profiled):
 
     spans, ring = profiled(query)
     names = [s[0] for s in spans]
-    vocabulary = {"cylon.host." + s for s in timing.HOST_STEPS}
+    vocabulary = {"cylon.host." + s for s in timing.HOST_STEPS
+                  if s != "sort_splitters"}
     assert {n for n in names if n.startswith("cylon.host.")} == vocabulary
     assert _host_step_literals() == (set(timing.HOST_STEPS), [])
     assert [n for n in names if n.startswith("cylon.op.")] == [
@@ -786,8 +788,67 @@ def test_op_and_host_spans_on_the_profilers_clock(env4, profiled):
     assert sum(n.startswith("cylon.host.") for n in names) == 12
     # one source, two sinks: the ring holds them beside launch.* / pull.*
     assert {"op.join", "op.shuffle", "op.groupby", "launch.join__count_fn",
-            "pull.host_array"} | {"host." + s for s in timing.HOST_STEPS} \
+            "pull.host_array"} | {n[len("cylon."):] for n in vocabulary} \
         <= set(ring)
+
+
+def test_distributed_groupby_sort_spans_on_the_profilers_clock(env4,
+                                                               profiled):
+    """``groupby_aggregate`` -> ``sort_table`` on a partitioned table under
+    ``jax.profiler`` (ISSUE 44): the two-phase groupby's three phases are
+    regions, the sort's one host decision between two device programs is
+    the named turn ``cylon.host.sort_splitters`` - no launch and no pull
+    inside it -, the two exchanges say whose they are (``site``), and the
+    sort's exchange region says how even its samples made the partition
+    (``recv_max`` <= ``recv_cap``, ``samples`` a shard)."""
+    from cylon_tpu import config, obs
+    from cylon_tpu.relational import groupby_aggregate, sort_table
+    left, _ = _toy(env4)
+    out = []
+
+    def query():
+        g = groupby_aggregate(left, "k", [("a", "sum")])
+        out[:] = [sort_table(g, "a_sum"), g.capacity]
+
+    taken = obs.counter("sort_sample_sorts")
+    before = taken.value
+    spans, ring = profiled(query)
+    assert taken.value - before == 2             # the warm-up and the run
+    names = [s[0] for s in spans]
+    assert [n for n in names if n.startswith("cylon.op.")] == [
+        "cylon.op.groupby", "cylon.op.shuffle", "cylon.op.sort"]
+    for region in ("groupby.combine", "groupby.shuffle", "groupby.final",
+                   "sort.sample", "sort.exchange", "sort.local",
+                   "host.sort_splitters"):
+        assert names.count("cylon." + region) == 1, region
+    for inner, outer in (
+            ("cylon.launch.groupby__combine_fn", "cylon.groupby.combine"),
+            ("cylon.op.shuffle", "cylon.groupby.shuffle"),
+            ("cylon.launch.groupby__final_fn", "cylon.groupby.final"),
+            ("cylon.groupby.final", "cylon.op.groupby"),
+            ("cylon.launch.sort__sample_fn", "cylon.sort.sample"),
+            ("cylon.host.sort_splitters", "cylon.sort.sample"),
+            ("cylon.launch.sort__target_fn", "cylon.sort.exchange"),
+            ("cylon.exchange.flat", "cylon.sort.exchange"),
+            ("cylon.sort.exchange", "cylon.op.sort")):
+        assert _inside(spans, inner, outer), (inner, outer)
+    step, = (s for s in spans if s[0] == "cylon.host.sort_splitters")
+    assert not any(step[1] <= b[1] and b[2] <= step[2] for b in spans
+                   if b[0].startswith(("cylon.launch.", "cylon.pull.")))
+    # the step lies between the sample's pull and the target program
+    assert max(s[2] for s in spans if s[0] == "cylon.pull.host_array"
+               and s[2] <= step[1]) <= step[1] <= min(
+        s[1] for s in spans if s[0] == "cylon.launch.sort__target_fn")
+    exch = [s[3] for s in spans if s[0] == "cylon.exchange.flat"]
+    assert [a["site"] for a in exch] == ["groupby.recv", "sort.recv"]
+    region, = (s[3] for s in spans if s[0] == "cylon.sort.exchange")
+    got = np.asarray(out[0].valid_counts)
+    assert int(region["samples"]) == min(out[1], config.sort_samples(4))
+    assert int(region["recv_max"]) == got.max() == int(exch[1]["recv_max"])
+    assert int(region["recv_cap"]) == out[0].capacity \
+        == int(exch[1]["recv_cap"]) >= got.max()
+    assert {"groupby.combine", "groupby.shuffle", "groupby.final",
+            "host.sort_splitters", "sort.exchange"} <= set(ring)
 
 
 @pytest.mark.parametrize("nested", [False, True])
