@@ -82,7 +82,7 @@ class PendingReduce:
 
 
 def dispatch_at_bucket(cache, sig, cap_full: int, call, read_meta,
-                       window=None) -> PendingReduce:
+                       window=None, phase: str = "") -> PendingReduce:
     """THE dispatch of a grouped reduce: every reduction, scatter and
     gather of the program runs over ``seg_cap`` slots, and the true group
     count is usually far below the row capacity ``cap_full``.  Enqueues
@@ -102,9 +102,9 @@ def dispatch_at_bucket(cache, sig, cap_full: int, call, read_meta,
     gather for a re-dispatch from the measured counts (None, or window 0:
     XLA's gather); a first-sight dispatch never has one.  The site's
     memory is ``cache[sig] = (bucket, windowed allowed, window)``;
-    ``resolve()`` returns ``(outputs, n_groups)``.  Registry counters:
-    ``grouped_reduce_windowed_dispatches``, ``..._window_overflows`` and a
-    plain settled dispatch's reason (:func:`_note_settled`, file's end)."""
+    ``resolve()`` returns ``(outputs, n_groups)``.  Registry counters
+    (``grouped_reduce_windowed_dispatches``, ``..._window_overflows``, a
+    plain one's reason) and the node's ``phase``: :func:`_note_settled`."""
     def enqueue(seg_cap, win):
         if win:
             _metrics.counter("grouped_reduce_windowed_dispatches").inc()
@@ -135,7 +135,7 @@ def dispatch_at_bucket(cache, sig, cap_full: int, call, read_meta,
             win = window(seg_cap, n_groups)[0] if window and allowed else 0
             res = enqueue(seg_cap, win)
         cache.put(sig, (bucket, allowed, win))
-        _note_settled(bucket, seg_cap, allowed, win, window, n_groups)
+        _note_settled(bucket, seg_cap, allowed, win, window, n_groups, phase)
         return res, n_groups
 
     return PendingReduce(resolve)
@@ -440,18 +440,16 @@ def _combine_fn(mesh: Mesh, ops: tuple, seg_cap: int, grouped: bool,
 
 
 @program_cache()
-def _final_fn(mesh: Mesh, ops: tuple, seg_cap: int, ddof: int, narrow: tuple):
-    """Phase 2 per shard: reduce shuffled intermediates under the new key
-    grouping, finalize each op.
-
-    Rides THE SORT PATH: instead of dense-ranking the keys (sort + gid
-    scatter-back) and per-intermediate segment scatters (~12 ns/row each,
-    worse under collision), the intermediates ride the one rank sort as u32
-    lanes (f64 sums via the index-lane side gather, see :func:`_sort_state`)
-    and every sum-like intermediate (sum/sumsq/count — reduced by summing)
-    comes out of the batched prefix-diff gather; only min/max extrema need
-    segment scatters.  The reference's phase-2 is ``ReduceShuffledResults``
-    (mapreduce/mapreduce.hpp:56-76)."""
+def _final_fn(mesh: Mesh, ops: tuple, seg_cap: int, ddof: int, narrow: tuple,
+              use_window: int = 0):
+    """Phase 2 per shard: reduce the shuffled intermediates under the new
+    key grouping and finalize each op (the reference's
+    ``ReduceShuffledResults``, mapreduce/mapreduce.hpp:56-76).  Rides THE
+    SORT PATH (:func:`_sort_state`): the intermediates ride the one rank
+    sort as u32 lanes (f64 sums by the index lane's side gather) and every
+    sum-like one (sum/sumsq/count) comes out of the batched prefix-diff
+    gather over ``seg_cap`` slots; only min/max extrema are segment
+    scatters.  ``use_window`` and the last output: as :func:`_raw_fn`'s."""
     from ..ops import lanes
 
     def per_shard(vc, by_datas, by_valids, inter_by_op):
@@ -474,10 +472,11 @@ def _final_fn(mesh: Mesh, ops: tuple, seg_cap: int, ddof: int, narrow: tuple):
         n_live = vc[my].astype(jnp.int32)
         starts = gbk.grouped_starts(first, mask, n_live, seg_cap)
         sum_idx = [j for j, k in enumerate(flat_kinds) if k == "sum"]
-        inters_b, key_out, kval_out, _wok = gbk.grouped_reduce(
+        inters_b, key_out, kval_out, win_ok = gbk.grouped_reduce(
             ["sum"] * len(sum_idx), [s_arrs[j] for j in sum_idx],
             [mask] * len(sum_idx), starts, n_live, list(s_by), list(s_byv),
-            seg_cap, key_narrow=narrow, blocked_scans=multi_shard())
+            seg_cap, key_narrow=narrow, use_window=use_window,
+            blocked_scans=multi_shard())
         red_flat = [None] * len(flat_arrs)
         for j, d in zip(sum_idx, inters_b):
             red_flat[j] = d["sum"]
@@ -498,7 +497,8 @@ def _final_fn(mesh: Mesh, ops: tuple, seg_cap: int, ddof: int, narrow: tuple):
             d, v = gbk.finalize(op, inter, ddof)
             res_d.append(d)
             res_v.append(v)
-        return key_out, kval_out, tuple(res_d), tuple(res_v), n_groups.reshape(1)
+        return (key_out, kval_out, tuple(res_d), tuple(res_v),
+                _meta_out(n_groups, win_ok, use_window))
 
     return jit(shard_map(per_shard, mesh=mesh,
                              in_specs=(REP, ROW, ROW, ROW),
@@ -911,16 +911,22 @@ def _groupby_aggregate_impl(table: Table, by, aggs, ddof: int = 1) -> Table:
             for inames in inames_by_op)
         vc2 = np.asarray(shuffled.valid_counts, np.int32)
         fin_cap = max(shuffled.capacity, 1)
+        fargs = (vc2, s_by_datas, s_by_valids, inter_by_op)
         with timing.region("groupby.final"):
-            key2, kval2, res_d, res_v, ng2 = _final_fn(
-                env.mesh, ops_t, fin_cap, ddof, narrow)(
-                    vc2, s_by_datas, s_by_valids, inter_by_op)
+            (key2, kval2, res_d, res_v, _), ng2 = dispatch_at_bucket(
+                _SEG_CACHE,
+                ("final-seg", env.serial, ops_t, tuple(by), narrow, ddof,
+                 fin_cap, int(shuffled.valid_counts.sum())), fin_cap,
+                lambda sc, win: _final_fn(env.mesh, ops_t, sc, ddof, narrow,
+                                          win)(*fargs),
+                partial(_read_meta, env.world_size),
+                _density_window(env.mesh, shuffled.valid_counts),
+                phase="final_").resolve()
             # phase 2 sums partial sums, whose bounds nobody knows
             _SUM_SCANS["pair64"].inc(sum(
                 nm not in ("min", "max") and np.dtype(a.dtype).kind in "iu"
                 for op, arrs in zip(ops_t, inter_by_op)
                 for nm, a in zip(INTER_NAMES[op], arrs)))
-            ng2 = host_array(ng2).astype(np.int64)
         out = _result_table(env, by, by_cols, key2, kval2, res_names, res_d,
                             res_v, res_types, res_dicts, ng2)
         out = _shrink(out, ng2)
@@ -1053,13 +1059,17 @@ _PLAIN = {why: _metrics.counter("grouped_reduce_plain_dispatches",
                       "remembered_plain")}
 
 
-def _note_settled(bucket, seg_cap, allowed, win, window, n_groups):
+def _note_settled(bucket, seg_cap, allowed, win, window, n_groups,
+                  phase: str = ""):
     """One settled dispatch of :func:`dispatch_at_bucket`: a plain one is
     counted under the reason it is plain - asked of the site's own
     ``window`` rule at the segment space the program ran at, no second
     copy of its thresholds - and the groupby plan node (where a profile
     is on) gets the segment bucket the site remembers, the window, and
-    the group density the rule was given."""
+    the group density the rule was given, as ``<phase>segment_space`` /
+    ``<phase>window`` / ``<phase>density``: a call with two sites (route
+    ``combine_shuffle``: phase 1 under the bare names, phase 2 under
+    ``final_``) says both."""
     from ..obs import plan as _plan
     why, dens = "no_window_rule", None
     if window is not None:
@@ -1070,8 +1080,10 @@ def _note_settled(bucket, seg_cap, allowed, win, window, n_groups):
         _PLAIN[why or "remembered_plain"].inc()
     node = _plan.current()          # None with no profile on
     if node is not None and node.op == "groupby":
-        node.annotate(segment_space=int(bucket), window=int(win),
-                      **({} if dens is None else {"density": round(dens, 6)}))
+        attrs = {"segment_space": int(bucket), "window": int(win)}
+        if dens is not None:
+            attrs["density"] = round(dens, 6)
+        node.annotate(**{phase + k: v for k, v in attrs.items()})
 
 
 # ---------------------------------------------------------------------------

@@ -516,6 +516,10 @@ def test_filter_programs_compile_for_v5e(topo, monkeypatch, world, cap,
 # refuses at any size, and its time grows with the rows.
 
 _GS_SHARD = 17408
+#: the cell's own phase 2 (ISSUE 45): a shard is the hash exchange's receive
+#: capacity, the segment space the bucket of its ~15.09M groups a chip -
+#: density 0.69, so ``pick_window`` gives 1024
+_GS_CELL_SHARD, _GS_CELL_SEG = 22020096, 15204352
 
 
 def _dist_sort_program(mesh, which: str, cap: int):
@@ -532,6 +536,9 @@ def _dist_sort_program(mesh, which: str, cap: int):
     if which == "final":
         return (rel_gb._final_fn(mesh, ("sum",), cap, 1, (True,)),
                 (vc, (col,), (None,), ((col,),)))
+    if which == "final_windowed":
+        return (rel_gb._final_fn(mesh, ("sum",), _GS_CELL_SEG, 1, (True,),
+                                 1024), (vc, (col,), (None,), ((col,),)))
     desc, npos, narrow = (False,), pack.NULL_LAST, (False,)
     if which == "sample":
         return (rel_sort._sample_fn(mesh, 64, desc, npos, narrow),
@@ -549,22 +556,29 @@ def _dist_sort_program(mesh, which: str, cap: int):
             (vc, (col, col), (None, None)))
 
 
-@pytest.mark.parametrize("which", ["final", "sample", "target",
-                                   "local_sort"])
-def test_dist_groupby_sort_compiles_for_four_chips(mesh4, which):
+@pytest.mark.parametrize("which", ["final", "final_windowed", "sample",
+                                   "target", "local_sort"])
+def test_dist_groupby_sort_compiles_for_four_chips(mesh4, monkeypatch, which):
     """``groupby__final_fn``, ``sort__sample_fn``, ``sort__target_fn`` and
     ``sort__local_sort_fn`` for four described chips.  Phase 2's 64-bit
     scans are all in blocks: no (hi, lo) pair ``reduce-window`` runs the
-    length of a shard, the form the rewriter dies on."""
+    length of a shard, the form the rewriter dies on.  ``final_windowed``:
+    phase 2 as ``dispatch_at_bucket`` settles it in the cell, at the cell's
+    own shapes - the segment space at the groups' bucket, the windowed
+    Pallas take inside (about a minute of XLA:TPU)."""
     import re
     from cylon_tpu.exec import compiler
     from cylon_tpu.ops import groupby as gbk
-    program, args = _dist_sort_program(mesh4, which, _GS_SHARD)
+    windowed = which == "final_windowed"
+    shard = _GS_CELL_SHARD if windowed else _GS_SHARD
+    if windowed:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    program, args = _dist_sort_program(mesh4, which, shard)
     compiled = compiler.aot_compile(program, *args)
     text = compiled.as_text()
-    assert not _has_kernel(compiled)
+    assert _has_kernel(compiled) == windowed
     sorts = re.findall(r"(?m)^.* = (.+) sort\(", text)
-    if which == "final":
+    if which.startswith("final"):
         wide = _wide_scans(compiled)
         assert wide                       # pair64: the sums ARE 64-bit scans
         for line in wide:
@@ -572,7 +586,7 @@ def test_dist_groupby_sort_compiles_for_four_chips(mesh4, which):
                 " reduce-window(")[0])
             assert shapes and all(
                 max(int(d) for d in s.split(",")) * gbk._SCAN_BLOCK
-                <= 2 * _GS_SHARD for s in shapes), line
+                <= 2 * shard for s in shapes), line
     elif which == "local_sort":
         # ONE sort of eight operands, the one-chip cell's: liveness, the
         # key's (hi, lo), four payload lanes, XLA's own index for stability

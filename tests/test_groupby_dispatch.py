@@ -1,7 +1,7 @@
 """The one dispatcher of a grouped reduce
 (``relational.groupby.dispatch_at_bucket``: predict the segment bucket,
 dispatch, re-dispatch on a mispredict or a window overflow, remember), its
-three call sites end to end, the compiler-crash classifier the recovery
+four call sites end to end, the compiler-crash classifier the recovery
 ladder's final rung keeps, the dense/scatter segment-reduction parity and
 the bounds of the program caches.
 """
@@ -154,19 +154,23 @@ def _site_query(site, env, rng):
         ref = ldf.merge(rdf, on="k")
         return run, ref, fused, "_fused_fn"
     run = lambda: groupby_aggregate(lt, "k", [("a", "sum")])   # noqa: E731
-    return run, ldf, rel_gb, "_combine_fn" if site == "combine" else "_raw_fn"
+    return run, ldf, rel_gb, f"_{site}_fn"
 
 
 @pytest.mark.parametrize("site,world", [("raw", "env1"), ("combine", "env4"),
-                                        ("fused", "env1")])
+                                        ("final", "env4"), ("fused", "env1")])
 def test_site_recovers_from_a_forced_mispredict(site, world, request, rng,
                                                 monkeypatch):
     """Each real call site of the dispatcher, first sight at a 2-slot
     segment space: the mispredict is seen in n_groups, the program runs
     again at the true bucket, the answer is pandas', and the next call is
     one dispatch at the remembered bucket."""
+    from cylon_tpu.relational.common import BoundedCache
     env = request.getfixturevalue(world)
     monkeypatch.setattr(rel_gb, "_FIRST_SEG_CAP", 2)
+    # the two sites of one distributed groupby share the query: no memory
+    # of the other's case
+    monkeypatch.setattr(rel_gb, "_SEG_CACHE", BoundedCache())
     run, ref, mod, builder = _site_query(site, env, rng)
     segs = []
     real = getattr(mod, builder)
@@ -240,7 +244,8 @@ def _sums_match_pandas_at_windows(t, df, log, windows_per_call):
         assert [static[-1] for static, _a, _o in log] == want, log
 
 
-@pytest.mark.parametrize("site,world", [("raw", "env1"), ("combine", "env4")])
+@pytest.mark.parametrize("site,world", [("raw", "env1"), ("combine", "env4"),
+                                        ("final", "env4")])
 def test_site_takes_the_window(site, world, request, rng, monkeypatch):
     """The standalone sites ask for the windowed gather under the fused
     path's rule.  A table with dead rows behind its live prefix (empty
@@ -248,10 +253,14 @@ def test_site_takes_the_window(site, world, request, rng, monkeypatch):
     lesson): first sight at 512 slots, then the true bucket WITH the
     window, no span overflow, every output the plain program's and
     pandas'; the second call is one dispatch with the remembered window;
-    the registry counts both."""
+    the registry counts both.  The distributed associative groupby is two
+    sites a call (``combine``, then ``final`` over the rows the hash
+    exchange delivered): each takes the window and keeps a memory of its
+    own."""
+    from cylon_tpu import config
     env = request.getfixturevalue(world)
-    log, traced, real = _forced_window(
-        monkeypatch, "_combine_fn" if site == "combine" else "_raw_fn")
+    log, traced, real = _forced_window(monkeypatch, f"_{site}_fn")
+    real.cache_clear(env.mesh)     # built by another case: trace it here
     n = 66000                      # cap 69632 on one shard: 3632 dead rows
     df = pd.DataFrame({"k": rng.integers(0, int(n * 0.9), n).astype(np.int64),
                        "a": rng.integers(0, int(n * 0.9), n).astype(np.int64)})
@@ -266,8 +275,21 @@ def test_site_takes_the_window(site, world, request, rng, monkeypatch):
     assert traced and set(traced) == {1024}             # the kernel ran
     meta = np.asarray(win_out[-1]).reshape(env.world_size, 2)
     assert meta[:, 1].all(), "the windowed gather reported a span overflow"
-    assert list(rel_gb._SEG_CACHE.values()) == [(seg_cap, True, 1024)]
-    assert tuple(_window_counters() - before) == (2, 0)
+    # two windowed dispatches a site; the distributed groupby is two sites
+    assert tuple(_window_counters() - before) == (2 if site == "raw" else 4, 0)
+    memory = [(seg_cap, True, 1024)]
+    if site != "raw":
+        # phase 1's bucket holds a shard's distinct keys, phase 2's the
+        # groups a shard owns after the exchange
+        ends = np.cumsum(np.asarray(t.valid_counts))
+        local = max(df["k"].iloc[lo:hi].nunique()
+                    for lo, hi in zip(ends - np.asarray(t.valid_counts), ends))
+        owned = groupby_aggregate(t, "k", [("a", "sum")]).valid_counts
+        both = [(config.pow2ceil(local), True, 1024),
+                (config.pow2ceil(int(owned.max())), True, 1024)]
+        assert memory[0] == both[site == "final"]    # the spied site's own
+        memory = both
+    assert list(rel_gb._SEG_CACHE.values()) == memory
     # every output against the plain program's, group by group on each shard
     plain = real(env.mesh, *static[:-1], 0)(*args)
     np.testing.assert_array_equal(np.asarray(plain[-1]), meta[:, 0])
