@@ -43,7 +43,7 @@ from ..utils import timing
 from ..utils.cache import jit, program_cache
 from ..utils.stages import stage
 from ..ctx.context import ROW_AXIS
-from ..ops import hashing
+from ..ops import hashing, pack
 
 shard_map = jax.shard_map
 
@@ -256,21 +256,87 @@ def skew_split_targets(mesh: Mesh, key_datas, key_valids,
 # of the two-hop route's grouped hops (topo/exchange._tier_round_fn).
 # ---------------------------------------------------------------------------
 
+_RIDE = _metrics.counter("exchange_dispatches", path="ride")
+_PERM = {why: _metrics.counter("exchange_dispatches", path="perm",
+                               reason=why)
+         for why in ("not_32bit", "over_operand_budget")}
+
+
+def ride_rule(cols) -> tuple[int, str | None]:
+    """How an exchange's rows get from source order to destination order —
+    the ONE rule, read from the arrays' shapes and dtypes:
+    ``(sort_operands, why_not)``.  The rows RIDE ``_prep_fn``'s sort by
+    target as its payload operands (a ``(cap, L)`` lane matrix is ``L`` of
+    them, a 1-D array one) where every array is 32-bit and the sort stays
+    within ``pack.SORT_OPERAND_BUDGET`` (a sort's compile time grows with
+    its operands): a moved lane costs ~1.1 ns a row there and ~3–4 through
+    XLA's row gather (PERF.md §6, PR 46).  Otherwise (``why_not``:
+    ``not_32bit`` — an array that is not 32-bit lanes: a float64 side
+    array —, ``over_operand_budget`` — a table past six lanes) the sort is
+    the 2-operand ``(target, position)`` one and the arrays are gathered at
+    its permutation."""
+    if any(np.dtype(c.dtype).itemsize != 4 or c.ndim > 2 for c in cols):
+        return 2, "not_32bit"
+    ops = 1 + sum(c.shape[1] if c.ndim == 2 else 1 for c in cols)
+    if ops > pack.SORT_OPERAND_BUDGET:
+        return 2, "over_operand_budget"
+    return ops, None
+
+
 @program_cache()
-def _prep_fn(mesh: Mesh, w: int):
-    """Per shard: the stable order of the rows by destination — the source
-    permutation ``perm``, computed once and reused by every round.
-    Destination ``d``'s rows are the run ``[offs[d], offs[d] + C[my, d])``
-    of it (``offs`` = exclusive prefix of the count matrix's row ``my``);
-    padding rows (target ``w``) sort last and belong to no run."""
+def _prep_fn(mesh: Mesh, w: int, ride: bool):
+    """Per shard: the arrays of ``cols`` in the stable order of the rows by
+    destination, computed once and read by every round.  Destination
+    ``d``'s rows are the run ``[offs[d], offs[d] + C[my, d])`` of it
+    (``offs`` = exclusive prefix of the count matrix's row ``my``); padding
+    rows (target ``w``) sort last and belong to no run.  ``ride``
+    (:func:`ride_rule`): the arrays' lanes are the payload operands of the
+    ONE sort by ``(target, position)``, which moves them exactly as the
+    permutation would, and the program holds no gather; else the sort
+    gives the permutation and each array is gathered at it."""
 
-    def per_shard(tgt):
-        idx = jnp.arange(tgt.shape[0], dtype=jnp.int32)
-        _tgt_s, perm = jax.lax.sort((tgt, idx), num_keys=1, is_stable=True)
-        return perm
+    def per_shard(tgt, cols):
+        if not ride:
+            idx = jnp.arange(tgt.shape[0], dtype=jnp.int32)
+            _tgt_s, perm = jax.lax.sort((tgt, idx), num_keys=1,
+                                        is_stable=True)
+            with stage("gather_rows"):
+                return tuple(col[perm] for col in cols)
+        lanes = [lane for col in cols for lane in
+                 ([col] if col.ndim == 1 else
+                  [col[:, j] for j in range(col.shape[1])])]
+        cap = tgt.shape[0]
+        bits = max(cap - 1, 1).bit_length()
+        if (w + 1) << bits <= 1 << 32:
+            # (target, position) in ONE word: the keys are unique, so the
+            # order is the stable one with no tie-break operand (XLA:TPU
+            # gives a stable sort an iota of its own: 2 + L operands)
+            key = (tgt.astype(jnp.uint32) << bits) \
+                | jnp.arange(cap, dtype=jnp.uint32)
+            srt = jax.lax.sort((key, *lanes), num_keys=1, is_stable=False)
+        else:
+            srt = jax.lax.sort((tgt, *lanes), num_keys=1, is_stable=True)
+        srt = iter(srt[1:])
+        return tuple(next(srt) if col.ndim == 1 else
+                     jnp.stack([next(srt) for _ in range(col.shape[1])],
+                               axis=1) for col in cols)
 
-    return jit(shard_map(per_shard, mesh=mesh, in_specs=(P(ROW_AXIS),),
-                             out_specs=P(ROW_AXIS)))
+    def fn(tgt, cols):
+        specs = (P(ROW_AXIS),) * len(cols)
+        return shard_map(per_shard, mesh=mesh, in_specs=(P(ROW_AXIS), specs),
+                         out_specs=specs)(tgt, cols)
+
+    return jit(fn)
+
+
+def sort_by_target(mesh: Mesh, w: int, tgt, cols: tuple) -> tuple:
+    """The arrays of ``cols`` target-sorted (``_prep_fn``) the way
+    :func:`ride_rule` says, counted in ``exchange_dispatches{path=…}``:
+    what every round body takes — the flat engine's and each hop's of the
+    two-hop route."""
+    _ops, why = ride_rule(cols)
+    (_RIDE if why is None else _PERM[why]).inc()
+    return _prep_fn(mesh, w, why is None)(tgt, tuple(cols))
 
 
 def _excl_prefix(c):
@@ -294,9 +360,7 @@ def send_fill(sorted_rows, starts, block: int):
     ``cap`` by config.pow2ceil's step): XLA clamps a slice's start so the
     slice fits, silently, and here that only happens to a window past
     ``cap`` — a later round of a stream that has ended, which holds no
-    valid row.  The padding is a copy's, not the gather's: a gathered row
-    costs 6 ns on a v5e, a copied one a hundredth of it (PERF.md §6,
-    PR 29)."""
+    valid row."""
     with stage("exchange_place"):
         pad = jnp.zeros((block,) + sorted_rows.shape[1:], sorted_rows.dtype)
         padded = jnp.concatenate([sorted_rows, pad])
@@ -341,12 +405,13 @@ def recv_place(out, recv, starts, left, block: int):
         return out
 
 
-def exchange_rounds(perm, counts, outs, cols, *, block: int, rounds: int,
+def exchange_rounds(counts, outs, srt, *, block: int, rounds: int,
                     members, groups=None):
-    """Per shard, inside ``shard_map``: every round of one exchange over
-    the group of ranks I trade with — ``members`` are its global ranks,
-    ascending (all ``w`` ranks for the flat engine, a tier's group for a
-    two-hop hop), ``groups`` the matching ``axis_index_groups``.
+    """Per shard, inside ``shard_map``: every round of one exchange of the
+    target-sorted arrays ``srt`` (:func:`sort_by_target`) over the group
+    of ranks I trade with — ``members`` are its global ranks, ascending
+    (all ``w`` ranks for the flat engine, a tier's group for a two-hop
+    hop), ``groups`` the matching ``axis_index_groups``.
 
     ``rounds > 1`` (skewed counts: some (src,dst) stream exceeds the
     block) runs ALL rounds inside one compiled program via
@@ -357,8 +422,6 @@ def exchange_rounds(perm, counts, outs, cols, *, block: int, rounds: int,
     offs = _excl_prefix(counts[my])[members]
     recv_counts = counts[members, my]
     roffs = _excl_prefix(recv_counts)
-    with stage("gather_rows"):
-        srt = tuple(col[perm] for col in cols)
 
     def one_round(r, outs):
         lo = r * jnp.int32(block)
@@ -378,19 +441,17 @@ def exchange_rounds(perm, counts, outs, cols, *, block: int, rounds: int,
 
 
 def round_program(mesh: Mesh, per_shard):
-    """The jitted ``(perm, counts, outs, cols) -> outs`` program around a
+    """The jitted ``(counts, outs, srt) -> outs`` program around a
     per-shard round body; ``outs`` is donated (the receive buffers are
     updated in place)."""
 
-    def fn(perm, counts, outs, cols):
-        n = len(cols)
-        specs_in = (P(ROW_AXIS), P(), (P(ROW_AXIS),) * n,
-                    (P(ROW_AXIS),) * n)
-        sm = shard_map(per_shard, mesh=mesh, in_specs=specs_in,
-                       out_specs=(P(ROW_AXIS),) * n)
-        return sm(perm, counts, outs, cols)
+    def fn(counts, outs, srt):
+        specs = (P(ROW_AXIS),) * len(srt)
+        sm = shard_map(per_shard, mesh=mesh, in_specs=(P(), specs, specs),
+                       out_specs=specs)
+        return sm(counts, outs, srt)
 
-    return jit(fn, donate_argnums=(2,))
+    return jit(fn, donate_argnums=(1,))
 
 
 @program_cache()
@@ -401,9 +462,8 @@ def _round_fn(mesh: Mesh, w: int, block: int, out_cap: int,
     (``exchange_rounds`` over all ``w`` ranks).  ``out_cap`` is the
     receive buffers' row count: part of the program's identity only."""
 
-    def per_shard(perm, counts, outs, cols):
-        return exchange_rounds(perm, counts, outs, cols, block=block,
-                               rounds=rounds,
+    def per_shard(counts, outs, srt):
+        return exchange_rounds(counts, outs, srt, block=block, rounds=rounds,
                                members=jnp.arange(w, dtype=jnp.int32))
 
     return round_program(mesh, per_shard)
@@ -576,6 +636,9 @@ def exchange(mesh: Mesh, tgt, counts: np.ndarray, cols: tuple,
         _metrics.counter("exchange_rows_total").inc(total)
         _metrics.counter("exchange_bytes_total").inc(total * row_bytes)
         _metrics.counter("exchange_count").inc()
+        # how the rows reach destination order (ride_rule; on the two-hop
+        # route hop 2's: hop 1 sorts one operand more, the target sidecar)
+        sort_ops, no_ride = ride_rule(cols)
         # how UNEVEN it was: the fullest destination's rows and the capacity
         # bucket they set (every chip's receive buffers, and so every later
         # whole-shard program's shape, are sized by the fullest chip)
@@ -624,7 +687,8 @@ def exchange(mesh: Mesh, tgt, counts: np.ndarray, cols: tuple,
     with timing.span("exchange." + route, rows=total,
                      bytes=total * row_bytes, recv_max=recv_max,
                      recv_cap=out_cap, block=block, rounds=rounds,
-                     site=owner):
+                     site=owner, path="perm" if no_ride else "ride",
+                     sort_operands=sort_ops):
         if hplan is not None:
             # the voted hierarchical route (cylon_tpu/topo/exchange): the
             # plan hash is consensus-adopted BEFORE the first hierarchical
@@ -642,13 +706,13 @@ def exchange(mesh: Mesh, tgt, counts: np.ndarray, cols: tuple,
                 # the multi-round protocol actually engaged
                 timing.bump("exchange.multiround")
             counts_i = np.asarray(counts, np.int32)
-            perm = _prep_fn(mesh, w)(tgt)
+            srt = sort_by_target(mesh, w, tgt, cols)
             outs = tuple(_alloc_fn(mesh, out_cap, str(c.dtype),
                                    c.shape[1:])() for c in cols)
             # all rounds run in ONE compiled program (fori_loop if
             # rounds>1)
             fn = _round_fn(mesh, w, block, out_cap, max(rounds, 1))
-            outs = fn(perm, counts_i, outs, tuple(cols))
+            outs = fn(counts_i, outs, srt)
     with timing.span("host.exchange_close"):
         # integrity audit tier (exec/integrity, docs/robustness.md): the
         # corruption drill first (so the audit below is what catches it),
@@ -696,14 +760,14 @@ def _trace_round(mesh):
     one = _unwrap(_round_fn(mesh, w, cap, out_cap, 1))
     i32 = np.int32
 
-    def both(perm, counts, outs, cols):
+    def both(counts, outs, srt):
         # single-round and scan-wrapped multi-round paths in one walk
-        a = one(perm, counts, outs, cols)
-        b = fn(perm, counts, outs, cols)
+        a = one(counts, outs, srt)
+        b = fn(counts, outs, srt)
         return a, b
 
-    args = (S((w * cap,), i32), S((w, w), i32),
-            (S((w * out_cap,), np.int64),), (S((w * cap,), np.int64),))
+    args = (S((w, w), i32), (S((w * out_cap,), np.int64),),
+            (S((w * cap,), np.int64),))
     return jax.make_jaxpr(both)(*args)
 
 
@@ -740,8 +804,16 @@ def _trace_skew_split_targets(mesh):
 
 def _trace_prep(mesh):
     w, cap, S = _decl_shapes(mesh)
-    fn = _unwrap(_prep_fn(mesh, w))
-    return jax.make_jaxpr(fn)(S((w * cap,), np.int32))
+    ride = _unwrap(_prep_fn(mesh, w, True))
+    perm = _unwrap(_prep_fn(mesh, w, False))
+
+    def both(tgt, lanes, side):
+        # the riding sort, and the permutation with its gathers
+        return ride(tgt, (lanes, tgt)), perm(tgt, (lanes, side))
+
+    return jax.make_jaxpr(both)(S((w * cap,), np.int32),
+                                S((w * cap, 2), np.uint32),
+                                S((w * cap,), np.float64))
 
 
 from ..analysis.registry import (declare_builder, decl_shapes as _decl_shapes,  # noqa: E402

@@ -176,13 +176,13 @@ def _tier_round_fn(mesh: Mesh, w: int, n_slices: int, hop: int,
     else:
         groups = [[s * r_ + j for s in range(n_slices)] for j in range(r_)]
 
-    def per_shard(perm, counts, outs, cols):
+    def per_shard(counts, outs, srt):
         my = jax.lax.axis_index(ROW_AXIS)
         if hop == 1:
             members = (my // r_) * r_ + jnp.arange(g, dtype=jnp.int32)
         else:
             members = jnp.arange(g, dtype=jnp.int32) * r_ + (my % r_)
-        return shf.exchange_rounds(perm, counts, outs, cols, block=block,
+        return shf.exchange_rounds(counts, outs, srt, block=block,
                                    rounds=rounds, members=members,
                                    groups=groups)
 
@@ -224,22 +224,22 @@ def two_hop(mesh: Mesh, plan, tgt, counts: np.ndarray, cols: tuple,
     # hop 1: slice-local alignment over ICI, final target as sidecar
     tgt1 = _hop1_targets_fn(mesh, w, s_)(tgt)
     c1_i = np.asarray(c1, np.int32)
-    perm1 = shf._prep_fn(mesh, w)(tgt1)
     cols1 = tuple(cols) + (tgt,)
+    srt1 = shf.sort_by_target(mesh, w, tgt1, cols1)
     outs1 = tuple(shf._alloc_fn(mesh, cap1, str(c.dtype), c.shape[1:])()
                   for c in cols1)
     outs1 = _tier_round_fn(mesh, w, s_, 1, block1, cap1,
-                           max(rounds1, 1))(perm1, c1_i, outs1, cols1)
+                           max(rounds1, 1))(c1_i, outs1, srt1)
 
     # hop 2: aggregated cross-slice delivery over DCN
     vc1 = np.asarray(p.per_gw, np.int32)
     tgt2 = _hop2_targets_fn(mesh, w, cap1)(vc1, outs1[-1])
     c2_i = np.asarray(c2, np.int32)
-    perm2 = shf._prep_fn(mesh, w)(tgt2)
+    srt2 = shf.sort_by_target(mesh, w, tgt2, outs1[:-1])
     outs = tuple(shf._alloc_fn(mesh, out_cap, str(c.dtype), c.shape[1:])()
                  for c in cols)
     outs = _tier_round_fn(mesh, w, s_, 2, block2, out_cap,
-                          max(rounds2, 1))(perm2, c2_i, outs, outs1[:-1])
+                          max(rounds2, 1))(c2_i, outs, srt2)
     return outs, counts.sum(axis=0).astype(np.int64)
 
 
@@ -366,13 +366,13 @@ def _trace_tier_round(mesh):
     hop1 = _unwrap(_tier_round_fn(mesh, w, n_slices, 1, block, out_cap, 3))
     hop2 = _unwrap(_tier_round_fn(mesh, w, n_slices, 2, block, out_cap, 1))
 
-    def both(perm, counts, outs, cols):
-        a = hop1(perm, counts, outs, cols)
-        b = hop2(perm, counts, outs, cols)
+    def both(counts, outs, srt):
+        a = hop1(counts, outs, srt)
+        b = hop2(counts, outs, srt)
         return a, b
 
-    args = (S((w * cap,), i32), S((w, w), i32),
-            (S((w * out_cap,), np.int64),), (S((w * cap,), np.int64),))
+    args = (S((w, w), i32), (S((w * out_cap,), np.int64),),
+            (S((w * cap,), np.int64),))
     return jax.make_jaxpr(both)(*args)
 
 

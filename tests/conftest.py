@@ -105,6 +105,18 @@ def env1():
     return ct.CylonEnv(config=ct.LocalConfig())
 
 
+@pytest.fixture
+def two_tier(env8, monkeypatch):
+    """The 8-rank session env re-declared as 2 slices of 4 (the CPU
+    simulation knob); restores the single-slice view on teardown."""
+    from cylon_tpu.topo import model as topo_model
+    monkeypatch.setenv("CYLON_TPU_SLICES", "2")
+    topo_model._reslice()
+    yield env8
+    monkeypatch.delenv("CYLON_TPU_SLICES")
+    topo_model._reslice()
+
+
 @pytest.fixture()
 def rng():
     return np.random.default_rng(42)

@@ -349,32 +349,49 @@ def _hlo_ops(text: str, opcode: str) -> int:
     return len(re.findall(rf"= \S+ {opcode}(?:-start)?\(", text))
 
 
-@pytest.mark.parametrize("cap,block,out_cap,rounds", [
-    (1 << 21, 17 << 15, 17 << 17, 1),
-    (1 << 23, 17 << 17, 17 << 19, 1),   # dist_join_groupby_8m_x4's shapes
-    (1 << 21, 17 << 13, 17 << 17, 3),
+@pytest.mark.parametrize("cap,lanes,block,out_cap,rounds", [
+    (1 << 23, 2, 17 << 17, 17 << 19, 1),   # dist_join_groupby_8m_x4's shapes
+    (1 << 21, 4, 17 << 13, 17 << 17, 3),   # groupby_sort_25m_x4's four lanes
+    (1 << 21, 2, 17 << 15, 17 << 17, 1),
 ])
-def test_shuffle_round_compiles_for_four_chips(mesh4, cap, block, out_cap,
-                                               rounds):
-    """One table's exchange rounds: the gather by ``perm``, the send blocks
-    as slices of the target-sorted rows, the all_to_all, each received
-    block's valid prefix copied to its final place (one u32 lane matrix of
-    two lanes; ``out_cap`` the next capacity of config.pow2ceil's family).
-    No scatter is left in the program, and exactly the one all_to_all."""
+def test_shuffle_round_compiles_for_four_chips(mesh4, cap, lanes, block,
+                                               out_cap, rounds):
+    """One table's exchange, one u32 lane matrix: ``_prep_fn`` with the
+    lanes riding its sort by target — ONE sort of ``1 + lanes`` operands,
+    the (target, position) key in one word, so the sort is not stable and
+    XLA:TPU adds no tie-break operand of its own — then ``_round_fn``: the
+    send blocks as slices of the target-sorted rows, the all_to_all, each
+    received block's valid prefix copied to its final place (``out_cap``
+    the next capacity of config.pow2ceil's family).  Neither program holds
+    a gather of rows or a scatter, and the rounds exactly the one
+    all_to_all."""
+    import re
     from cylon_tpu.ctx.context import ROW_AXIS
     from cylon_tpu.exec import compiler
     from cylon_tpu.parallel import shuffle
     w = 4
     rep, row = NamedSharding(mesh4, P()), NamedSharding(mesh4, P(ROW_AXIS))
     S = jax.ShapeDtypeStruct
-    prog = shuffle._round_fn(mesh4, w, block, out_cap, rounds)
+    mat = S((w * cap, lanes), np.uint32, sharding=row)
+    assert shuffle.ride_rule((mat,)) == (1 + lanes, None)
+    prep = compiler.aot_compile(
+        shuffle._prep_fn(mesh4, w, True),
+        S((w * cap,), np.int32, sharding=row), (mat,)).as_text()
+    sorts = re.findall(r"^.* = (.*?) sort\(", prep, re.M)
+    assert len(sorts) == 1, sorts
+    assert re.findall(r"[su]32\[\d+\]", sorts[0]) \
+        == ["u32[%d]" % cap] * (1 + lanes)
     text = compiler.aot_compile(
-        prog, S((w * cap,), np.int32, sharding=row),
+        shuffle._round_fn(mesh4, w, block, out_cap, rounds),
         S((w, w), np.int32, sharding=rep),
-        (S((w * out_cap, 2), np.uint32, sharding=row),),
-        (S((w * cap, 2), np.uint32, sharding=row),)).as_text()
+        (S((w * out_cap, lanes), np.uint32, sharding=row),), (mat,)).as_text()
     assert _hlo_ops(text, "all-to-all") == 1
-    assert "scatter" not in text
+    for program in (prep, text):
+        # the count matrix's row and column for the members are picked by
+        # two gathers of ``w`` numbers; no gather of rows
+        assert set(re.findall(r"= (\S+?)\{\S* gather\(", program)) \
+            <= {"s32[%d]" % w}
+        assert "scatter" not in program
 
 
 def test_shuffle_count_compiles_for_four_chips(mesh4):

@@ -185,23 +185,155 @@ def test_round_engine_at_forced_sizes(env4, block, out_cap, rounds):
     tgt = _streams(w, cap, counts, np.random.default_rng(3))
     cols = _payload(w, cap, seed=4)
     mesh = env4.mesh
-    perm = shuffle._prep_fn(mesh, w)(tgt)
+    srt = shuffle.sort_by_target(mesh, w, tgt, cols)
     outs = tuple(shuffle._alloc_fn(mesh, out_cap, str(c.dtype),
                                    c.shape[1:])() for c in cols)
     outs = shuffle._round_fn(mesh, w, block, out_cap, rounds)(
-        perm, counts.astype(np.int32), outs, cols)
+        counts.astype(np.int32), outs, srt)
+    for got, want in zip(outs, _reference(tgt, cols, w, out_cap)):
+        assert (np.asarray(got) == want).all()
+
+
+def _dispatches() -> dict:
+    from cylon_tpu.obs import metrics
+    return {k: v for k, v in metrics.snapshot().items()
+            if k.startswith("exchange_dispatches")}
+
+
+def _one_source_to_one_dest(w, rng):
+    # shard 1 sends its 20,000 rows to rank 2 and nobody else has a row:
+    # three rounds of the 8192-row block, three source shards empty
+    cap = 20_000
+    tgt = np.full(w * cap, w, np.int32)
+    tgt[cap:2 * cap] = 2
+    return cap, tgt
+
+
+def _every_row_to_one(w, rng):
+    return 900, np.full(w * 900, 1, np.int32)
+
+
+_RIDE = 'exchange_dispatches{path="ride"}'
+_PERM = 'exchange_dispatches{path="perm",reason="%s"}'
+
+#: case -> (u32 lanes, side array's dtype or None, traffic, rounds,
+#:          why the rows do not ride or None, sort operands)
+PATHS = {
+    "1_lane": (1, None, _uniform, 1, None, 2),
+    "2_lanes": (2, None, _uniform, 1, None, 3),
+    "4_lanes_3_rounds": (4, None, _one_source_to_one_dest, 3, None, 5),
+    "6_lanes": (6, None, _uniform, 1, None, 7),
+    "2_lanes_and_i32_sidecar": (2, np.int32, _uniform, 1, None, 4),
+    "2_lanes_empty_shard": (2, None, _empty_shard, 1, None, 3),
+    "4_lanes_every_row_to_one": (4, None, _every_row_to_one, 1, None, 5),
+    "7_lanes": (7, None, _uniform, 1, "over_operand_budget", 2),
+    "6_lanes_and_i32_sidecar": (6, np.int32, _empty_shard, 1,
+                                "over_operand_budget", 2),
+    "3_lanes_and_f64_side": (3, np.float64, _uniform, 1, "not_32bit", 2),
+    "f64_side_3_rounds": (2, np.float64, _one_source_to_one_dest, 3,
+                          "not_32bit", 2),
+}
+
+
+def _cols(rng, rows: int, lanes: int, side):
+    cols = (rng.integers(1, 1 << 32, (rows, lanes),
+                         dtype=np.uint64).astype(np.uint32),)
+    if side is np.float64:
+        cols += (rng.random(rows) + 1.0,)
+    elif side is not None:
+        cols += (rng.integers(1, 1 << 31, rows, side),)
+    return cols
+
+
+def _exchange_spans(fn):
+    """``fn()`` and the arguments of the ``exchange.<route>`` spans it
+    opened."""
+    from cylon_tpu.obs import trace
+    rec = trace.arm(capacity=256)
+    try:
+        out = fn()
+        return out, [e[6] for e in rec.events()
+                     if e[2] == "X" and e[3].startswith("exchange.")]
+    finally:
+        trace.disarm()
+
+
+def _bumped(before: dict) -> dict:
+    return {k: v - before[k] for k, v in _dispatches().items()
+            if v != before[k]}
+
+
+@pytest.mark.parametrize("case", list(PATHS))
+def test_riding_and_perm_paths_are_bit_equal(env4, case):
+    """How the rows reach destination order is ``shuffle.ride_rule``'s to
+    say, from the arrays' shapes and dtypes, and changes nothing that is
+    delivered: the path ``exchange`` takes (counted, and on its span), the
+    other one forced, and the riding sort's stable form (what a world too
+    wide for the one-word key gets) fill bit-equal receive buffers — the
+    numpy reference's."""
+    lanes, side, make, rounds, why, sort_ops = PATHS[case]
+    mesh, w = env4.mesh, env4.world_size
+    rng = np.random.default_rng(11)
+    cap, tgt = make(w, rng)
+    cols = _cols(rng, w * cap, lanes, side)
+    assert shuffle.ride_rule(cols) == (sort_ops, why)
+    counts = shuffle.count_targets(mesh, tgt)
+    before = _dispatches()
+    (outs, per_dest), (span,) = _exchange_spans(
+        lambda: shuffle.exchange(mesh, tgt, counts, cols))
+    assert _bumped(before) == {_PERM % why if why else _RIDE: 1}
+    assert span["path"] == ("perm" if why else "ride")
+    assert span["sort_operands"] == sort_ops and span["rounds"] == rounds
+    assert (per_dest == counts.sum(axis=0)).all()
+    out_cap = outs[0].shape[0] // w
+    want = _reference(tgt, cols, w, out_cap)
+    for fn in (shuffle._prep_fn(mesh, w, True),
+               shuffle._prep_fn(mesh, w, False),
+               shuffle._prep_fn(mesh, 1 << 32, True)):   # the stable form
+        alloc = tuple(shuffle._alloc_fn(mesh, out_cap, str(c.dtype),
+                                        c.shape[1:])() for c in cols)
+        forced = shuffle._round_fn(mesh, w, span["block"], out_cap, rounds)(
+            counts.astype(np.int32), alloc, fn(tgt, cols))
+        for got, taken, ref in zip(forced, outs, want):
+            got, taken = np.asarray(got), np.asarray(taken)
+            assert got.dtype == taken.dtype == ref.dtype
+            assert (got == ref).all() and (taken == ref).all()
+
+
+@pytest.mark.parametrize("lanes,side,hops", [
+    (2, None, {_RIDE: 2}),                 # hop 1: 1 + 2 + the sidecar
+    (6, None, {_PERM % "over_operand_budget": 1, _RIDE: 1}),
+    (3, np.float64, {_PERM % "not_32bit": 2}),
+])
+def test_two_hop_hops_take_the_same_rule(two_tier, lanes, side, hops):
+    """Each hop of the two-hop route target-sorts through
+    ``shuffle.sort_by_target``: hop 1 carries the final target as one
+    more 32-bit operand (riding with the lanes where the budget holds
+    both), hop 2 the table's own arrays; order-equal to the flat plan's
+    contract either way."""
+    mesh, w = two_tier.mesh, two_tier.world_size
+    rng = np.random.default_rng(17)
+    cap, tgt = _uniform(w, rng)
+    cols = _cols(rng, w * cap, lanes, side)
+    counts = shuffle.count_targets(mesh, tgt)
+    before = _dispatches()
+    (outs, per_dest), (span,) = _exchange_spans(
+        lambda: shuffle.exchange(mesh, tgt, counts, cols))
+    assert _bumped(before) == hops
+    ops, why = shuffle.ride_rule(cols)      # the span says hop 2's
+    assert (span["sort_operands"], span["path"]) == (
+        ops, "perm" if why else "ride")
+    out_cap = outs[0].shape[0] // w
     for got, want in zip(outs, _reference(tgt, cols, w, out_cap)):
         assert (np.asarray(got) == want).all()
 
 
 def test_block_over_receive_capacity_is_refused(env4):
     w = 4
-    perm = np.zeros(w * 8, np.int32)
     out = np.zeros(w * 4, np.int64)
     with pytest.raises(ValueError, match="exceeds the receive capacity"):
         shuffle._round_fn(env4.mesh, w, 8, 4, 1)(
-            perm, np.zeros((w, w), np.int32), (out,), (np.zeros(w * 8,
-                                                                np.int64),))
+            np.zeros((w, w), np.int32), (out,), (np.zeros(w * 8, np.int64),))
 
 
 def _lowered(prog, *args) -> str:
@@ -218,7 +350,7 @@ def test_round_program_holds_no_scatter_and_one_all_to_all(env4, rounds):
     S = jax.ShapeDtypeStruct
     text = _lowered(
         shuffle._round_fn(env4.mesh, w, block, out_cap, rounds),
-        S((w * cap,), np.int32), S((w, w), np.int32),
+        S((w, w), np.int32),
         (S((w * out_cap, 2), np.uint32),), (S((w * cap, 2), np.uint32),))
     assert "scatter" not in text
     assert len(re.findall(r"\ball_to_all\b", text)) == 1
@@ -243,7 +375,7 @@ def test_tier_round_program_shares_the_placements(env8, hop):
     text = _lowered(
         topo_exchange._tier_round_fn(env8.mesh, w, 2, hop, block, out_cap,
                                      2),
-        S((w * cap,), np.int32), S((w, w), np.int32),
+        S((w, w), np.int32),
         (S((w * out_cap,), np.float64),), (S((w * cap,), np.float64),))
     assert "scatter" not in text
     assert len(re.findall(r"\ball_to_all\b", text)) == 1

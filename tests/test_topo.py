@@ -24,17 +24,6 @@ from cylon_tpu.topo import exchange as topo_exchange, model as topo_model
 
 
 @pytest.fixture
-def two_tier(env8, monkeypatch):
-    """The 8-rank session env re-declared as 2 slices of 4 (the CPU
-    simulation knob); restores the single-slice view on teardown."""
-    monkeypatch.setenv("CYLON_TPU_SLICES", "2")
-    topo_model._reslice()
-    yield env8
-    monkeypatch.delenv("CYLON_TPU_SLICES")
-    topo_model._reslice()
-
-
-@pytest.fixture
 def flat_route(monkeypatch):
     monkeypatch.setattr(config, "TOPO_SHUFFLE", False)
     yield
