@@ -7,7 +7,7 @@ that has not touched JAX:
     python chip_smoke.py [--rows N] [--seed S]      # one chip
     python chip_smoke.py --chips 4 [--rows N]       # one host, four chips
 
-One chip (the default, what the driver runs): bench.py's workload — two
+One chip (the default, what the driver runs): the benchmark's workload — two
 tables of two int64 columns, keys uniform in ``[0, 0.9 n)`` — at 32M rows
 per side (the resident in-HBM regime), through the public entry points:
 
@@ -80,7 +80,7 @@ def check(cond, msg) -> None:
 # ---------------------------------------------------------------------------
 
 def make_inputs(rows: int, seed: int, unique: float = 0.9) -> dict:
-    """bench.py's workload: keys uniform in [0, unique * rows)."""
+    """The benchmark's workload: keys uniform in [0, unique * rows)."""
     import numpy as np
     max_val = max(int(rows * unique), 1)
     rng = np.random.default_rng(seed)

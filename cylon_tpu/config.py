@@ -84,7 +84,7 @@ BENCH_TIMINGS = _env_flag("CYLON_TPU_BENCH", False)
 #: attribution, but it SERIALIZES piece production against join compute,
 #: perturbing exactly the overlap the pipeline exists for.  ``async``:
 #: regions record dispatch-only wall time and the caller blocks ONCE at
-#: iteration end (bench.py) — phase numbers stop hiding overlap.
+#: iteration end (bench_smoke.py) — phase numbers stop hiding overlap.
 TIMING_ASYNC = os.environ.get("CYLON_TPU_TIMING", "block") == "async"
 
 #: Consume range pieces as PACKED windows (relational/piece.PackedPiece):
@@ -111,7 +111,7 @@ PACKED_OVERLAP = _env_flag("CYLON_TPU_PACKED_OVERLAP", True)
 #: piece loop reuses buffers instead of re-allocating per piece.  The
 #: HBM ledger credits donated bytes against pack admission
 #: (exec/memory.ensure_headroom(reuse=)).  Results are bit-equal with
-#: donation on or off (tests/test_pipeline.py::TestPackedPieces).
+#: donation on or off (tests/test_pipeline_packed.py::TestPackedPieces).
 DONATE_BUFFERS = _env_flag("CYLON_TPU_DONATE", True)
 
 #: Route the pipelined join's phase-1 probe (per-row range assignment

@@ -59,7 +59,7 @@ def _interleave() -> None:
     scheduler keeps the device busy ACROSS tenants.  A no-op (one
     module-global load) outside a scheduler.  Piece boundaries are
     also the periodic metrics-snapshot poll for entrypoints that never
-    run the scheduler loop (bench.py; CYLON_TPU_METRICS_JSON) — one
+    run the scheduler loop (CYLON_TPU_METRICS_JSON armed) — one
     list load when unarmed."""
     from ..obs import metrics
     metrics.maybe_write_snapshot()
@@ -308,7 +308,7 @@ class GroupBySink:
         from ..utils import timing
         with timing.sync_region("pipe.consume"):
             # the per-piece host sync of the sink pipeline: its ".block"
-            # twin is where the dispatch/block split (bench.py,
+            # twin is where the dispatch/block split (bench_smoke.py,
             # CYLON_TPU_TIMING=async) charges the device work that every
             # dispatch-only pipe.* marker above it enqueued
             out = h.resolve()

@@ -653,8 +653,8 @@ def _wire_code(fault: CylonError | None) -> int:
     """Ladder consensus encoding: ``Code*4 + sub`` where the predicted
     OOM shape sorts BELOW a real device OOM within the same Code.  The
     max then agrees not just on the retry rung but on the fault TYPE
-    every rank must raise on abort — callers above the ladder (e.g.
-    ``bench_tpch``) dispatch on the class, and a rank aborting with
+    every rank must raise on abort — callers above the ladder (a
+    driver's loop) dispatch on the class, and a rank aborting with
     `predicted` while a peer aborts with `device_oom` would take
     divergent abort-vs-retry branches."""
     if fault is None:

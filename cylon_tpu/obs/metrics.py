@@ -519,7 +519,7 @@ def autoarm() -> None:
 # the shared bench-detail collector
 # ---------------------------------------------------------------------------
 
-#: bench.py's spill-counter selection (exec/memory.stats keys) — the
+#: the spill-counter selection (exec/memory.stats keys) — the
 #: disk-tier pair (``disk_events``/``bytes_to_disk``) rides along so a
 #: bench number always says whether it was achieved HBM-resident,
 #: host-spilled, or out-of-core (docs/robustness.md "Disk tier & scan
@@ -550,8 +550,8 @@ def bench_detail(*, spill_keys=BENCH_SPILL_KEYS, ckpt_keys=BENCH_CKPT_KEYS,
                  audit_keys=BENCH_AUDIT_KEYS,
                  events: str | None = "drain", plan=None) -> dict:
     """The counter block every bench script previously hand-rolled:
-    recovery events (``events="drain"`` empties the log like bench.py
-    always did; ``"keep"`` reads without draining; ``None`` omits),
+    recovery events (``events="drain"`` empties the log, as a driver
+    wants; ``"keep"`` reads without draining; ``None`` omits),
     the selected spill-tier counters (exec/memory.stats) and the
     selected checkpoint counters (exec/checkpoint.stats).  Key names
     are exactly the stats() keys — the bench JSONs' schema is asserted

@@ -422,7 +422,7 @@ def profile_keys(pn, table, key_names, k: int = SKETCH_K) -> None:
 def key_profile(table, key_names, k: int = SKETCH_K,
                 m: int | None = None) -> dict | None:
     """Standalone heavy-hitter profile of ``table``'s key columns —
-    ``bench.py --skew`` reports this for the Zipf key column.  Returns
+    what a driver reports for a Zipf key column.  Returns
     None for empty tables.  ``est_max_rank_share`` is the estimated
     fraction of rows the hottest rank would receive under plain hash
     partitioning: the top key's share plus a uniform spread of the
@@ -522,9 +522,9 @@ def explain_analyze(fn, *args, reset_timings: bool = True,
 
     ``profile_keys=False`` skips the per-node heavy-hitter sampling —
     the one ANALYZE feature that adds device programs and mid-query
-    host pulls of its own.  bench.py's profiled iteration uses this so
-    its ``profiled_iter_s``/phase split stay comparable with
-    pre-profiler rounds (the BENCH_rNN baselines) and the async-mode
+    host pulls of its own.  A driver's profiled iteration uses this so
+    its phase split stays comparable with an unprofiled
+    iteration's and the async-mode
     one-designated-block contract holds.
 
     ``family`` names the query's admission SHAPE FAMILY: after the run

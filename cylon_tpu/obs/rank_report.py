@@ -11,7 +11,7 @@ towering over the median.  This module gathers every rank's phase table
 **Arming contract** (same as the checkpoint tier): unarmed —
 ``CYLON_TPU_RANK_REPORT`` unset and no :func:`arm` call — the report
 never runs: zero extra collectives, zero host syncs, zero allocations
-on the happy path (bench.py consults :func:`armed` before calling).
+on the happy path (a caller consults :func:`armed` before calling).
 Armed, the gather is ONE ``process_allgather`` of a packed float64
 vector over an agreed phase-name set (name agreement verified by crc —
 a rank whose phase table diverged structurally surfaces as a typed

@@ -25,7 +25,7 @@ Bit-equality contract: the kernel implements the IDENTICAL lexicographic
 operands are rebased to int32 through the order-preserving
 ``x ^ 0x8000_0000`` bijection, which preserves both ``>`` and ``==`` —
 so the counts are equal bit-for-bit, asserted for all four join hows in
-tests/test_pipeline.py).  Float64 key operands (kind 'f', NaN-aware
+tests/test_pipeline_packed.py).  Float64 key operands (kind 'f', NaN-aware
 compares) are NOT eligible — callers gate on :func:`supported` and keep
 the XLA path.
 

@@ -1141,7 +1141,7 @@ def join_tables_multi(tables: list, ons: list, how: str = "inner",
     acc = shuffled[0]
     acc_on = list(ons[0])
     for t, on in zip(shuffled[1:], ons[1:]):
-        # Post-suffix tracking of the ACCUMULATED left key names (ADVICE
+        # Post-suffix tracking of the ACCUMULATED left key names (review,
         # r5): when the key name sets are equal the keys coalesce onto the
         # left names; otherwise a left key colliding with a right column
         # is renamed with suffixes[0] (mirror of _join_tables_impl's

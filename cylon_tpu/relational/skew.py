@@ -85,7 +85,7 @@ __all__ = ["SkewPlan", "StitchState", "consume_unstitched", "detect",
            "split_exchange", "stitch_join_output", "last_plan",
            "record_plan", "combine_heavy_partials"]
 
-#: thread-local record of the most recently VOTED plan (bench.py's JSON
+#: thread-local record of the most recently VOTED plan (a driver's JSON
 #: detail and chaos_soak's same-plan-after-recovery assertions read it)
 _TLS = threading.local()
 

@@ -24,30 +24,30 @@ SF10 Q3/Q5 on 8 ranks).  This module provides:
   IN-subqueries over streaming-friendly partsupp semantics, — round
   12, the query profiler's acceptance workload — Q13's customer
   count-distribution (LEFT join + two-level groupby, its EXPLAIN
-  ANALYZE plan recorded in the bench detail), and — round 13, alongside
+  ANALYZE plan held to its shape by the tests), and — round 13, alongside
   the out-of-core disk tier — Q9's product-type profit: six tables,
   five joins (one two-key), the suite's widest join working set and the
   disk tier's natural TPC-H exerciser, and — round 14, alongside the
   adaptive skew-split join route — Q7's volume shipping: lineitem ⋈
   supplier/customer ⋈ nation×2 on a 25-value nation key, where EVERY
   key is a heavy hitter and the naturally skew-shaped Q18 (lineitem
-  groupby-HAVING + 3-way join) gets its EXPLAIN ANALYZE plan recorded
-  in the bench detail beside Q13's, and — round 15, alongside the
+  groupby-HAVING + 3-way join) has its EXPLAIN ANALYZE plan held to its
+  shape by the tests beside Q13's, and — round 15, alongside the
   multi-slice topology tier — Q8's national market share: seven tables
   chained through six shuffle-backed joins, the suite's widest
-  cross-slice working set, its EXPLAIN ANALYZE plan recorded in the
-  bench detail as the two-hop route's query-level audit
+  cross-slice working set, its EXPLAIN ANALYZE plan being the
+  two-hop route's query-level audit
   (docs/topology.md);
-* ``q*_pandas`` — the pandas oracles;
-* :func:`bench_tpch` — the ``bench.py --tpch`` entry.
+* ``q*_pandas`` — the pandas oracles.
+
+Nothing here times anything: the yardstick's TPC-H cell (``benchmark/``,
+``tpch_sf5_q3q5``) drives :func:`q3` and :func:`q5` itself.
 
 Dates are datetime64[ns] columns; scalar date predicates compare against
 integer nanoseconds (``_ts``) since epoch.
 """
 
 from __future__ import annotations
-
-import time
 
 import numpy as np
 import pandas as pd
@@ -624,7 +624,7 @@ def q13(dfs: dict, env=None, word: str = "special requests"):
     who never ordered) land in the c_count = 0 bucket — the left join's
     null extension is exactly what the count distribution measures.
     This is the profiler's acceptance workload: its EXPLAIN ANALYZE plan
-    is recorded in the tpch bench JSON detail (docs/observability.md)."""
+    is held to its shape by the tests (docs/observability.md)."""
     o = dfs["orders"]
     o = o[o["o_comment"] != word][["o_custkey", "o_orderkey"]]
     j = dfs["customer"][["c_custkey"]].merge(
@@ -1389,194 +1389,3 @@ def q20_pandas(pdfs: dict, name_prefix: str = "forest",
     s = s.merge(n[n.n_name == nation], left_on="s_nationkey",
                 right_on="n_nationkey")
     return s.sort_values("s_name")[["s_name"]].reset_index(drop=True)
-
-
-# ---------------------------------------------------------------------------
-# bench entry (bench.py --tpch)
-# ---------------------------------------------------------------------------
-
-# CX suppressed: the bench driver's halving loop is the single-process
-# top-of-stack entry, outside the SPMD region — when armed, the
-# run_with_recovery ladder has already consensus'd the fault before it
-# propagates here, so the rank-local classify/retry below never races a
-# peer mid-collective.
-def bench_tpch(scale: float = 1.0, iters: int = 3) -> dict:  # tracecheck: off[CX401,CX404]
-    """Runs the full query suite at ``scale``; on device OOM the scale halves
-    (the whole-working-set analog of bench.py's rows halving: TPC-H keeps
-    every base table plus query intermediates resident, so past the HBM
-    ceiling no operator-level chunking can save a single chip — the
-    deploy story for SF10+ is a pod slice, deploy/README.md)."""
-    import jax
-
-    from cylon_tpu.exec import checkpoint, recovery, scheduler
-    from cylon_tpu.status import Code, PredictedResourceExhausted
-    # the detail block reports THIS bench invocation's recoveries only
-    # (including failed-attempt events from the halving loop below)
-    recovery.reset_events()
-    checkpoint.reset_stats()
-    spilled_scales: set = set()
-    while True:
-        try:
-            return _bench_tpch_once(scale, iters)
-        except Exception as e:  # noqa: BLE001
-            # classify() is the taxonomy boundary — it also shims foreign
-            # exceptions that carry the XLA OOM message shape (ADVICE r5)
-            fault = recovery.classify(e)
-            if fault is None or fault.code != Code.OutOfMemory \
-                    or scale <= 0.02:
-                raise
-            predicted = isinstance(fault, PredictedResourceExhausted)
-            if predicted and scale not in spilled_scales \
-                    and scheduler.spill_retry() > 0:
-                # prefer the SPILL rung over in-process scale-halving:
-                # a predicted guard fired pre-allocation (HBM clean), so
-                # evicting resident state to host and retrying at the
-                # SAME scale keeps the benchmark's configuration intact
-                # (docs/robustness.md rung ordering); one spill attempt
-                # per scale — a re-fault then falls through to halving
-                spilled_scales.add(scale)
-                print(f"# TPC-H predicted OOM; spilled resident state, "
-                      f"retrying at SF{scale:g}", flush=True)
-                import gc
-                gc.collect()
-                continue
-            if jax.devices()[0].platform != "cpu" and not predicted:
-                # measured (round 5, an earlier runtime): a REAL device
-                # OOM on the TPU POISONS the process — the leaked HBM never returns
-                # and every later allocation fails, so in-process retries
-                # are doomed.  A PREDICTED guard error is different: it
-                # fired before any allocation, HBM is untouched, and the
-                # in-process scale-halving retry below is safe.  (With
-                # durable checkpointing armed the ladder's FINAL rung
-                # already converted this into a ResumableAbort carrying
-                # the resume token — classify() passes it through above
-                # — so this bare-abort advice is the UNARMED path only.)
-                resume_hint = (
-                    "; set CYLON_TPU_CKPT_DIR to make the fresh-process "
-                    "rerun fast-forward past completed pieces "
-                    "(CYLON_TPU_RESUME=1, docs/robustness.md)"
-                    if not checkpoint.enabled() else "")
-                raise RuntimeError(
-                    f"TPC-H SF{scale:g} exceeded device memory and "
-                    "this rig does not recover HBM after an OOM in the "
-                    "same process; rerun at a smaller --scale in a FRESH "
-                    "process, or use scripts/bench_tpch_q3q5.py "
-                    "(column-projected ingest) for large scales"
-                    + resume_hint) from e
-            scale = scale / 2
-            print(f"# TPC-H {fault.kind} OOM; retrying at SF{scale:g}",
-                  flush=True)
-            # the failed attempt's tables/intermediates sit in REFERENCE
-            # CYCLES (DeferredTable thunks close over their tables): the
-            # retry must not inherit their device buffers
-            import gc
-            gc.collect()
-
-
-def _bench_tpch_once(scale: float, iters: int) -> dict:
-    import jax
-    import cylon_tpu as ct
-    from cylon_tpu.ctx.context import CPUMeshConfig, TPUConfig
-
-    devs = jax.devices()
-    on_accel = devs[0].platform != "cpu"
-    env = ct.CylonEnv(config=TPUConfig() if on_accel else CPUMeshConfig())
-    dfs = generate_tables(scale=scale, env=env)
-
-    def run_query(fn):
-        import gc
-
-        def step():
-            out = fn(dfs, env=env)
-            if hasattr(out, "to_pandas"):
-                out.to_pandas()  # materialize to host = completion barrier
-            return out
-        step()  # warmup/compile
-        ts = []
-        for _ in range(iters):
-            t0 = time.perf_counter()
-            step()
-            ts.append(time.perf_counter() - t0)
-        # drop this query's intermediates (incl. cyclic DeferredTable
-        # state) before the next query allocates — at SF10 the base
-        # tables alone hold ~half of HBM
-        gc.collect()
-        return min(ts)
-
-    queries = {"q1": q1, "q3": q3, "q4": q4, "q5": q5, "q6": q6,
-               "q7": q7, "q8": q8, "q9": q9, "q10": q10, "q11": q11,
-               "q12": q12,
-               "q13": q13, "q14": q14, "q15": q15, "q16": q16,
-               "q17": q17, "q18": q18, "q19": q19, "q20": q20,
-               "q21": q21, "q22": q22}
-    times = {name: run_query(fn) for name, fn in queries.items()}
-    # the profiler's acceptance workload (docs/observability.md): one
-    # extra ANALYZE-profiled Q13 run whose plan tree — per-node
-    # rows/bytes/seconds with the phase-table reconciliation block —
-    # rides the bench JSON detail; round 14 adds the naturally
-    # skew-shaped Q18 beside it, so the skew route's decision (or its
-    # absence) on a real query is auditable from the same JSON
-    # (docs/skew.md)
-    from cylon_tpu import obs
-    q13_plan = obs.explain_analyze(lambda: q13(dfs, env=env).to_pandas())
-    q18_plan = obs.explain_analyze(
-        lambda: q18(dfs, env=env, quantity=150).to_pandas())
-    # round 15 adds Q8 beside them — the seven-table national market
-    # share, the suite's widest cross-slice working set: its plan tree
-    # carries every join's exchange totals (and, with the comm matrix
-    # armed on a multi-slice topology, the ICI/DCN tier split) so the
-    # two-hop route's effect on a real query is auditable from the
-    # same JSON (docs/topology.md)
-    q8_plan = obs.explain_analyze(lambda: q8(dfs, env=env).to_pandas())
-    return {
-        "metric": f"TPC-H SF{scale:g} {'+'.join(q.upper() for q in queries)}"
-                  " wall time",
-        "value": round(sum(times.values()), 4),
-        "unit": "seconds",
-        "vs_baseline": 0.0,
-        "detail": {"world": env.world_size, "platform": devs[0].platform,
-                   "scale": scale,
-                   # was this number achieved on the happy path or after
-                   # in-run degradation (docs/robustness.md)?
-                   "recovery_events": _recovery_events(),
-                   # resident vs host-spilled vs OUT-OF-CORE state
-                   # (exec/memory): disk_events/bytes_to_disk > 0 means
-                   # the number rode the disk tier
-                   **{k: v for k, v in _spill_stats().items() if k in
-                      ("spill_events", "bytes_spilled",
-                       "peak_ledger_bytes", "disk_events",
-                       "bytes_to_disk", "bytes_from_disk")},
-                   # durable checkpoint traffic (exec/checkpoint): did
-                   # this number include checkpoint writes, and did a
-                   # resumed run fast-forward instead of recomputing?
-                   # resume_world_mismatch alongside
-                   # resume_resharded_pieces says whether a topology
-                   # change resharded or threw the checkpoint away
-                   **{k: v for k, v in _ckpt_stats().items() if k in
-                      ("checkpoint_events", "bytes_checkpointed",
-                       "resume_fast_forwarded_pieces",
-                       "resume_resharded_pieces", "resume_world_mismatch")},
-                   # EXPLAIN ANALYZE of Q13 (obs/plan): the plan tree
-                   # with per-node seconds + the reconcile block — and
-                   # of the skew-shaped Q18, whose join nodes carry the
-                   # skew route decision when a plan armed (docs/skew.md)
-                   "q13_plan": q13_plan.to_dict(),
-                   "q18_plan": q18_plan.to_dict(),
-                   "q8_plan": q8_plan.to_dict(),
-                   **{f"{n}_s": round(t, 4) for n, t in times.items()}},
-    }
-
-
-def _recovery_events() -> list:
-    from cylon_tpu.exec import recovery
-    return recovery.drain_events()
-
-
-def _spill_stats() -> dict:
-    from cylon_tpu.exec import memory
-    return memory.stats()
-
-
-def _ckpt_stats() -> dict:
-    from cylon_tpu.exec import checkpoint
-    return checkpoint.stats()

@@ -95,7 +95,7 @@ def host_shard_blocks(x, world: int) -> list:
 def sync_pull(arr) -> None:
     """Force execution of everything feeding ``arr`` and wait.
 
-    The ONE barrier of the bench drivers (bench.py, scripts/*,
+    The ONE barrier of the bench drivers (benchmark/, scripts/*,
     chip_smoke.py): ``jax.block_until_ready``, which the directly
     attached runtime honours — no tiny host pull rides behind it."""
     import jax

@@ -6,7 +6,7 @@ tag, ...)`` macro that prints ``[BENCH] tag ms`` on rank 0 when built with
 runtime flag ``config.BENCH_TIMINGS`` (env ``CYLON_TPU_BENCH=1``): when off,
 :func:`region` is one ``jax.profiler.TraceAnnotation`` (a TraceMe that
 checks the profiler's flag) and nothing else; when on, wall-time per named
-region accumulates in a process-global table that ``bench.py`` snapshots
+region accumulates in a process-global table that a driver snapshots
 into its phase-breakdown detail.
 
 :func:`region` is the ONE choke point of the program's own tracing, with
@@ -39,7 +39,7 @@ attribution both slows the profiled iteration and HIDES overlap wins in
 the phase numbers.  ``CYLON_TPU_TIMING=async`` (config.TIMING_ASYNC)
 keeps the regions as dispatch-only markers: ``maybe_block`` becomes a
 no-op, each region records only the host time it took to ENQUEUE its
-work, and the caller blocks once at iteration end (bench.py's final
+work, and the caller blocks once at iteration end (its final
 output sync).  Phase numbers then read as "host time to dispatch": a
 phase that stops dominating dispatch has genuinely left the critical
 path.  Exact per-phase device attribution still needs ``block`` mode.
@@ -56,7 +56,7 @@ the fair-share policy needs per-session dispatch seconds even in
 production runs).  Scopes on different threads are DISJOINT by
 construction — no cross-tenant attribution bleed — while the
 process-global table keeps accumulating the union exactly as before
-(``bench.py``'s snapshot is unchanged).  :func:`last_region` is
+(a driver's snapshot is unchanged).  :func:`last_region` is
 likewise scope-local when a scope is active, so a watchdog fault raised
 on one tenant's thread carries that tenant's phase breadcrumb, not a
 neighbor's.
@@ -220,7 +220,7 @@ def span(name: str, **args):
     exchange's launches) — which nest inside the operator regions:
     entered into the phase/scope tables they would be counted twice in
     every sum over a table (a session's fair-share clock, a plan node's
-    seconds, bench.py's dispatch total).
+    seconds, a driver's dispatch total).
 
     Yields ``(annotation, args)``: what is known only inside the span (a
     filter's ``rows_out``) is added to both - ``set_metadata`` on the

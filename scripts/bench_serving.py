@@ -8,8 +8,8 @@ shuffle boundaries, the HBM ledger is the admission controller, cold
 tenants' packed sources spill under pressure, and every tenant's result
 must stay BIT-EQUAL to its solo (single-session) run.
 
-What one run produces (``SERVING_r01.json`` alongside the BENCH_r0x
-series):
+What one run produces (a JSON report: on stdout, or in the file ``--out``
+names):
 
 * per-tenant p50/p99 query latency, queries and rows/s served;
 * aggregate rows/s across the mix;
@@ -26,7 +26,7 @@ admission until earlier ones drain, and concurrent packers evict each
 other's cold sources through the consensus'd admission path.
 
 ``--families`` switches to the SHAPE-FAMILY compile-cost round
-(SERVING_r03, docs/serving.md "Compile-cost contract"): N single-
+(docs/serving.md "Compile-cost contract"): N single-
 controller tenants whose ingest row counts are near-misses inside ONE
 pow2 shape family run the same join+groupby mix, and the facade's
 compiled-program count must stay FLAT as the tenant count grows 4×
@@ -41,11 +41,10 @@ Usage::
 
     python scripts/bench_serving.py                    # 4 tenants
     python scripts/bench_serving.py --tenants 6 --queries 4 \
-        --policy fair --budget-mb 24 --out SERVING_rNN.json
+        --policy fair --budget-mb 24 --out serving.json
     python scripts/bench_serving.py --tenants 64 --smoke --preempt 8 \
-        --slo-ms 2000 --out SERVING_r02.json   # preemptive serving round
-    python scripts/bench_serving.py --families \
-        --out SERVING_r03.json                 # shape-family round
+        --slo-ms 2000                          # preemptive serving round
+    python scripts/bench_serving.py --families         # shape-family round
 
 Exit status 0 = completed and bit-equal; 1 otherwise.  A trimmed run is
 wired as a slow-marked test (tests/test_scheduler.py).
@@ -551,6 +550,15 @@ def run_families(tenants: int = 16, queries: int = 3,
     }
 
 
+def _write_report(report: dict, out: str | None) -> None:
+    if out is None:
+        print(json.dumps(report, indent=1))
+        return
+    with open(out, "w", encoding="utf-8") as f:
+        json.dump(report, f, indent=1)
+    print(f"# wrote {out}")
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--tenants", type=int, default=4)
@@ -585,7 +593,8 @@ def main() -> int:
                          "compiled-program count, cold vs warm latency, "
                          "bit-equality vs the SHAPE_FAMILIES=0 exact-"
                          "shape oracle); --tenants defaults to 16 here")
-    ap.add_argument("--out", default=None)
+    ap.add_argument("--out", default=None,
+                    help="write the JSON report here (default: stdout)")
     args = ap.parse_args()
 
     if args.families:
@@ -593,9 +602,7 @@ def main() -> int:
         report = run_families(tenants=tenants,
                               queries=max(args.queries, 2),
                               seed=args.seed)
-        out = args.out or os.path.join(REPO, "SERVING_r03.json")
-        with open(out, "w", encoding="utf-8") as f:
-            json.dump(report, f, indent=1)
+        _write_report(report, args.out)
         d = report["detail"]
         cp = d["compiled_programs"]
         print(f"# {report['metric']}: {report['value']} {report['unit']}")
@@ -607,11 +614,8 @@ def main() -> int:
               f"warm_p50={d['warm']['p50_s']}s "
               f"gap={d['cold_warm_gap']}x "
               f"bit_equal={d['bit_equal']}")
-        print(f"# wrote {out}")
         return 0 if (d["bit_equal"] and cp["flat"]
                      and not d["failures"]) else 1
-
-    args.out = args.out or os.path.join(REPO, "SERVING_r01.json")
 
     if args.smoke:
         args.queries = min(args.queries, 2)
@@ -629,8 +633,7 @@ def main() -> int:
                          budget_mb=budget, world=args.world,
                          seed=args.seed, slo_ms=args.slo_ms,
                          preempt_tenants=args.preempt, ckpt_dir=ckpt_dir)
-    with open(args.out, "w", encoding="utf-8") as f:
-        json.dump(report, f, indent=1)
+    _write_report(report, args.out)
     d = report["detail"]
     print(f"# {report['metric']}: {report['value']} {report['unit']}")
     print(f"# bit_equal={d['bit_equal']} "
@@ -641,7 +644,6 @@ def main() -> int:
     print(f"# preemptions={d['scheduler']['preemptions']} "
           f"requeues={d['scheduler']['requeues']} "
           f"outcomes={d['scheduler']['outcomes']}")
-    print(f"# wrote {args.out}")
     return 0 if (d["bit_equal"] and not d["failures"]) else 1
 
 

@@ -1,11 +1,12 @@
 #!/usr/bin/env python
 """Tiny-shape smoke run of the pipelined join+groupby dispatch path.
 
-``bench.py``'s north-star configuration (125M rows/chip through the
+The north-star configuration (125M rows/chip through the
 range-partitioned pipeline with a fused GroupBySink) only runs on
-accelerator rigs — a dispatch-path regression there (a phase silently
-dropped, the sink no longer engaging, the packed-piece path bailing to
-materialize) would otherwise surface first in a slow TPU bench round.
+accelerator rigs, and no benchmark cell takes that route yet — a
+dispatch-path regression there (a phase silently dropped, the sink no
+longer engaging, the packed-piece path bailing to materialize) would
+otherwise surface first on a chip.
 This script runs the SAME code path at <= 64k rows on whatever devices
 exist (CPU mesh included), asserts the expected phase markers were
 recorded, and checks the streamed result equals the monolithic

@@ -12,8 +12,8 @@ mesh under the serving scheduler (the ingest loop is a ``stream``-kind
 session; docs/serving.md), so the numbers describe ingest under mixed
 traffic, not a quiet machine.
 
-What one run produces (``STREAM_r01.json`` alongside BENCH_r0x /
-SERVING_r01):
+What one run produces (a JSON report on stdout, and in the file ``--out``
+names):
 
 * sustained ingest rows/s over the whole loop;
 * p50/p99 append-to-visible staleness — the wall time from an append's
@@ -30,7 +30,7 @@ Usage::
     python scripts/bench_streaming.py                  # default config
     python scripts/bench_streaming.py --smoke          # tiny CI shape
     python scripts/bench_streaming.py --batches 60 --rows 4000 \
-        --no-serve --out STREAM_r02.json
+        --no-serve --out stream.json
 
 Exit status 0 = completed, bit-equal, >= 1 window closed+evicted (the
 acceptance criteria); 1 otherwise.  ``--smoke`` runs as a slow-marked
@@ -271,7 +271,8 @@ def main() -> int:
                          "TPC-H tenant / serving scheduler)")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI shape; assert the acceptance criteria")
-    ap.add_argument("--out", default=os.path.join(REPO, "STREAM_r01.json"))
+    ap.add_argument("--out", default=None,
+                    help="also write the JSON report here")
     args = ap.parse_args()
     if args.smoke:
         args.batches, args.rows, args.keys = 6, 250, 16
@@ -280,9 +281,10 @@ def main() -> int:
     res = run(args)
     d = res["detail"]
     print(json.dumps(res, indent=2))
-    with open(args.out, "w", encoding="utf-8") as f:
-        json.dump(res, f, indent=2)
-        f.write("\n")
+    if args.out:
+        with open(args.out, "w", encoding="utf-8") as f:
+            json.dump(res, f, indent=2)
+            f.write("\n")
     ok = (res["value"] > 0 and d["bit_equal"] and d["windows_bit_equal"]
           and d["windows_closed"] >= 1 and d["window_evictions"] >= 1)
     print(f"# {'OK' if ok else 'FAIL'}: {res['value']} rows/s, "

@@ -5,10 +5,9 @@ Usage:
     python scripts/explain.py A.json B.json        # diff two runs
 
 Accepts either a raw ``QueryPlan.to_dict()`` payload (what
-``obs.explain_analyze(...).to_dict()`` serializes) or a bench JSON that
-carries one — ``detail.plan`` (bench.py), ``detail.plans.<q>``
-(scripts/bench_tpch_q3q5.py: the first query is shown; name one with
-``A.json:q5``) or ``detail.q13_plan`` (the tpch driver).
+``obs.explain_analyze(...).to_dict()`` serializes) or a driver's JSON that
+carries one — ``detail.plan``, ``detail.plans.<q>`` (the first query is
+shown; name one with ``A.json:q5``) or ``detail.q13_plan``.
 
 The diff aligns the two trees positionally, flags structural divergence
 (a different op or child count means the engine CHOSE a different plan

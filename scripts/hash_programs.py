@@ -7,14 +7,14 @@ Runs the benchmark's three query shapes at 2^16 rows on the CPU rig (one
 device and four), records every program they launch with its static key
 and argument shapes, lowers each for a DESCRIBED ``v5e:2x2`` (one chip and
 four) and writes ``sha256[:16]`` of the StableHLO text with ``loc`` stripped;
-then the windowed forms as ``tests/test_chip_compile.py`` builds them
+then the windowed forms as ``tests/chip_compile/helpers.py`` builds them
 (fused at 512 slots and at 8,192 with window 4096; ``groupby__raw_fn`` /
 ``_combine_fn`` at 16,384 with window 1024).  Mosaic's kernel body embeds
 the PATHS and LINE NUMBERS of the traced Python frames - THIS file's
 among them - so both trees must sit at the same path and hold the same copy
-of this script (copy the parent there and this file into it, run, copy the
-change there, run, compare the two files): a windowed program whose hash
-moved is a cold compile of that program on the chip (PERF.md, PRs 30-34).
+of this script and of that helper module (copy the parent there and the two
+files into it, run, copy the change there, run, compare the outputs): a
+windowed program whose hash moved compiles cold on the chip (PERF.md §6).
 Nothing runs on a TPU; nothing here is a device metric."""
 
 from __future__ import annotations
@@ -131,10 +131,10 @@ def hash_all(log: list, tests_dir: str) -> dict:
                 sharding=NamedSharding(mesh, P(*x[2]))),
             args, is_leaf=_is_array_spec))
 
-    # the windowed forms, as tests/test_chip_compile.py builds them; the
+    # the windowed forms, as tests/chip_compile/ builds them; the
     # builders ask the backend whether to interpret the kernel
     sys.path.insert(0, tests_dir)
-    import test_chip_compile as tcc
+    from chip_compile import helpers as tcc
     default_backend, jax.default_backend = jax.default_backend, lambda: "tpu"
     try:
         for world, mesh in meshes.items():

@@ -105,6 +105,13 @@ def env1():
     return ct.CylonEnv(config=ct.LocalConfig())
 
 
+@pytest.fixture(params=["env1", "env4"])
+def env(request):
+    """One device and the mesh of four; a module that wants other worlds
+    defines its own ``env`` (tests/test_pipeline.py)."""
+    return request.getfixturevalue(request.param)
+
+
 @pytest.fixture
 def two_tier(env8, monkeypatch):
     """The 8-rank session env re-declared as 2 slices of 4 (the CPU

@@ -59,7 +59,8 @@ def run_file(path: str, extra: list[str]) -> int:
 
 def main() -> int:
     extra = sys.argv[1:]
-    files = sorted(glob.glob(os.path.join(HERE, "test_*.py")))
+    files = sorted(glob.glob(os.path.join(HERE, "**", "test_*.py"),
+                             recursive=True))
     failed = []
     for f in files:
         print(f"== {os.path.basename(f)}", flush=True)

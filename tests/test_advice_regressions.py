@@ -1,4 +1,4 @@
-"""Regression tests for the round-1 advisor findings (ADVICE.md)."""
+"""Regression tests for the round-1 advisor findings."""
 
 import numpy as np
 import pandas as pd
@@ -130,7 +130,7 @@ class TestReviewFindings:
 
 
 class TestRound2Advice:
-    """Round-2 advisor findings (ADVICE.md r2)."""
+    """Round-2 advisor findings."""
 
     def test_bounded_cache_refresh_keeps_other_entries(self):
         from cylon_tpu.relational.common import BoundedCache
@@ -154,7 +154,7 @@ class TestRound2Advice:
 
 
 class TestRound3Advice:
-    """Round-3 advisor findings (ADVICE.md r3)."""
+    """Round-3 advisor findings."""
 
     def test_fused_pushdown_rejects_string_agg(self, env1):
         # sum over a STRING column of a deferred inner join must raise the

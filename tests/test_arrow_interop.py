@@ -4,14 +4,8 @@ dtype-exact round trips (VERDICT item 5 / reference table.hpp:61-82)."""
 import numpy as np
 import pandas as pd
 import pyarrow as pa
-import pytest
 
 import cylon_tpu as ct
-
-
-@pytest.fixture(params=["env1", "env4"])
-def env(request):
-    return request.getfixturevalue(request.param)
 
 
 def test_from_arrow_numeric_dtypes(env):

@@ -278,7 +278,7 @@ class TestCommMatrix:
             == metrics.counter("exchange_rows_total").value - rows0
 
     def test_profile_keys_opt_out(self, env4):
-        """bench.py's comparability knob: profile_keys=False skips the
+        """A driver's comparability knob: profile_keys=False skips the
         sampler's device programs; nodes carry no heavy profile."""
         lt, rt = _tables(env4, n=20000, hot_frac=0.9)
         qp = obs.explain_analyze(_query, lt, rt, profile_keys=False)

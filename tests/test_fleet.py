@@ -340,7 +340,7 @@ def test_chaos_soak_fleet():
 @pytest.mark.slow
 def test_bench_serving_preemptive_64(tmp_path):
     """ISSUE 18 acceptance: the 64-tenant preemptive serving round
-    (SERVING_r02 shape) — 8 high-priority arrivals against a running
+    — 8 high-priority arrivals against a running
     fleet, per-tenant p99 SLO attainment from the histogram registry,
     the per-tenant outcome table, and every answer bit-equal."""
     sys.path.insert(0, os.path.join(REPO, "scripts"))

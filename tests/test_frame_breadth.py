@@ -12,11 +12,6 @@ from cylon_tpu.status import CylonKeyError
 from utils import assert_frames_equal
 
 
-@pytest.fixture(params=["env1", "env4"])
-def env(request):
-    return request.getfixturevalue(request.param)
-
-
 @pytest.fixture
 def data(rng):
     df = pd.DataFrame({"id": np.arange(20),

@@ -4,17 +4,11 @@ identical results to the general path — checked against the pandas oracle."""
 
 import numpy as np
 import pandas as pd
-import pytest
 
 import cylon_tpu as ct
 from cylon_tpu.relational import groupby_aggregate, join_tables, sort_table
 
 from utils import assert_table_matches
-
-
-@pytest.fixture(params=["env1", "env4"])
-def env(request):
-    return request.getfixturevalue(request.param)
 
 
 def test_join_then_groupby_matches_oracle(env, rng):

@@ -521,7 +521,7 @@ class TestUnarmedContract:
         from cylon_tpu.obs import plan
         from cylon_tpu.relational import groupby_aggregate, join_tables
         assert hashlib.sha256(inspect.getsource(timing.span).encode()) \
-            .hexdigest()[:16] == "a4430b1221e83b64"
+            .hexdigest()[:16] == "8e5acb031509c9c7"
         left, right = _toy(env1, n=512)
 
         def query():

@@ -1,6 +1,6 @@
 """Windowed Pallas gather (ops/pallas_gather) — interpret-mode checks on
-the CPU rig; the real-TPU path is exercised by bench.py and the fused
-groupby dispatch."""
+the CPU rig; the real-TPU path is exercised by the benchmark's cells and
+compiled for a described chip by tests/chip_compile/."""
 
 import jax
 import jax.numpy as jnp

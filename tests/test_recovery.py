@@ -739,8 +739,8 @@ class TestGuardSiteTyped:
         """Ranks following a peer's agreed fault must synthesize a TYPED
         taxonomy fault of the SAME class (the wire encoding separates
         predicted from device OOM) — classify() passes it through,
-        keeping enclosing ladders and type-dispatching callers (e.g.
-        bench_tpch's abort-vs-halve) on the same branch on every rank."""
+        keeping enclosing ladders and type-dispatching callers (a
+        driver's abort-vs-halve) on the same branch on every rank."""
         from cylon_tpu.exec.recovery import _fault_from_wire, _wire_code
         for local in (PredictedResourceExhausted("x"), DeviceOOMError("x"),
                       CapacityOverflowError("x"), RankDesyncError("x")):

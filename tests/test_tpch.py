@@ -1,16 +1,13 @@
 """TPC-H Q3/Q5 against the pandas oracle (BASELINE.md config 4; reference
-validated on TPC-xBB subsets, docs/docs/release/cylon_release_0.4.0.md)."""
+validated on TPC-xBB subsets, docs/docs/release/cylon_release_0.4.0.md),
+the generator, and the disk tier under a TPC-H distribution.  The other
+queries: test_tpch_q1_q9.py, test_tpch_q10_q15.py, test_tpch_q16_q22.py."""
 
 import numpy as np
 import pandas as pd
 import pytest
 
 from cylon_tpu import tpch
-
-
-@pytest.fixture(params=["env1", "env4"])
-def env(request):
-    return request.getfixturevalue(request.param)
 
 
 def test_q3_matches_pandas(env):
@@ -48,298 +45,6 @@ def test_generator_cardinalities():
     od = pdfs["orders"].set_index("o_orderkey").o_orderdate
     assert (li.l_shipdate.to_numpy()
             > od.loc[li.l_orderkey].to_numpy()).all()
-
-
-def test_q1_matches_pandas(env):
-    import cylon_tpu as ct
-    pdfs = tpch.generate_pandas(scale=0.002, seed=3)
-    dfs = {k: ct.DataFrame(v, env=env) for k, v in pdfs.items()}
-    got = tpch.q1(dfs, env=env).to_pandas().reset_index(drop=True)
-    exp = tpch.q1_pandas(pdfs)
-    pd.testing.assert_frame_equal(got, exp[got.columns], check_dtype=False,
-                                  check_exact=False, rtol=1e-6)
-
-
-def test_q6_matches_pandas(env):
-    import cylon_tpu as ct
-    pdfs = tpch.generate_pandas(scale=0.002, seed=4)
-    dfs = {k: ct.DataFrame(v, env=env) for k, v in pdfs.items()}
-    got = tpch.q6(dfs, env=env)
-    exp = tpch.q6_pandas(pdfs)
-    assert abs(got - exp) <= 1e-6 * max(abs(exp), 1.0), (got, exp)
-
-
-def test_q4_matches_pandas(env):
-    pdfs = tpch.generate_pandas(scale=0.005, seed=7)
-    dfs = {k: __import__("cylon_tpu").DataFrame(v, env=env)
-           for k, v in pdfs.items()}
-    got = tpch.q4(dfs, env=env).to_pandas().reset_index(drop=True)
-    exp = tpch.q4_pandas(pdfs)
-    pd.testing.assert_frame_equal(got, exp, check_dtype=False)
-
-
-def test_q10_matches_pandas(env):
-    pdfs = tpch.generate_pandas(scale=0.01, seed=8)
-    dfs = {k: __import__("cylon_tpu").DataFrame(v, env=env)
-           for k, v in pdfs.items()}
-    got = tpch.q10(dfs, env=env).to_pandas().reset_index(drop=True)
-    exp = tpch.q10_pandas(pdfs)
-    pd.testing.assert_frame_equal(got, exp, check_dtype=False,
-                                  check_exact=False, rtol=1e-9)
-
-
-def test_q12_matches_pandas(env):
-    pdfs = tpch.generate_pandas(scale=0.01, seed=9)
-    dfs = {k: __import__("cylon_tpu").DataFrame(v, env=env)
-           for k, v in pdfs.items()}
-    got = tpch.q12(dfs, env=env).to_pandas().reset_index(drop=True)
-    exp = tpch.q12_pandas(pdfs)
-    pd.testing.assert_frame_equal(got, exp, check_dtype=False)
-
-
-def test_q14_matches_pandas(env):
-    import cylon_tpu as ct
-    pdfs = tpch.generate_pandas(scale=0.004, seed=14)
-    dfs = {k: ct.DataFrame(v, env=env) for k, v in pdfs.items()}
-    got = tpch.q14(dfs, env=env)
-    exp = tpch.q14_pandas(pdfs)
-    assert got == pytest.approx(exp, rel=1e-9)
-
-
-def test_q9_matches_pandas(env):
-    """Q9 (round 13, the out-of-core tier's wide-join exerciser): six
-    tables, five joins incl. the two-key partsupp edge, year-grouped
-    profit — bit-checked against the pandas oracle at env1/env4."""
-    import cylon_tpu as ct
-    pdfs = tpch.generate_pandas(scale=0.002, seed=9)
-    dfs = {k: ct.DataFrame(v, env=env) for k, v in pdfs.items()}
-    got = tpch.q9(dfs, env=env).to_pandas().reset_index(drop=True)
-    exp = tpch.q9_pandas(pdfs)
-    assert len(got) == len(exp) > 0
-    pd.testing.assert_frame_equal(got, exp[got.columns], check_dtype=False,
-                                  check_exact=False, rtol=1e-9)
-
-
-def test_q9_generator_year_column_is_derived():
-    """o_orderyear consumes no RNG draws: every pre-round-13 column
-    stays byte-identical (the regression-baseline rule)."""
-    pdfs = tpch.generate_pandas(scale=0.002, seed=9)
-    o = pdfs["orders"]
-    assert (o.o_orderyear.to_numpy()
-            == o.o_orderdate.dt.year.to_numpy()).all()
-
-
-def test_q18_matches_pandas(env):
-    import cylon_tpu as ct
-    # lower HAVING threshold so the tiny scale keeps qualifying orders
-    pdfs = tpch.generate_pandas(scale=0.004, seed=18)
-    dfs = {k: ct.DataFrame(v, env=env) for k, v in pdfs.items()}
-    got = tpch.q18(dfs, env=env, quantity=150).to_pandas() \
-        .reset_index(drop=True)
-    exp = tpch.q18_pandas(pdfs, quantity=150)
-    assert len(got) == len(exp) > 0
-    pd.testing.assert_frame_equal(got, exp, check_dtype=False,
-                                  check_exact=False, rtol=1e-9)
-
-
-def test_q19_matches_pandas(env):
-    import cylon_tpu as ct
-    # Q19's conjunctions select ~1e-5 of lineitem; this scale keeps a
-    # handful of qualifying rows so the assertion is non-vacuous
-    pdfs = tpch.generate_pandas(scale=0.05, seed=19)
-    dfs = {k: ct.DataFrame(v, env=env) for k, v in pdfs.items()}
-    got = tpch.q19(dfs, env=env)
-    exp = tpch.q19_pandas(pdfs)
-    assert exp != 0.0
-    assert got == pytest.approx(exp, rel=1e-9)
-
-
-@pytest.mark.parametrize("qname", ["q16", "q21", "q22"])
-def test_round5_queries_match_pandas(env, qname):
-    """Q16/Q21/Q22 — the semi/anti-join query family (round 5)."""
-    pdfs = tpch.generate_pandas(scale=0.004, seed=16)
-    dfs = {k: __import__("cylon_tpu").DataFrame(v, env=env)
-           for k, v in pdfs.items()}
-    got = getattr(tpch, qname)(dfs, env=env).to_pandas() \
-        .reset_index(drop=True)
-    exp = getattr(tpch, f"{qname}_pandas")(pdfs)
-    assert len(got) == len(exp)
-    pd.testing.assert_frame_equal(got, exp, check_dtype=False,
-                                  check_exact=False, rtol=1e-9)
-
-
-def test_q11_matches_pandas(env):
-    import cylon_tpu as ct
-    pdfs = tpch.generate_pandas(scale=0.004, seed=11)
-    dfs = {k: ct.DataFrame(v, env=env) for k, v in pdfs.items()}
-    got = tpch.q11(dfs, env=env).to_pandas().reset_index(drop=True)
-    exp = tpch.q11_pandas(pdfs)
-    assert len(got) == len(exp) > 0
-    pd.testing.assert_frame_equal(got, exp, check_dtype=False,
-                                  check_exact=False, rtol=1e-9)
-
-
-def test_q15_matches_pandas(env):
-    import cylon_tpu as ct
-    pdfs = tpch.generate_pandas(scale=0.01, seed=15)
-    dfs = {k: ct.DataFrame(v, env=env) for k, v in pdfs.items()}
-    got = tpch.q15(dfs, env=env).to_pandas().reset_index(drop=True)
-    exp = tpch.q15_pandas(pdfs)
-    assert len(got) == len(exp) > 0
-    pd.testing.assert_frame_equal(got, exp, check_dtype=False,
-                                  check_exact=False, rtol=1e-9)
-
-
-def test_q17_matches_pandas(env):
-    import cylon_tpu as ct
-    # brand x container selects ~1/1000 of parts; this scale keeps a
-    # handful of qualifying parts so the assertion is non-vacuous
-    pdfs = tpch.generate_pandas(scale=0.02, seed=17)
-    dfs = {k: ct.DataFrame(v, env=env) for k, v in pdfs.items()}
-    got = tpch.q17(dfs, env=env)
-    exp = tpch.q17_pandas(pdfs)
-    assert exp != 0.0
-    assert got == pytest.approx(exp, rel=1e-9)
-
-
-def test_q20_matches_pandas(env):
-    import cylon_tpu as ct
-    # ~1/6 of parts are forest-named; this scale keeps a non-vacuous
-    # supplier set through the nested INs + correlated half-sum
-    pdfs = tpch.generate_pandas(scale=0.01, seed=20)
-    dfs = {k: ct.DataFrame(v, env=env) for k, v in pdfs.items()}
-    got = tpch.q20(dfs, env=env).to_pandas().reset_index(drop=True)
-    exp = tpch.q20_pandas(pdfs)
-    assert len(got) == len(exp) > 0
-    pd.testing.assert_frame_equal(got, exp, check_dtype=False)
-
-
-def test_q13_matches_pandas(env):
-    """Q13 (round 12) — the LEFT-join count-distribution, bit-checked:
-    integer counts compare exactly, including the c_count = 0 bucket the
-    left join's null extension produces."""
-    import cylon_tpu as ct
-    pdfs = tpch.generate_pandas(scale=0.004, seed=13)
-    dfs = {k: ct.DataFrame(v, env=env) for k, v in pdfs.items()}
-    got = tpch.q13(dfs, env=env).to_pandas().reset_index(drop=True)
-    exp = tpch.q13_pandas(pdfs)
-    assert len(got) == len(exp) > 0
-    pd.testing.assert_frame_equal(got, exp, check_dtype=False)
-
-
-def test_q7_matches_pandas(env):
-    """Q7 (round 14, the adaptive skew-split route's TPC-H exerciser):
-    lineitem ⋈ supplier/customer ⋈ nation×2 on a 25-value nation key —
-    every key a heavy hitter — bit-checked against the pandas oracle at
-    env1/env4 with the skew route armed (its default)."""
-    import cylon_tpu as ct
-    pdfs = tpch.generate_pandas(scale=0.004, seed=7)
-    dfs = {k: ct.DataFrame(v, env=env) for k, v in pdfs.items()}
-    got = tpch.q7(dfs, env=env).to_pandas().reset_index(drop=True)
-    exp = tpch.q7_pandas(pdfs)
-    assert len(got) == len(exp) > 0
-    pd.testing.assert_frame_equal(got, exp[got.columns], check_dtype=False,
-                                  check_exact=False, rtol=1e-9)
-
-
-def test_q8_matches_pandas(env):
-    """Q8 (round 15, the multi-slice topology tier's TPC-H exerciser):
-    national market share — seven tables chained through six
-    shuffle-backed joins, the suite's widest cross-slice working set —
-    bit-checked against the pandas oracle at env1/env4 (docs/
-    topology.md; the two-tier-route equality legs live in
-    tests/test_topo.py)."""
-    import cylon_tpu as ct
-    pdfs = tpch.generate_pandas(scale=0.004, seed=8)
-    dfs = {k: ct.DataFrame(v, env=env) for k, v in pdfs.items()}
-    got = tpch.q8(dfs, env=env).to_pandas().reset_index(drop=True)
-    exp = tpch.q8_pandas(pdfs)
-    assert len(got) == len(exp) > 0
-    pd.testing.assert_frame_equal(got, exp[got.columns], check_dtype=False,
-                                  check_exact=False, rtol=1e-9)
-
-
-def test_q7_generator_year_column_is_derived():
-    """l_shipyear consumes no RNG draws: every pre-round-14 column
-    stays byte-identical (the regression-baseline rule)."""
-    pdfs = tpch.generate_pandas(scale=0.002, seed=7)
-    li = pdfs["lineitem"]
-    assert (li.l_shipyear.to_numpy()
-            == li.l_shipdate.dt.year.to_numpy()).all()
-
-
-def test_q18_explain_analyze_records_plan(env):
-    """Round 14: the naturally skew-shaped Q18's ANALYZE tree (recorded
-    as q18_plan in the tpch bench detail) carries its join route
-    decisions — with the skew route armed, every distributed join node
-    names a route and any skew_split node carries the voted plan
-    summary."""
-    import cylon_tpu as ct
-    from cylon_tpu import obs
-    pdfs = tpch.generate_pandas(scale=0.004, seed=18)
-    dfs = {k: ct.DataFrame(v, env=env) for k, v in pdfs.items()}
-    qp = obs.explain_analyze(
-        lambda: tpch.q18(dfs, env=env, quantity=150).to_pandas())
-    d = qp.to_dict()
-    assert d["roots"], "no plan nodes recorded"
-    joins = []
-
-    def walk(n):
-        if n["op"] == "join":
-            joins.append(n)
-        for c in n.get("children", ()):
-            walk(c)
-    for r in d["roots"]:
-        walk(r)
-    assert joins, "Q18 recorded no join nodes"
-    for n in joins:
-        attrs = n.get("attrs", {})
-        if attrs.get("route") == "skew_split":
-            plan = attrs.get("skew_plan")
-            assert plan and plan.get("plan_hash") and plan.get("fanout")
-
-
-def test_q13_explain_analyze_records_plan(env):
-    """The profiler's acceptance workload: EXPLAIN ANALYZE of Q13 at
-    SF0.01 produces a plan tree whose per-node seconds reconcile with
-    the global phase table (per-region equality up to fp summation) and
-    whose exchange bytes equal the always-on exchange counters."""
-    import cylon_tpu as ct
-    from cylon_tpu import obs
-    from cylon_tpu.obs import metrics
-    pdfs = tpch.generate_pandas(scale=0.01, seed=13)
-    dfs = {k: ct.DataFrame(v, env=env) for k, v in pdfs.items()}
-    rows0 = metrics.counter("exchange_rows_total").value
-    bytes0 = metrics.counter("exchange_bytes_total").value
-    qp = obs.explain_analyze(lambda: tpch.q13(dfs, env=env).to_pandas())
-    d = qp.to_dict()
-    assert d["roots"], "no plan nodes recorded"
-    ops = set()
-
-    def walk(n):
-        ops.add(n["op"])
-        for c in n.get("children", ()):
-            walk(c)
-    for r in d["roots"]:
-        walk(r)
-    assert "join" in ops and "groupby" in ops and "sort" in ops
-    rec = d["reconcile"]
-    # per-node seconds reconcile with the global phase table: every
-    # region second landed in exactly one node's self table
-    assert rec["node_s"] <= rec["phase_s"] + 1e-6
-    assert abs(rec["unattributed_s"]) <= max(0.05 * rec["phase_s"], 0.02)
-    for name, s in rec["per_phase_node_s"].items():
-        assert s == pytest.approx(d["global_phases"][name]["s"],
-                                  rel=1e-4, abs=2e-3), name
-    # exchange bytes attributed to nodes == the counter deltas
-    def sum_xchg(n):
-        return (n.get("bytes_exchanged", 0)
-                + sum(sum_xchg(c) for c in n.get("children", ())))
-    node_bytes = sum(sum_xchg(r) for r in d["roots"])
-    assert node_bytes == metrics.counter("exchange_bytes_total").value \
-        - bytes0
-    if env.world_size == 1:
-        assert metrics.counter("exchange_rows_total").value == rows0
 
 
 def test_round12_generator_addition():
@@ -396,8 +101,7 @@ def test_tpch_out_of_core_disk_tier_bit_equal(env4, monkeypatch, tmp_path):
     working set completes BIT-EQUAL to the uncapped run, with
     disk_events > 0 and bytes_to_disk > 0 — the whole residency ladder
     (device → host → spill files → mmap windows) under a real TPC-H
-    data distribution.  The full-scale run is `bench.py --tpch` under
-    the same env caps; the subprocess legs live in
+    data distribution.  The subprocess legs live in
     `scripts/chaos_soak.py --oocore`."""
     import cylon_tpu as ct
     from cylon_tpu import config
