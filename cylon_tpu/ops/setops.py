@@ -23,10 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ..utils.cache import jit
-
-
-def _first_index_per_group(gids, idx, num_segments_cap):
-    return jax.ops.segment_min(idx, gids, num_segments=num_segments_cap)
+from ..utils.stages import stage
 
 
 @partial(jit, static_argnames=("keep",))
@@ -38,11 +35,12 @@ def unique_flags(gids, mask=None, keep: str = "first"):
     idx = jnp.arange(n, dtype=jnp.int32)
     cap = n + 1
     g = gids if mask is None else jnp.where(mask, gids, jnp.int32(n))
-    if keep == "last":
-        rep = jax.ops.segment_max(idx, g, num_segments=cap)
-    else:
-        rep = jax.ops.segment_min(idx, g, num_segments=cap)
-    flag = rep[g] == idx
+    with stage("setop_flags"):
+        if keep == "last":
+            rep = jax.ops.segment_max(idx, g, num_segments=cap)
+        else:
+            rep = jax.ops.segment_min(idx, g, num_segments=cap)
+        flag = rep[g] == idx
     if mask is not None:
         flag = flag & mask
     return flag
@@ -58,26 +56,29 @@ def set_op_flags(gids_cat, side_is_b, op: str, mask=None):
     * intersect: first A-occurrence of groups present in both
     * subtract:  first A-occurrence of groups absent from B
     """
+    if op not in ("union", "intersect", "subtract"):
+        raise ValueError(f"unknown set op {op}")
     n = gids_cat.shape[0]
     idx = jnp.arange(n, dtype=jnp.int32)
     cap = n + 1
     g = gids_cat if mask is None else jnp.where(mask, gids_cat, jnp.int32(n))
-    a_row = (~side_is_b) if mask is None else ((~side_is_b) & mask)
-    b_row = side_is_b if mask is None else (side_is_b & mask)
-    in_b = jax.ops.segment_max(b_row.astype(jnp.int32), g, num_segments=cap)
-    # first A row of each group (n when group has no A row)
-    first_a = jax.ops.segment_min(jnp.where(a_row, idx, jnp.int32(n)), g,
-                                  num_segments=cap)
     if op == "union":
-        first_any = jax.ops.segment_min(idx, g, num_segments=cap)
-        flag = (first_any[g] == idx)
+        with stage("setop_flags"):
+            first_any = jax.ops.segment_min(idx, g, num_segments=cap)
+            flag = (first_any[g] == idx)
         if mask is not None:
             flag = flag & mask
         return flag
-    if op == "intersect":
-        flag = (first_a[g] == idx) & (in_b[g] > 0)
-    elif op == "subtract":
-        flag = (first_a[g] == idx) & (in_b[g] == 0)
-    else:
-        raise ValueError(f"unknown set op {op}")
+    a_row = (~side_is_b) if mask is None else ((~side_is_b) & mask)
+    b_row = side_is_b if mask is None else (side_is_b & mask)
+    with stage("setop_flags"):
+        in_b = jax.ops.segment_max(b_row.astype(jnp.int32), g,
+                                   num_segments=cap)
+        # first A row of each group (n when group has no A row)
+        first_a = jax.ops.segment_min(jnp.where(a_row, idx, jnp.int32(n)), g,
+                                      num_segments=cap)
+        if op == "intersect":
+            flag = (first_a[g] == idx) & (in_b[g] > 0)
+        else:
+            flag = (first_a[g] == idx) & (in_b[g] == 0)
     return flag & a_row

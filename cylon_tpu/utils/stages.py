@@ -109,3 +109,6 @@ def staged(name: str):
 # PR 30's lesson).
 STAGES["expr"] = ("elementwise column expressions: arithmetic, compares, "
                   "mask logic (series._expr_fn)")
+STAGES["setop_flags"] = ("set operations' membership flags: the segment "
+                         "min / max over the dense ranks and their gathers "
+                         "back to the rows (ops/setops.py)")
