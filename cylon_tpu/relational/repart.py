@@ -436,17 +436,17 @@ def tail(table: Table, n: int) -> Table:
 # row filter (reference: compute.pyx filter path — table[bool_mask])
 # ---------------------------------------------------------------------------
 
-#: one count a filter's materialize dispatch, by the path its rows took and
-#: - where XLA's gather moved them - the test that said so: the words of
-#: ``fused.window_rule``, a table with no u32 lane to stack, a shard
-#: capacity off the DMA tiling, or a measured tile span past the window.
-#: Registered at import so that a snapshot shows the whole family.
-_FILTER_WINDOWED = _metrics.counter("filter_dispatches", path="windowed")
-_FILTER_PLAIN = {why: _metrics.counter("filter_dispatches", path="plain",
-                                       reason=why)
-                 for why in ("not_tpu", "density_below_floor",
-                             "segment_space_small", "laneless_only",
-                             "unsupported_shape", "span_overflow")}
+def path_counters(family: str) -> tuple:
+    """``(windowed, {reason: plain})``: one count a materialize dispatch from
+    sorted kept positions, by the path its rows took and - where XLA's
+    gather moved them - the test that said so: the words of
+    ``fused.window_rule``, a table with no u32 lane to stack, a shard
+    capacity off the DMA tiling, or a measured tile span past the window."""
+    return _metrics.counter(family, path="windowed"), {
+        why: _metrics.counter(family, path="plain", reason=why)
+        for why in ("not_tpu", "density_below_floor",
+                    "segment_space_small", "laneless_only",
+                    "unsupported_shape", "span_overflow")}
 
 
 def _last_kept(srt, n_kept):
@@ -505,9 +505,33 @@ def _filter_mat_fn(mesh: Mesh, cap: int, out_cap: int, spec, window: int):
             out_d[i] = d
         return tuple(out_d), out_v
 
+    if mesh is None:        # the body alone: take_kept
+        return per_shard
     return jit(shard_map(per_shard, mesh=mesh,
                              in_specs=(REP, ROW, ROW, ROW),
                              out_specs=(ROW, ROW)))
+
+
+def take_kept(out_cap: int, spec, window: int):
+    """:func:`_filter_mat_fn`'s per-shard body ``(kept, srt, datas, valids)
+    -> (datas, valids)`` for a program that makes its source first (the set
+    operators').  It stays nested up there: Mosaic's kernel text embeds its
+    frames, and hoisting it would compile every windowed filter cold."""
+    return _filter_mat_fn.__wrapped__(None, 0, out_cap, spec, window)
+
+
+def kept_positions(flag, src_pos, fill: int):
+    """:func:`_filter_count_fn`'s ``(meta, srt)`` for a caller that knows
+    its kept rows' SOURCE positions: the flagged ``src_pos`` (distinct, so
+    ties are among the fill alone and the sort is unstable) ascending,
+    ``fill`` - the source's capacity - behind them."""
+    from ..ops import pallas_gather as pg
+    with stage("compact"):
+        n_kept = jnp.sum(flag, dtype=jnp.int32)
+        srt = jax.lax.sort(jnp.where(flag, src_pos, jnp.int32(fill)),
+                           is_stable=False)
+        span = pg.max_tile_span(srt, _last_kept(srt, n_kept))
+    return jnp.stack([n_kept, span]), srt
 
 
 def filter_window(mesh: Mesh, cap: int, out_cap: int, n_lanes: int,
@@ -533,6 +557,41 @@ def filter_window(mesh: Mesh, cap: int, out_cap: int, n_lanes: int,
     return window, ""
 
 
+_FILTER_PATHS = path_counters("filter_dispatches")
+
+
+def materialize_kept(mesh: Mesh, meta, srt, cap: int, live, n_lanes: int,
+                     paths: tuple, program, *source,
+                     thinning: bool = False) -> tuple:
+    """The host half of a count -> materialize pair whose count program kept
+    :func:`_filter_count_fn`'s contract: pull ``meta``, size the output
+    (``config.pow2ceil`` of the fullest shard's count; a capacity outside
+    that family bounds its own output), ask :func:`filter_window` with the
+    kept density of the fullest shard (``live``: the source's live rows a
+    shard), count the path in ``paths`` (:func:`path_counters`), launch
+    ``program(out_cap, window)`` on ``(counts, srt, *source)``.
+    ``thinning``: the kept rows are FIRST OCCURRENCES (the set operators'),
+    which thin out along their source, so the rule is asked with the
+    density of the widest tile the count program measured where that is
+    lower.  Returns ``((datas, valids), counts, said)``, ``said`` the plan
+    node's ``path`` / ``window`` / ``density`` / ``max_tile_span``."""
+    from ..ops import pallas_gather as pg
+    meta = host_array(meta).astype(np.int64).reshape(-1, 2)
+    counts, max_span = meta[:, 0], int(meta[:, 1].max())
+    out_cap = min(config.pow2ceil(int(counts.max())), cap)
+    full = int(counts.argmax())
+    density = float(counts[full]) / max(int(live[full]), 1)
+    window, why = filter_window(
+        mesh, cap, out_cap, n_lanes,
+        min(density, pg.TILE / max_span) if thinning else density, max_span)
+    windowed, plain = paths
+    (plain[why] if why else windowed).inc()
+    said = {"path": "plain" if why else "windowed", "window": window,
+            "density": round(density, 6), "max_tile_span": max_span}
+    out = program(out_cap, window)(counts.astype(np.int32), srt, *source)
+    return out, counts, said
+
+
 def filter_table(table: Table, flag) -> Table:
     """Keep rows whose boolean flag is set (flag: device bool array with the
     table's row layout).  Row order preserved; distribution keeps each row on
@@ -543,7 +602,7 @@ def filter_table(table: Table, flag) -> Table:
     the output's padding rows may hold).
 
     Two programs, one pull: the count program sorts the kept positions and
-    returns the counts with the widest tile span; the materialize program
+    returns the counts with the widest tile span; :func:`materialize_kept`
     moves the rows at that index - by the windowed Pallas take where
     :func:`filter_window` says the window serves (``filter_dispatches``
     counts the paths), by XLA's gather elsewhere."""
@@ -556,26 +615,17 @@ def filter_table(table: Table, flag) -> Table:
     ctx = _plan.node("filter", columns=len(items))
     with ctx as pn:
         meta, srt = _filter_count_fn(env.mesh, cap)(vc, flag)
-        meta = host_array(meta).astype(np.int64).reshape(-1, 2)
-        counts, max_span = meta[:, 0], int(meta[:, 1].max())
-        rows = {"rows_in": int(vc.sum()), "rows_out": int(counts.sum())}
-        ctx.span_args(**rows)
-        # (a capacity outside pow2ceil's family bounds its own output)
-        out_cap = min(config.pow2ceil(int(counts.max())), cap)
         cols = [c for _, c in items]
         spec = table_lane_spec(cols)
-        full = int(counts.argmax())
-        density = float(counts[full]) / max(int(vc[full]), 1)
-        window, why = filter_window(env.mesh, cap, out_cap, spec.n_lanes,
-                                    density, max_span)
-        (_FILTER_PLAIN[why] if why else _FILTER_WINDOWED).inc()
+        (out_d, out_v), counts, said = materialize_kept(
+            env.mesh, meta, srt, cap, vc, spec.n_lanes, _FILTER_PATHS,
+            lambda out_cap, window: _filter_mat_fn(env.mesh, cap, out_cap,
+                                                   spec, window),
+            *col_arrays(cols))
+        rows = {"rows_in": int(vc.sum()), "rows_out": int(counts.sum())}
+        ctx.span_args(**rows)
         if pn:
-            pn.set(**rows, path="plain" if why else "windowed",
-                   window=window, density=round(density, 6),
-                   max_tile_span=max_span)
-        out_d, out_v = _filter_mat_fn(env.mesh, cap, out_cap, spec, window)(
-            counts.astype(np.int32), srt, tuple(c.data for c in cols),
-            tuple(c.validity for c in cols))
+            pn.set(**rows, **said)
         return build_table(
             [n for n, _ in items], out_d, out_v, [c.type for c in cols],
             [c.dictionary for c in cols], counts, env,

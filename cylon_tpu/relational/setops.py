@@ -7,32 +7,39 @@ distributed wrappers (:1152-1166, shuffle both then local), ``Unique``
 (:1389/:1440 — repartition-to-match then compare).
 
 The reference builds ska::bytell hash sets over row comparators; here rows of
-both tables are dense-ranked together per shard (ops/pack.py — the dual-table
-comparator analog) and membership/uniqueness become segment min/max logic
-(ops/setops.py), followed by a static-capacity compaction.
+both tables are rank-sorted together per shard (ops/pack.py's key operands,
+then the row index: stable) and an operator is the filter's pair of programs
+(relational/repart) behind that sort: the count program reads the row flags
+off the sorted order (ops/setops.py), sorts the kept rows' SOURCE positions
+and returns ``[kept rows, widest tile span]``; the materialize program moves
+the rows at those positions by the filter's body - the windowed Pallas take
+where ``repart.filter_window`` says it serves.  Nothing is scattered back.
 """
 
 from __future__ import annotations
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from .. import config
 from ..utils.cache import jit, program_cache
-from ..core.column import Column
 from ..core.dtypes import LogicalType
 from ..core.table import Table
 from ..obs import metrics as _metrics
+from ..ops import lanes
 from ..ops import pack
 from ..ops import setops as setk
-from ..ops import sort as sortk
+from ..parallel import shuffle
 from ..status import InvalidError
 from ..utils.host import host_array
 from ..utils.stages import stage
+from . import repart
 from .common import (PAD_L, REP, ROW, check_same_env, col_arrays, live_mask,
-                     narrow32_flags, promote_key_pair, rebuild_like)
+                     narrow32_flags, promote_key_pair, rebuild_like,
+                     table_lane_spec)
 from .repart import repartition, shuffle_table
 
 shard_map = jax.shard_map
@@ -44,6 +51,9 @@ _DISPATCHES = {op: _metrics.counter("setop_dispatches", op=op)
                for op in ("unique", "union", "intersect", "subtract")}
 _ROWS_OUT = {op: _metrics.counter("setop_rows_out", op=op)
              for op in _DISPATCHES}
+#: one count a materialize dispatch, by the path its rows took
+#: (``repart.path_counters``: ``windowed``, or ``plain`` with the reason)
+_MAT_PATHS = repart.path_counters("setop_mat_dispatches")
 
 
 def plan_route(env, assume_colocated: bool = False) -> str:
@@ -65,47 +75,67 @@ def _said(ctx, pn, op: str, res: Table, route: str, **args) -> None:
         pn.set(rows_out=res.row_count, route=route)
 
 
+def _rank_sorted(keyops: pack.KeyOps) -> tuple:
+    """``(first, live, sidx)`` in the rank sort's order (keys, then the row
+    index, stable: a group of equal rows is one run in source order): the
+    sorted row starts its run; is no padding row (the liveness flag is
+    ``pack.key_operands``' first operand); its index in the ranked rows."""
+    idx = jnp.arange(keyops.n, dtype=jnp.int32)
+    with stage("sort_keys"):
+        srt = jax.lax.sort(keyops.ops + (idx,), num_keys=len(keyops.ops),
+                           is_stable=True)
+    with stage("setop_flags"):
+        first = (pack.neighbor_flags(srt[:-1], keyops.kinds) != 0) | (idx == 0)
+        live = srt[0] == 0
+    return first, live, srt[-1]
+
+
+def _taken(like: Table, meta, srt, cap: int, live, spec, program,
+           *source) -> Table:
+    """``repart.materialize_kept`` (first occurrences thin out) and its
+    result, in ``like``'s schema; the operator's plan node says the
+    dispatch as the ``filter`` node does."""
+    env = like.env
+    (out_d, out_v), counts, said = repart.materialize_kept(
+        env.mesh, meta, srt, cap, live, spec.n_lanes, _MAT_PATHS, program,
+        *source, thinning=True)
+    from ..obs import plan as _plan
+    pn = _plan.current()
+    if pn is not None and pn.op in ("unique", "set_op"):    # not a chunk's
+        pn.set(**said)
+    return rebuild_like(list(like.columns.items()), out_d, out_v, counts, env)
+
+
 # ---------------------------------------------------------------------------
 # unique (drop_duplicates)
 # ---------------------------------------------------------------------------
 
-def _unique_flags_per_shard(vc, key_datas, key_valids, keep: str, narrow):
-    cap = key_datas[0].shape[0]
-    mask = live_mask(vc, cap)
-    ko = pack.key_operands(list(key_datas), list(key_valids), row_mask=mask,
-                           pad_key=PAD_L, narrow32=narrow)
-    gids, _ = pack.dense_rank(ko)
-    return setk.unique_flags(gids, mask, keep), mask
-
-
 @program_cache()
 def _unique_count_fn(mesh: Mesh, keep: str, narrow: tuple):
-    """``narrow``: static per-key flags (common.narrow32_flags) - a 64-bit
-    integer key whose host-known bounds fit int32 sorts as ONE operand, not
-    a (hi, lo) pair: XLA:TPU compiles a sort in time that grows with its
-    operands (ops/pack.SORT_OPERAND_BUDGET)."""
+    """``repart._filter_count_fn``'s ``(meta, srt)`` for the kept occurrence
+    of each distinct key.  ``narrow``: static per-key flags
+    (common.narrow32_flags) - a 64-bit integer key whose host-known bounds
+    fit int32 sorts as ONE operand, not a (hi, lo) pair: XLA:TPU compiles a
+    sort in time that grows with its operands
+    (ops/pack.SORT_OPERAND_BUDGET)."""
     def per_shard(vc, key_datas, key_valids):
-        flags, _ = _unique_flags_per_shard(vc, key_datas, key_valids, keep,
-                                           narrow)
-        return jnp.sum(flags, dtype=jnp.int32).reshape(1)
+        cap = key_datas[0].shape[0]
+        first, live, sidx = _rank_sorted(pack.key_operands(
+            list(key_datas), list(key_valids), row_mask=live_mask(vc, cap),
+            pad_key=PAD_L, narrow32=narrow))
+        return repart.kept_positions(setk.unique_flags(first, live, keep),
+                                     sidx, cap)
 
     return jit(shard_map(per_shard, mesh=mesh, in_specs=(REP, ROW, ROW),
-                             out_specs=ROW))
+                             out_specs=(ROW, ROW)))
 
 
 @program_cache()
-def _unique_mat_fn(mesh: Mesh, keep: str, narrow: tuple, out_cap: int, spec):
-    from ..ops import lanes
-
-    def per_shard(vc, key_datas, key_valids, datas, valids):
-        flags, _ = _unique_flags_per_shard(vc, key_datas, key_valids, keep,
-                                           narrow)
-        idx, _total = sortk.compact_by_flag(flags, out_cap)
-        # ONE lane-matrix gather for all columns (+ f64 side gathers)
-        return lanes.gather_columns(spec, list(datas), list(valids), idx)
-
-    return jit(shard_map(per_shard, mesh=mesh,
-                             in_specs=(REP, ROW, ROW, ROW, ROW),
+def _unique_mat_fn(mesh: Mesh, spec, out_cap: int, window: int):
+    """``repart._filter_mat_fn``'s program under this family's name: the
+    table's rows at the sorted kept positions."""
+    return jit(shard_map(repart.take_kept(out_cap, spec, window), mesh=mesh,
+                             in_specs=(REP, ROW, ROW, ROW),
                              out_specs=(ROW, ROW)))
 
 
@@ -113,7 +143,7 @@ def unique_table(table: Table, subset=None, keep: str = "first") -> Table:
     """Drop duplicate rows (by ``subset`` columns, default all).  Distributed:
     shuffle by subset hash so equal rows co-locate; within a shard the
     (source rank, source position) receive order makes keep=first/last pick
-    the *globally* first/last occurrence."""
+    the *globally* first/last occurrence.  The kept rows keep their order."""
     env = table.env
     subset = list(subset) if subset is not None else table.column_names
     if keep not in ("first", "last"):
@@ -131,22 +161,18 @@ def unique_table(table: Table, subset=None, keep: str = "first") -> Table:
             pn.set(rows_in=rows_in)
         if env.world_size > 1:
             table = shuffle_table(table, subset)
-        key_cols = [table.column(n) for n in subset]
-        key_datas, key_valids = col_arrays(key_cols)
-        narrow = narrow32_flags(key_cols)
-        vc = np.asarray(table.valid_counts, np.int32)
-        counts = host_array(_unique_count_fn(env.mesh, keep, narrow)(
-            vc, key_datas, key_valids)).astype(np.int64)
-        out_cap = config.pow2ceil(int(counts.max()) if counts.size else 1)
-        items = list(table.columns.items())
-        datas = tuple(c.data for _, c in items)
-        valids = tuple(c.validity for _, c in items)
-        from .common import table_lane_spec
-        out_d, out_v = _unique_mat_fn(env.mesh, keep, narrow, out_cap,
-                                      table_lane_spec(
-                                          [c for _, c in items]))(
-            vc, key_datas, key_valids, datas, valids)
-        res = rebuild_like(items, out_d, out_v, counts, env)
+        res = table
+        if table.capacity:      # else: no row to rank or to take
+            key_cols = [table.column(n) for n in subset]
+            cols = list(table.columns.values())
+            vc = np.asarray(table.valid_counts, np.int32)
+            meta, srt = _unique_count_fn(
+                env.mesh, keep, narrow32_flags(key_cols))(
+                    vc, *col_arrays(key_cols))
+            spec = table_lane_spec(cols)
+            res = _taken(table, meta, srt, table.capacity, vc, spec,
+                         partial(_unique_mat_fn, env.mesh, spec),
+                         *col_arrays(cols))
         _said(ctx, pn, "unique", res, plan_route(env), keep=keep,
               rows_in=rows_in)
         return res
@@ -169,65 +195,73 @@ def _align_schemas(a: Table, b: Table):
             Table(cols_b, b.env, b.valid_counts))
 
 
-def _setop_flags_per_shard(vca, vcb, a_datas, a_valids, b_datas, b_valids,
-                           op: str, narrow):
-    cap_a, cap_b = a_datas[0].shape[0], b_datas[0].shape[0]
-    mask_a = live_mask(vca, cap_a)
-    mask_b = live_mask(vcb, cap_b)
-    # operand structures must match across the two tables: emit a null-flag
-    # operand for a column when EITHER side is nullable
-    need_nf = tuple((av is not None) or (bv is not None)
-                    for av, bv in zip(a_valids, b_valids))
-    ko_a = pack.key_operands(list(a_datas), list(a_valids), row_mask=mask_a,
-                             pad_key=PAD_L, need_null_flags=need_nf,
-                             narrow32=narrow)
-    ko_b = pack.key_operands(list(b_datas), list(b_valids), row_mask=mask_b,
-                             pad_key=PAD_L, need_null_flags=need_nf,
-                             narrow32=narrow)
-    gids_cat, _ = pack.dense_rank(pack.concat_keyops(ko_a, ko_b))
-    side_is_b = jnp.concatenate([jnp.zeros(cap_a, bool), jnp.ones(cap_b, bool)])
-    mask_cat = jnp.concatenate([mask_a, mask_b])
-    flags = setk.set_op_flags(gids_cat, side_is_b, op, mask_cat)
-    return flags
-
-
 @program_cache()
 def _setop_count_fn(mesh: Mesh, op: str, narrow: tuple):
-    """``narrow``: :func:`_unique_count_fn`'s, over BOTH tables' columns."""
+    """``repart._filter_count_fn``'s ``(meta, srt)`` for a set operation's
+    output rows, the positions in the materialize program's SOURCE
+    (:func:`_setop_mat_fn`).  ``union`` ranks ``[a; b]``, ``subtract`` /
+    ``intersect`` rank ``[b; a]`` (ops/setops.set_op_flags).  ``narrow``:
+    :func:`_unique_count_fn`'s, over BOTH tables' columns."""
     def per_shard(vca, vcb, a_datas, a_valids, b_datas, b_valids):
-        flags = _setop_flags_per_shard(vca, vcb, a_datas, a_valids, b_datas,
-                                       b_valids, op, narrow)
-        return jnp.sum(flags, dtype=jnp.int32).reshape(1)
+        cap_a, cap_b = a_datas[0].shape[0], b_datas[0].shape[0]
+        # operand structures must match across the two tables: emit a
+        # null-flag operand for a column when EITHER side is nullable
+        need_nf = tuple((av is not None) or (bv is not None)
+                        for av, bv in zip(a_valids, b_valids))
+        ko_a, ko_b = (pack.key_operands(
+            list(d), list(v), row_mask=live_mask(vc, d[0].shape[0]),
+            pad_key=PAD_L, need_null_flags=need_nf, narrow32=narrow)
+            for vc, d, v in ((vca, a_datas, a_valids),
+                             (vcb, b_datas, b_valids)))
+        first, live, sidx = _rank_sorted(
+            pack.concat_keyops(ko_a, ko_b) if op == "union"
+            else pack.concat_keyops(ko_b, ko_a))
+        with stage("setop_flags"):      # the side, the address: of sidx
+            if op == "union":
+                is_b = sidx >= cap_a
+                # b's rows stand directly behind a's LIVE rows in the source
+                n_a = vca[jax.lax.axis_index(shuffle.ROW_AXIS)]
+                src_pos = jnp.where(is_b, sidx - cap_a + n_a, sidx)
+                fill = cap_a + cap_b
+            else:
+                is_b = sidx < cap_b
+                src_pos, fill = sidx - cap_b, cap_a     # a kept row is a's
+        return repart.kept_positions(setk.set_op_flags(first, live, is_b, op),
+                                     src_pos, fill)
 
     return jit(shard_map(per_shard, mesh=mesh,
                              in_specs=(REP, REP, ROW, ROW, ROW, ROW),
-                             out_specs=ROW))
+                             out_specs=(ROW, ROW)))
 
 
 @program_cache()
-def _setop_mat_fn(mesh: Mesh, op: str, narrow: tuple, out_cap: int):
-    def per_shard(vca, vcb, a_datas, a_valids, b_datas, b_valids):
-        flags = _setop_flags_per_shard(vca, vcb, a_datas, a_valids, b_datas,
-                                       b_valids, op, narrow)
-        idx, _ = sortk.compact_by_flag(flags, out_cap)
+def _setop_mat_fn(mesh: Mesh, op: str, spec, out_cap: int, window: int):
+    """``repart._filter_mat_fn``'s body on the operator's source: ``a``
+    alone (``subtract`` / ``intersect``), or a's live rows with b's directly
+    behind them, built here (``union``) - addressed at ``cap_a + position``
+    the output tile that straddles a's padding would span it whole."""
+    if op != "union":
+        return _unique_mat_fn.__wrapped__(mesh, spec, out_cap, window)
+    take = repart.take_kept(out_cap, spec, window)
+
+    def behind(xa, xb, n_a):
+        return jax.lax.dynamic_update_slice(jnp.concatenate([xa, xb]), xb,
+                                            (n_a,))
+
+    def per_shard(kept, srt, vca, a_datas, a_valids, b_datas, b_valids):
+        n_a = vca[jax.lax.axis_index(shuffle.ROW_AXIS)]
         cap_a, cap_b = a_datas[0].shape[0], b_datas[0].shape[0]
-        n_cat = cap_a + cap_b
-        safe = jnp.clip(idx, 0, max(n_cat - 1, 0))
-        out_d, out_v = [], []
-        with stage("gather_rows"):
+        datas, valids = [], []
+        with stage("pack"):
             for da, va, db, vb in zip(a_datas, a_valids, b_datas, b_valids):
-                cat = jnp.concatenate([da, db])
-                out_d.append(cat[safe])
-                if va is None and vb is None:
-                    out_v.append(None)
-                else:
-                    va_ = va if va is not None else jnp.ones(cap_a, bool)
-                    vb_ = vb if vb is not None else jnp.ones(cap_b, bool)
-                    out_v.append(jnp.concatenate([va_, vb_])[safe])
-        return tuple(out_d), tuple(out_v)
+                datas.append(behind(da, db, n_a))
+                valids.append(None if va is None and vb is None else behind(
+                    jnp.ones(cap_a, bool) if va is None else va,
+                    jnp.ones(cap_b, bool) if vb is None else vb, n_a))
+        return take(kept, srt, tuple(datas), tuple(valids))
 
     return jit(shard_map(per_shard, mesh=mesh,
-                             in_specs=(REP, REP, ROW, ROW, ROW, ROW),
+                             in_specs=(REP, ROW, REP, ROW, ROW, ROW, ROW),
                              out_specs=(ROW, ROW)))
 
 
@@ -237,7 +271,8 @@ def set_operation(a: Table, b: Table, op: str,
     table.cpp:925-1110).  Distributed path shuffles both tables by full-row
     hash first (:1152-1166).  ``assume_colocated=True`` skips the shuffle
     AND schema alignment (pipelined execution pre-aligns and shuffles the
-    resident side once, exec/pipeline.pipelined_set_op).
+    resident side once, exec/pipeline.pipelined_set_op).  The output's rows
+    are a's kept rows in a's order, then (``union``) b's in b's.
 
     Device OOM falls back to the streaming chunked pipeline."""
     from .common import run_with_oom_fallback
@@ -290,18 +325,27 @@ def _set_operation_impl(a: Table, b: Table, op: str,
         a = shuffle_table(a, names)
         b = shuffle_table(b, names)
     cols_a, cols_b = ([t.column(n) for n in names] for t in (a, b))
-    a_datas, a_valids = col_arrays(cols_a)
-    b_datas, b_valids = col_arrays(cols_b)
+    a_arrays, b_arrays = col_arrays(cols_a), col_arrays(cols_b)
     narrow = narrow32_flags(cols_a, cols_b)
     vca = np.asarray(a.valid_counts, np.int32)
     vcb = np.asarray(b.valid_counts, np.int32)
-    counts = host_array(_setop_count_fn(env.mesh, op, narrow)(
-        vca, vcb, a_datas, a_valids, b_datas, b_valids)).astype(np.int64)
-    out_cap = config.pow2ceil(int(counts.max()) if counts.size else 1)
-    out_d, out_v = _setop_mat_fn(env.mesh, op, narrow, out_cap)(
-        vca, vcb, a_datas, a_valids, b_datas, b_valids)
-    return rebuild_like([(n, a.column(n)) for n in names], out_d, out_v,
-                        counts, env)
+    if op == "union":
+        # the source's lanes hold both tables' values and either's nulls
+        spec = lanes.plan_lanes(
+            tuple(str(c.data.dtype) for c in cols_a),
+            tuple(ca.validity is not None or cb.validity is not None
+                  for ca, cb in zip(cols_a, cols_b)), narrow)
+        cap, live = a.capacity + b.capacity, vca + vcb
+        source = (vca, *a_arrays, *b_arrays)
+    else:
+        spec = table_lane_spec(cols_a)
+        cap, live, source = a.capacity, vca, a_arrays
+    if not cap:         # no row to take: the (empty) source is the result
+        return a
+    meta, srt = _setop_count_fn(env.mesh, op, narrow)(
+        vca, vcb, *a_arrays, *b_arrays)
+    return _taken(a, meta, srt, cap, live, spec,
+                  partial(_setop_mat_fn, env.mesh, op, spec), *source)
 
 
 # ---------------------------------------------------------------------------

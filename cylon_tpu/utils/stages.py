@@ -109,6 +109,6 @@ def staged(name: str):
 # PR 30's lesson).
 STAGES["expr"] = ("elementwise column expressions: arithmetic, compares, "
                   "mask logic (series._expr_fn)")
-STAGES["setop_flags"] = ("set operations' membership flags: the segment "
-                         "min / max over the dense ranks and their gathers "
-                         "back to the rows (ops/setops.py)")
+STAGES["setop_flags"] = ("set operations' row flags, read off the rank "
+                         "sort's order: neighbour comparisons, liveness, "
+                         "side, source address (ops/setops.py)")

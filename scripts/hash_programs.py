@@ -9,7 +9,8 @@ and argument shapes, lowers each for a DESCRIBED ``v5e:2x2`` (one chip and
 four) and writes ``sha256[:16]`` of the StableHLO text with ``loc`` stripped;
 then the windowed forms as ``tests/chip_compile/helpers.py`` builds them
 (fused at 512 slots and at 8,192 with window 4096; ``groupby__raw_fn`` /
-``_combine_fn`` at 16,384 with window 1024).  Mosaic's kernel body embeds
+``_combine_fn`` at 16,384 with window 1024; ``repart__filter_count_fn``
+and ``_filter_mat_fn`` plain and at window 1024).  Mosaic's kernel body embeds
 the PATHS and LINE NUMBERS of the traced Python frames - THIS file's
 among them - so both trees must sit at the same path and hold the same copy
 of this script and of that helper module (copy the parent there and the two
@@ -151,8 +152,37 @@ def hash_all(log: list, tests_dir: str) -> dict:
             for site in ("raw", "combine"):
                 out[f"{world}dev groupby {site} 16384 w1024"] = text_hash(
                     tcc._groupby_program(mesh, site, 16384, 1024), gargs)
+            out.update(_filter_hashes(mesh, world))
     finally:
         jax.default_backend = default_backend
+    return out
+
+
+def _filter_hashes(mesh, world: int) -> dict:
+    """``filter_table``'s two programs (the TPC-H cell's) at a 65,536-row
+    shard of Q3's ``lineitem`` lanes: the count program, the materialize
+    program with XLA's gather and with the windowed take."""
+    import jax
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from cylon_tpu.ctx.context import ROW_AXIS
+    from cylon_tpu.ops import lanes
+    from cylon_tpu.relational import repart
+    cap, out_cap = 1 << 16, 1 << 15
+    spec = lanes.plan_lanes(("int64",) * 15, (False,) * 15,
+                            (True,) * 12 + (False,) * 3)
+    rep, row = NamedSharding(mesh, P()), NamedSharding(mesh, P(ROW_AXIS))
+    S = jax.ShapeDtypeStruct
+    vc = S((world,), np.int32, sharding=rep)
+    cols = tuple(S((world * cap,), np.int64, sharding=row) for _ in range(15))
+    out = {f"{world}dev filter count": text_hash(
+        repart._filter_count_fn(mesh, cap),
+        (vc, S((world * cap,), np.bool_, sharding=row)))}
+    for window in (0, 1024):
+        out[f"{world}dev filter mat w{window}"] = text_hash(
+            repart._filter_mat_fn(mesh, cap, out_cap, spec, window),
+            (vc, S((world * cap,), np.int32, sharding=row), cols,
+             (None,) * 15))
     return out
 
 

@@ -149,3 +149,57 @@ def _dist_sort_program(mesh, which: str, cap: int):
     return (rel_sort._local_sort_fn(mesh, desc, npos, narrow, vspec, (),
                                     (1,), False),
             (vc, (col, col), (None, None)))
+
+
+# ---- the set operators (ISSUE 49) -------------------------------------------
+# benchmark cell setops_dedup_32m's six programs at the cell's shapes: two
+# int64 columns within int32 bounds (2 lanes, 8 rows for the kernel),
+# 32,505,856-row shards, the seed-1 output capacities, window 2048 (the
+# widest tiles span ~1,000 / ~620 / ~620 source rows: PERF.md §6, PR 49).
+
+_SETOP_CAP = 32505856
+_SETOP_OUT_CAP = {"unique": 19922944, "union": 50331648, "subtract": 22020096}
+
+
+def _setop_programs(mesh, op: str, window: int = 2048):
+    """``(count program, its arguments, materialize program, its
+    arguments)`` of one operator of the cell (``unique`` keeps first by
+    ``k``)."""
+    from cylon_tpu.ctx.context import ROW_AXIS
+    from cylon_tpu.ops import lanes
+    from cylon_tpu.relational import setops
+    rep, row = NamedSharding(mesh, P()), NamedSharding(mesh, P(ROW_AXIS))
+    S = jax.ShapeDtypeStruct
+    vc = S((1,), np.int32, sharding=rep)
+    col = S((_SETOP_CAP,), np.int64, sharding=row)
+    two, none2 = (col, col), (None, None)
+    spec = lanes.plan_lanes(("int64",) * 2, (False,) * 2, (True,) * 2)
+    out_cap = _SETOP_OUT_CAP[op]
+    if op == "unique":
+        return (setops._unique_count_fn(mesh, "first", (True,)),
+                (vc, (col,), (None,)),
+                setops._unique_mat_fn(mesh, spec, out_cap, window),
+                (vc, S((_SETOP_CAP,), np.int32, sharding=row), two, none2))
+    srt = S((2 * _SETOP_CAP,), np.int32, sharding=row)
+    return (setops._setop_count_fn(mesh, op, (True, True)),
+            (vc, vc, two, none2, two, none2),
+            setops._setop_mat_fn(mesh, op, spec, out_cap, window),
+            (vc, srt, vc, two, none2, two, none2) if op == "union"
+            else (vc, srt, two, none2))
+
+
+def _check_setop_programs(mesh, op: str, rank_operands: int) -> None:
+    """Both programs of ``op`` compile for the described chip: the count
+    program holds the rank sort and ONE one-operand s32 sort and no
+    scatter, the materialize program the kernel and neither."""
+    import re
+    from cylon_tpu.analysis.registry import unwrap
+    count, cargs, mat, margs = _setop_programs(mesh, op)
+    text = jax.jit(unwrap(count)).lower(*cargs).compile().as_text()
+    sorts = re.findall(r"(?m)^.* = (\S+(?:, \S+)*) sort\(", text)
+    assert sorted(s.count("[") for s in sorts) == [1, rank_operands], sorts
+    assert " scatter(" not in text and "reduce-window" not in text
+    compiled = jax.jit(unwrap(mat)).lower(*margs).compile()
+    assert _has_kernel(compiled)
+    text = compiled.as_text()
+    assert " sort(" not in text and " scatter(" not in text

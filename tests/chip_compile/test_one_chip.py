@@ -1,6 +1,7 @@
 """One described chip: the two Pallas kernels at bench widths, the resident
 route's fused program, ``groupby__raw_fn`` with the window, the filter's
-two programs (the rules: this package's docstring)."""
+two programs, ``unique``'s two (the set operations' four are
+``test_setops.py``: the rules, this package's docstring)."""
 
 import numpy as np
 import pytest
@@ -9,8 +10,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .helpers import (_groupby_args, _groupby_program, _has_kernel,
-                      _wide_scans)
+from .helpers import (_check_setop_programs, _groupby_args,
+                      _groupby_program, _has_kernel, _wide_scans)
 
 
 # the fused join->groupby gather at bench shape: 64M concat rows of
@@ -221,3 +222,12 @@ def test_filter_programs_compile_for_v5e(topo, monkeypatch, filter_counts,
     assert " scatter(" not in text
     assert _has_kernel(mat) == bool(window)
     assert " sort(" not in mat.as_text() and " scatter(" not in mat.as_text()
+
+
+# ---- drop_duplicates (ISSUE 49) ---------------------------------------------
+# benchmark cell setops_dedup_32m's ``unique``: the filter's pair of programs
+# behind a 3-operand rank sort (helpers._setop_programs).
+
+def test_unique_programs_compile_for_v5e(mesh1, monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _check_setop_programs(mesh1, "unique", 3)

@@ -195,6 +195,7 @@ def test_counters_and_span_arguments(env1, host):
     moved = {k: after[k] - before[k] for k in after if after[k] != before[k]}
     assert moved == {'setop_dispatches{op="unique"}': 1,
                      'setop_dispatches{op="subtract"}': 1,
+                     'setop_mat_dispatches{path="plain",reason="not_tpu"}': 2,
                      'setop_rows_out{op="unique"}': u.row_count,
                      'setop_rows_out{op="subtract"}': s.row_count}
     assert said == [
@@ -204,111 +205,155 @@ def test_counters_and_span_arguments(env1, host):
          "kind": "subtract", "rows_in": 2 * ROWS}]
 
 
-@pytest.mark.parametrize("op", OPS)
+def _rank_sorted_numpy(gids, live):
+    """The rank sort on the host: ``(first, live, sidx)`` of the rows in
+    (liveness, gid, row index) order - what ``setops._rank_sorted`` hands
+    the flag kernels."""
+    sidx = np.lexsort((np.arange(len(gids)), gids, ~live))
+    g, lv = gids[sidx], live[sidx]
+    first = np.ones(len(gids), bool)
+    first[1:] = (g[1:] != g[:-1]) | (lv[1:] != lv[:-1])
+    return first, lv, sidx
+
+
+@pytest.mark.parametrize("op", OPS + ("unique_first", "unique_last"))
 def test_flag_kernels_equal_their_definition(op):
-    """``ops/setops.set_op_flags`` on a small concat against the three
-    definitions written out, masked rows never flagged."""
+    """``ops/setops.set_op_flags`` / ``unique_flags`` in the rank sort's
+    order, brought back to row order, against the definitions written out;
+    masked rows never flagged."""
     import jax.numpy as jnp
     from cylon_tpu.ops import setops as setk
     rng = np.random.default_rng(48)
     n_a, n_b = 300, 200
-    gids = rng.integers(0, 120, n_a + n_b).astype(np.int32)
-    side_b = np.arange(n_a + n_b) >= n_a
-    mask = rng.random(n_a + n_b) < 0.9
-    got = np.asarray(setk.set_op_flags(jnp.asarray(gids), jnp.asarray(side_b),
-                                       op, jnp.asarray(mask)))
-    in_b = set(gids[side_b & mask])
+    g_a, g_b = (rng.integers(0, 120, n).astype(np.int32) for n in (n_a, n_b))
+    m_a, m_b = (rng.random(n) < 0.9 for n in (n_a, n_b))
+    if op.startswith("unique"):
+        keep = op[len("unique_"):]
+        first, live, sidx = _rank_sorted_numpy(g_a, m_a)
+        got = np.zeros(n_a, bool)
+        got[sidx] = np.asarray(setk.unique_flags(
+            jnp.asarray(first), jnp.asarray(live), keep))
+        want = np.zeros(n_a, bool)
+        order = np.flatnonzero(m_a)
+        seen = set()
+        for i in (order if keep == "first" else order[::-1]):
+            want[i] = g_a[i] not in seen
+            seen.add(g_a[i])
+        assert np.array_equal(got, want) and want.sum() < m_a.sum()
+        return
+    # union ranks [a; b]; subtract / intersect rank [b; a]
+    a_first = op == "union"
+    gids = np.concatenate([g_a, g_b] if a_first else [g_b, g_a])
+    live = np.concatenate([m_a, m_b] if a_first else [m_b, m_a])
+    first, lv, sidx = _rank_sorted_numpy(gids, live)
+    is_b = (sidx >= n_a) if a_first else (sidx < n_b)
+    flags = np.asarray(setk.set_op_flags(
+        jnp.asarray(first), jnp.asarray(lv), jnp.asarray(is_b), op))
+    got = np.zeros(n_a + n_b, bool)         # in [a; b] row order
+    got[sidx if a_first else np.where(is_b, sidx + n_a, sidx - n_b)] = flags
+    in_b = set(g_b[m_b])
     want = np.zeros(n_a + n_b, bool)
     seen = set()
-    for i in np.flatnonzero(mask):
-        g = gids[i]
+    for i in np.flatnonzero(np.concatenate([m_a, m_b])):
+        g = np.concatenate([g_a, g_b])[i]
         if op == "union":
             want[i] = g not in seen
             seen.add(g)
-        elif not side_b[i]:
+        elif i < n_a:
             want[i] = g not in seen and ((g in in_b) == (op == "intersect"))
             seen.add(g)
-    assert np.array_equal(got, want)
+    assert np.array_equal(got, want) and want.any()
     with pytest.raises(ValueError):
-        setk.set_op_flags(jnp.asarray(gids), jnp.asarray(side_b), "xor")
+        setk.set_op_flags(jnp.asarray(first), jnp.asarray(lv),
+                          jnp.asarray(is_b), "xor")
+
+
+def _cell_programs(mesh, narrow=(True, True), cap=4096, out_cap=2048,
+                   window=0) -> dict:
+    """``name -> (program, argument shapes)`` of the cell's six programs
+    (and ``intersect``'s pair) at a small size: two int64 columns."""
+    import jax
+    from cylon_tpu.ops import lanes
+    S = jax.ShapeDtypeStruct
+    vc, col = S((1,), np.int32), S((cap,), np.int64)
+    two, none2 = (col, col), (None, None)
+    spec = lanes.plan_lanes(("int64", "int64"), (False, False), narrow)
+    programs = {
+        "unique_count": (setops._unique_count_fn(mesh, "first", narrow[:1]),
+                         (vc, (col,), (None,))),
+        "unique_mat": (setops._unique_mat_fn(mesh, spec, out_cap, window),
+                       (vc, S((cap,), np.int32), two, none2)),
+    }
+    for op in OPS:
+        programs[f"{op}_count"] = (setops._setop_count_fn(mesh, op, narrow),
+                                   (vc, vc, two, none2, two, none2))
+        srt = S((2 * cap,), np.int32)
+        programs[f"{op}_mat"] = (
+            setops._setop_mat_fn(mesh, op, spec, out_cap, window),
+            (vc, srt, vc, two, none2, two, none2) if op == "union"
+            else (vc, srt, two, none2))
+    return programs
+
+
+def _sorts(text: str) -> list:
+    """Operand count of every ``stablehlo.sort`` of a lowered program."""
+    import re
+    return sorted(len(s.split(",")) for s in re.findall(
+        r'"stablehlo.sort"\(([^)]*)\)', text))
 
 
 def test_flags_and_row_gathers_carry_their_stage(env1):
-    """The segment min / max and their gathers lower under
-    ``cylon.setop_flags`` in all six programs, the set operations'
-    materialize gathers under ``cylon.gather_rows``; ``dense_rank``'s
-    un-sort scatter stays where ``ops/pack.py`` names it (``gather_rows``:
-    a stage opened there would change other cells' programs)."""
-    import jax
+    """A count program lowers NO scatter and no scan, the stable rank sort
+    under ``cylon.sort_keys`` and the one-operand sort of the kept
+    positions under ``cylon.compact``, its flags under
+    ``cylon.setop_flags``; a materialize program lowers no sort and no
+    scatter either, and its row gather under ``cylon.gather_rows``."""
     from cylon_tpu.analysis.registry import unwrap
-    from cylon_tpu.ops import lanes
     from cylon_tpu.utils import stages
     assert "setop_flags" in stages.STAGES
-    S = jax.ShapeDtypeStruct
-    cap = 4096
-    vc, col = S((1,), np.int32), S((cap,), np.int64)
-    two, none2 = (col, col), (None, None)
-    spec = lanes.plan_lanes(("int64", "int64"), (False, False), (True, True))
-    narrow = (True, True)
-    programs = {
-        "unique_count": (setops._unique_count_fn(env1.mesh, "first", (True,)),
-                         (vc, (col,), (None,))),
-        "unique_mat": (setops._unique_mat_fn(env1.mesh, "first", (True,),
-                                             2048, spec),
-                       (vc, (col,), (None,), two, none2)),
-    }
-    for op in ("union", "subtract"):
-        programs[f"{op}_count"] = (setops._setop_count_fn(env1.mesh, op,
-                                                          narrow),
-                                   (vc, vc, two, none2, two, none2))
-        programs[f"{op}_mat"] = (setops._setop_mat_fn(env1.mesh, op, narrow,
-                                                      4096),
-                                 (vc, vc, two, none2, two, none2))
-    for name, (prog, args) in programs.items():
+    for name, (prog, args) in _cell_programs(env1.mesh).items():
         text = unwrap(prog).lower(*args).as_text(debug_info=True)
-        flagged = [ln for ln in text.splitlines()
-                   if "cylon.setop_flags" in ln]
-        assert any("scatter" in ln for ln in flagged), name
-        assert any("gather" in ln for ln in flagged), name
-        if name.endswith("_mat") and not name.startswith("unique"):
-            assert any("gather" in ln and "cylon.setops/cylon.gather_rows"
-                       in ln for ln in text.splitlines()), name
+        locs = [ln for ln in text.splitlines() if ln.startswith("#loc")]
+        for word in ("scatter", "cumsum", "reduce_window", "segment_"):
+            assert word not in text, (name, word)
+        if name.endswith("_count"):
+            assert _sorts(text) == [1, 3 if name.startswith("unique") else 4]
+            assert any("cylon.setops/cylon.sort_keys/sort" in ln
+                       for ln in locs), name
+            assert any("cylon.setops/cylon.compact/sort" in ln
+                       for ln in locs), name
+            assert any("cylon.setop_flags" in ln for ln in locs), name
+            assert not any("gather_rows" in ln for ln in locs), name
+        else:
+            assert _sorts(text) == [], name
+            assert any("cylon.gather_rows" in ln and "gather" in
+                       ln.rpartition("cylon.gather_rows")[2]
+                       for ln in locs), name
+            assert not any("cylon.setop_flags" in ln or "cylon.sort_keys"
+                           in ln for ln in locs), name
 
 
-# ---- the repair: a key whose bounds fit int32 is ONE sort operand ----------
+# ---- a key whose bounds fit int32 is ONE sort operand (PR 48) --------------
 # (XLA:TPU compiles a sort in time that grows with its operands: at the
-# cell's size the six programs' sorts of 4 / 6 operands compiled cold past
-# the check's stop; PERF.md §6, PR 48)
-
-def _sort_operands(prog, *args) -> int:
-    """Operands of the program's one multi-operand key sort."""
-    import re
-    from cylon_tpu.analysis.registry import unwrap
-    text = unwrap(prog).lower(*args).as_text()
-    sorts = re.findall(r'"stablehlo.sort"\(([^)]*)\)', text)
-    assert len(sorts) == 1, sorts
-    return len(sorts[0].split(","))
-
+# cell's size the parent's sorts of 4 / 6 operands compiled cold past the
+# check's stop; PERF.md §6, PR 48)
 
 @pytest.mark.parametrize("narrow,unique_ops,setop_ops", [
     ((True, True), 3, 4),       # liveness, k, [v,] idx
     ((False, True), 4, 5),      # k as (hi, lo)
     ((False, False), 4, 6)])
 def test_sort_operands_follow_the_bounds(env1, narrow, unique_ops, setop_ops):
-    import jax
-    S = jax.ShapeDtypeStruct
-    vc, col = S((1,), np.int32), S((4096,), np.int64)
-    two, none2 = (col, col), (None, None)
-    assert _sort_operands(
-        setops._unique_count_fn(env1.mesh, "first", narrow[:1]),
-        vc, (col,), (None,)) == unique_ops
-    for op in ("union", "subtract"):
-        assert _sort_operands(
-            setops._setop_count_fn(env1.mesh, op, narrow),
-            vc, vc, two, none2, two, none2) == setop_ops
-        assert _sort_operands(
-            setops._setop_mat_fn(env1.mesh, op, narrow, 4096),
-            vc, vc, two, none2, two, none2) == setop_ops
+    """The rank sort's operands in the count programs, beside the
+    one-operand sort of the kept positions; no sort at all in the
+    materialize programs, whose lanes follow the same bounds."""
+    from cylon_tpu.analysis.registry import unwrap
+    for name, (prog, args) in _cell_programs(env1.mesh, narrow).items():
+        sorts = _sorts(unwrap(prog).lower(*args).as_text())
+        if name.endswith("_mat"):
+            assert sorts == [], name
+        else:
+            assert sorts == [1, unique_ops if name.startswith("unique")
+                             else setop_ops], name
 
 
 @pytest.mark.parametrize("envname", ["env1", "env4"])
@@ -355,3 +400,236 @@ def test_narrow_and_wide_keys_give_the_same_rows(request, envname):
         if w is not None:
             assert np.array_equal(got_n, w)
     assert len(narrow[0]) == len(np.unique(a["k"]))
+
+
+# ---- PR 49: the flags are read off the sorted order, the rows move by the
+# filter's take: the same rows in the same ORDER as before ---------------------
+
+TYPED = ("k", "n", "f", "s", "d")
+
+
+def _typed(env, seed: int, n: int):
+    """Every kind of column the operators compare and move: a narrow int64,
+    a nullable int64, a float32 with ``-0.0`` / ``NaN``, a dictionary
+    string, a nullable float64 (laneless: a side gather) - few enough
+    distinct rows that most repeat.  The host rows come back with it."""
+    from cylon_tpu.core.column import Column
+    from cylon_tpu.core.dtypes import LogicalType
+    rng = np.random.default_rng(seed)
+    host = {
+        "k": rng.integers(0, 6, n).astype(np.int64),
+        "n": rng.integers(0, 2, n).astype(np.int64),
+        "nv": rng.random(n) < 0.7,
+        "f": rng.choice(np.array([-0.0, 0.0, np.nan, 1.5], np.float32), n),
+        "s": rng.choice(np.array(["x", "y", "zz"], object), n),
+        "d": rng.choice(np.array([-0.0, 0.0, np.nan, 2.5]), n),
+        "dv": rng.random(n) < 0.8,
+    }
+    table = ct.Table.from_pydict({
+        "k": host["k"],
+        "n": Column(host["n"], LogicalType.INT64, host["nv"], bounds=(0, 1)),
+        "f": host["f"], "s": host["s"],
+        "d": Column(host["d"], LogicalType.FLOAT64, host["dv"]),
+    }, env)
+    return table
+
+
+def _shard_rows(table) -> list:
+    """A list a shard of the table's live rows, in order: ``(identity,
+    bits)`` - what two rows are compared by (nulls equal whatever they
+    hold, ``-0.0 == 0.0``, ``NaN == NaN``) and what a moved row must still
+    hold, bit for bit."""
+    h = table.host_columns()
+    n = table.row_count
+    words = np.asarray(table.column("s").dictionary)
+
+    def valid(c):
+        return np.ones(n, bool) if h[c][1] is None else np.asarray(h[c][1])
+
+    def canon(x):
+        return "nan" if np.isnan(x) else float(x) + 0.0
+    rows = []
+    for i in range(n):
+        nv, dv = bool(valid("n")[i]), bool(valid("d")[i])
+        f, d = h["f"][0][i], h["d"][0][i]
+        s = str(words[h["s"][0][i]])
+        ident = (int(h["k"][0][i]), int(h["n"][0][i]) if nv else None,
+                 canon(f), s, canon(d) if dv else None)
+        bits = (int(h["k"][0][i]), int(h["n"][0][i]) if nv else None,
+                f.tobytes(), s, d.tobytes() if dv else None)
+        rows.append((ident, bits))
+    ends = np.cumsum(np.asarray(table.valid_counts))
+    return [rows[e - c:e] for e, c in zip(ends, table.valid_counts)]
+
+
+def _reference(op: str, a_rows: list, b_rows: list, subset=None) -> list:
+    """One shard's output rows, in order, by the operators' definitions
+    written out (``a``'s kept rows in ``a``'s order, then ``b``'s)."""
+    def ident(row):
+        return row[0] if subset is None else tuple(
+            row[0][TYPED.index(c)] for c in subset)
+    if op in ("first", "last"):
+        order = range(len(a_rows)) if op == "first" \
+            else range(len(a_rows) - 1, -1, -1)
+        seen, kept = set(), []
+        for i in order:
+            if ident(a_rows[i]) not in seen:
+                seen.add(ident(a_rows[i]))
+                kept.append(i)
+        return [a_rows[i][1] for i in sorted(kept)]
+    in_b = {ident(r) for r in b_rows}
+    seen, out = set(), []
+    for r in a_rows + (b_rows if op == "union" else []):
+        if ident(r) in seen:
+            continue
+        seen.add(ident(r))
+        if op == "union" or (ident(r) in in_b) == (op == "intersect"):
+            out.append(r[1])
+    return out
+
+
+@pytest.mark.parametrize("envname", ["env1", "env4"])
+@pytest.mark.parametrize("op", OPS + ("first", "last", "first_by_k_s"))
+def test_every_operator_keeps_the_rows_and_their_order(request, envname, op):
+    """All four operators and both ``keep`` on every column kind, a's
+    padding non-empty: the output IS the reference's list, row for row in
+    order and bit for bit.  On four chips the reference reads the shards
+    ``shuffle_table`` made (the exchange is not what is under test)."""
+    from cylon_tpu.relational import repart
+    env = request.getfixturevalue(envname)
+    a, b = _typed(env, 49, 1500), _typed(env, 50, 1100)
+    subset = ["k", "s"] if op == "first_by_k_s" else None
+    if op in OPS:
+        got = set_operation(a, b, op)
+        sa, sb = setops._align_schemas(a, b)
+        if env.world_size > 1:
+            sa, sb = (repart.shuffle_table(t, list(TYPED)) for t in (sa, sb))
+    else:
+        got = unique_table(a, subset=subset, keep=op.split("_")[0])
+        sa = repart.shuffle_table(a, subset or list(TYPED)) \
+            if env.world_size > 1 else a
+        sb = sa
+    assert int(sa.valid_counts.max()) < sa.capacity       # a has padding
+    want = [_reference(op.split("_")[0], ra, rb, subset)
+            for ra, rb in zip(_shard_rows(sa), _shard_rows(sb))]
+    assert [len(w) for w in want] == list(got.valid_counts)
+    assert [r[1] for part in _shard_rows(got) for r in part] \
+        == [r for w in want for r in w]
+    assert 0 < got.row_count < a.row_count + b.row_count
+
+
+def _lifted_rule(mesh, out_cap, density):
+    """``fused.window_rule`` without its platform and size tests
+    (tests/test_filter_windowed.py)."""
+    from cylon_tpu.ops import pallas_gather as pg
+    if density < pg.MIN_DENSITY:
+        return 0, "density_below_floor"
+    return pg.pick_window(density), ""
+
+
+def _mat_dispatches() -> dict:
+    return {k: v for k, v in metrics.snapshot().items()
+            if k.startswith(("setop_mat_dispatches", "filter_dispatches"))}
+
+
+#: case -> (operator, key domain)
+WINDOWED = {
+    "union": ("union", 12_000),
+    "subtract": ("subtract", 12_000),
+    # b a sample of a's rows, so that most of a's first occurrences stay
+    "intersect": ("intersect", 60_000),
+    "unique": ("unique", 60_000),
+    # late rows mostly repeat an earlier key: the first occurrences thin
+    # out down the table and the last tiles span more than the window the
+    # whole table's density would ask for (1024)
+    "unique_late_repeats": ("unique", 12_000),
+}
+
+
+@pytest.mark.parametrize("case", list(WINDOWED))
+def test_rows_move_by_the_windowed_take_where_the_rule_allows(
+        env1, monkeypatch, case):
+    """The kernel in interpret mode, the rule's platform and size tests
+    lifted: with a's padding four windows wide the union still measures a
+    tile span inside the window - b's rows are addressed directly behind
+    a's LIVE rows, not behind its capacity - and the dispatch counts
+    ``windowed`` and returns the plain path's rows in their order.  The
+    rule is asked with the sparsest tile's density (first occurrences thin
+    out: ``repart.materialize_kept(thinning=True)``), so the window holds
+    the widest tile whatever the table's own density."""
+    from functools import partial
+    import jax
+    from cylon_tpu.ops import pallas_gather as pg
+    from cylon_tpu.relational import fused, repart
+    op, domain = WINDOWED[case]
+    rng = np.random.default_rng(49)
+    n_a, n_b, cap = 17_000, 16_000, 32_768
+    rows_a = {"k": rng.integers(0, domain, n_a).astype(np.int64),
+              "v": rng.integers(0, 4, n_a).astype(np.int64)}
+    pick = rng.permutation(n_a)[:n_b]
+    rows_b = {c: x[pick] for c, x in rows_a.items()} if op == "intersect" \
+        else {"k": rng.integers(0, domain, n_b).astype(np.int64),
+              "v": rng.integers(0, 4, n_b).astype(np.int64)}
+    a, b = (repart.repad_table(ct.Table.from_pydict(rows, env1), cap)
+            for rows in (rows_a, rows_b))
+
+    def run():
+        return obs.explain_analyze(
+            lambda: unique_table(a, subset=["k"]) if op == "unique"
+            else set_operation(a, b, op), profile_keys=False)
+    plain = run()
+    assert plain.roots[0].attrs["path"] == "plain"
+    monkeypatch.setattr(fused, "window_rule", _lifted_rule)
+    for mod in (repart, setops):
+        monkeypatch.setattr(mod, "shard_map",
+                            partial(jax.shard_map, check_vma=False))
+    before = _mat_dispatches()
+    win = run()
+    after = _mat_dispatches()
+    assert {k: after[k] - before[k] for k in after if after[k] != before[k]} \
+        == {'setop_mat_dispatches{path="windowed"}': 1}
+    attrs = win.roots[0].attrs
+    span, window = attrs["max_tile_span"], attrs["window"]
+    assert attrs["path"] == "windowed" and cap - n_a >= 4 * window
+    assert 0 < span <= window == pg.pick_window(
+        min(attrs["density"], pg.TILE / span))
+    assert pg.pick_window(attrs["density"]) == 1024     # the table's own
+    assert (span > 1024) == (case == "unique_late_repeats")
+    assert attrs["density"] == round(win.result.row_count / (
+        n_a + n_b if op == "union" else n_a), 6)
+    for name, (d, v) in win.result.host_columns().items():
+        assert v is None
+        assert np.array_equal(d, plain.result.host_columns()[name][0]), name
+    if op == "union":       # b's kept rows came from behind a's padding
+        assert win.result.row_count > n_a
+
+
+@pytest.mark.parametrize("envname", ["env1", "env4"])
+def test_plan_nodes_say_the_materialize_dispatch(request, host, envname):
+    """``unique`` / ``set_op`` nodes carry the filter node's ``path`` /
+    ``window`` / ``density`` / ``max_tile_span``; ``setop_mat_dispatches``
+    counts one a call and ``filter_dispatches`` none (the TPC-H cell's
+    share reads that family over the whole process)."""
+    env = request.getfixturevalue(envname)
+    t = _device(env, host)
+    before = _mat_dispatches()
+    plan = obs.explain_analyze(lambda: (
+        unique_table(t["a"], subset=["k"]),
+        set_operation(t["a"], t["b"], "union"),
+        set_operation(t["a"], t["b"], "intersect")), profile_keys=False)
+    after = _mat_dispatches()
+    assert {k: after[k] - before[k] for k in after if after[k] != before[k]} \
+        == {'setop_mat_dispatches{path="plain",reason="not_tpu"}': 3}
+    assert len(before) == 14        # both families whole, from import on
+    nodes = [n for n in plan.roots if n.op in ("unique", "set_op")]
+    assert len(nodes) == 3
+    for node, res in zip(nodes, plan.result):
+        assert node.attrs["path"] == "plain" and node.attrs["window"] == 0
+        assert 0 < node.attrs["density"] <= 1
+        assert node.attrs["max_tile_span"] > 0
+        assert node.rows_out == res.row_count
+    # the kept share of the fullest shard's live SOURCE rows
+    if env.world_size == 1:
+        assert [n.attrs["density"] for n in nodes] == [
+            round(r.row_count / live, 6) for r, live in
+            zip(plan.result, (ROWS, 2 * ROWS, ROWS))]
