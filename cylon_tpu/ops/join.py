@@ -120,13 +120,16 @@ class PayloadLayout(NamedTuple):
 
 def payload_layout(lspec, rspec, key_cols: tuple, key_dtypes: tuple,
                    need_nf: tuple, narrow: tuple,
-                   all_live: bool) -> PayloadLayout:
+                   all_live: bool, fold: bool = False) -> PayloadLayout:
     """The layout for a join whose left / right lane matrices (``lspec`` /
     ``rspec``: :class:`~.lanes.LaneSpec`, None where that side does not
     ride) go through the sort of a key tuple of physical ``key_dtypes``
-    packed with ``need_nf`` / ``narrow`` (:func:`.pack.key_operands`; no
-    liveness operand when ``all_live``).  ``key_cols[i]``: the left lane
-    column that IS key column i (None: no left output column is).
+    packed with ``need_nf`` / ``narrow`` / ``fold``
+    (:func:`.pack.key_operands`; no liveness operand when ``all_live``, or
+    when ``fold`` put it into the leading key operand - a live row's
+    operand is its key either way, and nobody reads a lane at padding).
+    ``key_cols[i]``: the left lane column that IS key column i (None: no
+    left output column is).
 
     Rule 2 takes a key's lanes from its sorted operands where the two are
     bit-equal, decided on the physical dtype alone: integer kinds (a string
@@ -137,7 +140,7 @@ def payload_layout(lspec, rspec, key_cols: tuple, key_dtypes: tuple,
     ``(hi, lo)`` pair.  Floats decline (their operands are canonicalised),
     and so does a two-lane column beside a narrow operand."""
     kinds, slots = key_operand_slots(key_dtypes, need_nf, narrow,
-                                     row_mask=not all_live)
+                                     row_mask=not all_live, fold=fold)
     nl = lspec.n_lanes if lspec is not None else 0
     alias = [-1] * nl
     for ci, dt, nf, ops in zip(key_cols if nl else (), key_dtypes, need_nf,
@@ -199,13 +202,18 @@ def join_sort_state(ko_l: KeyOps, ko_r: KeyOps, payloads: tuple = (),
     no tie-break operand of its own here: it reuses an operand that
     already is an iota, and ``idx`` is one.)
 
-    Invariant every consumer of the state relies on (:func:`live_sides`):
-    *a liveness operand leads the sort ⇔ ``n_live`` is not None ⇔ padding
-    occupies sorted positions ``[n_live, N)``*.  Callers build both sides'
-    operands with a ``row_mask`` (:func:`.pack.key_operands`: live rows 0,
-    padding 4 / 5) exactly when the tables are not at capacity, so live
-    rows sort first whatever their keys (int64 max included) and
-    ``n_live`` is the two sides' valid counts summed — a per-shard scalar.
+    Invariant every consumer of the state relies on (:func:`live_sides`;
+    the set operators', the groupby's and the sort's rank sorts keep the
+    same one): *padding occupies sorted positions ``[n_live, N)`` ⇔
+    ``n_live`` is not None* — by a liveness operand or by the sentinel.
+    Callers build both sides' operands with a ``row_mask``
+    (:func:`.pack.key_operands`) exactly when the tables are not at
+    capacity: padding then carries 4 / 5 in a leading liveness operand
+    (live rows 0), or - ``fold``, where ``pack.fold_room`` finds the
+    leading key operand has room - that operand's two top values, which no
+    live key reaches.  Either way live rows sort first whatever their keys
+    and ``n_live`` is the two sides' valid counts summed — a per-shard
+    scalar; the two tables' padding never compares equal.
 
     ``payloads``: optional (n_l+n_r,) arrays carried through the sort
     (:func:`payload_operands`) — every operand is one more pass of the
@@ -256,11 +264,11 @@ def join_carry(bnd, idx_s, n_live, n_l: int, how: str) -> tuple:
     explodes superlinearly with array size (~200 s at 2M rows, measured).
 
     ``n_live``: int32 scalar, the live rows of the concat — they are the
-    sorted prefix ``[0, n_live)`` because the liveness operand led the
-    sort (:func:`join_sort_state`'s invariant), so row liveness is a
-    position compare.  ``None`` asserts every concat row is live
-    (host-known ``valid_counts == capacity``; no liveness operand was
-    sorted)."""
+    sorted prefix ``[0, n_live)`` because padding sorted last, by its
+    liveness operand or by the sentinel in the leading key operand
+    (:func:`join_sort_state`'s invariant), so row liveness is a position
+    compare.  ``None`` asserts every concat row is live (host-known
+    ``valid_counts == capacity``; no padding was sorted)."""
     n = bnd.shape[0]
     pos = jnp.arange(n, dtype=jnp.int32)
     lefts_b, rights_b, _live = live_sides(idx_s, n_l, n_live)

@@ -34,6 +34,15 @@ Descending order uses arithmetic transforms before packing (``~x`` for ints
 only for columns that can actually hold nulls (callers coordinate the
 static operand structure across tables with ``need_null_flags``).
 
+**Row liveness (padding).**  A table under its capacity has padding rows
+that must sort behind every live row.  They carry a pad key (``PAD_L`` /
+``PAD_R``: distinct per table): in a leading liveness operand of their own,
+or - where :func:`fold_room` proves the FIRST key column's first operand
+never reaches its two top values (a null flag, a narrow / 32-bit integer
+with host-known bounds, a float32 image) - inside that operand
+(``key_operands(fold=True)``), one operand fewer through the sort.  After
+the sort nobody reads it: liveness is the position compare ``p < n_live``.
+
 Every downstream op (join, groupby, set ops, unique) then works on a single
 int32 id column — the moral equivalent of the reference flattening multi-col
 keys to one binary column before hashing.
@@ -52,6 +61,13 @@ from ..utils.stages import stage, staged
 NULL_FIRST = 0
 NULL_LAST = 2
 
+#: what a padding row carries where its table is ranked: distinct per table,
+#: so padding never rank-equals across the two tables of a join.  In a
+#: liveness operand or a null-flag operand (whose live values are 0 / 0-2)
+#: the pad key itself; folded into a 32-bit value operand, the operand
+#: type's ``max - 1`` / ``max`` (:func:`key_operands`, ``fold``).
+PAD_L, PAD_R = 4, 5
+
 #: The most operands (keys, index and payload lanes together) one
 #: ``lax.sort`` is given where the caller has another way to move its
 #: payload.  XLA:TPU compiles a sort in time that grows with its OPERAND
@@ -68,18 +84,19 @@ SORT_OPERAND_BUDGET = 7
 
 
 def sort_operand_nbytes(dtypes, need_nf, narrow, rows: int,
-                        row_mask: bool = True) -> int:
+                        row_mask: bool = True, fold: bool = False) -> int:
     """Host-side static size of the operand set :func:`key_operands`
     materializes for ``rows`` rows — the per-piece sort scratch a join
     over this key structure will hold resident while it runs.  Mirrors
-    the packing rules above (liveness flag + per-column null flag + one
-    or two native value lanes; f64 stays a single 8-byte operand).
+    the packing rules above (liveness flag, unless ``fold`` put it into
+    the leading operand, + per-column null flag + one or two native value
+    lanes; f64 stays a single 8-byte operand).
 
     This is the "registration at pack time" half of the HBM ledger
     (:mod:`cylon_tpu.exec.memory`): piece working-set sizing consults it
     so admission of a new packed source accounts for the transient
     operands its consumer will add on top of the resident matrices."""
-    per_row = 4 if row_mask else 0
+    per_row = 4 if row_mask and not fold else 0
     for dt, nf, nw in zip(dtypes, need_nf, narrow):
         if nf:
             per_row += 4
@@ -155,18 +172,73 @@ def _sort_value(x: jax.Array, descending: bool,
     raise TypeError(f"unsortable dtype {dt}")
 
 
+def fold_room(dtype, need_nf: bool, bounds, descending: bool = False) -> bool:
+    """THE rule of whether row liveness folds into the leading key operand
+    (:func:`key_operands`, ``fold``), in dtype space: has the FIRST key
+    column's first operand two values above every live row's?  ``dtype``:
+    the column's physical dtype; ``need_nf``: it sorts behind a null-flag
+    operand; ``bounds``: the host-known ``(lo, hi)`` of that column in
+    EVERY table ranked together (None where a table's are unknown).
+
+    * a null-flag operand holds 0 / 1 / 2 and padding takes the pad key in
+      it: always room, whatever follows;
+    * a bool, an 8- / 16-bit integer and a float32 (whose order-preserving
+      u32 image never passes the canonical NaN's ``0xFFC00000``) use a
+      fraction of their 32-bit operand: always room;
+    * a 32-bit integer, or a 64-bit one whose bounds fit int32 (the ONE
+      operand of ``narrow32``), where the bounds prove ``hi <= max - 2`` of
+      the operand's type - descending, where the operand is ``~x``,
+      ``lo >= min + 2``;
+    * a wide ``(hi, lo)`` pair, an f64 key and a 32-bit column whose bounds
+      nobody knows keep the liveness operand."""
+    if need_nf:
+        return True
+    d = np.dtype(dtype)
+    if d.kind == "b" or (d.kind in "iu" and d.itemsize < 4):
+        return True
+    if d.kind == "f":
+        return d.itemsize == 4
+    if d.kind not in "iu" or any(b is None for b in bounds):
+        return False
+    lo = min(int(b[0]) for b in bounds)
+    hi = max(int(b[1]) for b in bounds)
+    info = np.iinfo(np.int32 if d.itemsize == 8 else d)
+    if d.itemsize == 8 and not (info.min <= lo and hi <= info.max):
+        return False            # not narrow: a (hi, lo) pair leads
+    return lo >= info.min + 2 if descending else hi <= info.max - 2
+
+
+def _fold_pad(op: jax.Array, row_mask, pad_key: int) -> jax.Array:
+    """``op`` with padding rows at the operand type's ``max - 1``
+    (:data:`PAD_L`) / ``max`` (:data:`PAD_R`): above every live value
+    :func:`fold_room` admits, distinct per table."""
+    if op.dtype.itemsize != 4 or not jnp.issubdtype(op.dtype, jnp.integer):
+        raise ValueError(f"liveness cannot fold into a {op.dtype} operand "
+                         "(ops/pack.fold_room decides; narrow32 must agree)")
+    top = int(np.iinfo(op.dtype).max) - (PAD_R - pad_key)
+    return jnp.where(row_mask, op, jnp.asarray(top, op.dtype))
+
+
 @staged("pack")
 def key_operands(datas, validities=None, row_mask=None, descendings=None,
-                 nulls_position: int = NULL_LAST, pad_key: int = 4,
-                 need_null_flags=None, narrow32=None) -> KeyOps:
+                 nulls_position: int = NULL_LAST, pad_key: int = PAD_L,
+                 need_null_flags=None, narrow32=None,
+                 fold: bool = False) -> KeyOps:
     """Build the lexicographic sort-operand list for a key tuple.
 
     For each nullable key column: a null-flag operand then the packed value
     operand(s) — valid rows get flag 1, nulls get 0 (first) or 2 (last),
     matching pandas ``na_position`` independently of ascending/descending.
-    A leading row-liveness operand is added when ``row_mask`` is given;
-    padding rows sort last with flag ``pad_key`` (use distinct pad keys per
-    table so padding never matches across tables in a dense rank).
+
+    With a ``row_mask`` padding rows sort last, behind every live row
+    whatever its keys, and carry ``pad_key`` (use distinct pad keys per
+    table so padding never matches across tables in a dense rank): in a
+    leading row-liveness operand (live 0, padding ``pad_key``), or, with
+    ``fold`` (:func:`fold_room` said the first column's first operand has
+    room), INSIDE that operand - the null flag takes ``pad_key``, a 32-bit
+    value operand its type's ``max - 1`` / ``max`` - and no liveness
+    operand is built: one operand fewer through the sort, the same order.
+    Either way the live rows are the sorted prefix.
 
     ``need_null_flags`` (tuple of bool per column) forces/suppresses the
     null-flag operand statically — callers ranking TWO tables together must
@@ -176,7 +248,8 @@ def key_operands(datas, validities=None, row_mask=None, descendings=None,
     """
     ops, kinds = [], []
     n = datas[0].shape[0]
-    if row_mask is not None:
+    fold = bool(fold) and row_mask is not None
+    if row_mask is not None and not fold:
         ops.append(jnp.where(row_mask, jnp.int32(0), jnp.int32(pad_key)))
         kinds.append("i")
     for i, d in enumerate(datas):
@@ -190,20 +263,29 @@ def key_operands(datas, validities=None, row_mask=None, descendings=None,
             else:
                 nf = jnp.where(v, jnp.int32(1), jnp.int32(nulls_position))
                 d = jnp.where(v, d, jnp.zeros_like(d))
+            if fold and not ops:
+                nf = jnp.where(row_mask, nf, jnp.int32(pad_key))
             ops.append(nf)
             kinds.append("i")
         nrw = bool(narrow32[i]) if narrow32 is not None else False
-        for val, kind in _sort_value(d, desc, narrow=nrw):
+        vals = _sort_value(d, desc, narrow=nrw)
+        if fold and not ops:
+            if len(vals) != 1:
+                raise ValueError("liveness cannot fold into a wide pair "
+                                 "(ops/pack.fold_room decides)")
+            vals = [(_fold_pad(vals[0][0], row_mask, pad_key), vals[0][1])]
+        for val, kind in vals:
             ops.append(val)
             kinds.append(kind)
     return KeyOps(tuple(ops), tuple(kinds))
 
 
 def key_operand_slots(dtypes, need_null_flags, narrow32,
-                      row_mask: bool = True) -> tuple:
+                      row_mask: bool = True, fold: bool = False) -> tuple:
     """Static ``(kinds, slots)`` of the operand list :func:`key_operands`
     (ascending keys) produces for this key structure: ``kinds`` the
-    operand KIND tuple - liveness flag (with a ``row_mask``), then per
+    operand KIND tuple - liveness flag (with a ``row_mask`` and no
+    ``fold``), then per
     column an optional null flag plus the value operand kind(s) -
     and ``slots[i]`` the positions of column i's VALUE operand(s) in it
     (one, or ``(hi, lo)`` for a wide 64-bit integer).  This is
@@ -211,7 +293,7 @@ def key_operand_slots(dtypes, need_null_flags, narrow32,
     built): keep the two in lockstep - the Pallas probe's eligibility
     gate, exec/pipeline's static operand counts and the join's payload
     layout (ops/join.payload_layout) all read this."""
-    kinds = ["i"] if row_mask else []
+    kinds = ["i"] if row_mask and not fold else []
     slots = []
     for dt, nf, nrw in zip(dtypes, need_null_flags, narrow32):
         if nf:
@@ -233,9 +315,12 @@ def key_operand_slots(dtypes, need_null_flags, narrow32,
     return tuple(kinds), tuple(slots)
 
 
-def key_operand_kinds(dtypes, need_null_flags, narrow32) -> tuple:
-    """:func:`key_operand_slots`' kinds alone, liveness flag included."""
-    return key_operand_slots(dtypes, need_null_flags, narrow32)[0]
+def key_operand_kinds(dtypes, need_null_flags, narrow32,
+                      fold: bool = False) -> tuple:
+    """:func:`key_operand_slots`' kinds alone, liveness flag included
+    (where ``fold`` did not put it into the leading operand)."""
+    return key_operand_slots(dtypes, need_null_flags, narrow32,
+                             fold=fold)[0]
 
 
 def concat_keyops(a: KeyOps, b: KeyOps) -> KeyOps:

@@ -21,14 +21,14 @@ from ..core.column import Column
 from ..core.dtypes import LogicalType, physical_np_dtype
 from ..core.table import Table
 from ..ctx.context import ROW_AXIS, CylonEnv
+from ..obs import metrics as _metrics
+from ..ops import pack
+from ..ops.pack import PAD_L, PAD_R  # noqa: F401 (the tables' pad keys)
 from ..status import CylonTypeError, InvalidError
 from ..utils.stages import staged
 
 ROW = P(ROW_AXIS)
 REP = P()
-
-#: distinct pad keys per table so padding rows never rank-equal across tables
-PAD_L, PAD_R = 4, 5
 
 
 class BoundedCache(dict):
@@ -132,6 +132,56 @@ def narrow32_flags(*col_lists) -> tuple:
     n = len(col_lists[0])
     return tuple(all(fits_int32(cl[i]) for cl in col_lists)
                  for i in range(n))
+
+
+def key_bounds(type, dictionary, bounds):  # noqa: A002
+    """Host-known ``(lo, hi)`` of a key column's physical values, from its
+    ``Column.type`` / ``.dictionary`` / ``.bounds`` (or a packed piece's
+    metadata): the bounds, or what a dictionary-coded string column's
+    sorted dictionary proves of its int32 codes (``[0, len)``; hashed
+    codes use int64's width and prove nothing)."""
+    if bounds is None and type == LogicalType.STRING \
+            and isinstance(dictionary, np.ndarray):
+        return (0, max(len(dictionary) - 1, 0))
+    return bounds
+
+
+#: how a key sort that has padding to keep behind its live rows did it, one
+#: count a dispatched sort, by the operator that asked: ``folded`` into the
+#: leading key operand (one operand fewer), a liveness ``operand`` of its
+#: own (the leading key has no room: :func:`fold_liveness`), or ``all_live``
+#: (tables at capacity: no padding, neither).  Registered at import so that
+#: a snapshot shows the whole family.
+_KEY_SORT_LIVENESS = {
+    (form, site): _metrics.counter("key_sort_liveness", form=form, site=site)
+    for form in ("folded", "operand", "all_live")
+    for site in ("join", "setops", "groupby", "sort")}
+
+
+def note_liveness(site: str, fold: bool, all_live: bool = False) -> bool:
+    """Count one key sort of ``site`` under the form its liveness takes;
+    returns ``fold`` as the builders' static: False where ``all_live`` (no
+    ``row_mask`` reaches the packer, so it would only split a cache)."""
+    fold = bool(fold) and not all_live
+    _KEY_SORT_LIVENESS["all_live" if all_live else
+                       "folded" if fold else "operand", site].inc()
+    return fold
+
+
+def fold_liveness(*col_lists, descending: bool = False) -> bool:
+    """Static: may row liveness ride INSIDE the leading key operand of the
+    sort that ranks these tables (``pack.key_operands(fold=...)``), so that
+    no liveness operand is built?  A sibling of :func:`narrow32_flags`
+    (pass the aligned key columns of all tables ranked together, and the
+    first key's ``descending``); the rule itself is ``ops/pack.fold_room``
+    on the FIRST key column's dtype, null flag and :func:`key_bounds` -
+    what the code can observe in its input, no knob.  A key with no room
+    keeps the operand, and that program is what it was."""
+    firsts = [cl[0] for cl in col_lists]
+    return pack.fold_room(firsts[0].data.dtype,
+                          any(c.validity is not None for c in firsts),
+                          [key_bounds(c.type, c.dictionary, c.bounds)
+                           for c in firsts], descending)
 
 
 def table_lane_spec(cols: list[Column]):

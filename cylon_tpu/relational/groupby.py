@@ -35,8 +35,8 @@ from ..utils.stages import stage, staged
 from ..status import InvalidError
 from ..utils import timing
 from ..utils.host import host_array
-from .common import (PAD_L, REP, ROW, BoundedCache, col_arrays,
-                     live_mask, multi_shard, narrow32_flags)
+from .common import (PAD_L, REP, ROW, BoundedCache, col_arrays, fold_liveness,
+                     live_mask, multi_shard, narrow32_flags, note_liveness)
 from .repart import shuffle_table
 
 shard_map = jax.shard_map
@@ -212,10 +212,10 @@ def _normalize_aggs(aggs):
 
 
 def _group_keys(by_datas, by_valids, vc, grouped: bool = False,
-                narrow: tuple | None = None):
+                narrow: tuple | None = None, fold: bool = False):
     """Per-shard dense group ids; padding rows route to trash segment ``cap``
-    and never contribute a group (live rows sort first, so live ranks are a
-    dense prefix 0..n_groups-1).
+    and never contribute a group (live rows sort first - ``fold``: as
+    :func:`_sort_state`'s - so live ranks are a dense prefix 0..n_groups-1).
 
     ``grouped=True`` (table carries ``grouped_by`` metadata — join/sort
     output): equal keys are already contiguous, so ids come from boundary
@@ -230,7 +230,7 @@ def _group_keys(by_datas, by_valids, vc, grouped: bool = False,
                                                   narrow)
         return gids, n_groups, mask, first
     ko = pack.key_operands(list(by_datas), list(by_valids), row_mask=mask,
-                           pad_key=PAD_L, narrow32=narrow)
+                           pad_key=PAD_L, narrow32=narrow, fold=fold)
     gids, _ = pack.dense_rank(ko)
     n_groups = jnp.max(jnp.where(mask, gids, -1)) + 1
     gids = jnp.where(mask, gids, cap)
@@ -284,21 +284,21 @@ def _rep_keys(by_datas, by_valids, gids, seg_cap):
 
 
 def _sort_state(vc, by_datas, by_valids, val_datas, val_valids, narrow,
-                vspec):
+                vspec, fold: bool = False):
     """THE SORT PATH (non-grouped input): key operands + value/key u32
     payload lanes through one ``lax.sort`` — the input becomes
     run-contiguous, so downstream reductions use the grouped machinery.
     Returns (gids, n_groups, mask, first, by_datas, by_valids, val_datas,
     val_valids) with the column arrays replaced by their sorted versions.
-    Padding rows sort last (pad-key operand), so the live prefix is exactly
-    the first vc[rank] positions."""
+    Padding sorts last (a liveness operand; ``fold``, common.fold_liveness:
+    inside the leading key operand), so the live prefix is vc[rank] long."""
     from ..ops import lanes
     cap = by_datas[0].shape[0]
     my = jax.lax.axis_index(ROW_AXIS)
     n_live = vc[my].astype(jnp.int32)
     mask0 = live_mask(vc, cap)
     ko = pack.key_operands(list(by_datas), list(by_valids), row_mask=mask0,
-                           pad_key=PAD_L, narrow32=narrow)
+                           pad_key=PAD_L, narrow32=narrow, fold=fold)
     all_datas = list(val_datas) + list(by_datas)
     # n_lanes == 0 (every column laneless f64, none nullable): nothing to
     # pack — the index lane alone carries the permutation
@@ -306,13 +306,13 @@ def _sort_state(vc, by_datas, by_valids, val_datas, val_valids, narrow,
                              list(val_valids) + list(by_valids))
             if vspec.n_lanes else None)
     # laneless (f64) columns cannot ride the sort — any f64 bitcast or sort
-    # payload SIGSEGVs the XLA:TPU compiler — so a u32 row-index lane rides
-    # and ONE (cap, K) matrix gather at the sorted permutation moves them
-    # (~6 ns/row/col at K=5 against ~16 for 1-D gathers, measured v5e); so
-    # do the lanes of a sort past pack.SORT_OPERAND_BUDGET (``wide``)
+    # payload SIGSEGVs the XLA:TPU compiler — so a u32 row-index lane rides and
+    # ONE (cap, K) matrix gather at the permutation moves them (~6 ns/row/col
+    # at K=5 against ~16 for 1-D gathers, v5e); so do the lanes of a sort past
+    # pack.SORT_OPERAND_BUDGET (``wide``: a folded flag counts as what it was)
     laneless = tuple(i for i, c in enumerate(vspec.cols) if not c.lanes)
     nk, nl = len(ko.ops), vspec.n_lanes
-    wide = nk + nl + bool(laneless) > pack.SORT_OPERAND_BUDGET
+    wide = nk + fold + nl + bool(laneless) > pack.SORT_OPERAND_BUDGET
     extra = (jnp.arange(cap, dtype=jnp.uint32),) if laneless or wide else ()
     lane_ops = tuple(vmat[:, j] for j in range(0 if wide else nl))
     with stage("sort_keys"):
@@ -391,7 +391,7 @@ def _runs_reduce(specs_ops, val_datas, vmasks, gids, first, mask, vc,
 @program_cache()
 def _combine_fn(mesh: Mesh, ops: tuple, seg_cap: int, grouped: bool,
                 narrow: tuple, vnarrow: tuple = (), vspec=None,
-                val_map: tuple = (), use_window: int = 0):
+                val_map: tuple = (), use_window: int = 0, fold: bool = False):
     """Phase 1 per shard: group keys, reduce each (col, op) into
     intermediate arrays of static length seg_cap (rank-ordered dense
     prefix), gather per-group key representatives.  With ``vspec`` the
@@ -409,10 +409,10 @@ def _combine_fn(mesh: Mesh, ops: tuple, seg_cap: int, grouped: bool,
         if vspec is not None and not grouped:
             (gids, n_groups, mask, first, by_datas, by_valids, uval_datas,
              uval_valids) = _sort_state(vc, by_datas, by_valids, uval_datas,
-                                        uval_valids, narrow, vspec)
+                                        uval_valids, narrow, vspec, fold)
         else:
-            gids, n_groups, mask, first = _group_keys(by_datas, by_valids,
-                                                      vc, grouped, narrow)
+            gids, n_groups, mask, first = _group_keys(by_datas, by_valids, vc,
+                                                      grouped, narrow, fold)
         val_datas = tuple(uval_datas[j] for j in val_map)
         val_valids = tuple(uval_valids[j] for j in val_map)
         vmasks = [_value_mask(mask, val_datas[i], val_valids[i])
@@ -441,7 +441,7 @@ def _combine_fn(mesh: Mesh, ops: tuple, seg_cap: int, grouped: bool,
 
 @program_cache()
 def _final_fn(mesh: Mesh, ops: tuple, seg_cap: int, ddof: int, narrow: tuple,
-              use_window: int = 0):
+              use_window: int = 0, fold: bool = False):
     """Phase 2 per shard: reduce the shuffled intermediates under the new
     key grouping and finalize each op (the reference's
     ``ReduceShuffledResults``, mapreduce/mapreduce.hpp:56-76).  Rides THE
@@ -467,7 +467,7 @@ def _final_fn(mesh: Mesh, ops: tuple, seg_cap: int, ddof: int, narrow: tuple,
             (False,) * len(flat_arrs) + narrow)
         (gids, n_groups, mask, first, s_by, s_byv, s_arrs, _) = _sort_state(
             vc, by_datas, by_valids, tuple(flat_arrs),
-            (None,) * len(flat_arrs), narrow, vspec)
+            (None,) * len(flat_arrs), narrow, vspec, fold)
         my = jax.lax.axis_index(ROW_AXIS)
         n_live = vc[my].astype(jnp.int32)
         starts = gbk.grouped_starts(first, mask, n_live, seg_cap)
@@ -508,7 +508,7 @@ def _final_fn(mesh: Mesh, ops: tuple, seg_cap: int, ddof: int, narrow: tuple,
 @program_cache()
 def _raw_fn(mesh: Mesh, specs: tuple, seg_cap: int, ddof: int, grouped: bool,
             narrow: tuple, vnarrow: tuple = (), vspec=None,
-            val_map: tuple = (), use_window: int = 0):
+            val_map: tuple = (), use_window: int = 0, fold: bool = False):
     """Single-phase per shard over raw (already co-located) rows — used for
     non-associative ops, the local path, and the grouped-input fast path
     (join/sort output: no shuffle, no rank sort).  ``vnarrow``: per spec,
@@ -532,8 +532,8 @@ def _raw_fn(mesh: Mesh, specs: tuple, seg_cap: int, ddof: int, grouped: bool,
 
     ``use_window`` (a window size, 0 = off; a dimension of the program
     cache, as :func:`~.fused._fused_fn`'s last static): that gather
-    through the windowed Pallas kernel.  The last output is
-    :func:`_meta_out`."""
+    through the windowed Pallas kernel.  ``fold``: :func:`_sort_state`'s.
+    The last output is :func:`_meta_out`."""
 
     def per_shard(vc, by_datas, by_valids, uval_datas, uval_valids):
         # uval_*: one array per DISTINCT value column; val_map expands to
@@ -541,10 +541,10 @@ def _raw_fn(mesh: Mesh, specs: tuple, seg_cap: int, ddof: int, grouped: bool,
         if vspec is not None and not grouped:
             (gids, n_groups, mask, first, by_datas, by_valids, uval_datas,
              uval_valids) = _sort_state(vc, by_datas, by_valids, uval_datas,
-                                        uval_valids, narrow, vspec)
+                                        uval_valids, narrow, vspec, fold)
         else:
             gids, n_groups, mask, first = _group_keys(
-                by_datas, by_valids, vc, grouped, narrow)
+                by_datas, by_valids, vc, grouped, narrow, fold)
         val_datas = tuple(uval_datas[j] for j in val_map)
         val_valids = tuple(uval_valids[j] for j in val_map)
         vmasks = [_value_mask(mask, val_datas[i], val_valids[i])
@@ -857,7 +857,7 @@ def _groupby_aggregate_impl(table: Table, by, aggs, ddof: int = 1) -> Table:
     grouped = (table.grouped_by is not None
                and tuple(by) == tuple(table.grouped_by))
     narrow = narrow32_flags(by_cols)
-
+    fold = not grouped and note_liveness("groupby", fold_liveness(by_cols))
     if distributed and all_assoc and not grouped:
         # phase 1: local pre-combine (reference groupby.cpp:76-81), riding
         # the sort path when the columns lane-pack (see _raw_fn/vspec)
@@ -883,7 +883,7 @@ def _groupby_aggregate_impl(table: Table, by, aggs, ddof: int = 1) -> Table:
                  cap_full, int(table.valid_counts.sum())), cap_full,
                 lambda sc, win: _combine_fn(env.mesh, ops_t, sc, False,
                                             narrow, cforms, cspec, val_map,
-                                            win)(*cargs),
+                                            win, fold)(*cargs),
                 partial(_read_meta, env.world_size),
                 # the gather the window serves exists on the sort path alone
                 _density_window(env.mesh, table.valid_counts)
@@ -906,6 +906,12 @@ def _groupby_aggregate_impl(table: Table, by, aggs, ddof: int = 1) -> Table:
             inter_table = _shrink(Table(cols, env, n_groups), n_groups)
             shuffled = shuffle_table(inter_table, by, owner="groupby.recv")
         s_by_datas, s_by_valids = col_arrays([shuffled.column(n) for n in by])
+        # phase 2 ranks phase 1's keys: the table's values, so its bounds,
+        # under the received column's null flag
+        k0 = by_cols[0]
+        ffold = note_liveness("groupby", fold_liveness([Column(
+            s_by_datas[0], k0.type, s_by_valids[0], k0.dictionary,
+            bounds=k0.bounds)]))
         inter_by_op = tuple(
             tuple(shuffled.column(cn).data for cn in inames)
             for inames in inames_by_op)
@@ -918,7 +924,7 @@ def _groupby_aggregate_impl(table: Table, by, aggs, ddof: int = 1) -> Table:
                 ("final-seg", env.serial, ops_t, tuple(by), narrow, ddof,
                  fin_cap, int(shuffled.valid_counts.sum())), fin_cap,
                 lambda sc, win: _final_fn(env.mesh, ops_t, sc, ddof, narrow,
-                                          win)(*fargs),
+                                          win, ffold)(*fargs),
                 partial(_read_meta, env.world_size),
                 _density_window(env.mesh, shuffled.valid_counts),
                 phase="final_").resolve()
@@ -978,7 +984,7 @@ def _groupby_aggregate_impl(table: Table, by, aggs, ddof: int = 1) -> Table:
              int(work.valid_counts.sum())), cap_full,
             lambda sc, win: _raw_fn(env.mesh, spec_t, sc, ddof, grouped,
                                     narrow, vnarrow, vspec, val_map,
-                                    win)(*args),
+                                    win, fold)(*args),
             partial(_read_meta, env.world_size),
             # the gather the window serves exists on run-contiguous input
             # alone (grouped, or the sort path)

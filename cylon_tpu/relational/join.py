@@ -45,7 +45,8 @@ from ..utils.host import host_array
 from .common import (PAD_L, PAD_R, REP, ROW, BoundedCache, build_table,
                      check_same_env,
                      sample_positions,
-                     col_arrays, live_count, live_mask, narrow32_flags,
+                     col_arrays, fold_liveness, key_bounds,
+                     live_count, live_mask, narrow32_flags, note_liveness,
                      promote_key_pair)
 from .piece import PackedPiece
 from .repart import shuffle_table
@@ -273,7 +274,8 @@ def _shuffle_for_join(lwork: Table, rwork: Table, left_on, right_on,
 
 def _sorted_state(vcl, vcr, l_datas, l_valids, r_datas, r_valids,
                   narrow: tuple, payloads: tuple = (),
-                  all_live: bool = False, keep: tuple = ()):
+                  all_live: bool = False, keep: tuple = (),
+                  fold: bool = False):
     """Per-shard single-sort join state (bnd, idx_s, n_live, ``pl_s`` =
     the ``keep`` sorted key operands then the sorted payloads,
     ops/join.PayloadLayout).
@@ -282,10 +284,12 @@ def _sorted_state(vcl, vcr, l_datas, l_valids, r_datas, r_valids,
     null-flag presence per key column is the union of the two sides' and the
     narrow-key decision is made by the caller for the pair.
 
-    ``all_live=True`` (host-known: both tables' valid_counts == capacity)
-    drops the row-liveness sort operand (n_live=None) — one less sort
-    pass; otherwise that operand leads the sort, which is what makes the
-    live rows the sorted prefix ``[0, n_live)``."""
+    ``all_live=True`` (host-known: both tables' valid_counts == capacity:
+    no padding at all) builds no row mask (n_live=None); otherwise padding
+    sorts last - by a liveness operand that leads the sort, or, with
+    ``fold`` (common.fold_liveness: the leading key operand has room), by
+    that operand's two top values, one operand fewer - which is what makes
+    the live rows the sorted prefix ``[0, n_live)``."""
     cap_l, cap_r = l_datas[0].shape[0], r_datas[0].shape[0]
     mask_l = None if all_live else live_mask(vcl, cap_l)
     mask_r = None if all_live else live_mask(vcr, cap_r)
@@ -293,16 +297,17 @@ def _sorted_state(vcl, vcr, l_datas, l_valids, r_datas, r_valids,
                     for lv, rv in zip(l_valids, r_valids))
     ko_l = pack.key_operands(list(l_datas), list(l_valids), row_mask=mask_l,
                              pad_key=PAD_L, need_null_flags=need_nf,
-                             narrow32=narrow)
+                             narrow32=narrow, fold=fold)
     ko_r = pack.key_operands(list(r_datas), list(r_valids), row_mask=mask_r,
                              pad_key=PAD_R, need_null_flags=need_nf,
-                             narrow32=narrow)
+                             narrow32=narrow, fold=fold)
     bnd, idx_s, pl_s = joink.join_sort_state(ko_l, ko_r, payloads, keep)
     return bnd, idx_s, None if all_live else live_count(vcl, vcr), pl_s
 
 
 @program_cache()
-def _semi_flag_fn(mesh: Mesh, narrow: tuple, all_live: bool, anti: bool):
+def _semi_flag_fn(mesh: Mesh, narrow: tuple, all_live: bool, anti: bool,
+                  fold: bool = False):
     """Per-left-row matched flag for SEMI/ANTI joins over the single-sort
     state: one run of the boundary algebra (right-count per key run), no
     output expansion at all — the output is a filter of the left table.
@@ -315,7 +320,7 @@ def _semi_flag_fn(mesh: Mesh, narrow: tuple, all_live: bool, anti: bool):
         cap_l = l_datas[0].shape[0]
         bnd, idx_s, n_live, _pl = _sorted_state(
             vcl, vcr, l_datas, l_valids, r_datas, r_valids, narrow, (),
-            all_live)
+            all_live, fold=fold)
         n = bnd.shape[0]
         pos = jnp.arange(n, dtype=jnp.int32)
         lefts_b, rights_b, _live = joink.live_sides(idx_s, cap_l, n_live)
@@ -342,7 +347,8 @@ def _count_fn(mesh: Mesh, how: str, narrow: tuple,
               lspec: lanes.LaneSpec | None = None,
               rspec: lanes.LaneSpec | None = None,
               layout: joink.PayloadLayout = joink.PayloadLayout(),
-              all_live: bool = False, slim: bool = False):
+              all_live: bool = False, slim: bool = False,
+              fold: bool = False):
     """Phase 1: sort once; return per-shard exact counts + carried state.
 
     With ``lspec``/``rspec`` (inner/left joins over fully-laneable output
@@ -354,7 +360,9 @@ def _count_fn(mesh: Mesh, how: str, narrow: tuple,
     that phase 2 already does.  ``layout`` (ops/join.PayloadLayout, built
     on the host by ``ops/join.payload_layout`` from these specs) says which
     operands carry them: the two sides share operands and a key column's
-    lanes come back from the sorted key."""
+    lanes come back from the sorted key.  ``fold`` (common.fold_liveness,
+    the same answer ``layout`` was built with): padding sorts last inside
+    the leading key operand and no liveness operand is built."""
     assert (layout.nl, layout.nr) == tuple(
         0 if sp is None else sp.n_lanes for sp in (lspec, rspec))
 
@@ -369,7 +377,7 @@ def _count_fn(mesh: Mesh, how: str, narrow: tuple,
         payloads = joink.payload_operands(layout, lmat, rmat, cap_l, cap_r)
         bnd, idx_s, n_live, pl_s = _sorted_state(
             vcl, vcr, l_datas, l_valids, r_datas, r_valids, narrow, payloads,
-            all_live, layout.kept_keys)
+            all_live, layout.kept_keys, fold)
         n, carry = joink.join_carry(bnd, idx_s, n_live, cap_l, how)
         if slim:
             # deferred-join state: only what the fused consumer needs
@@ -559,7 +567,7 @@ def _packed_count_fn(mesh: Mesh, how: str, narrow: tuple, need_nf: tuple,
                      layout: joink.PayloadLayout,
                      kil: tuple, kir: tuple, cap_l: int, cap_r: int,
                      n_arrs_l: int, n_arrs_r: int, all_live: bool,
-                     slim: bool = False):
+                     slim: bool = False, fold: bool = False):
     """Phase 1 over packed windows: slice both windows, unpack only the
     KEY columns, sort once, return per-shard exact counts + carried state.
     The window's OWN lanes ride the sort as payload where ``layout``
@@ -578,10 +586,10 @@ def _packed_count_fn(mesh: Mesh, how: str, narrow: tuple, need_nf: tuple,
         mask_r = None if all_live else live_mask(vcr, cap_r)
         ko_l = pack.key_operands(l_datas, l_valids, row_mask=mask_l,
                                  pad_key=PAD_L, need_null_flags=need_nf,
-                                 narrow32=narrow)
+                                 narrow32=narrow, fold=fold)
         ko_r = pack.key_operands(r_datas, r_valids, row_mask=mask_r,
                                  pad_key=PAD_R, need_null_flags=need_nf,
-                                 narrow32=narrow)
+                                 narrow32=narrow, fold=fold)
         # a side that does not ride has no lane in ``layout``: its window
         # is handed over all the same and nothing of it is read
         payloads = joink.payload_operands(layout, mat_l, mat_r, cap_l, cap_r)
@@ -835,13 +843,20 @@ def _packed_statics(pl: PackedPiece, pr: PackedPiece, left_on, right_on,
     carry_match = can_carry(pr.spec) and pr.spec.n_lanes <= 8
     all_live = bool((pl.lens == pl.piece_cap).all()
                     and (pr.lens == pr.piece_cap).all())
+    # common.fold_liveness over piece metadata: the first key's dtype,
+    # null flag and host bounds on both sides
+    fold = not all_live and pack.fold_room(
+        pl.spec.cols[kil[0]].dtype, need_nf[0],
+        [key_bounds(*p.meta[i][1:])
+         for p, i in ((pl, kil[0]), (pr, kir[0]))])
     # the window's own spec holds every column, the keys among them
     layout = joink.payload_layout(
         pl.spec if carry_emit else None, pr.spec if carry_match else None,
         kil, tuple(pl.spec.cols[i].dtype for i in kil), need_nf, narrow,
-        all_live)
+        all_live, fold)
     return (kil, kir, need_nf, narrow, coalesce, tuple(plan), tuple(names),
-            tuple(types), tuple(dicts), tuple(bounds), layout, all_live)
+            tuple(types), tuple(dicts), tuple(bounds), layout, all_live,
+            fold)
 
 
 def prewarm_packed_join(pl: PackedPiece, pr: PackedPiece, left_on,
@@ -858,14 +873,14 @@ def prewarm_packed_join(pl: PackedPiece, pr: PackedPiece, left_on,
         return
     try:
         (kil, kir, need_nf, narrow, coalesce, _plan, _names, _types,
-         _dicts, _bounds, layout, all_live) = _packed_statics(
+         _dicts, _bounds, layout, all_live, fold) = _packed_statics(
             pl, pr, left_on, right_on, how, suffixes, coalesce_keys)
         slim = (how == "inner" and layout.nl and layout.nr
                 and coalesce and allow_defer)
         fn = _packed_count_fn(
             pl.env.mesh, how, narrow, need_nf, pl.spec, pr.spec, layout,
             kil, kir, pl.piece_cap, pr.piece_cap, len(pl.arrs),
-            len(pr.arrs), all_live, bool(slim))
+            len(pr.arrs), all_live, bool(slim), fold)
         vcl = np.asarray(pl.lens, np.int32)
         vcr = np.asarray(pr.lens, np.int32)
         from ..exec.compiler import aot_compile
@@ -888,7 +903,7 @@ def _join_packed_impl(pl: PackedPiece, pr: PackedPiece, left_on, right_on,
     memory.touch(pl.reg)
     memory.touch(pr.reg)
     (kil, kir, need_nf, narrow, coalesce, plan, names, types, dicts,
-     bounds, layout, all_live) = _packed_statics(
+     bounds, layout, all_live, fold) = _packed_statics(
         pl, pr, left_on, right_on, how, suffixes, coalesce_keys)
     cap_l, cap_r = pl.piece_cap, pr.piece_cap
     vcl = np.asarray(pl.lens, np.int32)
@@ -898,9 +913,10 @@ def _join_packed_impl(pl: PackedPiece, pr: PackedPiece, left_on, right_on,
                  and allow_defer)
     fn = _packed_count_fn(env.mesh, how, narrow, need_nf, pl.spec, pr.spec,
                           layout, kil, kir, cap_l, cap_r, len(pl.arrs),
-                          len(pr.arrs), all_live, defer)
+                          len(pr.arrs), all_live, defer, fold)
     args = (vcl, vcr, pl.starts, pr.starts) + pl.arrs + pr.arrs
     _note_sort(layout)
+    note_liveness("join", fold, all_live)
 
     if defer:
         with timing.region("join.sort_count"):
@@ -1215,9 +1231,11 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
         # no expansion (reference: JoinTables' semi/anti shapes)
         all_live_sa = bool((vcl == lwork.capacity).all()
                            and (vcr == rwork.capacity).all())
+        fold = note_liveness("join", fold_liveness(l_key_cols, r_key_cols),
+                             all_live_sa)
         with timing.region("join.semi"):
             flag = _semi_flag_fn(env.mesh, narrow, all_live_sa,
-                                 how == "anti")(
+                                 how == "anti", fold)(
                 vcl, vcr, l_datas, l_valids, r_datas, r_valids)
         from .repart import filter_table
         return filter_table(lwork, flag)
@@ -1327,6 +1345,10 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
                         and (vcr == rwork.capacity).all())
         need_nf = tuple((lv is not None) or (rv is not None)
                         for lv, rv in zip(l_valids, r_valids))
+        # padding sorts last inside the leading key operand where it has
+        # room (one rule, common.fold_liveness), else by a liveness operand
+        fold = note_liveness("join", fold_liveness(l_key_cols, r_key_cols),
+                             all_live)
 
         # ---- deferred materialization (reference ops-DAG slot, C9) -------
         # Inner joins whose output columns fully ride the phase-1 sort can hand
@@ -1359,7 +1381,9 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
             # within pack.SORT_OPERAND_BUDGET (its compile time grows with
             # every operand): the keys' operands and the index come first,
             # the two sides share what is left; a side that does not fit is
-            # gathered at the take index
+            # gathered at the take index.  A liveness flag folded into the
+            # key still counts as the operand it was: no join changes route
+            # because an operand came free (ROADMAP S13)
             room = pack.SORT_OPERAND_BUDGET - 1 - len(pack.key_operand_slots(
                 tuple(d.dtype for d in l_datas), need_nf, narrow,
                 row_mask=not all_live)[0])
@@ -1382,12 +1406,12 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
         # sides share operands, a left key column is the sorted key itself
         layout = joink.payload_layout(
             cl_spec, cr_spec, tuple(l_key_lane.get(n) for n in left_on),
-            tuple(d.dtype for d in l_datas), need_nf, narrow, all_live)
+            tuple(d.dtype for d in l_datas), need_nf, narrow, all_live, fold)
 
     if defer:
         with timing.region("join.sort_count"):
             res = _count_fn(env.mesh, how, narrow, cl_spec, cr_spec, layout,
-                            all_live, slim=True)(*count_args)
+                            all_live, slim=True, fold=fold)(*count_args)
         _note_sort(layout)
         counts_dev, idx_s_s, bnd_s = res[0], res[1], res[2]
         pl_s = tuple(res[3:])
@@ -1484,7 +1508,7 @@ def _join_tables_impl(left: Table, right: Table, left_on, right_on,
 
     with timing.region("join.sort_count"):
         res = _count_fn(env.mesh, how, narrow, cl_spec, cr_spec, layout,
-                        all_live)(*count_args)
+                        all_live, fold=fold)(*count_args)
         counts_dev, carry = res[0], res[1:7]
         pl_s = tuple(res[7:])
     _note_sort(layout)

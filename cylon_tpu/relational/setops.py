@@ -37,9 +37,9 @@ from ..status import InvalidError
 from ..utils.host import host_array
 from ..utils.stages import stage
 from . import repart
-from .common import (PAD_L, REP, ROW, check_same_env, col_arrays, live_mask,
-                     narrow32_flags, promote_key_pair, rebuild_like,
-                     table_lane_spec)
+from .common import (PAD_L, REP, ROW, check_same_env, col_arrays, live_count,
+                     fold_liveness, live_mask, narrow32_flags, note_liveness,
+                     promote_key_pair, rebuild_like, table_lane_spec)
 from .repart import repartition, shuffle_table
 
 shard_map = jax.shard_map
@@ -75,18 +75,18 @@ def _said(ctx, pn, op: str, res: Table, route: str, **args) -> None:
         pn.set(rows_out=res.row_count, route=route)
 
 
-def _rank_sorted(keyops: pack.KeyOps) -> tuple:
+def _rank_sorted(keyops: pack.KeyOps, n_live) -> tuple:
     """``(first, live, sidx)`` in the rank sort's order (keys, then the row
     index, stable: a group of equal rows is one run in source order): the
-    sorted row starts its run; is no padding row (the liveness flag is
-    ``pack.key_operands``' first operand); its index in the ranked rows."""
+    sorted row starts its run; is no padding row (padding sorted last, so
+    the position compare ``p < n_live``); its index in the ranked rows."""
     idx = jnp.arange(keyops.n, dtype=jnp.int32)
     with stage("sort_keys"):
         srt = jax.lax.sort(keyops.ops + (idx,), num_keys=len(keyops.ops),
                            is_stable=True)
     with stage("setop_flags"):
         first = (pack.neighbor_flags(srt[:-1], keyops.kinds) != 0) | (idx == 0)
-        live = srt[0] == 0
+        live = idx < n_live
     return first, live, srt[-1]
 
 
@@ -111,18 +111,18 @@ def _taken(like: Table, meta, srt, cap: int, live, spec, program,
 # ---------------------------------------------------------------------------
 
 @program_cache()
-def _unique_count_fn(mesh: Mesh, keep: str, narrow: tuple):
+def _unique_count_fn(mesh: Mesh, keep: str, narrow: tuple, fold: bool = False):
     """``repart._filter_count_fn``'s ``(meta, srt)`` for the kept occurrence
     of each distinct key.  ``narrow``: static per-key flags
     (common.narrow32_flags) - a 64-bit integer key whose host-known bounds
-    fit int32 sorts as ONE operand, not a (hi, lo) pair: XLA:TPU compiles a
-    sort in time that grows with its operands
-    (ops/pack.SORT_OPERAND_BUDGET)."""
+    fit int32 sorts as ONE operand, not a (hi, lo) pair; ``fold``
+    (common.fold_liveness): padding sorts last INSIDE the leading operand.
+    XLA:TPU compiles, and runs, a sort by its operands (ops/pack)."""
     def per_shard(vc, key_datas, key_valids):
-        cap = key_datas[0].shape[0]
+        cap, my = key_datas[0].shape[0], jax.lax.axis_index(shuffle.ROW_AXIS)
         first, live, sidx = _rank_sorted(pack.key_operands(
             list(key_datas), list(key_valids), row_mask=live_mask(vc, cap),
-            pad_key=PAD_L, narrow32=narrow))
+            pad_key=PAD_L, narrow32=narrow, fold=fold), vc[my])
         return repart.kept_positions(setk.unique_flags(first, live, keep),
                                      sidx, cap)
 
@@ -166,9 +166,9 @@ def unique_table(table: Table, subset=None, keep: str = "first") -> Table:
             key_cols = [table.column(n) for n in subset]
             cols = list(table.columns.values())
             vc = np.asarray(table.valid_counts, np.int32)
-            meta, srt = _unique_count_fn(
-                env.mesh, keep, narrow32_flags(key_cols))(
-                    vc, *col_arrays(key_cols))
+            fold = note_liveness("setops", fold_liveness(key_cols))
+            meta, srt = _unique_count_fn(env.mesh, keep, narrow32_flags(
+                key_cols), fold)(vc, *col_arrays(key_cols))
             spec = table_lane_spec(cols)
             res = _taken(table, meta, srt, table.capacity, vc, spec,
                          partial(_unique_mat_fn, env.mesh, spec),
@@ -196,12 +196,12 @@ def _align_schemas(a: Table, b: Table):
 
 
 @program_cache()
-def _setop_count_fn(mesh: Mesh, op: str, narrow: tuple):
+def _setop_count_fn(mesh: Mesh, op: str, narrow: tuple, fold: bool = False):
     """``repart._filter_count_fn``'s ``(meta, srt)`` for a set operation's
     output rows, the positions in the materialize program's SOURCE
     (:func:`_setop_mat_fn`).  ``union`` ranks ``[a; b]``, ``subtract`` /
-    ``intersect`` rank ``[b; a]`` (ops/setops.set_op_flags).  ``narrow``:
-    :func:`_unique_count_fn`'s, over BOTH tables' columns."""
+    ``intersect`` rank ``[b; a]`` (ops/setops.set_op_flags).  ``narrow``,
+    ``fold``: :func:`_unique_count_fn`'s, over BOTH tables' columns."""
     def per_shard(vca, vcb, a_datas, a_valids, b_datas, b_valids):
         cap_a, cap_b = a_datas[0].shape[0], b_datas[0].shape[0]
         # operand structures must match across the two tables: emit a
@@ -209,13 +209,13 @@ def _setop_count_fn(mesh: Mesh, op: str, narrow: tuple):
         need_nf = tuple((av is not None) or (bv is not None)
                         for av, bv in zip(a_valids, b_valids))
         ko_a, ko_b = (pack.key_operands(
-            list(d), list(v), row_mask=live_mask(vc, d[0].shape[0]),
+            list(d), list(v), row_mask=live_mask(vc, d[0].shape[0]), fold=fold,
             pad_key=PAD_L, need_null_flags=need_nf, narrow32=narrow)
             for vc, d, v in ((vca, a_datas, a_valids),
                              (vcb, b_datas, b_valids)))
         first, live, sidx = _rank_sorted(
             pack.concat_keyops(ko_a, ko_b) if op == "union"
-            else pack.concat_keyops(ko_b, ko_a))
+            else pack.concat_keyops(ko_b, ko_a), live_count(vca, vcb))
         with stage("setop_flags"):      # the side, the address: of sidx
             if op == "union":
                 is_b = sidx >= cap_a
@@ -327,6 +327,7 @@ def _set_operation_impl(a: Table, b: Table, op: str,
     cols_a, cols_b = ([t.column(n) for n in names] for t in (a, b))
     a_arrays, b_arrays = col_arrays(cols_a), col_arrays(cols_b)
     narrow = narrow32_flags(cols_a, cols_b)
+    fold = fold_liveness(cols_a, cols_b)
     vca = np.asarray(a.valid_counts, np.int32)
     vcb = np.asarray(b.valid_counts, np.int32)
     if op == "union":
@@ -342,8 +343,9 @@ def _set_operation_impl(a: Table, b: Table, op: str,
         cap, live, source = a.capacity, vca, a_arrays
     if not cap:         # no row to take: the (empty) source is the result
         return a
-    meta, srt = _setop_count_fn(env.mesh, op, narrow)(
-        vca, vcb, *a_arrays, *b_arrays)
+    meta, srt = _setop_count_fn(
+        env.mesh, op, narrow, note_liveness("setops", fold))(
+            vca, vcb, *a_arrays, *b_arrays)
     return _taken(a, meta, srt, cap, live, spec,
                   partial(_setop_mat_fn, env.mesh, op, spec), *source)
 

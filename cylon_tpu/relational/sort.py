@@ -36,8 +36,9 @@ from ..status import InvalidError
 from ..utils import timing
 from ..utils.host import host_array
 from ..utils.stages import stage
-from .common import (PAD_L, REP, ROW, col_arrays, live_mask,
-                     narrow32_flags, rebuild_like, sample_positions)
+from .common import (PAD_L, REP, ROW, col_arrays, fold_liveness, live_mask,
+                     narrow32_flags, note_liveness, rebuild_like,
+                     sample_positions)
 from .repart import exchange_by_targets
 from ..parallel import shuffle
 
@@ -61,7 +62,8 @@ def _norm_dirs(by, ascending):
 @program_cache()
 def _local_sort_fn(mesh: Mesh, descendings: tuple, nulls_position: int,
                    narrow: tuple, vspec, f64_idx: tuple = (),
-                   by_idx: tuple = (0,), donate: bool = False):
+                   by_idx: tuple = (0,), donate: bool = False,
+                   fold: bool = False):
     """Per-shard multi-key sort.  Laneable columns RIDE THE SORT as u32
     payload lanes (~1.7 ns/row/lane measured) via ``vspec`` (a LaneSpec
     over the full column list, f64 columns planned laneless); f64 columns
@@ -74,7 +76,13 @@ def _local_sort_fn(mesh: Mesh, descendings: tuple, nulls_position: int,
     — lint rule TS108).  ``donate`` consumes the caller's column buffers
     (the pipeline's phase-1 sorts, whose inputs are exclusively owned
     fresh shuffle outputs): XLA reuses them for the sorted output
-    instead of holding input + output live together."""
+    instead of holding input + output live together.
+
+    Padding sorts last: by a liveness operand, or - ``fold``
+    (common.fold_liveness on the first key and its direction) - inside the
+    leading key operand, one operand fewer.  The budget below counts a
+    folded flag as the operand it was, so no table changes its path
+    because an operand came free."""
     from ..ops import lanes
     n_index = 1 if f64_idx else 0     # the row index rides for f64 columns
 
@@ -86,8 +94,8 @@ def _local_sort_fn(mesh: Mesh, descendings: tuple, nulls_position: int,
         ko = pack.key_operands(list(by_datas), list(by_valids), row_mask=mask,
                                descendings=list(descendings),
                                nulls_position=nulls_position, pad_key=PAD_L,
-                               narrow32=narrow or None)
-        if (len(ko.ops) + vspec.n_lanes + n_index
+                               narrow32=narrow or None, fold=fold)
+        if (len(ko.ops) + fold + vspec.n_lanes + n_index
                 > pack.SORT_OPERAND_BUDGET or vspec.n_lanes == 0):
             # wide tables (or all-f64, nothing laneable): ONE lane-matrix
             # gather at the permutation (plus f64 side gathers inside
@@ -457,11 +465,13 @@ def local_sort_table(table: Table, by, ascending=True,
     valids = tuple(c.validity for _, c in items)
     from .common import table_lane_spec
     narrow = narrow32_flags(by_cols)
+    fold = note_liveness("sort", fold_liveness(by_cols,
+                                               descending=descendings[0]))
     vspec = table_lane_spec([c for _, c in items])
     f64_idx = tuple(i for i, c in enumerate(vspec.cols) if not c.lanes)
     with timing.region("sort.local"):
         out_d, out_v = _local_sort_fn(env.mesh, descendings, npos, narrow,
-                                      vspec, f64_idx, by_idx, donate)(
+                                      vspec, f64_idx, by_idx, donate, fold)(
             vc, datas, valids)
     cols = {}
     for (n, c), d, v in zip(items, out_d, out_v):

@@ -14,6 +14,16 @@ checked against the 3-operand sort's before it is timed.  Times are host
 clock around ``block_until_ready``, the median of ``--reps`` calls after
 one warm call.
 
+Since ISSUE 50 also the fold itself, at the shapes PR 35 did not time
+(``FOLD_SHAPES``): each cell's liveness-led key sort as it was - the flag,
+the key column(s), then what rides - beside the same sort with the flag
+folded into the leading key (``where(live, key, max - 1 | max)``: one
+operand and one sort key fewer, ``ops/pack.key_operands(fold=True)``) - the
+join's ``x4 -> x3``, the set operations' ``x4 -> x3`` with two key columns
+at 65,011,712 rows, ``unique``'s ``x3 -> x2`` at 32,505,856 and the
+groupby's unstable ``x4 -> x3`` at 25,165,824; the live prefix of the
+folded sort's keys is checked against the unfolded one's.
+
     chiprun -- python scripts/sort_operands_bench.py --out chiprun_out/sort_operands_bench.json
 """
 
@@ -32,6 +42,20 @@ SHAPES = (
     ("join_groupby_32m", 65_011_712, 32_000_000),
     ("dist_join_groupby_8m_x4", 17_825_792, 8_388_608),
     ("dist_join_groupby_8m_zipf_x4", 20_447_232, 9_700_000),
+)
+
+
+#: (the cell's sort, rows a shard, live rows a table, tables ranked together,
+#: further key columns, uint32 payloads, the row index rides, stable)
+FOLD_SHAPES = (
+    ("join_groupby_32m join__count_fn", 65_011_712, 32_000_000, 2, 0, 1,
+     True, True),
+    ("setops_dedup_32m setop_count_fn", 65_011_712, 32_000_000, 2, 1, 0,
+     True, True),
+    ("setops_dedup_32m unique_count_fn", 32_505_856, 32_000_000, 1, 0, 0,
+     True, True),
+    ("groupby_sort_25m groupby__raw_fn", 25_165_824, 25_000_000, 1, 0, 2,
+     False, False),
 )
 
 
@@ -57,17 +81,78 @@ def sort_n(flag, key, pays, n_ops: int):
                         num_keys=2, is_stable=True)
 
 
-def time_sort(args, n_ops: int, reps: int):
-    f = jax.jit(sort_n, static_argnums=3)
+def _timed(f, args, reps: int):
+    """(outputs, median ms, best ms, seconds of the first call: compile +
+    one run) of ``f(*args)``."""
     t0 = time.perf_counter()
-    out = jax.block_until_ready(f(*args, n_ops))
-    first_s = time.perf_counter() - t0          # compile + one call
+    out = jax.block_until_ready(f(*args))
+    first_s = time.perf_counter() - t0
     times = []
     for _ in range(reps):
         t0 = time.perf_counter()
-        jax.block_until_ready(f(*args, n_ops))
+        jax.block_until_ready(f(*args))
         times.append((time.perf_counter() - t0) * 1e3)
     return out, statistics.median(times), min(times), first_s
+
+
+def time_sort(args, n_ops: int, reps: int):
+    return _timed(jax.jit(sort_n, static_argnums=3), (*args, n_ops), reps)
+
+
+def fold_sort(flag, key, pays, n_keys2: int, n_pays: int, with_idx: bool,
+              stable: bool, fold: bool):
+    """A cell's liveness-led key sort, or the same sort with the flag folded
+    into the leading key: padding at int32's ``max - 1`` (flag 4) / ``max``
+    (flag 5), above every live key.  Further key columns are the low bits
+    of the first payloads.  Returns (sorted leading key, the other sorted
+    operands)."""
+    if fold:
+        top = jnp.int32(2**31 - 1 - 5) + flag
+        lead = (jnp.where(flag == 0, key, top),)
+    else:
+        lead = (flag, key)
+    keys2 = tuple((p & 3).astype(jnp.int32) for p in pays[:n_keys2])
+    idx = (jnp.arange(key.shape[0], dtype=jnp.int32),) if with_idx else ()
+    out = jax.lax.sort(lead + keys2 + idx + tuple(pays[n_keys2:][:n_pays]),
+                       num_keys=len(lead) + n_keys2, is_stable=stable)
+    return out[len(lead) - 1], out[len(lead):]
+
+
+def time_fold(args, statics, reps: int):
+    return _timed(jax.jit(fold_sort, static_argnums=(3, 4, 5, 6, 7)),
+                  (*args, *statics), reps)
+
+
+def fold_rows(a) -> list:
+    rows = []
+    for cell, n, live, tables, n_keys2, n_pays, with_idx, stable in FOLD_SHAPES:
+        n, live = (max(int(v * a.scale), 8) for v in (n, live))
+        # one table: its live rows lead, the padding behind them
+        flag, key, pays = jax.jit(make_inputs, static_argnums=(1, 2))(
+            a.seed % (2**31), n * (3 - tables), live)
+        args = (flag[:n], key[:n], tuple(p[:n] for p in pays))
+        n_live, ref = live * tables, None
+        for fold in (False, True):
+            (lead, rest), med, best, first_s = time_fold(
+                args, (n_keys2, n_pays, with_idx, stable, fold), a.reps)
+            operands = 2 - fold + n_keys2 + with_idx + n_pays
+            row = {"cell": cell, "rows": n, "operands": operands,
+                   "folded": fold, "stable": stable, "ms_median": med,
+                   "ms_min": best, "first_call_s": first_s,
+                   "ns_per_row_operand": med * 1e6 / n / operands}
+            if ref is None:
+                ref, base_ms = (lead, rest), med
+            else:
+                row["ms_saved_by_fold"] = base_ms - med
+                row["live_prefix_equal"] = bool(
+                    jnp.array_equal(lead[:n_live], ref[0][:n_live]) and all(
+                        jnp.array_equal(x[:n_live], y[:n_live])
+                        for x, y in zip(rest[:n_keys2 + (with_idx and stable)],
+                                        ref[1])))
+            print(json.dumps(row), flush=True)
+            rows.append(row)
+            del lead, rest
+    return rows
 
 
 def main():
@@ -77,13 +162,17 @@ def main():
     ap.add_argument("--scale", type=float, default=1.0,
                     help="shrink every shape (CPU rehearsal)")
     ap.add_argument("--operands", default="3,4,5,6")
+    ap.add_argument("--shapes", default="all",
+                    choices=("all", "operands", "fold"),
+                    help="PR 35's operand ladder, ISSUE 50's fold pairs, "
+                         "or both")
     ap.add_argument("--out", default="")
     a = ap.parse_args()
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind}
     print(json.dumps({"device": device}), flush=True)
     rows = []
-    for cell, n, live_side in SHAPES:
+    for cell, n, live_side in SHAPES if a.shapes != "fold" else ():
         n, live_side = (max(int(v * a.scale), 8) for v in (n, live_side))
         args = jax.jit(make_inputs, static_argnums=(1, 2))(
             a.seed % (2**31), n, live_side)
@@ -103,6 +192,8 @@ def main():
             print(json.dumps(row), flush=True)
             rows.append(row)
             del out
+    if a.shapes != "operands":
+        rows += fold_rows(a)
     if a.out:
         with open(a.out, "w", encoding="utf-8") as f:
             json.dump({"device": device, "seed": a.seed, "reps": a.reps,

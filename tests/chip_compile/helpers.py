@@ -13,6 +13,17 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 _ROWS4 = 17 << 19
 
 
+def _fold(builder) -> dict:
+    """``fold=True`` for a builder of this tree: the cells' leading keys
+    are int64 within int32 bounds, so padding sorts last INSIDE the key
+    operand and no liveness operand is built (ISSUE 50).  A tree from
+    before has no such word and sorts the operand
+    (``scripts/hash_programs.py`` runs this module against both)."""
+    import inspect
+    known = "fold" in inspect.signature(builder).parameters
+    return {"fold": True} if known else {}
+
+
 def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
@@ -50,15 +61,18 @@ def _join_specs(n_sums: int = 2):
     """Lane specs and payload layout of the benchmark's join, all int64
     within int32 bounds, tables under capacity: left (k, a), right (b) -
     or, for four sums, left (k, a, c), right (b, d).  The key's lane is
-    the sorted key operand; the others share one operand a pair."""
+    the sorted key operand, padding's sentinel inside it; the others
+    share one operand a pair."""
     from cylon_tpu.ops import join as joink, lanes
     nl = n_sums // 2
     lspec = lanes.plan_lanes(("int64",) * (1 + nl), (False,) * (1 + nl),
                              (True,) * (1 + nl))
     rspec = lanes.plan_lanes(("int64",) * nl, (False,) * nl, (True,) * nl)
+    fold = _fold(joink.payload_layout)
     layout = joink.payload_layout(lspec, rspec, (0,), ("int64",), (False,),
-                                  (True,), False)
-    assert layout.sort_operands == 3 + nl and layout.n_arrays == 1 + nl
+                                  (True,), False, **fold)
+    assert layout.sort_operands == 3 - len(fold) + nl
+    assert layout.n_arrays == 1 + nl
     return lspec, rspec, layout
 
 
@@ -96,9 +110,11 @@ def _groupby_program(mesh, site: str, seg_cap: int, window: int,
     vspec = lanes.plan_lanes(("int64", "int64"), (False, False), (True, True))
     if site == "combine":
         return rel_gb._combine_fn(mesh, ("sum",), seg_cap, False, (True,),
-                                  (_scan(form),), vspec, (0,), window)
+                                  (_scan(form),), vspec, (0,), window,
+                                  **_fold(rel_gb._combine_fn))
     return rel_gb._raw_fn(mesh, (("sum", 0.5),), seg_cap, 1, False, (True,),
-                          (_scan(form),), vspec, (0,), window)
+                          (_scan(form),), vspec, (0,), window,
+                          **_fold(rel_gb._raw_fn))
 
 
 # ---- the distributed groupby -> sort (ISSUE 44) ------------------------------
@@ -128,12 +144,14 @@ def _dist_sort_program(mesh, which: str, cap: int):
     S = jax.ShapeDtypeStruct
     vc = S((w,), np.int32, sharding=rep)
     col = S((w * cap,), np.int64, sharding=row)
+    fold = _fold(rel_gb._final_fn)      # the key's bounds reach phase 2
     if which == "final":
-        return (rel_gb._final_fn(mesh, ("sum",), cap, 1, (True,)),
+        return (rel_gb._final_fn(mesh, ("sum",), cap, 1, (True,), **fold),
                 (vc, (col,), (None,), ((col,),)))
     if which == "final_windowed":
         return (rel_gb._final_fn(mesh, ("sum",), _GS_CELL_SEG, 1, (True,),
-                                 1024), (vc, (col,), (None,), ((col,),)))
+                                 1024, **fold),
+                (vc, (col,), (None,), ((col,),)))
     desc, npos, narrow = (False,), pack.NULL_LAST, (False,)
     if which == "sample":
         return (rel_sort._sample_fn(mesh, 64, desc, npos, narrow),
@@ -143,7 +161,8 @@ def _dist_sort_program(mesh, which: str, cap: int):
                      S((w - 1,), np.uint32, sharding=rep))
         return (rel_sort._target_fn(mesh, desc, npos, narrow),
                 (vc, (col,), (None,), splitters))
-    # the result of phase 2: the key and the sum, both wide by then
+    # the result of phase 2: the key and the sum, both wide by then (a
+    # wide pair leads: the liveness operand stays)
     vspec = lanes.plan_lanes(("int64", "int64"), (False, False),
                              (False, False))
     return (rel_sort._local_sort_fn(mesh, desc, npos, narrow, vspec, (),
@@ -176,12 +195,14 @@ def _setop_programs(mesh, op: str, window: int = 2048):
     spec = lanes.plan_lanes(("int64",) * 2, (False,) * 2, (True,) * 2)
     out_cap = _SETOP_OUT_CAP[op]
     if op == "unique":
-        return (setops._unique_count_fn(mesh, "first", (True,)),
+        return (setops._unique_count_fn(mesh, "first", (True,),
+                                        **_fold(setops._unique_count_fn)),
                 (vc, (col,), (None,)),
                 setops._unique_mat_fn(mesh, spec, out_cap, window),
                 (vc, S((_SETOP_CAP,), np.int32, sharding=row), two, none2))
     srt = S((2 * _SETOP_CAP,), np.int32, sharding=row)
-    return (setops._setop_count_fn(mesh, op, (True, True)),
+    return (setops._setop_count_fn(mesh, op, (True, True),
+                                   **_fold(setops._setop_count_fn)),
             (vc, vc, two, none2, two, none2),
             setops._setop_mat_fn(mesh, op, spec, out_cap, window),
             (vc, srt, vc, two, none2, two, none2) if op == "union"

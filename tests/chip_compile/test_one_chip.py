@@ -143,7 +143,7 @@ def test_packed_piece_join_compiles_for_v5e(mesh1, env1, monkeypatch):
 @pytest.mark.parametrize("cap,seg_cap", [
     (69632, 40960),
     # groupby_sort_25m's own shapes: 25M rows, ~15.09M groups (about a
-    # minute of XLA:TPU, most of it the 4-operand sort)
+    # minute of XLA:TPU, most of it the 3-operand sort)
     (25165824, 15204352),
 ])
 def test_windowed_raw_groupby_compiles_for_v5e(mesh1, monkeypatch, cap,
@@ -226,8 +226,9 @@ def test_filter_programs_compile_for_v5e(topo, monkeypatch, filter_counts,
 
 # ---- drop_duplicates (ISSUE 49) ---------------------------------------------
 # benchmark cell setops_dedup_32m's ``unique``: the filter's pair of programs
-# behind a 3-operand rank sort (helpers._setop_programs).
+# behind a 2-operand rank sort - ``k`` with padding's sentinel inside it, the
+# row index (helpers._setop_programs).
 
 def test_unique_programs_compile_for_v5e(mesh1, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    _check_setop_programs(mesh1, "unique", 3)
+    _check_setop_programs(mesh1, "unique", 2)

@@ -198,7 +198,7 @@ def _forced_window(monkeypatch, builder):
     inside shard_map, so the programs skip that check) and the site starts
     from an empty memory.  Returns the log of ``(statics, arguments,
     outputs)`` per dispatch (``statics[1]`` the segment space,
-    ``statics[-1]`` the window), the list of the kernel's traces and the
+    ``statics[-2]`` the window), the list of the kernel's traces and the
     builder itself."""
     from functools import partial
 
@@ -241,7 +241,7 @@ def _sums_match_pandas_at_windows(t, df, log, windows_per_call):
         got = (groupby_aggregate(t, "k", [("a", "sum")]).to_pandas()
                .sort_values("k").reset_index(drop=True))
         pd.testing.assert_frame_equal(got, exp, check_dtype=False)
-        assert [static[-1] for static, _a, _o in log] == want, log
+        assert [static[-2] for static, _a, _o in log] == want, log
 
 
 @pytest.mark.parametrize("site,world", [("raw", "env1"), ("combine", "env4"),
@@ -291,7 +291,7 @@ def test_site_takes_the_window(site, world, request, rng, monkeypatch):
         memory = both
     assert list(rel_gb._SEG_CACHE.values()) == memory
     # every output against the plain program's, group by group on each shard
-    plain = real(env.mesh, *static[:-1], 0)(*args)
+    plain = real(env.mesh, *static[:-2], 0, static[-1])(*args)
     np.testing.assert_array_equal(np.asarray(plain[-1]), meta[:, 0])
     for a, b in zip(jax.tree.leaves(win_out[:-1]),
                     jax.tree.leaves(plain[:-1])):

@@ -728,6 +728,16 @@ def _layout_cases():
     return cases
 
 
+def _live_sides(idx_s, cap_l, vcl, vcr):
+    """(live left rows, live right rows) of a sorted state, shard by shard,
+    read off ``idx_s`` and the valid counts."""
+    idx = np.asarray(idx_s)
+    shard = np.arange(idx.size) // (idx.size // len(vcl))
+    left = idx < cap_l
+    return (left & (idx < np.asarray(vcl)[shard]),
+            ~left & (idx - cap_l < np.asarray(vcr)[shard]))
+
+
 def _masked(d, v, slot_ok):
     """A result column as compared: zeros where no row or a null is."""
     ok = slot_ok if v is None else slot_ok & np.asarray(v)
@@ -770,11 +780,16 @@ def test_payload_layout(request, rng, monkeypatch, how, key, lanes_, f64,
     assert nl - len(layout.riding) == aliased
     assert layout.n_payloads == max(nl - aliased, nr)
     assert layout.sort_operands == layout.n_keys + 1 + layout.n_payloads
-    assert layout.n_keys == (not full) + (key == "nullable") \
+    # liveness folds into the leading key operand wherever it has room
+    # (ISSUE 50): every kind but the wide pair, which keeps its operand
+    fold = ckw.get("fold", False)
+    assert fold == (not full and key != "wide")
+    assert layout.n_keys == (not full and not fold) + (key == "nullable") \
         + (2 if key == "wide" else 1)
 
     # the state the real count program builds, against the old layout's
-    res = real_count(env.mesh, *cstatic[:7], slim=True)(*cargs)
+    # (which sorts a liveness operand: idx_s and bnd must not move)
+    res = real_count(env.mesh, *cstatic[:7], slim=True, fold=fold)(*cargs)
     n_rows, idx_s, bnd, pl_s = res[0], res[1], res[2], tuple(res[3:])
     assert len(pl_s) == layout.n_arrays
     (mstatic, _mkw, margs, (new_d, new_v)), = mats[-1:]
@@ -789,14 +804,16 @@ def test_payload_layout(request, rng, monkeypatch, how, key, lanes_, f64,
     np.testing.assert_array_equal(np.asarray(bnd), np.asarray(o_bnd))
     le, ri = joink.payload_lanes(layout, pl_s)
     assert (len(le), len(ri)) == (nl, nr)
-    left_row = np.asarray(idx_s) < cap_l
+    # a lane is read at its own side's LIVE rows (an aliased key lane holds
+    # the folded sentinel at padding, where nobody reads it)
+    left_row, right_row = _live_sides(idx_s, cap_l, cargs[0], cargs[1])
     for new, old in zip(le, o_le):
         assert new.dtype == jnp.uint32
         np.testing.assert_array_equal(np.asarray(new)[left_row],
                                       np.asarray(old)[left_row])
     for new, old in zip(ri, o_ri):
-        np.testing.assert_array_equal(np.asarray(new)[~left_row],
-                                      np.asarray(old)[~left_row])
+        np.testing.assert_array_equal(np.asarray(new)[right_row],
+                                      np.asarray(old)[right_row])
     slot = np.arange(env.world_size * out_cap)
     slot_ok = slot % out_cap < np.asarray(n_rows)[slot // out_cap]
     assert len(new_d) == len(old_d) == len(plan)
@@ -884,7 +901,9 @@ def test_payload_layout_packed_piece(env1, rng, monkeypatch, how, key):
         aliased = _ALIASED[key]
         assert layout.nl - len(layout.riding) == aliased
         assert layout.n_payloads == max(layout.nl - aliased, layout.nr)
-        res = real(env1.mesh, *static[:13], True)(*args)
+        fold = static[14]
+        assert fold == (not all_live and key != "wide")
+        res = real(env1.mesh, *static[:13], True, fold)(*args)
         idx_s, bnd, pl_s = res[1], res[2], tuple(res[3:])
 
         def old(vcl, vcr, sl, sr, *arrs):
@@ -907,25 +926,35 @@ def test_payload_layout_packed_piece(env1, rng, monkeypatch, how, key):
         o_bnd, o_idx, o_pl = jax.jit(jax.shard_map(
             old, mesh=env1.mesh, in_specs=(REP,) * 4 + (ROW,) * (n_al + n_ar),
             out_specs=ROW))(*args)
-        np.testing.assert_array_equal(np.asarray(idx_s), np.asarray(o_idx))
-        np.testing.assert_array_equal(np.asarray(bnd), np.asarray(o_bnd))
+        # over the live prefix and the first padding row (a window's
+        # padding is the next piece's rows: with the sentinel they tie and
+        # keep source order, under a liveness operand their keys order
+        # them - nobody reads either)
+        n_live = int(args[0][0] + args[1][0]) + 1
+        np.testing.assert_array_equal(np.asarray(idx_s)[:n_live - 1],
+                                      np.asarray(o_idx)[:n_live - 1])
+        np.testing.assert_array_equal(np.asarray(bnd)[:n_live],
+                                      np.asarray(o_bnd)[:n_live])
         le, ri = joink.payload_lanes(layout, pl_s)
-        left_row = np.asarray(idx_s) < cap_l
+        left_row, right_row = _live_sides(idx_s, cap_l, args[0], args[1])
+        left_row[n_live - 1:] = right_row[n_live - 1:] = False
         assert len(le + ri) == len(o_pl)
         for i, (new, old_lane) in enumerate(zip(le + ri, o_pl)):
-            rows = left_row if i < lspec.n_lanes else ~left_row
+            rows = left_row if i < lspec.n_lanes else right_row
             np.testing.assert_array_equal(np.asarray(new)[rows],
                                           np.asarray(old_lane)[rows])
 
 
 @pytest.mark.parametrize("key,operands,num_keys,was", [
-    ("narrow", 4, 2, 6), ("wide", 5, 3, 8)])
+    ("narrow", 3, 1, 6), ("wide", 5, 3, 8)])
 def test_count_program_holds_one_sort_of_the_layouts_operands(
         env1, rng, monkeypatch, key, operands, num_keys, was):
     """The benchmark cells' schema (left k, a; right k, b; int64; tables
     not at capacity; inner join on k, deferred): ``join__count_fn`` holds
-    ONE stable sort of 4 operands (6 until PR 34), and with a key that
-    does not narrow 5 (was 8)."""
+    ONE stable sort of 3 operands - key, ``idx``, ``a`` over ``b``: padding
+    sorts last inside the key operand (ISSUE 50; 4 until then, 6 until
+    PR 34) -, and with a key that does not narrow, whose pair has no room
+    for it, 5 (was 8)."""
     import jax
     from cylon_tpu.analysis import registry
     from cylon_tpu.analysis.jaxpr_check import iter_eqns
@@ -941,9 +970,12 @@ def test_count_program_holds_one_sort_of_the_layouts_operands(
     monkeypatch.undo()
     (static, kw, args, _res), = counts
     layout = static[4]
-    assert kw == {"slim": True} and layout.sort_operands == operands
+    fold = key == "narrow"
+    assert kw == {"slim": True, "fold": fold}
+    assert layout.sort_operands == operands
     # the old layout's count: every lane of either side, and the key again
-    assert layout.n_keys + 1 + layout.nl + layout.nr == was
+    # (and the liveness operand where it has since been folded)
+    assert layout.n_keys + fold + 1 + layout.nl + layout.nr == was
     traced = jax.make_jaxpr(registry.unwrap(real(env1.mesh, *static, **kw)))(
         *args)
     sorts = [e for e, _ in iter_eqns(traced) if e.primitive.name == "sort"]

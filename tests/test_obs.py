@@ -966,15 +966,18 @@ def test_join_sort_counters_and_plan_fields(env1, how, fused):
     (ISSUE 35): ``join_sort_operands`` / ``join_sort_dispatches`` take one
     count program's static numbers a join, on the host; the ``join`` node
     shows ``sort_operands``, ``payload_operands``, ``aliased_key_lanes``.
-    ``_toy``'s schema is the benchmark cells': liveness + narrow key + idx
-    + one operand shared by ``a`` and ``b``, the key's lane aliased."""
+    ``_toy``'s schema is the benchmark cells': narrow key (padding's
+    sentinel inside it since ISSUE 50: no liveness operand) + idx + one
+    operand shared by ``a`` and ``b``, the key's lane aliased; the registry
+    counts the sort's liveness form (``key_sort_liveness``)."""
     from cylon_tpu.relational import groupby_aggregate, join_tables
     assert {"join_sort_operands", "join_sort_dispatches"} \
         <= set(metrics.snapshot())           # registered at import
     left, right = _toy(env1, n=4000)         # under capacity, as the cells
     ops, joins = (obs.counter(c) for c in (
         "join_sort_operands", "join_sort_dispatches"))
-    before = ops.value, joins.value
+    folded = obs.counter("key_sort_liveness", form="folded", site="join")
+    before = ops.value, joins.value, folded.value
 
     def query():
         j = join_tables(left, right, "k", "k", how=how)
@@ -983,11 +986,12 @@ def test_join_sort_counters_and_plan_fields(env1, how, fused):
         return j.to_pandas()
 
     plan = obs.explain_analyze(query)
-    assert (ops.value - before[0], joins.value - before[1]) == (4, 1)
+    assert (ops.value - before[0], joins.value - before[1],
+            folded.value - before[2]) == (3, 1, 1)
     node, = [n for n in plan.to_dict()["roots"] if n["op"] == "join"]
     assert {k: node["attrs"][k] for k in (
         "sort_operands", "payload_operands", "aliased_key_lanes")} \
-        == {"sort_operands": 4, "payload_operands": 1,
+        == {"sort_operands": 3, "payload_operands": 1,
             "aliased_key_lanes": 1}
 
 
@@ -998,8 +1002,9 @@ def test_benchmark_reads_join_sort_operands_per_join(tmp_path, monkeypatch,
     the join cell's 65,536-row twin (the benchmark's test helpers; the CPU
     has no device plane, so the trace reduction is stood in for as in
     ``benchmark/tests/test_zipf.py``).  The twin's tables are AT capacity
-    (65,536 rows), so its sort has no liveness operand: 3.0, where the
-    cells' 32,000,000 rows padded to 32,505,856 read 4.0."""
+    (65,536 rows), so its sort has no padding to place: 3.0, as the
+    cells' 32,000,000 rows padded to 32,505,856 read since ISSUE 50 (the
+    sentinel rides in the key; 4.0 until then)."""
     import importlib.util
     bench_tests = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark", "tests")

@@ -78,15 +78,18 @@ def test_sort_table_rides_within_the_budget(env1, rng, monkeypatch, n_wide,
     (operands,) = _sort_operands(program, args)
     assert operands <= pack.SORT_OPERAND_BUDGET
     lanes = 1 + 2 * max(n_wide, n_keys)
-    keys = 1 + (2 * n_keys if n_wide else 1)
+    # a wide pair leads with a liveness operand; the narrow key carries
+    # padding inside itself (ISSUE 50) - and still counts against the
+    # budget as the operand it was, so ``rides`` is what it was
+    keys = 2 * n_keys + 1 if n_wide else 1
     # riding: keys + every lane; past the budget: keys + the row index
     assert operands == (keys + lanes if rides else keys + 1)
 
 
 @pytest.mark.parametrize("envname", ["env1", "env4"])
 @pytest.mark.parametrize("n_keys,n_vals,rides", [
-    (1, 1, True),       # liveness + key, value + key lanes: 4 (accepted)
-    (2, 1, True),       # 3 + 3
+    (1, 1, True),       # key, value + key lanes: 3 (4 until ISSUE 50)
+    (2, 1, True),       # 2 + 3
     (3, 1, False),      # Q3's GROUP BY: a wide key among three
     (2, 4, False),
 ])
@@ -118,7 +121,9 @@ def test_groupby_sort_rides_within_the_budget(request, rng, monkeypatch,
         drop=True), check_dtype=False)
     widest = max(max(_sort_operands(p, a), default=0) for p, a in log)
     assert 0 < widest <= pack.SORT_OPERAND_BUDGET
-    key_ops = 1 + n_keys + (n_keys == 3)
+    # no liveness operand: it rides in the narrow first key (ISSUE 50); the
+    # ``wide`` decision counts it as the operand it was
+    key_ops = n_keys + (n_keys == 3)
     lanes = n_vals + n_keys + (n_keys == 3)
     if env.world_size == 1:
         assert widest == (key_ops + lanes if rides else key_ops + 1)

@@ -211,10 +211,10 @@ def test_payload_layout_helpers_are_staged(env1, launched):
     n = left.capacity + right.capacity
     sort, = [e for e, _ in _staged_eqns(traced["join__count_fn"])
              if e.primitive.name == "sort"]
-    assert len(sort.invars) == 4
+    assert len(sort.invars) == 3        # key, idx, a over b (ISSUE 50)
     shared = [st for e, st in _staged_eqns(traced["join__count_fn"])
               if e.primitive.name == "concatenate"
-              and e.outvars[0] is sort.invars[3]]
+              and e.outvars[0] is sort.invars[2]]
     assert len(shared) == 1 and shared[0][-1] == "pack"
     casts = [st for e, st in _staged_eqns(traced["fused__fused_fn"])
              if e.primitive.name == "bitcast_convert_type"
