@@ -47,6 +47,21 @@ def copy_with_tiny_cells(tmp_path) -> str:
     return dst
 
 
+def twin_metrics_of(bench_dir: str, cell: str) -> None:
+    """A cell's operator metrics list it by name, so its tiny twin gets
+    twins of them, as a file-only PR would write them: every metric file of
+    ``bench_dir`` whose ``workloads`` names ``cell`` again as
+    ``tiny_<name>``, listing ``tiny_<cell>`` alone.  The list-less metrics
+    reach the twin with no file."""
+    mdir = os.path.join(bench_dir, "metrics")
+    for name in sorted(os.listdir(mdir)):
+        with open(os.path.join(mdir, name), encoding="utf-8") as f:
+            m = json.load(f)
+        if cell in m.get("workloads", ()):
+            m.update(name="tiny_" + m["name"], workloads=["tiny_" + cell])
+            _write(os.path.join(mdir, "tiny_" + name), m)
+
+
 def load_run(bench_dir: str):
     """``run.py`` of ``bench_dir`` as a fresh module (its ``lib`` too)."""
     for name in [m for m in sys.modules if m == "lib" or m.startswith("lib.")

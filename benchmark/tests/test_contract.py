@@ -136,9 +136,27 @@ def test_per_layer_matches_the_metric_files(bench):
         assert {k: f[k] for k in m} == m
         assert os.path.isfile(os.path.join(BENCH_DIR, "readers",
                                            f["reader"] + ".py"))
-    # every cell reports at least one per-layer metric
+    # every cell reports at least one metric of its own operators (one with
+    # a list: README.md, the two classes), not only what every cell gets
+    # for nothing
     for c in cells:
-        assert any(c in m.get("workloads", cells) for m in per)
+        assert any(c in m.get("workloads", ()) for m in per), c
+
+
+def test_one_reading_has_one_name_in_a_cell(bench):
+    """Two metric files with the same ``(reader, args)`` never list the same
+    cell (a file with no list lists all): a later file-only PR can still
+    give its cell a twin of an operator metric, but no cell prints one
+    reading under two names."""
+    cells = [w["name"] for w in bench["workloads"]]
+    seen = {}
+    for m in bench["per_layer"]:
+        f = _load("metrics", m["name"])
+        reading = (f["reader"], json.dumps(f.get("args", {}), sort_keys=True))
+        for cell in m.get("workloads", cells):
+            assert (reading, cell) not in seen, \
+                (m["name"], seen[reading, cell], cell)
+            seen[reading, cell] = m["name"]
 
 
 def test_files_under_paths_are_named_from_name_characters():

@@ -26,15 +26,7 @@ from test_zipf_fixed_hot import _DRIVER  # noqa: E402
 
 def _twin(tmp_path, seed: int, trace: int):
     bench_dir = helpers.copy_with_tiny_cells(tmp_path)
-    # the cell's metric files list the cell by name: the twin gets twins
-    mdir = os.path.join(bench_dir, "metrics")
-    for name in [f for f in os.listdir(mdir) if f.startswith("gsx4_")]:
-        with open(os.path.join(mdir, name)) as f:
-            m = json.load(f)
-        assert m["workloads"] == [CELL]
-        m.update(name="tiny_" + m["name"], workloads=["tiny_" + CELL])
-        with open(os.path.join(mdir, "tiny_" + name), "w") as f:
-            json.dump(m, f)
+    helpers.twin_metrics_of(bench_dir, CELL)
     proc = subprocess.run(
         [sys.executable, "-c", _DRIVER, bench_dir,
          os.path.dirname(os.path.abspath(__file__)),
@@ -143,9 +135,11 @@ def test_tiny_twin_traced_reports_what_a_host_plane_can_give(tmp_path):
     m = {k[5:]: v["value"] for k, v in line["metrics"].items()
          if k.startswith("tiny_")}
     # the readers of whole spans need the device plane's reduction: the chip's
-    assert {"gsx4_exchange_mb_per_query", "gsx4_sort_recv_max_mrows_per_query",
-            "gsx4_sort_recv_cap_mrows_per_query",
-            "gsx4_sum_scans_32bit_share"} == set(m), sorted(m)
+    assert {"exchange_mb_per_query", "gsx4_sort_recv_max_mrows_per_query",
+            "gsx4_sort_recv_cap_mrows_per_query", "sum_scans_32bit_share",
+            "exchange_ride_share", "key_sort_folded_share",
+            # the query module's own spans, on the host's clock
+            "groupby_call_ms", "sort_call_ms"} == set(m), sorted(m)
     # ... but the spans they read are in this run's trace, with the
     # arguments that tell the two exchanges apart
     from lib import xspace
@@ -167,7 +161,7 @@ def test_tiny_twin_traced_reports_what_a_host_plane_can_give(tmp_path):
     assert all(int(a["recv_max"]) <= int(a["recv_cap"]) for a in of_sort)
     # phase 1 sums bounded values (32-bit scan), phase 2 partial sums whose
     # bounds nobody knows (pair64): half and half
-    assert m["gsx4_sum_scans_32bit_share"] == 0.5
+    assert m["sum_scans_32bit_share"] == 0.5
     said = [ln for ln in err.splitlines() if "sorted result a chip: " in ln]
     per_chip = json.loads(said[-1].split("a chip: ")[1].split(" rows")[0])
     assert m["gsx4_sort_recv_max_mrows_per_query"] == pytest.approx(

@@ -64,12 +64,19 @@ def test_configuration_is_the_issue_s():
                             "warmups": 2, "traced_queries": 3}
     assert cell["expect"]["routes"] == [["unique", "local"],
                                         ["set_op", "local"]]
-    # every per-layer metric of the new layer lists this cell alone
+    # every per-layer metric of the layer lists this cell alone (PR 51: the
+    # readings other cells have too live under their shared names, in
+    # their own layers)
     mine = [m for m in files.metric_files(BENCH_DIR)
             if m["layer"] == "set ops"]
-    assert len(mine) == 13
+    assert len(mine) == 8
     assert all(m["workloads"] == [CELL] and m["name"].startswith("setops_")
                and m["moves"] == "rows_per_s" for m in mine)
+    shared = {m["name"] for m in files.metric_files(BENCH_DIR)
+              if CELL in m.get("workloads", [CELL])
+              and not m["name"].startswith("setops_")}
+    assert {"host_pull_wait_ms", "programs_per_query",
+            "unscoped_device_share", "gather_rows_device_ms"} <= shared
 
 
 def _frame(cols: dict, result: str) -> pd.DataFrame:
@@ -126,13 +133,7 @@ def test_control_by_key_alone_is_caught_and_the_reference_is_not(qm):
 
 def _twin(tmp_path, seed: int, trace: int):
     bench_dir = helpers.copy_with_tiny_cells(tmp_path)
-    mdir = os.path.join(bench_dir, "metrics")
-    for name in [f for f in os.listdir(mdir) if f.startswith("setops_")]:
-        with open(os.path.join(mdir, name)) as f:
-            m = json.load(f)
-        m.update(name="tiny_" + m["name"], workloads=["tiny_" + CELL])
-        with open(os.path.join(mdir, "tiny_" + name), "w") as f:
-            json.dump(m, f)
+    helpers.twin_metrics_of(bench_dir, CELL)
     proc = subprocess.run(
         [sys.executable, "-c", _DRIVER, bench_dir,
          os.path.dirname(os.path.abspath(__file__)),
@@ -168,7 +169,7 @@ def test_tiny_twin(tmp_path):
 
 def test_tiny_twin_traced_reports_what_a_host_plane_can_give(tmp_path):
     """``--trace 1``: the spans' means and ``rows_out`` read off the
-    operator spans equal the result's own rows (the other ten metrics read
+    operator spans equal the result's own rows (the cell's other metrics read
     the device plane: the chip's)."""
     line, _err, _ = _twin(tmp_path, 7, trace=1)
     assert line["correct"] is True, line["compared"]
@@ -176,7 +177,8 @@ def test_tiny_twin_traced_reports_what_a_host_plane_can_give(tmp_path):
          if k.startswith("tiny_")}
     assert set(m) == {"setops_unique_ms", "setops_union_ms",
                       "setops_subtract_ms",
-                      "setops_rows_out_mrows_per_query"}, sorted(m)
+                      "setops_rows_out_mrows_per_query",
+                      "key_sort_folded_share"}, sorted(m)
     assert min(m.values()) > 0
 
 

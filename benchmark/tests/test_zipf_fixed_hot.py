@@ -164,15 +164,7 @@ sys.exit(run.main(["--workload", sys.argv[4], "--seed", sys.argv[5],
 
 def _twin(tmp_path, seed: int, trace: int):
     bench_dir = helpers.copy_with_tiny_cells(tmp_path)
-    # the cell's metric files list the cell by name: the twin gets twins
-    mdir = os.path.join(bench_dir, "metrics")
-    for name in [f for f in os.listdir(mdir) if f.startswith("dzipf_")]:
-        with open(os.path.join(mdir, name)) as f:
-            m = json.load(f)
-        assert m["workloads"] == [CELL]
-        m.update(name="tiny_" + m["name"], workloads=["tiny_" + CELL])
-        with open(os.path.join(mdir, "tiny_" + name), "w") as f:
-            json.dump(m, f)
+    helpers.twin_metrics_of(bench_dir, CELL)
     proc = subprocess.run(
         [sys.executable, "-c", _DRIVER, bench_dir,
          os.path.dirname(os.path.abspath(__file__)),
@@ -186,8 +178,9 @@ def _twin(tmp_path, seed: int, trace: int):
 def test_tiny_twin_on_four_cpu_devices_traced(tmp_path):
     """65,536 rows a side over four devices, ``--trace 1``: correct, the
     routes of the workload file with no environment variable set, two
-    exchanges a query, and the four new metrics - ``recv_max`` equal to
-    numpy's own per-destination count under the engine's hash."""
+    exchanges a query, and the cell's metrics that need no device plane -
+    ``recv_max`` equal to numpy's own per-destination count under the
+    engine's hash."""
     seed = 2**31 + 34
     line, err, bench_dir = _twin(tmp_path, seed, trace=1)
     assert line["correct"] is True, line["compared"]
@@ -199,8 +192,13 @@ def test_tiny_twin_on_four_cpu_devices_traced(tmp_path):
          if k.startswith("tiny_")}
     assert {"dzipf_recv_max_mrows_per_query", "dzipf_recv_cap_mrows_per_query",
             "dzipf_exchange_block_mrows_per_query",
-            "dzipf_split_keys_per_join",
-            "dzipf_exchange_mb_per_query"} == set(m)   # the rest: the chip's
+            "dzipf_split_keys_per_join", "exchange_mb_per_query",
+            # the registry's counters, which every operator metric of the
+            # cell that reads one finds on the CPU too
+            "exchange_ride_share", "join_sort_operands_per_join",
+            "sum_scans_32bit_share", "key_sort_folded_share",
+            # the query module's own spans, on the host's clock
+            "join_call_ms", "groupby_call_ms"} == set(m)   # the rest: the chip's
     assert m["dzipf_split_keys_per_join"] == 0.0
 
     # the reference's own count: the same tables, the engine's hash
